@@ -314,9 +314,6 @@ def _add_serve(sub):
   p.add_argument('--dead_letter', default=None,
                  help='Append quarantined-request records (with '
                  'request attribution) to this JSONL sidecar.')
-  p.add_argument('--compilation_cache_dir', default=None,
-                 help='Persistent JAX compilation cache: restarts skip '
-                 'the jit compile, so /readyz flips in seconds.')
   p.add_argument('--random_init', action='store_true',
                  help='Serve randomly initialized weights from '
                  '--config instead of a checkpoint (tests/demos).')
@@ -421,9 +418,8 @@ def _add_autoscale(sub):
   p.add_argument('--serve_arg', action='append', default=[],
                  metavar='ARG',
                  help='Extra argv token for spawned `dctpu serve` '
-                 'replicas; repeatable (e.g. --serve_arg=--random_init '
-                 '--serve_arg=--compilation_cache_dir=/ramdisk/cc). '
-                 'Spawns always get --host 127.0.0.1 --port 0.')
+                 'replicas; repeatable (e.g. --serve_arg=--random_init). '
+                 'Replicas inherit JAX_COMPILATION_CACHE_DIR. Spawns always get --host 127.0.0.1 --port 0.')
   p.add_argument('--leave_managed', action='store_true',
                  help='On exit, leave spawned replicas serving instead '
                  'of draining them (an autoscaler restart then adopts '
@@ -820,6 +816,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     return 130
 
 
+_JIT_COMMANDS = frozenset({
+    'run', 'serve', 'train', 'evaluate', 'export', 'distill', 'flywheel',
+})
+
+
 def _dispatch(args) -> int:
   if getattr(args, 'trace', None):
     # --trace is sugar for DCTPU_TRACE: the env var is what each tier's
@@ -827,6 +828,13 @@ def _dispatch(args) -> int:
     import os
 
     os.environ['DCTPU_TRACE'] = args.trace
+
+  if args.command in _JIT_COMMANDS:
+    # One persistent compile cache for every command that jits:
+    # $JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache.
+    from deepconsensus_tpu.utils import compile_cache
+
+    compile_cache.enable()
 
   if args.command == 'trace':
     import json
@@ -931,13 +939,6 @@ def _dispatch(args) -> int:
     from deepconsensus_tpu.serve import server as server_lib
     from deepconsensus_tpu.serve.service import ServeOptions
 
-    if args.compilation_cache_dir:
-      import jax
-
-      jax.config.update(
-          'jax_compilation_cache_dir', args.compilation_cache_dir)
-      jax.config.update(
-          'jax_persistent_cache_min_compile_time_secs', 0.0)
     dc_cal = args.dc_calibration
     if dc_cal is None and args.checkpoint:
       params_json = config_lib.read_params_from_json(args.checkpoint)

@@ -167,7 +167,7 @@ def run_distillation(
         )
     )
 
-  align_loss = train_lib.make_loss(params)
+  align_loss = trainer.loss_fn
   student_alpha = float(params.student_alpha)
   distill_alpha = float(params.distill_alpha)
   temperature = float(params.temperature)

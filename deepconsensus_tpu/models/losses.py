@@ -66,6 +66,8 @@ class AlignmentLoss:
       eps: float = 1e-7,
       inf: float = 1e9,
       use_pallas: bool = False,
+      mesh=None,
+      batch_axis: str = 'data',
   ):
     self.del_cost = del_cost
     self.loss_reg = loss_reg
@@ -75,6 +77,22 @@ class AlignmentLoss:
     # Whole-DP Pallas kernels (ops/wavefront_pallas): forward scorer +
     # custom-VJP backward, so training differentiates through Pallas.
     self.use_pallas = use_pallas
+    # The mesh the surrounding step is partitioned over, if any. XLA
+    # cannot partition a Mosaic kernel by itself ("wrap the call in a
+    # shard_map"), and the DP is independent per example, so on a
+    # multi-device mesh the Pallas scorers run under a shard_map over
+    # the batch axis (replicated over every other axis).
+    self.mesh = mesh
+    self.batch_axis = batch_axis
+
+  def _pallas_scores(self, scorer, subs_costs, ins_costs, seq_lens, *static):
+    call = lambda subs, ins, lens: scorer(subs, ins, lens, *static)
+    if self.mesh is not None and self.mesh.size > 1:
+      spec = jax.sharding.PartitionSpec(self.batch_axis)
+      call = jax.shard_map(
+          call, mesh=self.mesh, in_specs=(spec, spec, spec), out_specs=spec,
+          check_vma=False)
+    return call(subs_costs, ins_costs, seq_lens)
 
   def per_example(self, y_true: Array, y_pred: Array) -> Array:
     """[B] loss values for y_true [B, m] ints and y_pred [B, n, V]."""
@@ -98,7 +116,8 @@ class AlignmentLoss:
       if self.use_pallas:
         from deepconsensus_tpu.ops import wavefront_pallas
 
-        return wavefront_pallas.alignment_scores_vjp(
+        return self._pallas_scores(
+            wavefront_pallas.alignment_scores_vjp,
             subs_costs, ins_costs, seq_lens, self.del_cost,
             self.loss_reg, self.inf,
         )
@@ -108,7 +127,8 @@ class AlignmentLoss:
     if self.use_pallas:
       from deepconsensus_tpu.ops import wavefront_pallas
 
-      return wavefront_pallas.banded_alignment_scores_vjp(
+      return self._pallas_scores(
+          wavefront_pallas.banded_alignment_scores_vjp,
           subs_costs, ins_costs, seq_lens, self.del_cost,
           self.loss_reg, int(self.width), self.inf,
       )

@@ -38,31 +38,12 @@ from deepconsensus_tpu.models import data as data_lib
 from deepconsensus_tpu.models import losses as losses_lib
 from deepconsensus_tpu.models import metrics as metrics_lib
 from deepconsensus_tpu.models import model as model_lib
+from deepconsensus_tpu.ops import pallas_util
 from deepconsensus_tpu.parallel import mesh as mesh_lib
 from deepconsensus_tpu.parallel import partition_rules
 from deepconsensus_tpu.parallel import ring_attention as ring_lib
 from deepconsensus_tpu.preprocess.pileup import row_indices
-
-
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> None:
-  """Persistent XLA compilation cache: the differentiated wavefront
-  scans compile slowly on TPU, so amortize across processes.
-
-  Directory resolution: explicit arg > DC_TPU_COMPILE_CACHE env var >
-  per-user default. Set DC_TPU_COMPILE_CACHE=off to disable.
-  """
-  cache_dir = cache_dir or os.environ.get('DC_TPU_COMPILE_CACHE')
-  if cache_dir == 'off':
-    return
-  if cache_dir is None:
-    cache_dir = os.path.join(
-        os.path.expanduser('~'), '.cache', 'dctpu_jax_cache'
-    )
-  try:
-    jax.config.update('jax_compilation_cache_dir', cache_dir)
-    jax.config.update('jax_persistent_cache_min_compile_time_secs', 10)
-  except AttributeError:  # pragma: no cover - older jax
-    pass
+from deepconsensus_tpu.utils import compile_cache
 
 
 class TrainState(ts_lib.TrainState):
@@ -136,13 +117,18 @@ def resolve_pallas_wavefront(params: ml_collections.ConfigDict) -> bool:
   return bool(flag)
 
 
-def make_loss(params: ml_collections.ConfigDict) -> losses_lib.AlignmentLoss:
+def make_loss(params: ml_collections.ConfigDict,
+              mesh=None) -> losses_lib.AlignmentLoss:
+  """mesh: the mesh the calling step is partitioned over (None for a
+  single-device step); the Pallas scorers need it to run per shard."""
   width = params.get('band_width', None)
   return losses_lib.AlignmentLoss(
       del_cost=params.del_cost,
       loss_reg=params.loss_reg,
       width=width,
       use_pallas=resolve_pallas_wavefront(params),
+      mesh=mesh,
+      batch_axis=mesh_lib.DATA_AXIS,
   )
 
 
@@ -196,12 +182,12 @@ class Trainer:
     # legitimately re-traces.
     self.n_train_forward_shapes = 0
     os.makedirs(self.out_dir, exist_ok=True)
-    enable_compilation_cache()
+    compile_cache.enable()
     self.model = model_lib.get_model(self.params)
-    self.loss_fn = make_loss(self.params)
     self.alignment_metric = metrics_lib.AlignmentMetric()
     if self.mesh is None:
       self.mesh = mesh_lib.make_mesh()
+    self.loss_fn = make_loss(self.params, mesh=self.mesh)
     self._ckpt_dir = os.path.join(os.path.abspath(self.out_dir), 'checkpoints')
     self._checkpointer = ocp.StandardCheckpointer()
     self._metrics_tsv = os.path.join(self.out_dir, 'checkpoint_metrics.tsv')
@@ -1425,6 +1411,7 @@ def run_training(
     trainer.mesh = mesh_lib.make_mesh(dp=new_dp, tp=tp,
                                       devices=list(devices))
     trainer._cached_eval_step = None  # eval recompiles on the new mesh
+    trainer.loss_fn = make_loss(trainer.params, mesh=trainer.mesh)
     if contaminated:
       latest = trainer.latest_valid_checkpoint()
       if latest is None:
@@ -1803,6 +1790,14 @@ def run_training(
       fault_counters['train_step_p99_s'] = step_times['p99']
     if fault_counters:
       trainer.log_metrics(step, 'faults', fault_counters)
+    # The device this run really used, the resolved loss path and how
+    # its Pallas calls resolved (compiled vs interpreter).
+    trainer.log_metrics(step, 'device', {
+        **pallas_util.execution_report(),
+        'use_pallas_wavefront': int(resolve_pallas_wavefront(params)),
+        'n_model_axis_sharded_params': mesh_lib.count_model_sharded(
+            jax.tree.map(lambda a: a.sharding, state.params)),
+    })
     if profile_dir:
       jax.profiler.stop_trace()
   if jax.process_count() > 1:

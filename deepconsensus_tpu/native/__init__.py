@@ -26,15 +26,19 @@ _build_failed = False
 
 
 def _build() -> bool:
+  # Link into a private name and rename into place: another process
+  # (an xdist worker, a pool child) never loads a half-written library.
+  tmp = f'{_LIB}.{os.getpid()}.tmp'
   cmd = [
       'g++', '-O3', '-shared', '-fPIC', '-std=c++17', _SRC,
-      '-o', _LIB, '-lz', '-lpthread',
+      '-o', tmp, '-lz', '-lpthread',
   ]
   try:
     subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    os.replace(tmp, _LIB)
     return True
   except (subprocess.CalledProcessError, FileNotFoundError,
-          subprocess.TimeoutExpired) as e:
+          subprocess.TimeoutExpired, OSError) as e:
     log.warning('native build failed (%s); using pure-Python fallback', e)
     return False
 

@@ -33,10 +33,6 @@ from deepconsensus_tpu.ops import pallas_util
 
 Array = jnp.ndarray
 
-# jax >= 0.8 renamed TPUCompilerParams -> CompilerParams; accept either
-# so the kernel builds across the versions this repo sees.
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-
 _NEG = -1e9
 
 # Above this window length the whole-L kernel (banded_attention.py)
@@ -191,7 +187,7 @@ def _forward(q, k, v, attn_win_size, interpret, emit_lse):
           pltpu.VMEM((plan.group, plan.block_q), jnp.float32),
           pltpu.VMEM((plan.group, plan.block_q, d), jnp.float32),
       ],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=('parallel', 'parallel', 'arbitrary'),
       ),
       interpret=pallas_util.resolve_interpret(interpret),
@@ -379,7 +375,7 @@ def _vjp_bwd(attn_win_size, interpret, res, do):
       out_shape=jax.ShapeDtypeStruct((plan.n, lq, d), q.dtype),
       scratch_shapes=[pltpu.VMEM((plan.group, plan.block_q, d),
                                  jnp.float32)],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=('parallel', 'parallel', 'arbitrary'),
       ),
       interpret=interp,
@@ -429,7 +425,7 @@ def _vjp_bwd(attn_win_size, interpret, res, do):
           pltpu.VMEM((plan.group, plan.block_k, d), jnp.float32),
           pltpu.VMEM((plan.group, plan.block_k, d), jnp.float32),
       ],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=('parallel', 'parallel', 'arbitrary'),
       ),
       interpret=interp,

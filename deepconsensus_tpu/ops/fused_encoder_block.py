@@ -11,9 +11,12 @@ program per tile of windows, with the same batch-major tiling
 
 One pallas_call per encoder block, not one for the whole stack: five
 layers of f32 weights (~29 MB at the distilled student's 280/2048
-shape) would blow the ~16 MB VMEM budget, while a single block's
-weights plus the [tile*L, filter] relu intermediate stay near 14 MB at
-tile=8.
+shape) would not fit next to the activations. A single block at
+tile=8 needs ~21 MiB of scoped VMEM in f32 (double-buffered
+[280, 2048] + [2048, 280] weights plus the [tile*L, filter] relu
+intermediate) and ~18 MiB in bf16 — over the compiler's 16 MiB default
+scope, so the call raises the limit explicitly
+(pallas_util.BATCH_TILE_VMEM_LIMIT_BYTES; a v5e core has 128 MiB).
 
 Quantization support (params.quantize_matmuls=int8): each matmul
 weight arrives as a `QuantizedWeight` — either a plain f32/bf16 kernel
@@ -43,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepconsensus_tpu.ops import fused_window_attention as fwa
+from deepconsensus_tpu.ops import pallas_util
 
 Array = jnp.ndarray
 
@@ -112,12 +116,14 @@ def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
   x2 = x.reshape(tile * length, hidden)
 
   def proj(w):
-    return _dequant_matmul(x2, w[0], w[1]).reshape(
-        tile, length, num_heads, head_dim)
+    return _dequant_matmul(x2, w[0], w[1]).reshape(tile, length, hidden)
 
   q = proj(wq) * qscale
   k = proj(wk)
   v = proj(wv)
+  # Heads are lane slices of the [tile, L, H] projections: Mosaic has
+  # no shape cast that splits the lane dimension into (heads, depth).
+  head = lambda t, h: t[:, :, h * head_dim:(h + 1) * head_dim]
   band = mask
   if band is None and attn_win_size is not None:
     rows = jax.lax.broadcasted_iota(jnp.int32, (tile, length, length), 1)
@@ -126,7 +132,7 @@ def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
   outs = []
   for h in range(num_heads):
     s = jax.lax.dot_general(
-        q[:, :, h, :], k[:, :, h, :], (((2,), (2,)), ((0,), (0,))),
+        head(q, h), head(k, h), (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )  # [tile, L, L]
     if band is not None:
@@ -136,7 +142,7 @@ def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
     p = jnp.exp(sd - m)
     w = (p / jnp.sum(p, axis=2, keepdims=True)).astype(jnp.float32)
     outs.append(jax.lax.dot_general(
-        w, v[:, :, h, :], (((2,), (1,)), ((0,), (0,))),
+        w, head(v, h), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ))
   o = jnp.concatenate(outs, axis=-1).reshape(tile * length, hidden)
@@ -274,6 +280,7 @@ def _block_call(xp: Array, block: EncoderBlockWeights, *, num_heads,
       out_specs=pl.BlockSpec((tile, length, hidden), lambda i: (i, 0, 0),
                              memory_space=pltpu.VMEM),
       out_shape=jax.ShapeDtypeStruct((bp, length, hidden), compute_dtype),
+      compiler_params=pallas_util.batch_tile_compiler_params(),
       interpret=interpret,
   )(*inputs)
 
@@ -324,8 +331,6 @@ def fused_encoder_stack(
   ragged mask (band AND same-window AND valid) instead of the static
   band alone. FFN/residual halves are position-wise and unaffected.
   """
-  from deepconsensus_tpu.ops import pallas_util
-
   b, length, hidden = x.shape
   if hidden % num_heads:
     raise ValueError('hidden size must divide num_heads')

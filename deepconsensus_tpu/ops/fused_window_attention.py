@@ -1,8 +1,7 @@
 """Pallas TPU kernel: batch-major fused embed->condense->attention.
 
 The L=100 production hot path. The per-(batch, head) kernels in
-ops/banded_attention.py measured 0.82x the XLA path *inside the model*
-at the production window length (MEASURED_FLASH_r2.json): with L=100
+ops/banded_attention.py hand each grid program one window: with L=100
 every per-window matmul is smaller than one 128x128 MXU tile, so a
 grid that hands each program one window (or one batch*head pair)
 starves the systolic array no matter how well it tiles. The short-
@@ -161,11 +160,14 @@ def _row_chunk(tile: int, length: int, spec: FamilySpec) -> int:
 
 
 def _embed_condense(ids, table_vals, w_cond, specs, tile, length, hidden):
-  """One-hot embed + condense for a tile: x[b, l, :] accumulated per
-  row-chunk as a two-axis contraction, so neither the one-hot nor the
-  pre-condense concat ever leaves VMEM. Shared between the kernel and
-  the jnp reference (plain jnp ops only)."""
-  x = jnp.zeros((tile, length, hidden), jnp.float32)
+  """One-hot embed + condense for a tile, accumulated per row-chunk so
+  neither the one-hot nor the 560-wide pre-condense concat ever leaves
+  VMEM. Every matmul contracts exactly one dimension of 2-D operands
+  (Mosaic implements nothing else): the chunk's rows embed in one
+  [tile*c*L, vocab] x [vocab, width] product, then each row condenses
+  through its own [width, hidden] slice of the condenser. Shared
+  between the kernel and the jnp reference (plain jnp ops only)."""
+  x = jnp.zeros((tile * length, hidden), jnp.float32)
   for spec in specs:
     table = table_vals[spec.table_idx].astype(jnp.float32)
     chunk = _row_chunk(tile, length, spec)
@@ -185,15 +187,32 @@ def _embed_condense(ids, table_vals, w_cond, specs, tile, length, hidden):
           preferred_element_type=jnp.float32,
       ).reshape(tile, c, length, spec.width)
       w0 = spec.cond_offset + c0 * spec.width
-      w_slice = w_cond[w0:w0 + c * spec.width, :].reshape(
-          c, spec.width, hidden)
-      # Contract (row, width) against the condenser rows owned by this
-      # chunk: the 560-wide concat never materializes.
-      x = x + jax.lax.dot_general(
-          emb, w_slice, (((1, 3), (0, 1)), ((), ())),
-          preferred_element_type=jnp.float32,
-      )
-  return x
+      for j in range(c):
+        # Row j's width-wide embedding against the condenser rows it
+        # owns: the concat never materializes.
+        x = x + jax.lax.dot_general(
+            emb[:, j].reshape(tile * length, spec.width),
+            w_cond[w0 + j * spec.width:w0 + (j + 1) * spec.width, :],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+  return x.reshape(tile, length, hidden)
+
+
+def embed_condense_tile(ids_ref, table_vals, w_cond, specs, x_ref, length,
+                        hidden):
+  """Kernel side of _embed_condense: fills the [tile, L, H] f32 scratch
+  x_ref one window per loop trip. Embedding is per position, so nothing
+  is lost by not batching it across the tile, while unrolling it over
+  the whole tile made Mosaic's compile time grow ~7x per doubling of
+  the tile (23 minutes at the default 8)."""
+
+  def one_window(t, carry):
+    x_ref[pl.ds(t, 1)] = _embed_condense(
+        ids_ref[pl.ds(t, 1)], table_vals, w_cond, specs, 1, length, hidden)
+    return carry
+
+  jax.lax.fori_loop(0, ids_ref.shape[0], one_window, 0)
 
 
 def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
@@ -210,11 +229,14 @@ def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
     return jax.lax.dot_general(
         x2, w.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).reshape(tile, length, num_heads, head_dim)
+    ).reshape(tile, length, hidden)
 
   q = proj(wq) * qscale
   k = proj(wk)
   v = proj(wv)
+  # Heads are lane slices of the [tile, L, H] projections: Mosaic has
+  # no shape cast that splits the lane dimension into (heads, depth).
+  head = lambda t, h: t[:, :, h * head_dim:(h + 1) * head_dim]
   if attn_win_size is not None:
     rows = jax.lax.broadcasted_iota(jnp.int32, (tile, length, length), 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (tile, length, length), 2)
@@ -222,7 +244,7 @@ def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
   outs = []
   for h in range(num_heads):
     s = jax.lax.dot_general(
-        q[:, :, h, :], k[:, :, h, :], (((2,), (2,)), ((0,), (0,))),
+        head(q, h), head(k, h), (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )  # [tile, L, L]
     if attn_win_size is not None:
@@ -232,7 +254,7 @@ def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
     p = jnp.exp(sd - m)
     w = (p / jnp.sum(p, axis=2, keepdims=True)).astype(jnp.float32)
     outs.append(jax.lax.dot_general(
-        w, v[:, :, h, :], (((2,), (1,)), ((0,), (0,))),
+        w, head(v, h), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ))
   o = jnp.concatenate(outs, axis=-1).reshape(tile * length, hidden)
@@ -249,14 +271,13 @@ def _kernel(*refs, specs, n_tables, num_heads, qscale, attn_win_size,
   table_refs = refs[1:1 + n_tables]
   w_cond_ref, wq_ref, wk_ref, wv_ref, wo_ref, pos_ref = refs[
       1 + n_tables:7 + n_tables]
-  xbase_ref, attn_ref = refs[7 + n_tables:9 + n_tables]
+  xbase_ref, attn_ref, x_ref = refs[7 + n_tables:10 + n_tables]
 
-  tile = ids_ref.shape[0]
-  ids = ids_ref[:]
   table_vals = [t[:] for t in table_refs]
   w_cond = w_cond_ref[:].astype(jnp.float32)
-  x = _embed_condense(ids, table_vals, w_cond, specs, tile, length, hidden)
-  x = x + pos_ref[:].astype(jnp.float32)[None]
+  embed_condense_tile(ids_ref, table_vals, w_cond, specs, x_ref, length,
+                      hidden)
+  x = x_ref[:] + pos_ref[:].astype(jnp.float32)[None]
   xbase_ref[:] = x.astype(xbase_ref.dtype)
   out = _attention(
       x, wq_ref[:], wk_ref[:], wv_ref[:], wo_ref[:],
@@ -360,6 +381,8 @@ def fused_embed_condense_attention(
           jax.ShapeDtypeStruct((b + pad, length, hidden), compute_dtype),
           jax.ShapeDtypeStruct((b + pad, length, hidden), compute_dtype),
       ],
+      scratch_shapes=[pltpu.VMEM((tile, length, hidden), jnp.float32)],
+      compiler_params=pallas_util.batch_tile_compiler_params(),
       interpret=pallas_util.resolve_interpret(interpret),
   )(*inputs)
   return x_base[:b], attn_out[:b]

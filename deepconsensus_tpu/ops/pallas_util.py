@@ -1,14 +1,55 @@
 """Shared helpers for the Pallas TPU kernels."""
 from __future__ import annotations
 
-from typing import Optional
+import collections
+from typing import Any, Dict, Optional
 
 import jax
+
+# How many pallas_call sites resolved each way in this process (counted
+# when a call is traced, i.e. once per compiled shape). The run and
+# train sidecars carry these through execution_report() so a kernel
+# that quietly ran in the interpreter on a TPU host is visible.
+_resolved: collections.Counter = collections.Counter()
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
   """None -> interpret everywhere but real TPU, so the same flag runs
   the kernels under CPU tests and the virtual mesh."""
   if interpret is None:
-    return jax.default_backend() != 'tpu'
+    interpret = jax.default_backend() != 'tpu'
+  _resolved['interpret' if interpret else 'compiled'] += 1
   return bool(interpret)
+
+
+def execution_report() -> Dict[str, Any]:
+  """Where this process really runs: the device as JAX reports it and
+  how the Pallas calls traced so far were resolved."""
+  devices = jax.devices()
+  return {
+      'platform': devices[0].platform,
+      'device_kind': devices[0].device_kind,
+      'device_count': len(devices),
+      'pallas_interpret_default': int(jax.default_backend() != 'tpu'),
+      'n_pallas_calls_compiled': _resolved['compiled'],
+      'n_pallas_calls_interpret': _resolved['interpret'],
+  }
+
+
+# Scoped-VMEM ceiling for the batch-tiled kernels (fused front end,
+# ragged front end, fused encoder block). The compiler's default scope
+# is 16 MiB; at the production 280/2048 shape and tile=8 the f32
+# encoder block asks for 20.66 MiB and the 200-wide ragged front end
+# for more than 16. 48 MiB leaves room for a DC_TPU_FUSED_TILE=16
+# sweep and stays far under the 128 MiB a v5e core has.
+BATCH_TILE_VMEM_LIMIT_BYTES = 48 << 20
+
+
+def batch_tile_compiler_params():
+  """Mosaic parameters for a kernel whose 1-D grid walks independent
+  tiles of windows."""
+  from jax.experimental.pallas import tpu as pltpu
+
+  return pltpu.CompilerParams(
+      dimension_semantics=('parallel',),
+      vmem_limit_bytes=BATCH_TILE_VMEM_LIMIT_BYTES)

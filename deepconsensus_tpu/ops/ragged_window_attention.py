@@ -92,6 +92,37 @@ def windows_per_slot(buckets: Sequence[int]) -> int:
   return b[-1] // b[0]
 
 
+def _geometry(lengths: Array, p: Array
+              ) -> Tuple[Array, Array, Array, Array]:
+  """slot_geometry in the orientation of p: an int32 position iota of
+  shape [B, S, 1] (positions on sublanes) or [B, 1, S] (on lanes).
+  Deriving each orientation from lengths directly keeps the kernel
+  free of [B, S] -> [B, S, 1] relayouts, a shape cast Mosaic does not
+  implement."""
+  lengths = lengths.astype(jnp.int32)
+  b, wps = lengths.shape
+  lengths3 = lengths.reshape(b, 1, wps)
+  seg = jnp.zeros(p.shape, jnp.int32)
+  width = jnp.zeros(p.shape, jnp.int32)
+  start = jnp.zeros(p.shape, jnp.int32)
+  cur = jnp.zeros((b, 1, 1), jnp.int32)
+  for j in range(wps):
+    w_j = lengths3[:, :, j:j + 1]
+    nxt = cur + w_j
+    sel = (p >= cur) & (p < nxt)
+    seg = jnp.where(sel, j, seg)
+    width = jnp.where(sel, w_j, width)
+    start = jnp.where(sel, cur, start)
+    cur = nxt
+  valid = p < cur
+  return seg, start, width, valid
+
+
+def _positions(b: int, slot_len: int, axis: int) -> Array:
+  shape = (b, slot_len, 1) if axis == 1 else (b, 1, slot_len)
+  return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
 def slot_geometry(lengths: Array, slot_len: int
                   ) -> Tuple[Array, Array, Array, Array]:
   """Per-position window geometry derived from per-slot window lengths.
@@ -106,23 +137,9 @@ def slot_geometry(lengths: Array, slot_len: int
   cumsum primitive), so the same helper runs inside the Pallas kernel,
   the jnp reference, and the XLA model path.
   """
-  lengths = lengths.astype(jnp.int32)
-  b, wps = lengths.shape
-  p = jax.lax.broadcasted_iota(jnp.int32, (b, slot_len), 1)
-  seg = jnp.zeros((b, slot_len), jnp.int32)
-  width = jnp.zeros((b, slot_len), jnp.int32)
-  start = jnp.zeros((b, slot_len), jnp.int32)
-  cur = jnp.zeros((b, 1), jnp.int32)
-  for j in range(wps):
-    w_j = lengths[:, j:j + 1]
-    nxt = cur + w_j
-    sel = (p >= cur) & (p < nxt)
-    seg = jnp.where(sel, j, seg)
-    width = jnp.where(sel, w_j, width)
-    start = jnp.where(sel, cur, start)
-    cur = nxt
-  valid = p < cur
-  return seg, start, width, valid
+  return tuple(
+      a[:, 0, :]
+      for a in _geometry(lengths, _positions(lengths.shape[0], slot_len, 2)))
 
 
 def ragged_attention_mask(lengths: Array, slot_len: int,
@@ -132,27 +149,28 @@ def ragged_attention_mask(lengths: Array, slot_len: int,
   absolute-position band equals the window-relative band (|i - j| is
   offset-invariant), so this is exactly the per-width band the
   bucketed path applies."""
-  seg, _start, _width, valid = slot_geometry(lengths, slot_len)
-  mask = (seg[:, :, None] == seg[:, None, :])
-  mask = mask & valid[:, :, None] & valid[:, None, :]
+  b = lengths.shape[0]
+  seg_q, _, _, valid_q = _geometry(lengths, _positions(b, slot_len, 1))
+  seg_k, _, _, valid_k = _geometry(lengths, _positions(b, slot_len, 2))
+  mask = (seg_q == seg_k) & valid_q & valid_k
   if attn_win_size is not None:
-    b = lengths.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (b, slot_len, slot_len), 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (b, slot_len, slot_len), 2)
     mask = mask & (jnp.abs(rows - cols) <= attn_win_size)
   return mask
 
 
-def _pos_contribution(start: Array, valid: Array, pos: Array) -> Array:
+def _pos_contribution(lengths: Array, slot_len: int, pos: Array) -> Array:
   """Per-position sinusoidal encoding pos[p - start(p)] as a one-hot
   matmul (MXU-friendly and exact: one 1 per row, so the accumulation
   has a single non-zero term). Invalid positions contribute zero."""
-  b, slot_len = start.shape
+  b = lengths.shape[0]
   pos_len = pos.shape[0]
-  p = jax.lax.broadcasted_iota(jnp.int32, (b, slot_len), 1)
+  p = _positions(b, slot_len, 1)
+  _seg, start, _width, valid = _geometry(lengths, p)
   off = jnp.clip(p - start, 0, pos_len - 1)
   k = jax.lax.broadcasted_iota(jnp.int32, (b, slot_len, pos_len), 2)
-  onehot = ((off[:, :, None] == k) & valid[:, :, None]).astype(jnp.float32)
+  onehot = ((off == k) & valid).astype(jnp.float32)
   return jax.lax.dot_general(
       onehot.reshape(b * slot_len, pos_len), pos.astype(jnp.float32),
       (((1,), (0,)), ((), ())),
@@ -175,15 +193,18 @@ def _ragged_attention(x, mask, wq, wk, wv, wo, *, num_heads, qscale,
     return jax.lax.dot_general(
         x2, w.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).reshape(tile, slot_len, num_heads, head_dim)
+    ).reshape(tile, slot_len, hidden)
 
   q = proj(wq) * qscale
   k = proj(wk)
   v = proj(wv)
+  # Heads are lane slices of the [tile, L, H] projections: Mosaic has
+  # no shape cast that splits the lane dimension into (heads, depth).
+  head = lambda t, h: t[:, :, h * head_dim:(h + 1) * head_dim]
   outs = []
   for h in range(num_heads):
     s = jax.lax.dot_general(
-        q[:, :, h, :], k[:, :, h, :], (((2,), (2,)), ((0,), (0,))),
+        head(q, h), head(k, h), (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )  # [tile, S, S]
     s = jnp.where(mask, s, _NEG)
@@ -192,7 +213,7 @@ def _ragged_attention(x, mask, wq, wk, wv, wo, *, num_heads, qscale,
     p = jnp.exp(sd - m)
     w = (p / jnp.sum(p, axis=2, keepdims=True)).astype(jnp.float32)
     outs.append(jax.lax.dot_general(
-        w, v[:, :, h, :], (((2,), (1,)), ((0,), (0,))),
+        w, head(v, h), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ))
   o = jnp.concatenate(outs, axis=-1).reshape(tile * slot_len, hidden)
@@ -210,18 +231,15 @@ def _kernel(*refs, specs, n_tables, num_heads, qscale, attn_win_size,
   table_refs = refs[2:2 + n_tables]
   w_cond_ref, wq_ref, wk_ref, wv_ref, wo_ref, pos_ref = refs[
       2 + n_tables:8 + n_tables]
-  xbase_ref, attn_ref = refs[8 + n_tables:10 + n_tables]
+  xbase_ref, attn_ref, x_ref = refs[8 + n_tables:11 + n_tables]
 
-  tile = ids_ref.shape[0]
-  ids = ids_ref[:]
   lengths = lengths_ref[:]
   table_vals = [t[:] for t in table_refs]
   w_cond = w_cond_ref[:].astype(jnp.float32)
-  _seg, start, _width, valid = slot_geometry(lengths, slot_len)
   mask = ragged_attention_mask(lengths, slot_len, attn_win_size)
-  x = fwa._embed_condense(
-      ids, table_vals, w_cond, specs, tile, slot_len, hidden)
-  x = x + _pos_contribution(start, valid, pos_ref[:])
+  fwa.embed_condense_tile(
+      ids_ref, table_vals, w_cond, specs, x_ref, slot_len, hidden)
+  x = x_ref[:] + _pos_contribution(lengths, slot_len, pos_ref[:])
   xbase_ref[:] = x.astype(xbase_ref.dtype)
   out = _ragged_attention(
       x, mask, wq_ref[:], wk_ref[:], wv_ref[:], wo_ref[:],
@@ -331,6 +349,8 @@ def ragged_embed_condense_attention(
           jax.ShapeDtypeStruct((b + pad, slot_len, hidden), compute_dtype),
           jax.ShapeDtypeStruct((b + pad, slot_len, hidden), compute_dtype),
       ],
+      scratch_shapes=[pltpu.VMEM((tile, slot_len, hidden), jnp.float32)],
+      compiler_params=pallas_util.batch_tile_compiler_params(),
       interpret=pallas_util.resolve_interpret(interpret),
   )(*inputs)
   return x_base[:b], attn_out[:b]
@@ -365,12 +385,11 @@ def reference_ragged_forward(
           next(s.width for s in specs if s.table_idx == i) ** 0.5)
       for i, key in enumerate(table_keys)
   ]
-  _seg, start, _width, valid = slot_geometry(lengths, slot_len)
   mask = ragged_attention_mask(lengths, slot_len, attn_win_size)
   x = fwa._embed_condense(ids, table_vals, w_cond.astype(jnp.float32),
                           specs, b, slot_len, hidden)
   if pos is not None:
-    x = x + _pos_contribution(start, valid, pos)
+    x = x + _pos_contribution(lengths, slot_len, pos)
   out = _ragged_attention(
       x, mask, wq, wk, wv, wo, num_heads=num_heads,
       qscale=head_dim ** -0.5, slot_len=slot_len,

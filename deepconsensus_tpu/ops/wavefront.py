@@ -25,7 +25,7 @@ Array = jnp.ndarray
 # full train-step XLA compile from ~4 min to >9 min on this stack, so
 # the default stays 1. Set DC_TPU_SCAN_UNROLL for long production runs
 # where the persistent compilation cache
-# (train.enable_compilation_cache) amortizes the one-time cost.
+# (utils/compile_cache.py) amortizes the one-time cost.
 import os as _os
 
 SCAN_UNROLL = int(_os.environ.get('DC_TPU_SCAN_UNROLL', '1'))

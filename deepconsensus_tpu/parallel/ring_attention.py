@@ -19,10 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-  from jax import shard_map  # jax >= 0.8
-except ImportError:
-  from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 Array = jnp.ndarray
 
@@ -38,17 +35,8 @@ n_blockwise_traces = 0
 
 def _mark_varying(x: Array, axis_name: str) -> Array:
   """Marks x device-varying over axis_name so the scan carry types line
-  up with the ppermuted K/V blocks. jax >= 0.8 spells this
-  jax.lax.pcast(to='varying'), 0.5-0.7 jax.lax.pvary; older versions
-  don't track varying-ness in the type system, so identity is correct
-  there."""
-  pcast = getattr(jax.lax, 'pcast', None)
-  if pcast is not None:
-    return pcast(x, axis_name, to='varying')
-  pvary = getattr(jax.lax, 'pvary', None)
-  if pvary is not None:
-    return pvary(x, axis_name)
-  return x
+  up with the ppermuted K/V blocks."""
+  return jax.lax.pcast(x, axis_name, to='varying')
 
 
 def _block_attention(

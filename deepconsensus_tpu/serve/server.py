@@ -335,6 +335,7 @@ def serve_main(runner, options, serve_options: ServeOptions,
   bound port. stop_event (threading.Event) is the in-process stand-in
   for SIGTERM when serve_main runs off the main thread.
   """
+  from deepconsensus_tpu.ops import pallas_util
   from deepconsensus_tpu.serve.service import ConsensusService
 
   # Fleet tracing: every tier appends to the shared trace file named
@@ -357,6 +358,9 @@ def serve_main(runner, options, serve_options: ServeOptions,
       'host': host,
       'port': bound_port,
       'warmup_s': round(warm_s, 3),
+      # The device the warmed forward really runs on, and how its
+      # Pallas calls resolved (ops/pallas_util.py).
+      'device': pallas_util.execution_report(),
   }
   log.info('dctpu serve ready on %s:%d (warmup %.3fs)',
            host, bound_port, warm_s)

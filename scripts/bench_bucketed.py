@@ -11,8 +11,8 @@ byte-identity verdict: each bucket's windows must come back identical
 to the same windows run through a dedicated single-bucket engine.
 Exit 1 = identity violation — investigate before reading the perf
 numbers. The padded-position fraction is stream arithmetic
-(backend-independent); the windows/s delta is what the measure_r4.sh
-forward_bucketed stage exists to capture on live chips.
+(backend-independent); the windows/s delta means something only on a
+TPU and is not measured yet.
 """
 import argparse
 import json

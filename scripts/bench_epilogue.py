@@ -6,9 +6,9 @@ drained, 2 bytes/position) and once with the host quality path (int32
 ids + f32 max_prob, 8 bytes/position), and prints one JSON line per
 variant plus a summary line with the measured reduction and a
 byte-identity verdict. The bytes ratio is backend-independent; the
-windows/s delta is the number the measure_r4.sh forward_epilogue stage
-exists to capture on live chips (on CPU it mostly measures the host
-log10/round work the epilogue removes).
+windows/s delta means something only on a TPU and is not measured yet
+(on CPU it mostly measures the host log10/round work the epilogue
+removes).
 """
 import argparse
 import json

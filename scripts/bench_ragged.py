@@ -15,12 +15,11 @@ or the ragged run compiled more than one forward shape — investigate
 before reading the perf numbers.
 
 The padded-position fraction and n_forward_shapes are stream
-arithmetic (backend-independent); the windows/s delta is what the
-measure_r4.sh forward_ragged stage exists to capture on live chips,
-and the host-gap-per-pack number (device_compute gaps minus the
-h2d-transfer-covered portion, per pack) is the residency signal the
-forward_ragged_resident stage watches: a device-resident pack loop
-leaves transfer-only gaps.
+arithmetic (backend-independent); the windows/s delta means something
+only on a TPU and is not measured yet. The host-gap-per-pack number
+(device_compute gaps minus the h2d-transfer-covered portion, per pack)
+is the residency signal: a device-resident pack loop leaves
+transfer-only gaps.
 """
 import argparse
 import json

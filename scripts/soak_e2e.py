@@ -237,8 +237,10 @@ def fleet_soak(args) -> int:
   env = dict(os.environ)
   env['PYTHONPATH'] = '/root/repo:' + env.get('PYTHONPATH', '')
   env['JAX_PLATFORMS'] = env.get('JAX_PLATFORMS', 'cpu')
-  cache_dir = os.path.join(args.out_dir, 'jit_cache')
-  os.makedirs(cache_dir, exist_ok=True)
+  # Every spawned tier inherits the variable, so replicas share one
+  # compile cache (an operator's own setting wins).
+  env.setdefault('JAX_COMPILATION_CACHE_DIR',
+                 os.path.join(args.out_dir, 'jit_cache'))
   # One shared Chrome-trace file for the whole fleet: every tier
   # (replicas, featurize worker, router) appends spans to it, and the
   # post-soak connectivity check joins them by trace id.
@@ -252,8 +254,7 @@ def fleet_soak(args) -> int:
         ['serve', '--random_init',
          '--config', 'transformer_learn_values+test',
          '--port', '0', '--min_quality', '0',
-         '--batch_size', str(args.serve_batch_size),
-         '--compilation_cache_dir', cache_dir], env)
+         '--batch_size', str(args.serve_batch_size)], env)
 
   replicas = []  # [proc, port] — mutated by the rolling restart
   t_first = time.time()
@@ -308,9 +309,7 @@ def fleet_soak(args) -> int:
                 '--serve_arg=--min_quality',
                 '--serve_arg=0',
                 '--serve_arg=--batch_size',
-                f'--serve_arg={args.serve_batch_size}',
-                '--serve_arg=--compilation_cache_dir',
-                f'--serve_arg={cache_dir}']
+                f'--serve_arg={args.serve_batch_size}']
   scaler_proc, scaler_ready = _spawn(scaler_cmd, env)
   print(json.dumps(scaler_ready), flush=True)
 

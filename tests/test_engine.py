@@ -106,8 +106,8 @@ def test_engine_compiles_once_per_shape(real_engine, params):
           _raw_windows(params, n, seed=i + 1))
       assert ids.shape == (n, params.max_length)
       assert quals.dtype == np.uint8
-  assert count[0] == 0, (
-      f'{count[0]} re-lowerings behind the engine boundary: the '
+  assert count() == 0, (
+      f'{count()} re-lowerings behind the engine boundary: the '
       'forward is recompiled per submission instead of per shape')
 
 
@@ -432,8 +432,8 @@ def test_engine_compiles_once_per_bucket(params):
     out_ids, _ = engine.predict_windows(
         [_win(params, w, rng) for w in (100, 200, 200, 100, 100, 200)])
     assert [i.shape[0] for i in out_ids] == [100, 200, 200, 100, 100, 200]
-  assert count[0] == 0, (
-      f'{count[0]} re-lowerings across bucketed packs: each bucket '
+  assert count() == 0, (
+      f'{count()} re-lowerings across bucketed packs: each bucket '
       'must compile once and reuse its executable')
   assert runner.dispatch_stats()['n_forward_shapes'] == 2
 

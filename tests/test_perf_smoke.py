@@ -47,6 +47,6 @@ def test_forward_compiles_once_per_shape(runner):
     for i, n in enumerate((BATCH, BATCH, BATCH // 2, 3, 1)):
       ids, quals = runner.predict(_rows(runner, n, i + 1))
       assert ids.shape == (n, runner.params.max_length)
-  assert count[0] == 0, (
-      f'{count[0]} re-lowerings in steady state: the forward is being '
+  assert count() == 0, (
+      f'{count()} re-lowerings in steady state: the forward is being '
       'recompiled per batch instead of reused per shape')

@@ -1,0 +1,267 @@
+"""What the TPU compiler accepts, asked without a TPU.
+
+The only file in the repo that describes the chip: every kernel and
+jitted forward the main path runs is lowered and compiled for a
+described (not attached) `v5e:2x2` device at production shapes
+(6 layers x hidden 280 x filter 2048, 85 rows x L=100, batch 1024 for
+inference and 256 for the loss). A compile that passes here is not a
+chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
+first, at no chip time.
+
+Rules this file keeps (they are what makes it safe under pytest-xdist):
+the topology is described inside a module-scoped fixture, never at
+import/collection time; every compile happens in this process; the
+persistent compile cache is off around the module (a described-device
+executable cannot be read back without a chip).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from deepconsensus_tpu.calibration import lib as calibration_lib
+from deepconsensus_tpu.models import config as config_lib
+from deepconsensus_tpu.models import losses as losses_lib
+from deepconsensus_tpu.models import model as model_lib
+from deepconsensus_tpu.models import quantize as quantize_lib
+from deepconsensus_tpu.ops import pallas_util
+
+BATCH = 1024
+TRAIN_BATCH = 256
+
+
+@pytest.fixture(scope='module')
+def topo():
+  os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+  from jax.experimental import topologies
+  from jax.experimental.compilation_cache import compilation_cache
+
+  try:
+    desc = topologies.get_topology_desc(
+        platform='tpu', topology_name='v5e:2x2')
+  except Exception as e:  # any failure to describe means: not here
+    pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+  cache_was_on = jax.config.jax_enable_compilation_cache
+  jax.config.update('jax_enable_compilation_cache', False)
+  compilation_cache.reset_cache()
+  yield desc
+  jax.config.update('jax_enable_compilation_cache', cache_was_on)
+  compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+  return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+  """Steers every Pallas call to the Mosaic compiler: the backend here
+  is still the CPU, so the default would resolve to interpret mode."""
+  monkeypatch.setattr(pallas_util, 'resolve_interpret', lambda _: False)
+
+
+def _params(**overrides):
+  p = config_lib.get_config('transformer_learn_values+test')
+  with p.unlocked():
+    for key, value in overrides.items():
+      p[key] = value
+  config_lib.finalize_params(p, is_training=False)
+  return p
+
+
+def _abstract(tree, sharding):
+  return jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+      tree)
+
+
+def _variables(p):
+  """Real (CPU) variables for p with the load-time levers applied — the
+  int8 variant needs values to quantize, not just shapes."""
+  variables = model_lib.get_model(p).init(
+      jax.random.PRNGKey(0),
+      jnp.zeros((1, p.total_rows, p.max_length, 1), jnp.float32))
+  return quantize_lib.prepare_inference_variables(variables, p)[0]
+
+
+def _compile_forward(p, sharding, batch=BATCH, length=None, lengths=None):
+  model = model_lib.get_model(p)
+  variables = _abstract(_variables(p), sharding)
+  rows = jax.ShapeDtypeStruct(
+      (batch, p.total_rows, length or p.max_length, 1), jnp.float32,
+      sharding=sharding)
+  if lengths is None:
+    return jax.jit(model.apply).lower(variables, rows).compile()
+  lens = jax.ShapeDtypeStruct(lengths, jnp.int32, sharding=sharding)
+  fn = lambda v, r, l: model.apply(v, r, window_lengths=l)
+  return jax.jit(fn).lower(variables, rows, lens).compile()
+
+
+def _n_kernels(compiled):
+  return compiled.as_text().count('tpu_custom_call')
+
+
+def test_xla_forward_b1024(one_chip):
+  compiled = _compile_forward(_params(), one_chip)
+  assert _n_kernels(compiled) == 0
+  # Fits one v5e chip (16 GB) with room for the dispatch pipeline.
+  assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_fused_front_end_b1024(one_chip, compiled_kernels):
+  from deepconsensus_tpu.ops import fused_window_attention as fwa
+
+  p = _params()
+  specs, table_keys, cond_in = fwa.build_family_specs(p)
+  h = p.hidden_size
+  sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+      shape, dt, sharding=one_chip)
+  tables = {
+      s.name if s.name != 'ccs' else 'bases': sds((s.vocab, s.width))
+      for s in specs}
+  tables = {k: tables[k] for k in table_keys}
+
+  def fn(rows, tables, w_cond, wq, wk, wv, wo, pos):
+    return fwa.fused_embed_condense_attention(
+        rows, tables, w_cond, wq, wk, wv, wo, pos, specs=specs,
+        table_keys=table_keys, num_heads=p.num_heads,
+        attn_win_size=p.attn_win_size, compute_dtype=jnp.bfloat16)
+
+  compiled = jax.jit(fn).lower(
+      sds((BATCH, p.total_rows, p.max_length)), tables, sds((cond_in, h)),
+      sds((h, h)), sds((h, h)), sds((h, h)), sds((h, h)),
+      sds((p.max_length, h))).compile()
+  assert _n_kernels(compiled) == 1
+
+
+@pytest.mark.parametrize('levers', [
+    dict(dtype='float32'),
+    dict(dtype='bfloat16', inference_dtype='bfloat16'),
+    dict(dtype='bfloat16', inference_dtype='bfloat16',
+         quantize_matmuls='int8'),
+], ids=['f32', 'bf16', 'bf16_int8'])
+def test_fused_hotpath_forward_b1024(one_chip, compiled_kernels, levers):
+  """Front end + the fused encoder stack (one kernel per block), the
+  whole use_fused_hotpath forward the runner would jit."""
+  p = _params(use_fused_hotpath=True, **levers)
+  compiled = _compile_forward(p, one_chip)
+  assert _n_kernels(compiled) == 1 + p.num_hidden_layers
+
+
+def test_ragged_forward_b512(one_chip, compiled_kernels):
+  """The single ragged pack stream: 200-wide slots holding two windows
+  each, the shape `--use_ragged_kernel --window_buckets 100,200`
+  dispatches (batch 1024 windows = 512 slots)."""
+  p = _params(use_fused_hotpath=True, window_buckets=(100, 200),
+              dtype='bfloat16', inference_dtype='bfloat16')
+  compiled = _compile_forward(
+      p, one_chip, batch=BATCH // 2, length=200, lengths=(BATCH // 2, 2))
+  assert _n_kernels(compiled) == 1 + p.num_hidden_layers
+
+
+def test_phred_epilogue_pallas_b1024(one_chip, compiled_kernels):
+  from deepconsensus_tpu.ops import output_plane
+
+  thresholds = output_plane.quality_thresholds(
+      calibration_lib.parse_calibration_string('skip'), 93)
+  preds = jax.ShapeDtypeStruct((BATCH, 100, 5), jnp.float32,
+                               sharding=one_chip)
+  compiled = jax.jit(
+      lambda x: output_plane.phred_epilogue_pallas(x, thresholds)
+  ).lower(preds).compile()
+  assert _n_kernels(compiled) == 1
+
+
+@pytest.mark.parametrize('grad', [False, True], ids=['forward', 'grad'])
+def test_wavefront_loss_b256(one_chip, compiled_kernels, grad):
+  """What `dctpu train` takes by itself on a TPU backend
+  (train.resolve_pallas_wavefront)."""
+  loss = losses_lib.AlignmentLoss(del_cost=10.0, loss_reg=0.1,
+                                  use_pallas=True)
+  y_true = jax.ShapeDtypeStruct((TRAIN_BATCH, 100), jnp.int32,
+                                sharding=one_chip)
+  y_pred = jax.ShapeDtypeStruct((TRAIN_BATCH, 100, 5), jnp.float32,
+                                sharding=one_chip)
+  fn = jax.grad(loss, argnums=1) if grad else loss
+  compiled = jax.jit(fn).lower(y_true, y_pred).compile()
+  assert _n_kernels(compiled) >= 1
+
+
+def test_wavefront_loss_grad_on_dp2_tp2_mesh(topo, compiled_kernels):
+  """`dctpu train --dp 2 --tp 2`: XLA refuses to partition a Mosaic
+  kernel by itself, so the loss runs its scorers under a shard_map."""
+  from deepconsensus_tpu.parallel import mesh as mesh_lib
+
+  mesh = Mesh(np.array(topo.devices).reshape(2, 2),
+              (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
+  batch_sh = mesh_lib.batch_sharding(mesh)
+  loss = losses_lib.AlignmentLoss(del_cost=10.0, loss_reg=0.1,
+                                  use_pallas=True, mesh=mesh)
+  y_true = jax.ShapeDtypeStruct((TRAIN_BATCH, 100), jnp.int32,
+                                sharding=batch_sh)
+  y_pred = jax.ShapeDtypeStruct((TRAIN_BATCH, 100, 5), jnp.float32,
+                                sharding=batch_sh)
+  compiled = jax.jit(jax.grad(loss, argnums=1)).lower(
+      y_true, y_pred).compile()
+  assert _n_kernels(compiled) >= 1
+
+
+def test_banded_attention_l100(one_chip, compiled_kernels):
+  from deepconsensus_tpu.ops import banded_attention
+
+  qkv = jax.ShapeDtypeStruct((TRAIN_BATCH, 100, 2, 140), jnp.bfloat16,
+                             sharding=one_chip)
+  compiled = jax.jit(
+      lambda q, k, v: banded_attention.banded_attention(q, k, v, 12)
+  ).lower(qkv, qkv, qkv).compile()
+  assert _n_kernels(compiled) >= 1
+
+
+def test_flash_band_attention_l500(one_chip, compiled_kernels):
+  from deepconsensus_tpu.ops import flash_band_attention
+
+  qkv = jax.ShapeDtypeStruct((64, 500, 2, 140), jnp.bfloat16,
+                             sharding=one_chip)
+  compiled = jax.jit(
+      lambda q, k, v: flash_band_attention.flash_band_attention(q, k, v, 12)
+  ).lower(qkv, qkv, qkv).compile()
+  assert _n_kernels(compiled) >= 1
+
+
+def test_dp4_forward_on_v5e_2x2_mesh(topo):
+  """`dctpu run --dp 4`: the runner's jitted forward (uint8 pack in,
+  epilogue planes out) with the batch sharded over all four chips."""
+  from deepconsensus_tpu.inference import runner as runner_lib
+  from deepconsensus_tpu.ops import output_plane
+  from deepconsensus_tpu.parallel import mesh as mesh_lib
+
+  p = _params()
+  mesh = Mesh(np.array(topo.devices).reshape(4, 1),
+              (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
+  replicated = NamedSharding(mesh, PartitionSpec())
+  batch_sh = mesh_lib.batch_sharding(mesh)
+  model = model_lib.get_model(p)
+  thresholds = output_plane.quality_thresholds(
+      calibration_lib.parse_calibration_string('skip'), 93)
+
+  def forward(variables, main_u8, sn):
+    rows = runner_lib._assemble_rows(main_u8, sn, None)
+    return output_plane.phred_epilogue(model.apply(variables, rows),
+                                       thresholds)
+
+  variables = _abstract(_variables(p), replicated)
+  main_u8 = jax.ShapeDtypeStruct(
+      (BATCH, p.total_rows - 4, p.max_length, 1), jnp.uint8,
+      sharding=batch_sh)
+  sn = jax.ShapeDtypeStruct((BATCH, 4), jnp.float32, sharding=batch_sh)
+  compiled = jax.jit(
+      forward, in_shardings=(replicated, batch_sh, batch_sh),
+      out_shardings=(batch_sh, batch_sh),
+  ).lower(variables, main_u8, sn).compile()
+  ids, quals = compiled.output_shardings
+  assert ids.spec == quals.spec == batch_sh.spec
+  assert len(ids.device_set) == 4

@@ -2,8 +2,9 @@
 prefetch-overlapped transfers, and the training degradation ladder.
 
 All multichip drills run over the 8 forced host-platform CPU devices
-from conftest.py; real-chip numbers come from measure_r4.sh
-(train_dp2/train_dp4 stages) and bench.py's train_dp_scaling stage.
+from conftest.py; real-chip scaling is not measured
+(scripts/bench_train_scaling.py is the tool; chip_smoke.py --chips 4
+proves dp x tp trains on four chips).
 
 Cross-dp identity, precisely: at equal global batch and seed the
 dp=8 run consumes byte-identical batches in the same order as dp=1
@@ -260,6 +261,33 @@ def test_optimizer_moments_shard_like_their_params(tmp_path):
 
 # ----------------------------------------------------------------------
 # Cross-dp loss-curve identity + prefetch overlap counters
+
+
+@pytest.mark.parametrize('width', [None, 4], ids=['full', 'banded'])
+def test_pallas_loss_runs_per_shard_on_a_dp_tp_mesh(width):
+  """On a TPU the loss takes the Pallas wavefront by itself, and XLA
+  cannot partition a Mosaic kernel: on a multi-device mesh the scorers
+  run under a shard_map over the data axis. Loss and gradient must
+  match the scan DP on unsharded inputs."""
+  import jax.numpy as jnp
+
+  from deepconsensus_tpu.models import losses as losses_lib
+
+  mesh = mesh_lib.make_mesh(dp=2, tp=2, devices=jax.devices()[:4])
+  rng = np.random.default_rng(0)
+  y_true = jnp.asarray(rng.integers(0, 5, size=(8, 20)), jnp.int32)
+  y_pred = jax.nn.softmax(
+      jnp.asarray(rng.normal(size=(8, 20, 5)), jnp.float32))
+  scan = losses_lib.AlignmentLoss(10.0, 0.1, width=width)
+  pallas = losses_lib.AlignmentLoss(10.0, 0.1, width=width, use_pallas=True,
+                                    mesh=mesh)
+  batch_sh = mesh_lib.batch_sharding(mesh)
+  want, want_grad = jax.jit(jax.value_and_grad(scan, argnums=1))(
+      y_true, y_pred)
+  got, got_grad = jax.jit(jax.value_and_grad(pallas, argnums=1))(
+      jax.device_put(y_true, batch_sh), jax.device_put(y_pred, batch_sh))
+  np.testing.assert_allclose(got, want, rtol=1e-5)
+  np.testing.assert_allclose(got_grad, want_grad, rtol=1e-4, atol=1e-4)
 
 
 def test_dp8_loss_curve_matches_single_device(shards, dp8_run, tmp_path):
