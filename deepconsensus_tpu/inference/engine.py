@@ -105,10 +105,122 @@ def skipped_window_arrays(
 
 
 # ----------------------------------------------------------------------
+# What both packers share: the stage sites and the in-flight queue
+
+
+class _PackerBase:
+  """The pieces `_WindowPacker` and `_RaggedPacker` have in common: the
+  poison set, the `pack_wait` stamp, drain -> deliver of the oldest
+  in-flight pack, flush. Subclasses cut packs (`_cut_packs`), drain the
+  oldest in-flight pack under their own fault policy (`_drain_one`) and
+  say how its rows reach their tickets (`_deliver_rows`). One span
+  vocabulary for both:
+  `pack_cut` around a cut, `deliver` around a pack's delivery; the
+  runner adds `dispatch` and `finalize_drain` under the same parent."""
+
+  def __init__(self, runner, options, timing_rows: List[Dict[str, Any]],
+               on_pack_failure: PackFailureFn, deliver: DeliverFn,
+               poisoned: Optional[set] = None,
+               pack_clock: Optional[List[int]] = None):
+    self._runner = runner
+    self._depth = max(1, options.dispatch_depth)
+    self._timing_rows = timing_rows
+    self._on_pack_failure = on_pack_failure
+    self._deliver = deliver
+    self._buffered = 0
+    self._in_flight: 'collections.deque' = collections.deque()
+    # Shared across a bucketed engine's packers: one poison set (the
+    # caller doesn't know which bucket a ticket landed in) and one
+    # global pack clock (every bucket's dispatches tick it) so the
+    # starvation rule can measure "packs the OTHER buckets cut while my
+    # tail sat buffered".
+    self._poisoned: set = poisoned if poisoned is not None else set()
+    self._pack_clock: List[int] = (
+        pack_clock if pack_clock is not None else [0])
+    # Wall stamp of the moment the current buffered tail started
+    # waiting, for the pack_wait interval: how long rows sat buffered
+    # before their pack was cut.
+    self._t_buf_start = 0.0
+    # The runner's metrics registry, when it has one (test stubs don't).
+    self._obs = getattr(runner, 'obs', None)
+    self.n_packs = 0
+    self.n_pack_rows = 0
+    self.n_pad_rows = 0
+    self.n_starvation_flushes = 0
+    self.n_flush_pad_rows = 0
+    self.n_oom_bisections = 0
+    self.n_device_faults = 0
+    self.n_dispatch_timeouts = 0
+    self.model_wall = 0.0
+
+  def poison(self, ticket: Ticket) -> None:
+    """Fault injection: the pack containing this ticket fails at
+    dispatch (simulates a window payload that breaks the model stage —
+    DCTPU_FAULT_POISON_WINDOW)."""
+    self._poisoned.add(id(ticket))
+
+  def _raise_if_poisoned(self, tickets: List[Ticket], what: str) -> None:
+    if not self._poisoned:
+      return
+    hit = [t for t in tickets if id(t) in self._poisoned]
+    if hit:
+      for t in hit:
+        self._poisoned.discard(id(t))
+      # dclint: allow=typed-faults (fault-injection hook: must be
+      # a bare RuntimeError so it trips the pack-failure path the
+      # same way a real dispatch error would)
+      raise RuntimeError(
+          'injected poison window payload '
+          f'({faults_lib.ENV_POISON_WINDOW}; {len(hit)} window(s) '
+          f'in {what})')
+
+  def _stamp_pack_wait(self, bucket: int, n_rows: int) -> None:
+    """The pack_wait interval of the pack just cut: from the moment its
+    first row was buffered (or the previous cut) to now. A wait, not
+    work: consecutive ones tile the timeline by construction."""
+    t_cut = time.time()
+    obs_lib.record_stage(
+        self._obs, obs_lib.trace.STAGE_PACK_WAIT,
+        self._t_buf_start or t_cut, t_cut, cat=obs_lib.trace.CAT_WAIT,
+        bucket=bucket, n_rows=n_rows)
+    # Any leftover tail starts a fresh wait from this cut.
+    self._t_buf_start = t_cut
+
+  def _deliver_pack(self, entry, pred_ids: np.ndarray,
+                    quality: np.ndarray, t0: float) -> None:
+    """One drained pack to its tickets. `entry` is what the subclass
+    keeps per window of the pack (tickets, or ragged placements)."""
+    n_rows = len(entry)
+    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_DELIVER,
+                       n_rows=n_rows):
+      # uint8 transport into the stitch plane (values are 0..4 / 0..93).
+      ids_u8 = pred_ids.astype(np.uint8)
+      quals_u8 = quality.astype(np.uint8)
+      elapsed = time.time() - t0
+      self.model_wall += elapsed
+      self._deliver_rows(entry, ids_u8, quals_u8)
+    self._timing_rows.append(dict(
+        stage='run_model', runtime=elapsed, n_zmws=0,
+        n_examples=n_rows, n_subreads=0))
+
+  def flush(self, drain: bool = True) -> None:
+    """Cuts the buffered tail as final (padded) packs — the only
+    partial packs of a run; with drain, also resolves every in-flight
+    pack (end of input)."""
+    self._cut_packs(flush=True)
+    while drain and self._in_flight:
+      self._drain_one()
+
+  @property
+  def has_work(self) -> bool:
+    return bool(self._buffered or self._in_flight)
+
+
+# ----------------------------------------------------------------------
 # Cross-batch window packer
 
 
-class _WindowPacker:
+class _WindowPacker(_PackerBase):
   """Cross-batch window packer feeding the fixed-shape compiled forward.
 
   Formatted model-input rows accumulate across submissions; full
@@ -133,41 +245,14 @@ class _WindowPacker:
                on_pack_failure: PackFailureFn, deliver: DeliverFn,
                poisoned: Optional[set] = None,
                pack_clock: Optional[List[int]] = None):
-    self._runner = runner
+    super().__init__(runner, options, timing_rows, on_pack_failure,
+                     deliver, poisoned, pack_clock)
     self._batch = options.batch_size
-    self._depth = max(1, options.dispatch_depth)
     self._degrade = getattr(options, 'on_device_error', 'fail') == 'degrade'
-    self._timing_rows = timing_rows
-    self._on_pack_failure = on_pack_failure
-    self._deliver = deliver
     self._rows: List[np.ndarray] = []
     self._tickets: List[Ticket] = []
-    self._buffered = 0
-    self._in_flight: 'collections.deque' = collections.deque()
-    # Shared across a bucketed engine's packers: one poison set (the
-    # caller doesn't know which bucket a ticket landed in) and one
-    # global pack clock (every bucket's dispatches tick it) so the
-    # starvation rule below can measure "packs the OTHER buckets cut
-    # while my tail sat buffered".
-    self._poisoned: set = poisoned if poisoned is not None else set()
-    self._pack_clock: List[int] = (
-        pack_clock if pack_clock is not None else [0])
     # Clock reading when the current buffered tail started waiting.
     self._starve_mark = 0
-    # Wall stamp of the same event, for the pack_wait span: how long
-    # rows sat buffered before their pack was cut.
-    self._t_buf_start = 0.0
-    # The runner's metrics registry, when it has one (test stubs don't).
-    self._obs = getattr(runner, 'obs', None)
-    self.n_packs = 0
-    self.n_pack_rows = 0
-    self.n_pad_rows = 0
-    self.n_starvation_flushes = 0
-    self.n_flush_pad_rows = 0
-    self.n_oom_bisections = 0
-    self.n_device_faults = 0
-    self.n_dispatch_timeouts = 0
-    self.model_wall = 0.0
 
   def add(self, rows: np.ndarray, tickets: Sequence[Ticket]) -> None:
     """Buffers one submission's formatted model rows ([k, R, L, 1],
@@ -196,23 +281,22 @@ class _WindowPacker:
       self.n_flush_pad_rows += self._batch - self._buffered
       self._cut_packs(flush=True)
 
-  def poison(self, ticket: Ticket) -> None:
-    """Fault injection: the pack containing this ticket fails at
-    dispatch (simulates a window payload that breaks the model stage —
-    DCTPU_FAULT_POISON_WINDOW)."""
-    self._poisoned.add(id(ticket))
-
   def _cut_packs(self, flush: bool) -> None:
     while self._buffered >= self._batch or (flush and self._buffered):
-      if len(self._rows) > 1:
-        self._rows = [np.concatenate(self._rows)]
-      buf = self._rows[0]
-      n = min(self._batch, self._buffered)
-      pack, rest = buf[:n], buf[n:]
-      self._rows = [rest] if len(rest) else []
-      tickets = self._tickets[:n]
-      del self._tickets[:n]
-      self._buffered -= n
+      with obs_lib.stage(self._obs, obs_lib.trace.STAGE_PACK_CUT) as st:
+        copied = 0
+        if len(self._rows) > 1:
+          # The carried tail and the new rows, copied into one array.
+          self._rows = [np.concatenate(self._rows)]
+          copied = self._rows[0].nbytes
+        buf = self._rows[0]
+        n = min(self._batch, self._buffered)
+        pack, rest = buf[:n], buf[n:]
+        self._rows = [rest] if len(rest) else []
+        tickets = self._tickets[:n]
+        del self._tickets[:n]
+        self._buffered -= n
+        st.set(n_rows=n, bytes_concatenated=copied)
       self._dispatch(pack, tickets)
 
   def _dispatch(self, pack: np.ndarray, tickets: List[Ticket]) -> None:
@@ -220,28 +304,11 @@ class _WindowPacker:
     self.n_packs += 1
     self._pack_clock[0] += 1
     self._starve_mark = self._pack_clock[0]
-    t_cut = time.time()
-    obs_lib.record_stage(
-        self._obs, obs_lib.trace.STAGE_PACK_WAIT,
-        self._t_buf_start or t_cut, t_cut,
-        bucket=int(pack.shape[2]), n_rows=len(pack))
-    # Any leftover tail starts a fresh wait from this cut.
-    self._t_buf_start = t_cut
+    self._stamp_pack_wait(int(pack.shape[2]), len(pack))
     self.n_pack_rows += len(pack)
     self.n_pad_rows += self._batch - len(pack)
     try:
-      if self._poisoned:
-        hit = [t for t in tickets if id(t) in self._poisoned]
-        if hit:
-          for t in hit:
-            self._poisoned.discard(id(t))
-          # dclint: allow=typed-faults (fault-injection hook: must be
-          # a bare RuntimeError so it trips the pack-failure path the
-          # same way a real dispatch error would)
-          raise RuntimeError(
-              'injected poison window payload '
-              f'({faults_lib.ENV_POISON_WINDOW}; {len(hit)} window(s) '
-              f'in pack {seq})')
+      self._raise_if_poisoned(tickets, f'pack {seq}')
       handle = self._runner.dispatch(pack)
     except Exception as e:
       self._handle_pack_fault(pack if self._degrade else None,
@@ -264,18 +331,10 @@ class _WindowPacker:
       return
     self._deliver_pack(tickets, pred_ids, quality, t0)
 
-  def _deliver_pack(self, tickets: List[Ticket], pred_ids: np.ndarray,
-                    quality: np.ndarray, t0: float) -> None:
-    # uint8 transport into the stitch plane (values are 0..4 / 0..93).
-    ids_u8 = pred_ids.astype(np.uint8)
-    quals_u8 = quality.astype(np.uint8)
-    elapsed = time.time() - t0
-    self.model_wall += elapsed
+  def _deliver_rows(self, tickets: List[Ticket], ids_u8: np.ndarray,
+                    quals_u8: np.ndarray) -> None:
     for ticket, row_ids, row_quals in zip(tickets, ids_u8, quals_u8):
       self._deliver(ticket, row_ids, row_quals)
-    self._timing_rows.append(dict(
-        stage='run_model', runtime=elapsed, n_zmws=0,
-        n_examples=len(tickets), n_subreads=0))
 
   def _handle_pack_fault(self, pack: Optional[np.ndarray],
                          tickets: List[Ticket], seq: int,
@@ -355,23 +414,12 @@ class _WindowPacker:
       return
     self._deliver_pack(tickets, pred_ids, quality, t0)
 
-  def flush(self, drain: bool = True) -> None:
-    """Cuts the sub-batch tail as a final (padded) pack; with drain,
-    also resolves every in-flight pack (end of input)."""
-    self._cut_packs(flush=True)
-    while drain and self._in_flight:
-      self._drain_one()
-
-  @property
-  def has_work(self) -> bool:
-    return bool(self._buffered or self._in_flight)
-
 
 # ----------------------------------------------------------------------
 # Single-stream ragged packer (use_ragged_kernel)
 
 
-class _RaggedPacker:
+class _RaggedPacker(_PackerBase):
   """One pack stream for every bucket width: mixed-width windows pack
   into fixed [n_slots, R, slot_len, 1] slots (slot_len = the largest
   bucket) with a per-slot int32 `lengths` vector, and dispatch through
@@ -411,7 +459,8 @@ class _RaggedPacker:
         raise ValueError(
             'ragged packing needs a bucket divisibility chain '
             f'(each bucket divides the next): {buckets}')
-    self._runner = runner
+    super().__init__(runner, options, timing_rows, on_pack_failure,
+                     deliver, poisoned, pack_clock)
     self._buckets = buckets
     self._slot_len = buckets[-1]
     self._wps = self._slot_len // buckets[0]  # windows per slot, max
@@ -422,33 +471,14 @@ class _RaggedPacker:
       # The compiled slot batch must split over the data axis.
       n_slots = ((n_slots + dp - 1) // dp) * dp
     self._n_slots = n_slots
-    self._depth = max(1, options.dispatch_depth)
-    self._timing_rows = timing_rows
-    self._on_pack_failure = on_pack_failure
-    self._deliver = deliver
     # Per-width FIFO queues of (rows [R, w, 1], ticket): within a
     # width, placement order == submission order, which is what the
     # byte-identity contract pins downstream.
     self._queues: Dict[int, 'collections.deque'] = {
         w: collections.deque() for w in buckets}
-    self._buffered = 0
-    self._in_flight: 'collections.deque' = collections.deque()
-    self._poisoned: set = poisoned if poisoned is not None else set()
-    self._pack_clock: List[int] = (
-        pack_clock if pack_clock is not None else [0])
-    self._t_buf_start = 0.0
-    self._obs = getattr(runner, 'obs', None)
-    self.n_packs = 0
-    self.n_pack_rows = 0
-    self.n_pad_rows = 0
-    # Structurally zero on the single-stream path (no starvation
-    # flush); kept so the engine can aggregate uniformly.
-    self.n_starvation_flushes = 0
-    self.n_flush_pad_rows = 0
-    self.n_oom_bisections = 0
-    self.n_device_faults = 0
-    self.n_dispatch_timeouts = 0
-    self.model_wall = 0.0
+    # n_starvation_flushes / n_flush_pad_rows stay structurally zero on
+    # the single-stream path (no starvation flush); the engine
+    # aggregates them uniformly.
 
   @property
   def slot_len(self) -> int:
@@ -485,9 +515,6 @@ class _RaggedPacker:
     can starve behind another's traffic."""
     del limit
 
-  def poison(self, ticket: Ticket) -> None:
-    self._poisoned.add(id(ticket))
-
   def _plan(self, allow_partial: bool) -> Optional[List[Tuple[int, int, int]]]:
     """Greedy largest-first slot plan: [(slot, offset, width), ...] in
     per-width FIFO order, or None when the slots cannot all be filled
@@ -515,25 +542,27 @@ class _RaggedPacker:
     return plan
 
   def _cut_packs(self, flush: bool) -> None:
-    while True:
-      plan = self._plan(allow_partial=False)
-      if plan is None:
-        break
-      self._dispatch(plan)
+    while self._cut_one(allow_partial=False):
+      pass
     while flush and self._buffered:
-      self._dispatch(self._plan(allow_partial=True))
+      self._cut_one(allow_partial=True)
 
-  def _dispatch(self, plan: List[Tuple[int, int, int]]) -> None:
-    seq = self.n_packs
-    self.n_packs += 1
-    self._pack_clock[0] += 1
-    t_cut = time.time()
-    obs_lib.record_stage(
-        self._obs, obs_lib.trace.STAGE_PACK_WAIT,
-        self._t_buf_start or t_cut, t_cut,
-        bucket=self._slot_len, n_rows=len(plan))
-    self._t_buf_start = t_cut
-    # Materialize the pack from the plan, popping each width's FIFO.
+  def _cut_one(self, allow_partial: bool) -> bool:
+    """Plans one pack, gathers it and dispatches it; False when no pack
+    can be cut. The `pack_cut` stage covers plan and gather (a plan that
+    finds nothing to cut is one with n_rows 0), not the dispatch."""
+    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_PACK_CUT) as st:
+      plan = self._plan(allow_partial=allow_partial)
+      st.set(n_rows=len(plan or ()), bytes_concatenated=0)
+      if plan is None:
+        return False
+      pack, lengths, placements, used = self._gather(plan)
+      st.set(bytes_concatenated=pack.nbytes)
+    self._dispatch(pack, lengths, placements, used)
+    return True
+
+  def _gather(self, plan: List[Tuple[int, int, int]]):
+    """Materializes the pack from the plan, popping each width's FIFO."""
     first_row = self._queues[plan[0][2]][0][0]
     n_rows = first_row.shape[0]
     pack = np.zeros((self._n_slots, n_rows, self._slot_len, 1),
@@ -549,26 +578,24 @@ class _RaggedPacker:
       slot_fill[slot] += 1
       placements.append((ticket, slot, off, width))
       used += width
+    return pack, lengths, placements, used
+
+  def _dispatch(self, pack: np.ndarray, lengths: np.ndarray,
+                placements: List[Tuple[Ticket, int, int, int]],
+                used: int) -> None:
+    seq = self.n_packs
+    self.n_packs += 1
+    self._pack_clock[0] += 1
+    self._stamp_pack_wait(self._slot_len, len(placements))
     self._buffered -= len(placements)
     self.n_pack_rows += len(placements)
     # Unused position capacity in min-bucket units: the windows a full
     # pack of the same shape could additionally have carried.
     self.n_pad_rows += (
         self._n_slots * self._slot_len - used) // self._buckets[0]
-    tickets = [p[0] for p in placements]
     try:
-      if self._poisoned:
-        hit = [t for t in tickets if id(t) in self._poisoned]
-        if hit:
-          for t in hit:
-            self._poisoned.discard(id(t))
-          # dclint: allow=typed-faults (fault-injection hook: must be
-          # a bare RuntimeError so it trips the pack-failure path the
-          # same way a real dispatch error would)
-          raise RuntimeError(
-              'injected poison window payload '
-              f'({faults_lib.ENV_POISON_WINDOW}; {len(hit)} window(s) '
-              f'in ragged pack {seq})')
+      self._raise_if_poisoned([p[0] for p in placements],
+                              f'ragged pack {seq}')
       handle = self._runner.dispatch_ragged(pack, lengths)
     except Exception as e:
       self._handle_pack_fault(placements, seq, e)
@@ -585,17 +612,13 @@ class _RaggedPacker:
     except Exception as e:
       self._handle_pack_fault(placements, seq, e)
       return
-    # uint8 transport into the stitch plane (values are 0..4 / 0..93).
-    ids_u8 = pred_ids.astype(np.uint8)
-    quals_u8 = quality.astype(np.uint8)
-    elapsed = time.time() - t0
-    self.model_wall += elapsed
+    self._deliver_pack(placements, pred_ids, quality, t0)
+
+  def _deliver_rows(self, placements, ids_u8: np.ndarray,
+                    quals_u8: np.ndarray) -> None:
     for ticket, slot, off, width in placements:
       self._deliver(ticket, ids_u8[slot, off:off + width],
                     quals_u8[slot, off:off + width])
-    self._timing_rows.append(dict(
-        stage='run_model', runtime=elapsed, n_zmws=0,
-        n_examples=len(placements), n_subreads=0))
 
   def _handle_pack_fault(self, placements, seq: int,
                          error: BaseException) -> None:
@@ -605,18 +628,6 @@ class _RaggedPacker:
       if isinstance(error, faults_lib.DispatchTimeoutError):
         self.n_dispatch_timeouts += 1
     self._on_pack_failure([p[0] for p in placements], seq, error)
-
-  def flush(self, drain: bool = True) -> None:
-    """Cuts the buffered tail as final (zero-length-padded) packs;
-    with drain, also resolves every in-flight pack (end of input).
-    The ONLY place partial packs exist on the ragged path."""
-    self._cut_packs(flush=True)
-    while drain and self._in_flight:
-      self._drain_one()
-
-  @property
-  def has_work(self) -> bool:
-    return bool(self._buffered or self._in_flight)
 
 
 # ----------------------------------------------------------------------
@@ -664,6 +675,8 @@ class ConsensusEngine:
     # dispatches at the same [n_slots, R, slot_len] shape.
     self._ragged = bool(getattr(options, 'use_ragged_kernel', False))
     self._ragged_packer: Optional[_RaggedPacker] = None
+    # The runner's metrics registry, when it has one (test stubs don't).
+    self._obs = getattr(runner, 'obs', None)
 
   def _resolve_buckets(self) -> Tuple[int, ...]:
     buckets = getattr(self.options, 'window_buckets', None)
@@ -783,28 +796,7 @@ class ConsensusEngine:
     mixed widths are grouped per bucket. Full packs dispatch
     immediately; each bucket's tail waits for more windows, the
     starvation flush, or flush()."""
-    from deepconsensus_tpu.models import data as data_lib
-
-    if len(raw_windows) != len(tickets):
-      # dclint: allow=typed-faults (caller API misuse guard, not a
-      # data-plane fault: both args come from the same client code)
-      raise ValueError(
-          f'{len(raw_windows)} windows vs {len(tickets)} tickets')
-    if not len(raw_windows):
-      return
-    if isinstance(raw_windows, np.ndarray) and raw_windows.dtype != object:
-      rows = data_lib.format_rows_batch(
-          np.asarray(raw_windows), self.runner.params,
-          window_buckets=self._buckets)
-      self._add_rows(rows, list(tickets))
-    else:
-      for width, (ws, ts) in sorted(
-          self._group_by_width(raw_windows, tickets).items()):
-        self._add_rows(
-            data_lib.format_rows_batch(np.stack(ws), self.runner.params,
-                                       window_buckets=self._buckets),
-            ts)
-    self._flush_starved()
+    self._submit(raw_windows, tickets, formatted=False)
 
   def submit_formatted(self, rows,
                        tickets: Sequence[Ticket]) -> None:
@@ -812,30 +804,65 @@ class ConsensusEngine:
     serve retry path re-dispatches without re-formatting). Accepts a
     uniform [k, R, L, 1] array or a sequence of [R, L, 1] rows with
     mixed L."""
-    if len(rows) != len(tickets):
+    self._submit(rows, tickets, formatted=True)
+
+  def _submit(self, windows, tickets: Sequence[Ticket],
+              formatted: bool) -> None:
+    """Both submits: one `submit` stage on the caller's thread, and
+    under it `stack_windows` (what taking a list and not an array
+    costs: the grouping, then each np.stack), `format_rows`, and the
+    packer's `pack_cut` / `dispatch` / `finalize_drain` / `deliver`."""
+    if len(windows) != len(tickets):
       # dclint: allow=typed-faults (caller API misuse guard, not a
       # data-plane fault: both args come from the same client code)
-      raise ValueError(f'{len(rows)} rows vs {len(tickets)} tickets')
-    if not len(rows):
+      raise ValueError(
+          f'{len(windows)} {"rows" if formatted else "windows"} vs '
+          f'{len(tickets)} tickets')
+    if not len(windows):
       return
-    if isinstance(rows, np.ndarray) and rows.dtype != object:
-      self._add_rows(np.asarray(rows), list(tickets))
-    else:
-      for _width, (ws, ts) in sorted(
-          self._group_by_width(rows, tickets).items()):
-        self._add_rows(np.stack(ws), ts)
-    self._flush_starved()
+    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_SUBMIT,
+                       n_windows=len(tickets), formatted=int(formatted)):
+      if isinstance(windows, np.ndarray) and windows.dtype != object:
+        self._format_and_add(np.asarray(windows), list(tickets), formatted)
+      else:
+        with obs_lib.stage(self._obs, obs_lib.trace.STAGE_STACK,
+                           n_rows=len(tickets), bytes=0):
+          groups = sorted(self._group_by_width(windows, tickets).items())
+        for _width, (ws, ts) in groups:
+          # No name for the stacked rows here: format_rows_batch's result
+          # replaces them, and they are freed there, not after the add.
+          self._format_and_add(self._stack(ws), ts, formatted)
+      self._flush_starved()
+
+  def _stack(self, windows: list) -> np.ndarray:
+    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_STACK) as st:
+      rows = np.stack(windows)
+      st.set(n_rows=len(rows), bytes=rows.nbytes)
+    return rows
+
+  def _format_and_add(self, rows: np.ndarray, tickets: List[Ticket],
+                      formatted: bool) -> None:
+    if not formatted:
+      from deepconsensus_tpu.models import data as data_lib
+
+      with obs_lib.stage(self._obs, obs_lib.trace.STAGE_FORMAT) as st:
+        rows = data_lib.format_rows_batch(
+            rows, self.runner.params, window_buckets=self._buckets)
+        st.set(n_rows=len(rows), bytes=rows.nbytes)
+    self._add_rows(rows, tickets)
 
   def flush(self, drain: bool = True) -> None:
     """Cuts every bucket's buffered tail as a padded pack; with drain,
     resolves every in-flight pack (every submitted ticket has been
     delivered or failed when this returns). Tails cut for all buckets
     before any drain so the end-of-input packs overlap on device."""
-    for packer in self._all_packers():
-      packer.flush(drain=False)
-    if drain:
+    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_FLUSH,
+                       drain=int(drain)):
       for packer in self._all_packers():
-        packer.flush(drain=True)
+        packer.flush(drain=False)
+      if drain:
+        for packer in self._all_packers():
+          packer.flush(drain=True)
 
   def poison_ticket(self, ticket: Ticket) -> None:
     # Shared across buckets: the caller doesn't know (or care) which

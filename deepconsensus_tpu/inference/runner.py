@@ -841,47 +841,67 @@ class ModelRunner:
     """
     n = rows.shape[0]
     batch = batch_size or self.options.batch_size
-    if n < batch:
-      pad = np.zeros((batch - n,) + rows.shape[1:], rows.dtype)
-      rows = np.concatenate([rows, pad])
+    width = int(rows.shape[2])
+    with obs_lib.stage(self.obs, obs_lib.trace.STAGE_DISPATCH,
+                       pack=self._n_dispatched + 1, bucket=width, n_rows=n):
+      with obs_lib.stage(self.obs, obs_lib.trace.STAGE_PACK_CAST) as st:
+        bytes_in = rows.nbytes
+        if n < batch:
+          pad = np.zeros((batch - n,) + rows.shape[1:], rows.dtype)
+          rows = np.concatenate([rows, pad])
+        main_u8 = self._cast_main_u8(rows)
+        sn = np.ascontiguousarray(
+            rows[:, -_SN_ROWS:, 0, 0].astype(np.float32))
+        st.set(bytes_in=bytes_in, bytes_out=main_u8.nbytes + sn.nbytes)
+      # Per-bucket compile-once accounting: jit keeps one executable per
+      # distinct (batch, L); the set is the compile count.
+      return self._place_pack((main_u8, sn), n=n, n_rows=n, bucket=width,
+                              shape_key=(batch, width))
+
+  def _cast_main_u8(self, rows: np.ndarray) -> np.ndarray:
+    """The non-SN rows of a pack as uint8, ccs_bq biased by +1: spaced
+    ccs_bq holds -1 sentinels, so 0..94 makes the cast lossless (the
+    device side subtracts 1 back; zero pad positions round-trip
+    0 -> 1 -> 0)."""
     main = rows[:, :-_SN_ROWS]
     main_u8 = main.astype(np.uint8)
     if self._bq_row is not None:
-      # Spaced ccs_bq holds -1 sentinels; bias to 0..94 so the uint8
-      # cast is lossless (the device side subtracts 1 back).
       main_u8[:, self._bq_row] = (main[:, self._bq_row] + 1.0).astype(
           np.uint8)
-    sn = np.ascontiguousarray(rows[:, -_SN_ROWS:, 0, 0].astype(np.float32))
-    width = int(rows.shape[2])
+    return main_u8
+
+  def _place_pack(self, host_arrays, n: int, n_rows: int, bucket: int,
+                  shape_key, ragged: bool = False) -> _DispatchHandle:
+    """The half of a dispatch that both pack layouts share: launch the
+    previous pack's forward, start this pack's async transfer, count,
+    and leave the handle in the transfer slot."""
     # Launch the previous pack's forward BEFORE starting this pack's
     # transfer, so the device_put below overlaps its compute.
     self._launch_pending()
-    t_h2d = time.time()
-    if self._input_sharding is not None:
-      main_dev = jax.device_put(main_u8, self._input_sharding)
-      sn_dev = jax.device_put(sn, self._input_sharding)
-      self._n_dispatched_sharded += 1
-    else:
-      main_dev = jax.device_put(main_u8)
-      sn_dev = jax.device_put(sn)
-    self._n_dispatched += 1
-    # Distinct devices holding a shard of the placed pack (metadata
-    # only, no sync): dp=4 must read 4 here, not 1.
-    self._pack_shard_devices = len(
-        {shard.device for shard in main_dev.addressable_shards})
-    obs_lib.record_stage(self.obs, obs_lib.trace.STAGE_H2D,
-                         t_h2d, time.time(), pack=self._n_dispatched,
-                         bucket=width, dp=self.mesh_dp, n_rows=n)
+    with obs_lib.stage(
+        self.obs, obs_lib.trace.STAGE_H2D, pack=self._n_dispatched + 1,
+        bucket=bucket, dp=self.mesh_dp, n_rows=n_rows,
+        bytes=sum(a.nbytes for a in host_arrays)):
+      if self._input_sharding is not None:
+        placed = tuple(jax.device_put(a, self._input_sharding)
+                       for a in host_arrays)
+        self._n_dispatched_sharded += 1
+      else:
+        placed = tuple(jax.device_put(a) for a in host_arrays)
+      self._n_dispatched += 1
+      # Distinct devices holding a shard of the placed pack (metadata
+      # only, no sync): dp=4 must read 4 here, not 1.
+      self._pack_shard_devices = len(
+          {shard.device for shard in placed[0].addressable_shards})
     if self._device_epilogue:
       self._n_epilogue_packs += 1
-    # Per-bucket compile-once accounting: jit keeps one executable per
-    # distinct (batch, L); the set is the compile count.
-    self._forward_shapes.add((batch, width))
-    self._n_dispatched_by_bucket[width] = (
-        self._n_dispatched_by_bucket.get(width, 0) + 1)
-    handle = _DispatchHandle((main_dev, sn_dev), n)
+    self._forward_shapes.add(shape_key)
+    self._n_dispatched_by_bucket[bucket] = (
+        self._n_dispatched_by_bucket.get(bucket, 0) + 1)
+    handle = _DispatchHandle(placed, n)
     handle.seq = self._n_dispatched
-    handle.bucket = width
+    handle.bucket = bucket
+    handle.ragged = ragged
     self._pending = handle
     return handle
 
@@ -905,53 +925,30 @@ class ModelRunner:
           'artifacts serve the bucketed path only)')
     n_slots = int(rows.shape[0])
     slot_len = int(rows.shape[2])
-    lengths = np.ascontiguousarray(np.asarray(lengths, dtype=np.int32))
-    main = rows[:, :-_SN_ROWS]
-    main_u8 = main.astype(np.uint8)
-    if self._bq_row is not None:
-      # Same lossless +1 bias as dispatch(); zero pad positions round-
-      # trip 0 -> 1 -> 0 through the device-side -1.
-      main_u8[:, self._bq_row] = (main[:, self._bq_row] + 1.0).astype(
-          np.uint8)
-    # Per-window SN scalars, sampled at each window's start column
-    # (the packer broadcast them across the window, like the raw
-    # feature layout). Empty window slots carry zeros.
-    starts = np.zeros_like(lengths)
-    starts[:, 1:] = np.cumsum(lengths[:, :-1], axis=1)
-    sn_planes = rows[:, -_SN_ROWS:, :, 0]  # [n_slots, 4, slot_len]
-    sn_w = np.take_along_axis(
-        sn_planes, np.clip(starts, 0, slot_len - 1)[:, None, :], axis=2)
-    sn_w = sn_w.transpose(0, 2, 1) * (lengths > 0)[:, :, None]
-    sn_w = np.ascontiguousarray(sn_w.astype(np.float32))
-    n_windows = int((lengths > 0).sum())
-    self._launch_pending()
-    t_h2d = time.time()
-    if self._input_sharding is not None:
-      main_dev = jax.device_put(main_u8, self._input_sharding)
-      sn_dev = jax.device_put(sn_w, self._input_sharding)
-      len_dev = jax.device_put(lengths, self._input_sharding)
-      self._n_dispatched_sharded += 1
-    else:
-      main_dev = jax.device_put(main_u8)
-      sn_dev = jax.device_put(sn_w)
-      len_dev = jax.device_put(lengths)
-    self._n_dispatched += 1
-    obs_lib.record_stage(self.obs, obs_lib.trace.STAGE_H2D,
-                         t_h2d, time.time(), pack=self._n_dispatched,
-                         bucket=slot_len, dp=self.mesh_dp,
-                         n_rows=n_windows)
-    if self._device_epilogue:
-      self._n_epilogue_packs += 1
-    # One entry for the whole run: the collapse the ragged path buys.
-    self._forward_shapes.add(('ragged', n_slots, slot_len))
-    self._n_dispatched_by_bucket[slot_len] = (
-        self._n_dispatched_by_bucket.get(slot_len, 0) + 1)
-    handle = _DispatchHandle((main_dev, sn_dev, len_dev), n_slots)
-    handle.seq = self._n_dispatched
-    handle.bucket = slot_len
-    handle.ragged = True
-    self._pending = handle
-    return handle
+    n_windows = int((np.asarray(lengths) > 0).sum())
+    with obs_lib.stage(self.obs, obs_lib.trace.STAGE_DISPATCH,
+                       pack=self._n_dispatched + 1, bucket=slot_len,
+                       n_rows=n_windows):
+      with obs_lib.stage(self.obs, obs_lib.trace.STAGE_PACK_CAST) as st:
+        lengths = np.ascontiguousarray(np.asarray(lengths, dtype=np.int32))
+        main_u8 = self._cast_main_u8(rows)
+        # Per-window SN scalars, sampled at each window's start column
+        # (the packer broadcast them across the window, like the raw
+        # feature layout). Empty window slots carry zeros.
+        starts = np.zeros_like(lengths)
+        starts[:, 1:] = np.cumsum(lengths[:, :-1], axis=1)
+        sn_planes = rows[:, -_SN_ROWS:, :, 0]  # [n_slots, 4, slot_len]
+        sn_w = np.take_along_axis(
+            sn_planes, np.clip(starts, 0, slot_len - 1)[:, None, :], axis=2)
+        sn_w = sn_w.transpose(0, 2, 1) * (lengths > 0)[:, :, None]
+        sn_w = np.ascontiguousarray(sn_w.astype(np.float32))
+        st.set(bytes_in=rows.nbytes,
+               bytes_out=main_u8.nbytes + sn_w.nbytes + lengths.nbytes)
+      # One shape for the whole run: the collapse the ragged path buys.
+      return self._place_pack(
+          (main_u8, sn_w, lengths), n=n_slots, n_rows=n_windows,
+          bucket=slot_len, shape_key=('ragged', n_slots, slot_len),
+          ragged=True)
 
   def _launch_pending(self) -> None:
     """Launches the forward for the pack currently in the transfer
@@ -976,15 +973,19 @@ class ModelRunner:
     # signal dctpu trace reconciles against the counters.
     handle.t_launch = time.time()
     fwd = self._ragged_forward if handle.ragged else self._forward
-    try:
-      faults.injected_device_fault(handle.seq)
-      handle.hang_s = faults.injected_device_hang(handle.seq)
-      handle.outputs = fwd(self.variables, *inputs)
-    # dclint: allow=typed-faults (deferred-launch error capture: the
-    # classified error is re-raised at finalize time, where
-    # pack-failure routing can attribute it to the right tickets)
-    except Exception as e:
-      handle.error = faults.classify_device_error(e)
+    # Host time to enqueue the forward: where a full runtime queue
+    # would block.
+    with obs_lib.stage(self.obs, obs_lib.trace.STAGE_LAUNCH,
+                       pack=handle.seq):
+      try:
+        faults.injected_device_fault(handle.seq)
+        handle.hang_s = faults.injected_device_hang(handle.seq)
+        handle.outputs = fwd(self.variables, *inputs)
+      # dclint: allow=typed-faults (deferred-launch error capture: the
+      # classified error is re-raised at finalize time, where
+      # pack-failure routing can attribute it to the right tickets)
+      except Exception as e:
+        handle.error = faults.classify_device_error(e)
 
   def raw_outputs(self, dispatched: _DispatchHandle):
     """Device arrays (pred_ids, max_prob, n) for a dispatch handle —
@@ -1103,19 +1104,24 @@ class ModelRunner:
     ordering is the span-derived overlap fraction: an overlapped pack
     was launched by a later dispatch (launch stamp BEFORE finalize
     began); a direct launch happens inside finalize."""
-    t_fin = time.time()
+    handle = dispatched
     try:
-      return self._drain_sync(dispatched)
+      with obs_lib.stage(self.obs, obs_lib.trace.STAGE_FINALIZE,
+                         pack=handle.seq) as st:
+        try:
+          return self._drain_sync(handle)
+        finally:
+          st.set(bytes=self._d2h_bytes_per_pack)
     finally:
-      t_end = time.time()
-      handle = dispatched
-      obs_lib.record_stage(self.obs, obs_lib.trace.STAGE_FINALIZE,
-                           t_fin, t_end, pack=handle.seq)
       if handle.t_launch:
+        # A wait, not work: launch to the end of the drain on the host's
+        # clock. At dispatch depth d it spans about d pack periods, so
+        # it is no measure of device time.
         obs_lib.record_stage(
             self.obs, obs_lib.trace.STAGE_DEVICE_COMPUTE,
-            handle.t_launch, t_end, pack=handle.seq,
-            bucket=handle.bucket, dp=self.mesh_dp, n_rows=handle.n)
+            handle.t_launch, time.time(), cat=obs_lib.trace.CAT_WAIT,
+            pack=handle.seq, bucket=handle.bucket, dp=self.mesh_dp,
+            n_rows=handle.n)
 
   def _drain_sync(self, dispatched) -> Tuple[np.ndarray, np.ndarray]:
     """The blocking half of finalize: device sync, plus host quality

@@ -3,13 +3,22 @@
 Reads a Chrome-trace-event file written by obs.trace (possibly by many
 fleet processes appending to one file) and derives:
 
-* per-stage time breakdown — total span-duration and union-of-interval
-  coverage per pipeline stage (featurize, pack_wait, h2d_transfer,
-  device_compute, finalize_drain, stitch);
-* critical-path attribution — each stage's coverage as a fraction of
-  the end-to-end wall interval, sorted so the stage that bounds the
-  pipeline tops the list (stages overlap by design, so fractions sum
-  past 1.0 exactly when the pipeline is doing its job);
+* per-stage time breakdown — total span duration and count per
+  pipeline stage, and each stage's SELF time: its duration minus what
+  its child stages cover, children found through `args.parent` ->
+  `args.span` within one process (obs.stage writes both). A stage with
+  no id (stamped after the fact: featurize, stitch) has no children, so
+  its self time is its duration;
+* waits apart — `pack_wait` and `device_compute` (`cat: "wait"`) are
+  intervals between two events, not work on a thread: consecutive
+  `pack_wait`s tile the timeline by construction and a `device_compute`
+  runs from a forward's launch to the end of its drain on the host's
+  clock (about dispatch_depth pack periods). They have totals and
+  counts, no self time, and never enter the critical path;
+* critical-path attribution — stages ordered by self time, each as a
+  fraction of the end-to-end wall interval: the stage where a thread
+  actually spent the time tops the list (with one thread the fractions
+  sum to at most 1.0);
 * straggler packs — the slowest decile of device_compute spans with
   their bucket / dp / row-count context;
 * a span-derived transfer-overlap fraction that must agree with the
@@ -28,7 +37,7 @@ they reconcile within float rounding — bench.py asserts within 1%.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 from deepconsensus_tpu import faults as faults_lib
 from deepconsensus_tpu.obs import trace as trace_lib
@@ -64,25 +73,6 @@ def load_trace(path: str) -> List[Dict[str, Any]]:
 def _complete_spans(events: List[Dict[str, Any]]
                     ) -> List[Dict[str, Any]]:
   return [e for e in events if e.get('ph') == 'X']
-
-
-def _union_s(intervals: List[Tuple[float, float]]) -> float:
-  """Total length of the union of [start, end) intervals, in seconds
-  (inputs in microseconds)."""
-  if not intervals:
-    return 0.0
-  total = 0.0
-  cur_lo, cur_hi = None, None
-  for lo, hi in sorted(intervals):
-    if cur_lo is None:
-      cur_lo, cur_hi = lo, hi
-    elif lo <= cur_hi:
-      cur_hi = max(cur_hi, hi)
-    else:
-      total += cur_hi - cur_lo
-      cur_lo, cur_hi = lo, hi
-  total += cur_hi - cur_lo
-  return total / 1e6
 
 
 def tier_names(events: List[Dict[str, Any]]) -> Dict[int, str]:
@@ -163,57 +153,49 @@ def span_overlap(events: List[Dict[str, Any]]) -> Dict[str, Any]:
   }
 
 
-def device_gaps(events: List[Dict[str, Any]]) -> Dict[str, Any]:
-  """Host gaps between consecutive device_compute spans, per pid.
+def _is_wait(event: Dict[str, Any]) -> bool:
+  """cat 'wait', or one of the wait names in a trace written before the
+  category existed."""
+  return (event.get('cat') == trace_lib.CAT_WAIT
+          or event.get('name') in trace_lib.WAITS)
 
-  The device-residency signal for the pack loop: in a fully resident
-  run (weights pinned, donated pack buffers cycling device-side) the
-  only thing between pack N's compute ending and pack N+1's compute
-  starting is the H2D transfer of a later pack's uint8 planes — so
-  each gap should be covered by h2d_transfer spans. Residual
-  uncovered time (host_gap_s) is host work on the critical path: pack
-  assembly stalls, per-pack weight re-transfer, python overhead.
-  transfer_only_fraction is the covered share of all gap time (1.0
-  when there are no gaps at all)."""
-  compute: Dict[int, List[Tuple[float, float]]] = {}
-  h2d: Dict[int, List[Tuple[float, float]]] = {}
-  for e in _complete_spans(events):
-    name = e.get('name')
-    if name not in (trace_lib.STAGE_DEVICE_COMPUTE, trace_lib.STAGE_H2D):
-      continue
+
+def self_times(events: List[Dict[str, Any]]) -> Dict[str, Dict[str, Any]]:
+  """stage name -> {'total_s', 'self_s', 'count', 'under'}: over the
+  work stages (cat 'stage', waits left out), a stage's self time is its
+  duration minus the durations of the stages whose `args.parent` is its
+  `args.span` in the same process (children of one parent run one after
+  the other on its thread, so they do not overlap). 'under' lists the
+  names of the stages it ran under ('' at top level)."""
+  stages = [e for e in _complete_spans(events)
+            if e.get('cat') == trace_lib.CAT_STAGE and not _is_wait(e)]
+  name_of: Dict[Tuple[int, Any], str] = {}
+  child_us: Dict[Tuple[int, Any], float] = {}
+  for e in stages:
+    args = e.get('args') or {}
     pid = int(e.get('pid', 0))
-    ts = float(e['ts'])
-    iv = (ts, ts + float(e.get('dur', 0.0)))
-    (compute if name == trace_lib.STAGE_DEVICE_COMPUTE else h2d
-     ).setdefault(pid, []).append(iv)
-  n_gaps = 0
-  gap_s = 0.0
-  transfer_s = 0.0
-  max_host_gap_s = 0.0
-  for pid, intervals in compute.items():
-    intervals.sort()
-    transfers = h2d.get(pid, [])
-    for (_lo_a, hi_a), (lo_b, _hi_b) in zip(intervals, intervals[1:]):
-      if lo_b <= hi_a:
-        continue  # overlapping/adjacent compute: no host gap at all
-      n_gaps += 1
-      gap = (lo_b - hi_a) / 1e6
-      covered = _union_s([
-          (max(lo, hi_a), min(hi, lo_b))
-          for lo, hi in transfers if hi > hi_a and lo < lo_b])
-      gap_s += gap
-      transfer_s += covered
-      max_host_gap_s = max(max_host_gap_s, gap - covered)
-  host_gap_s = gap_s - transfer_s
+    if 'span' in args:
+      name_of[(pid, args['span'])] = str(e.get('name', ''))
+    if 'parent' in args:
+      key = (pid, args['parent'])
+      child_us[key] = child_us.get(key, 0.0) + float(e.get('dur', 0.0))
+  out: Dict[str, Dict[str, Any]] = {}
+  for e in stages:
+    args = e.get('args') or {}
+    pid = int(e.get('pid', 0))
+    dur = float(e.get('dur', 0.0))
+    own = dur - child_us.get((pid, args.get('span')), 0.0)
+    row = out.setdefault(str(e.get('name', '')), {
+        'total_s': 0.0, 'self_s': 0.0, 'count': 0, 'under': set()})
+    row['total_s'] += dur / 1e6
+    row['self_s'] += max(0.0, own) / 1e6
+    row['count'] += 1
+    row['under'].add(name_of.get((pid, args.get('parent')), ''))
   return {
-      'n_gaps': n_gaps,
-      'gap_s': round(gap_s, 6),
-      'transfer_s': round(transfer_s, 6),
-      'host_gap_s': round(host_gap_s, 6),
-      'max_host_gap_s': round(max_host_gap_s, 6),
-      'transfer_only_fraction': (
-          round(transfer_s / gap_s, 4) if gap_s else 1.0),
-  }
+      name: {'total_s': round(row['total_s'], 6),
+             'self_s': round(row['self_s'], 6),
+             'count': row['count'], 'under': sorted(row['under'])}
+      for name, row in sorted(out.items())}
 
 
 def summarize(events: List[Dict[str, Any]],
@@ -229,24 +211,27 @@ def summarize(events: List[Dict[str, Any]],
 
   stage_totals: Dict[str, float] = {}
   stage_counts: Dict[str, int] = {}
-  stage_intervals: Dict[str, List[Tuple[float, float]]] = {}
+  waits: Dict[str, Dict[str, Any]] = {}
   for e in spans:
-    if e.get('cat') != 'stage':
+    if e.get('cat') not in (trace_lib.CAT_STAGE, trace_lib.CAT_WAIT):
       continue
     name = str(e.get('name', ''))
-    ts = float(e['ts'])
-    dur = float(e.get('dur', 0.0))
-    stage_totals[name] = stage_totals.get(name, 0.0) + dur / 1e6
+    dur_s = float(e.get('dur', 0.0)) / 1e6
+    stage_totals[name] = stage_totals.get(name, 0.0) + dur_s
     stage_counts[name] = stage_counts.get(name, 0) + 1
-    stage_intervals.setdefault(name, []).append((ts, ts + dur))
+    if _is_wait(e):
+      row = waits.setdefault(name, {'total_s': 0.0, 'count': 0})
+      row['total_s'] += dur_s
+      row['count'] += 1
 
-  coverage = {name: _union_s(iv) for name, iv in stage_intervals.items()}
+  self_time = self_times(events)
   critical_path = sorted(
       ({'stage': name,
-        'coverage_s': round(cov, 6),
-        'fraction_of_wall': round(cov / wall_s, 4) if wall_s else 0.0}
-       for name, cov in coverage.items()),
-      key=lambda row: -row['coverage_s'])
+        'self_s': row['self_s'],
+        'fraction_of_wall': (round(row['self_s'] / wall_s, 4)
+                             if wall_s else 0.0)}
+       for name, row in self_time.items()),
+      key=lambda row: -row['self_s'])
 
   compute_spans = sorted(
       (e for e in spans
@@ -275,12 +260,13 @@ def summarize(events: List[Dict[str, Any]],
       'stage_totals_s': {k: round(v, 6)
                          for k, v in sorted(stage_totals.items())},
       'stage_counts': dict(sorted(stage_counts.items())),
-      'stage_coverage_s': {k: round(v, 6)
-                           for k, v in sorted(coverage.items())},
+      'self_time': self_time,
+      'waits': {name: {'total_s': round(row['total_s'], 6),
+                       'count': row['count']}
+                for name, row in sorted(waits.items())},
       'critical_path': critical_path,
       'stragglers': stragglers,
       'overlap': span_overlap(events),
-      'device_gaps': device_gaps(events),
       'n_traces': len(trace_groups(events)),
   }
 
@@ -295,29 +281,28 @@ def format_summary(summary: Dict[str, Any]) -> str:
     tiers = ', '.join(f'{pid}={name}'
                       for pid, name in sorted(summary['tiers'].items()))
     lines.append(f'tiers: {tiers}')
-  lines.append('per-stage breakdown (critical-path order):')
-  totals = summary['stage_totals_s']
-  counts = summary['stage_counts']
+  lines.append('self time per stage (critical-path order):')
+  self_time = summary['self_time']
   for row in summary['critical_path']:
     stage = row['stage']
+    st = self_time[stage]
+    under = ', '.join(u or '-' for u in st['under'])
     lines.append(
-        f'  {stage:<16} coverage {row["coverage_s"]:>10.4f}s '
+        f'  {stage:<16} self {st["self_s"]:>10.4f}s '
         f'({100 * row["fraction_of_wall"]:5.1f}% of wall)  '
-        f'total {totals.get(stage, 0.0):>10.4f}s  '
-        f'n={counts.get(stage, 0)}')
+        f'total {st["total_s"]:>10.4f}s  n={st["count"]}  '
+        f'under: {under}')
+  if summary.get('waits'):
+    lines.append('waits (intervals between two events, not work; '
+                 'no self time):')
+    for name, row in summary['waits'].items():
+      lines.append(f'  {name:<16} total {row["total_s"]:>10.4f}s  '
+                   f'n={row["count"]}')
   overlap = summary['overlap']
   lines.append(
       f'transfer overlap (span-derived): '
       f'{overlap["n_overlapped"]}/{overlap["n_packs"]} packs '
       f'(fraction {overlap["span_overlap_fraction"]})')
-  gaps = summary.get('device_gaps')
-  if gaps:
-    lines.append(
-        f'device gaps: {gaps["n_gaps"]} gaps totalling '
-        f'{gaps["gap_s"]:.4f}s, host (non-transfer) '
-        f'{gaps["host_gap_s"]:.4f}s, transfer-only fraction '
-        f'{gaps["transfer_only_fraction"]} '
-        f'(max host gap {gaps["max_host_gap_s"]:.4f}s)')
   if summary['stragglers']:
     lines.append('straggler packs (slowest decile of device compute):')
     for row in summary['stragglers'][:10]:

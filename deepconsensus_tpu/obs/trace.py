@@ -8,20 +8,39 @@ fleet traffic, the CLI for batch runs) and carried across processes in
 the ``X-Dctpu-Trace-Id`` protocol header. Load the file straight into
 Perfetto / chrome://tracing, or summarize it with ``dctpu trace``.
 
+Two kinds of span. A *stage* (``cat: "stage"``) is work on a thread,
+opened lexically with ``obs.stage(registry, name)``: its ``args.span``
+is an id unique in the process and ``args.parent`` the id of the stage
+that encloses it on the same thread (absent at top level), so a reader
+can take a stage's self time: its duration minus what its children
+cover. A *wait* (``cat: "wait"``: ``pack_wait``, ``device_compute``) is
+an interval stamped after the fact between two events; it has no parent
+and is no one's child, and ``dctpu trace`` lists it apart.
+
 File format. Chrome's JSON trace format tolerates a missing closing
 ``]`` and a trailing comma, so the file is written as a ``[`` header
 line followed by one complete-event object per line, each line ending
-``,``. Each line is a single O_APPEND write, which POSIX keeps atomic
-for these sizes, so N fleet processes share ONE trace file with no
-coordination: the header is written only by the process that wins the
-O_CREAT|O_EXCL race, and every other writer just appends events. pid
-distinguishes tiers (a process_name metadata event labels each).
+``,``. Events are kept in memory and written out as whole lines, one
+O_APPEND write per flush: at ``close()`` / ``configure(None)``, at
+interpreter exit and whenever ``FLUSH_EVENTS`` events are buffered (so
+a resident server stays bounded). N fleet processes share ONE trace
+file with no coordination: the header is written only by the process
+that wins the O_CREAT|O_EXCL race, every other writer just appends
+whole lines. pid distinguishes tiers (a process_name metadata event
+labels each). A forked child starts with an empty buffer; a child that
+leaves through ``os._exit`` (a multiprocessing worker) calls
+``flush()`` itself if it has traced anything.
 
 Overhead when off. Tracing is enabled by ``DCTPU_TRACE=<path>`` (or
 ``configure(path)``); when unset, ``enabled()`` is a module-global
-``is None`` check and ``span()`` yields a no-op context — the hot path
-pays one branch, which is the acceptance bar for "zero measurable
-overhead with tracing off".
+``is None`` check, ``span()`` yields a no-op context and a stage costs
+its two clock reads and its histogram observation: no event is built.
+
+Profiler bridge. While tracing is on and ``jax`` is already imported, a
+stage also enters ``jax.profiler.TraceAnnotation(name)``, so a profiler
+capture of the process shows the program's stages on the host plane
+beside the device's operations, on the profiler's clock. This module
+never imports jax itself (the router has none).
 
 Timestamps are wall-clock microseconds (``time.time()``): the one
 clock every fleet process shares, so cross-tier spans land on one
@@ -31,29 +50,67 @@ come from the same clock in the same thread.
 """
 from __future__ import annotations
 
+import atexit
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 ENV_TRACE = 'DCTPU_TRACE'
 
-# Stage-span categories (docs/observability.md#span-model). `cat` is
-# 'stage' for pipeline stages, 'request' for per-request tier spans.
+# Span categories (docs/observability.md#span-model): 'stage' for work
+# on a thread, 'wait' for intervals stamped after the fact, 'request'
+# for per-request tier spans.
+CAT_STAGE = 'stage'
+CAT_WAIT = 'wait'
+
 STAGE_FEATURIZE = 'featurize'
-STAGE_PACK_WAIT = 'pack_wait'
+STAGE_SUBMIT = 'submit'
+STAGE_FLUSH = 'flush'
+STAGE_STACK = 'stack_windows'
+STAGE_FORMAT = 'format_rows'
+STAGE_PACK_CUT = 'pack_cut'
+STAGE_DISPATCH = 'dispatch'
+STAGE_PACK_CAST = 'pack_cast'
+STAGE_LAUNCH = 'forward_launch'
 STAGE_H2D = 'h2d_transfer'
-STAGE_DEVICE_COMPUTE = 'device_compute'
 STAGE_FINALIZE = 'finalize_drain'
+STAGE_DELIVER = 'deliver'
 STAGE_STITCH = 'stitch'
-STAGES = (STAGE_FEATURIZE, STAGE_PACK_WAIT, STAGE_H2D,
-          STAGE_DEVICE_COMPUTE, STAGE_FINALIZE, STAGE_STITCH)
+STAGE_PACK_WAIT = 'pack_wait'
+STAGE_DEVICE_COMPUTE = 'device_compute'
+WAITS = (STAGE_PACK_WAIT, STAGE_DEVICE_COMPUTE)
+
+# Events buffered before a flush: a resident server's memory bound.
+FLUSH_EVENTS = 4096
+
+
+def _json_default(value: Any) -> Any:
+  """Numpy scalars (a count taken from a shape or nbytes) as plain
+  numbers; anything else by its text, so a flush never raises."""
+  item = getattr(value, 'item', None)
+  return item() if callable(item) else str(value)
+
+
+def _write_lines(fd: int, events: List[Dict[str, Any]]) -> None:
+  """One os.write of complete lines: writers that share a file
+  interleave by whole flushes, never inside a line."""
+  if fd < 0 or not events:
+    return
+  data = ''.join(
+      json.dumps(e, separators=(',', ':'), default=_json_default) + ',\n'
+      for e in events).encode()
+  while data:  # a short write continues where it stopped
+    data = data[os.write(fd, data):]
 
 
 class TraceWriter:
-  """Appends Chrome trace events to one (possibly shared) file."""
+  """Buffers Chrome trace events and appends them, whole lines at a
+  time, to one (possibly shared) file."""
 
   def __init__(self, path: str, tier: str = ''):
     self.path = path
@@ -71,6 +128,7 @@ class TraceWriter:
     except FileExistsError:
       pass
     self._fd = os.open(path, os.O_WRONLY | os.O_APPEND)  # guarded by: self._lock
+    self._events: List[Dict[str, Any]] = []  # guarded by: self._lock
     if tier:
       self._emit_raw({
           'name': 'process_name', 'ph': 'M', 'pid': self._pid, 'tid': 0,
@@ -78,9 +136,11 @@ class TraceWriter:
       })
 
   def _emit_raw(self, event: Dict[str, Any]) -> None:
-    line = (json.dumps(event, separators=(',', ':')) + ',\n').encode()
     with self._lock:
-      os.write(self._fd, line)
+      self._events.append(event)
+      full = len(self._events) >= FLUSH_EVENTS
+    if full:
+      self.flush()
 
   def complete_event(self, name: str, cat: str, ts_s: float, dur_s: float,
                      args: Optional[Dict[str, Any]] = None) -> None:
@@ -92,11 +152,28 @@ class TraceWriter:
         'args': args or {},
     })
 
+  def flush(self) -> None:
+    """Writes out what is buffered."""
+    with self._lock:
+      events, self._events = self._events, []
+      _write_lines(self._fd, events)
+
   def close(self) -> None:
     with self._lock:
+      events, self._events = self._events, []
+      _write_lines(self._fd, events)
       if self._fd >= 0:
         os.close(self._fd)
         self._fd = -1
+
+  def reset_after_fork(self) -> None:
+    """In a forked child: the parent's buffered events are the parent's
+    to write, and the lock may have been held by a thread that does not
+    exist here."""
+    self._lock = threading.Lock()
+    self._pid = os.getpid()
+    with self._lock:
+      self._events = []
 
 
 # Module state: one writer per process. `_writer is None` is the
@@ -105,10 +182,13 @@ class TraceWriter:
 # threads exist; after that the cell is read-only)
 _writer: Optional[TraceWriter] = None
 _local = threading.local()
+# Stage ids, unique in the process (next() on a count is atomic).
+_span_ids = itertools.count(1)
 
 
 def configure(path: Optional[str], tier: str = '') -> Optional[TraceWriter]:
-  """Enables tracing to `path` (None/'' disables). Returns the writer."""
+  """Enables tracing to `path` (None/'' disables, and writes out what
+  the last writer still held). Returns the writer."""
   global _writer
   if _writer is not None:
     _writer.close()
@@ -131,6 +211,23 @@ def enabled() -> bool:
 
 def writer() -> Optional[TraceWriter]:
   return _writer
+
+
+def flush() -> None:
+  """Writes out the buffered events (also runs at interpreter exit)."""
+  w = _writer
+  if w is not None:
+    w.flush()
+
+
+def _after_fork_in_child() -> None:
+  w = _writer
+  if w is not None:
+    w.reset_after_fork()
+
+
+atexit.register(flush)
+os.register_at_fork(after_in_child=_after_fork_in_child)
 
 
 def mint_trace_id() -> str:
@@ -165,8 +262,9 @@ def complete_event(name: str, cat: str, t0: float, t1: float,
 @contextlib.contextmanager
 def span(name: str, cat: str = 'stage',
          **args: Any) -> Iterator[None]:
-  """Context-managed stage span. The tracing-off path is one global
-  read and an empty yield."""
+  """Context-managed span with no id and no histogram (request-level
+  spans; pipeline stages use obs.stage). The tracing-off path is one
+  global read and an empty yield."""
   if _writer is None:
     yield
     return
@@ -175,3 +273,72 @@ def span(name: str, cat: str = 'stage',
     yield
   finally:
     complete_event(name, cat, t0, time.time(), args)
+
+
+def _profiler_annotation(name: str):
+  """jax.profiler.TraceAnnotation(name) where jax is already imported
+  (and far enough along to have its profiler), else None."""
+  jax = sys.modules.get('jax')
+  cls = getattr(getattr(jax, 'profiler', None), 'TraceAnnotation', None)
+  return cls(name) if cls is not None else None
+
+
+class Stage:
+  """One lexically scoped stage: `with obs.stage(registry, name, **args)`.
+
+  Feeds the same interval to the `stage_<name>_s` histogram and to the
+  span. With tracing on it takes a process-unique id, records the
+  enclosing stage of this thread as its parent, and pushes itself on
+  the thread's stack for its own children. `set()` adds counts that are
+  only known inside the block (bytes of a result). Once per submit or
+  per pack, never per window: it is a few microseconds, not free.
+  """
+
+  __slots__ = ('_registry', 'name', 'args', 't0', '_span', '_parent',
+               '_annotation')
+
+  def __init__(self, registry, name: str, args: Dict[str, Any]):
+    self._registry = registry
+    self.name = name
+    self.args = args
+    self.t0 = 0.0
+    self._span = 0
+    self._parent = 0
+    self._annotation = None
+
+  def set(self, **args: Any) -> None:
+    self.args.update(args)
+
+  def __enter__(self) -> 'Stage':
+    if _writer is not None:
+      stack = getattr(_local, 'stack', None)
+      if stack is None:
+        stack = _local.stack = []
+      self._span = next(_span_ids)
+      self._parent = stack[-1] if stack else 0
+      stack.append(self._span)
+      self._annotation = _profiler_annotation(self.name)
+      if self._annotation is not None:
+        self._annotation.__enter__()
+    self.t0 = time.time()
+    return self
+
+  def __exit__(self, exc_type, exc, tb) -> None:
+    t1 = time.time()
+    if self._registry is not None:
+      self._registry.observe(f'stage_{self.name}_s', t1 - self.t0)
+    if not self._span:
+      return
+    if self._annotation is not None:
+      self._annotation.__exit__(exc_type, exc, tb)
+    stack = _local.stack
+    if stack and stack[-1] == self._span:
+      stack.pop()
+    elif self._span in stack:
+      # A generator abandoned mid-stage left its stages above this one.
+      del stack[stack.index(self._span):]
+    args = self.args
+    args['span'] = self._span
+    if self._parent:
+      args['parent'] = self._parent
+    complete_event(self.name, CAT_STAGE, self.t0, t1, args)

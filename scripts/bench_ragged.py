@@ -7,7 +7,7 @@ bucket) and once with use_ragged_kernel (ONE pack stream, every width
 packed back-to-back into fixed [n_slots, R, slot_len] slots, a single
 compiled forward for the whole run). Prints one JSON line per variant
 (windows/s, padded-position fraction, per-bucket pack counts,
-n_forward_shapes, host-gap-per-pack from trace spans) plus a summary
+n_forward_shapes) plus a summary
 line with the measured speedup, the padding delta, and a delivery
 byte-identity verdict: every window's (ids, quals) from the ragged run
 must be identical to the bucketed run's. Exit 1 = identity violation
@@ -16,10 +16,9 @@ before reading the perf numbers.
 
 The padded-position fraction and n_forward_shapes are stream
 arithmetic (backend-independent); the windows/s delta means something
-only on a TPU and is not measured yet. The host-gap-per-pack number
-(device_compute gaps minus the h2d-transfer-covered portion, per pack)
-is the residency signal: a device-resident pack loop leaves
-transfer-only gaps.
+only on a TPU and is not measured yet. Where the host's time goes a
+pack is `dctpu trace` of a traced run (self time per stage); whether the
+device idles is read off the device's own trace (benchmark/).
 """
 import argparse
 import json
@@ -64,20 +63,6 @@ def _mixed_stream(params, np, buckets, n_windows, long_frac, seed=12):
   return stream, widths
 
 
-def _host_gap_per_pack(summarize_lib, trace_path, n_packs):
-  """device_compute gap accounting from the run's trace spans: the
-  residency number is host time per pack NOT covered by an H2D
-  transfer."""
-  events = summarize_lib.load_trace(trace_path)
-  gaps = summarize_lib.device_gaps(events)
-  return {
-      'n_gaps': gaps['n_gaps'],
-      'host_gap_per_pack_s': round(
-          gaps['host_gap_s'] / max(1, n_packs), 6),
-      'transfer_only_fraction': gaps['transfer_only_fraction'],
-  }
-
-
 def main():
   ap = argparse.ArgumentParser()
   ap.add_argument('--batch', type=int, default=1024)
@@ -93,8 +78,6 @@ def main():
                   help='also write the summary dict to this JSON path')
   args = ap.parse_args()
 
-  import tempfile
-
   import jax
   import jax.numpy as jnp
   import numpy as np
@@ -103,8 +86,6 @@ def main():
   from deepconsensus_tpu.inference import runner as runner_lib
   from deepconsensus_tpu.models import config as config_lib
   from deepconsensus_tpu.models import model as model_lib
-  from deepconsensus_tpu.obs import summarize as summarize_lib
-  from deepconsensus_tpu.obs import trace as trace_lib
 
   params = config_lib.get_config(args.config)
   config_lib.finalize_params(params, is_training=False)
@@ -118,7 +99,6 @@ def main():
   stream, widths = _mixed_stream(params, np, buckets, args.windows,
                                  args.long_frac)
   useful = int(widths.sum())
-  tmpdir = tempfile.mkdtemp(prefix='bench_ragged_')
 
   results = {}
   deliveries = {}
@@ -151,15 +131,10 @@ def main():
       for b in buckets:
         runner.predict(
             np.zeros((args.batch, params.total_rows, b, 1), np.float32))
-    trace_path = f'{tmpdir}/{name}_trace.jsonl'
-    trace_lib.configure(trace_path, tier='run')
-    try:
-      t0 = time.perf_counter()
-      engine.submit_formatted(stream, list(range(args.windows)))
-      engine.flush()
-      dt = time.perf_counter() - t0
-    finally:
-      trace_lib.configure(None)
+    t0 = time.perf_counter()
+    engine.submit_formatted(stream, list(range(args.windows)))
+    engine.flush()
+    dt = time.perf_counter() - t0
     stats = engine.stats()
     if use_ragged:
       rp = engine._ragged_packer
@@ -179,8 +154,6 @@ def main():
                               in stats['n_packs_by_bucket'].items()},
         'n_forward_shapes': stats.get('n_forward_shapes', 0),
         'n_starvation_flushes': stats.get('n_starvation_flushes', 0),
-        'host_gaps': _host_gap_per_pack(summarize_lib, trace_path,
-                                        engine.n_packs),
         'config': args.config,
     }
     results[name] = line

@@ -9,12 +9,16 @@ Covers the four obs subsystems in isolation plus their contracts:
   * trace spans — Chrome-trace JSONL framing (one `[` header however
     many writers share the file, atomic one-line appends), the
     tracing-off fast path, thread-local trace-id stamping, and the
-    record_stage contract that feeds the SAME measured interval to
-    both the histogram and the span (the reconciliation guarantee
-    bench.py asserts end to end);
-  * summarize — per-stage totals/coverage, critical-path ordering,
-    straggler extraction, span-derived overlap (launch-before-finalize
-    ordering), trace-group connectivity, corrupt-file typing;
+    record_stage / stage contract that feeds the SAME measured interval
+    to both the histogram and the span (the reconciliation guarantee
+    bench.py asserts end to end), the per-thread stage stack behind
+    `args.span` / `args.parent`, and the buffered writer (nothing
+    before the threshold, everything at close, whole lines, an empty
+    buffer after a fork);
+  * summarize — per-stage totals, self time through `args.parent`,
+    waits apart, critical-path ordering by self time, straggler
+    extraction, span-derived overlap (launch-before-finalize ordering),
+    trace-group connectivity, corrupt-file typing;
   * profiler — guarded on-demand capture status dicts;
 
 plus the `dctpu trace` CLI and dead-letter trace-id stamping.
@@ -224,6 +228,228 @@ class TestTraceSpans:
     assert not trace_lib.enabled()
 
 
+class TestStage:
+
+  def _events(self, path):
+    return [e for e in summarize_lib.load_trace(path) if e['ph'] == 'X']
+
+  def test_nesting_gives_span_and_parent(self, tmp_path):
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    reg = metrics_lib.MetricsRegistry()
+    with obs_lib.stage(reg, 'submit', n_windows=3) as outer:
+      with obs_lib.stage(reg, 'format_rows') as st:
+        st.set(n_rows=3, bytes=12)
+      with obs_lib.stage(reg, 'dispatch'):
+        with obs_lib.stage(reg, 'pack_cast'):
+          pass
+    with obs_lib.stage(reg, 'flush'):
+      pass
+    trace_lib.configure(None)
+    events = {e['name']: e for e in self._events(path)}
+    assert set(events) == {'submit', 'format_rows', 'dispatch',
+                           'pack_cast', 'flush'}
+    ids = {name: e['args']['span'] for name, e in events.items()}
+    assert len(set(ids.values())) == 5
+    assert 'parent' not in events['submit']['args']
+    assert 'parent' not in events['flush']['args']
+    assert events['format_rows']['args']['parent'] == ids['submit']
+    assert events['dispatch']['args']['parent'] == ids['submit']
+    assert events['pack_cast']['args']['parent'] == ids['dispatch']
+    assert events['format_rows']['args']['bytes'] == 12
+    assert events['submit']['args']['n_windows'] == 3
+    assert all(e['cat'] == 'stage' for e in events.values())
+    assert outer.name == 'submit'
+    assert reg.histogram('stage_pack_cast_s').snapshot()['count'] == 1
+
+  def test_threads_do_not_see_each_others_stack(self, tmp_path):
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+      with obs_lib.stage(None, 'featurize'):
+        with obs_lib.stage(None, 'decode'):
+          inside.set()
+          assert release.wait(10)
+
+    t = threading.Thread(target=other)
+    with obs_lib.stage(None, 'submit'):
+      t.start()
+      assert inside.wait(10)
+      # The other thread is two stages deep right now.
+      with obs_lib.stage(None, 'dispatch'):
+        pass
+      release.set()
+      t.join(10)
+    assert not t.is_alive()
+    trace_lib.configure(None)
+    events = {e['name']: e for e in self._events(path)}
+    assert events['dispatch']['args']['parent'] == (
+        events['submit']['args']['span'])
+    assert 'parent' not in events['featurize']['args']
+    assert events['decode']['args']['parent'] == (
+        events['featurize']['args']['span'])
+    assert events['decode']['tid'] != events['dispatch']['tid']
+
+  def test_stage_records_on_error_and_unwinds_the_stack(self, tmp_path):
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    with pytest.raises(RuntimeError):
+      with obs_lib.stage(None, 'submit'):
+        with obs_lib.stage(None, 'dispatch'):
+          raise RuntimeError('boom')
+    with obs_lib.stage(None, 'flush'):
+      pass
+    trace_lib.configure(None)
+    events = {e['name']: e for e in self._events(path)}
+    assert set(events) == {'submit', 'dispatch', 'flush'}
+    assert 'parent' not in events['flush']['args']
+
+  def test_off_opens_no_file_and_builds_no_event(self, tmp_path,
+                                                 monkeypatch):
+    monkeypatch.delenv(trace_lib.ENV_TRACE, raising=False)
+    assert trace_lib.configure_from_env() is None
+    opened = []
+    monkeypatch.setattr(trace_lib.os, 'open',
+                        lambda *a, **k: opened.append(a))
+    monkeypatch.setattr(trace_lib, 'complete_event',
+                        lambda *a, **k: opened.append(a))
+    reg = metrics_lib.MetricsRegistry()
+    with obs_lib.stage(reg, 'submit', n_windows=1) as st:
+      st.set(bytes=4)
+      with obs_lib.stage(reg, 'dispatch'):
+        pass
+    assert not opened
+    assert not getattr(trace_lib._local, 'stack', None)
+    assert reg.histogram('stage_submit_s').snapshot()['count'] == 1
+    assert reg.histogram('stage_dispatch_s').snapshot()['count'] == 1
+
+  def test_record_stage_wait_category_has_no_ids(self, tmp_path):
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    with obs_lib.stage(None, 'submit'):
+      obs_lib.record_stage(None, trace_lib.STAGE_PACK_WAIT, 1.0, 2.0,
+                           cat=trace_lib.CAT_WAIT, bucket=100)
+    trace_lib.configure(None)
+    wait = next(e for e in self._events(path) if e['name'] == 'pack_wait')
+    assert wait['cat'] == 'wait'
+    assert wait['args'] == {'bucket': 100}
+
+
+class TestBufferedWriter:
+
+  def _lines(self, path):
+    return open(path).read().splitlines()
+
+  def test_nothing_before_the_threshold_everything_at_close(
+      self, tmp_path, monkeypatch):
+    monkeypatch.setattr(trace_lib, 'FLUSH_EVENTS', 8)
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='serve')  # one metadata event
+    for i in range(6):
+      trace_lib.complete_event('stitch', 'stage', float(i), i + 0.5)
+    assert self._lines(path) == ['[']
+    trace_lib.complete_event('stitch', 'stage', 6.0, 6.5)  # the eighth
+    assert len(self._lines(path)) == 1 + 8
+    for i in range(3):
+      trace_lib.complete_event('stitch', 'stage', float(i), i + 0.5)
+    assert len(self._lines(path)) == 1 + 8
+    trace_lib.configure(None)
+    lines = self._lines(path)
+    assert len(lines) == 1 + 11
+    assert all(line.endswith('},') for line in lines[1:])
+    assert len(summarize_lib.load_trace(path)) == 11
+
+  def test_flush_writes_out_and_keeps_tracing(self, tmp_path):
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path)
+    trace_lib.complete_event('stitch', 'stage', 0.0, 1.0)
+    assert self._lines(path) == ['[']
+    trace_lib.flush()
+    assert len(self._lines(path)) == 2
+    trace_lib.flush()  # nothing twice
+    assert len(self._lines(path)) == 2
+    assert trace_lib.enabled()
+
+  def test_one_write_per_flush_of_whole_lines(self, tmp_path, monkeypatch):
+    path = str(tmp_path / 'trace.jsonl')
+    writer = trace_lib.TraceWriter(path)
+    for i in range(5):
+      writer.complete_event('stitch', 'stage', float(i), 0.5, {'i': i})
+    writes = []
+    real_write = os.write
+    monkeypatch.setattr(
+        trace_lib.os, 'write',
+        lambda fd, data: writes.append(data) or real_write(fd, data))
+    writer.close()
+    assert len(writes) == 1
+    assert writes[0].endswith(b',\n') and writes[0].count(b'\n') == 5
+
+  def test_two_writers_interleave_whole_lines(self, tmp_path, monkeypatch):
+    monkeypatch.setattr(trace_lib, 'FLUSH_EVENTS', 16)
+    path = str(tmp_path / 'shared.jsonl')
+    writers = [trace_lib.TraceWriter(path, tier=t) for t in ('a', 'b')]
+    n = 200
+
+    def emit(w, tag):
+      for i in range(n):
+        w.complete_event(tag, 'stage', float(i), 0.25,
+                         {'i': i, 'pad': 'x' * 200})
+
+    threads = [threading.Thread(target=emit, args=(w, t))
+               for w, t in zip(writers, ('route', 'serve_request'))]
+    for t in threads:
+      t.start()
+    for t in threads:
+      t.join(30)
+    assert not any(t.is_alive() for t in threads)
+    for w in writers:
+      w.close()
+    lines = self._lines(path)
+    assert lines.count('[') == 1 and lines[0] == '['
+    events = summarize_lib.load_trace(path)  # every line parses whole
+    for tag in ('route', 'serve_request'):
+      assert [e['args']['i'] for e in events if e['name'] == tag] == (
+          list(range(n)))
+
+  def test_numpy_counts_in_args_do_not_break_the_flush(self, tmp_path):
+    import numpy as np
+
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path)
+    trace_lib.complete_event('pack_cut', 'stage', 0.0, 1.0,
+                             {'n_rows': np.int64(7), 'what': object})
+    trace_lib.configure(None)
+    event = summarize_lib.load_trace(path)[0]
+    assert event['args']['n_rows'] == 7
+    assert isinstance(event['args']['what'], str)
+
+  @pytest.mark.skipif(not hasattr(os, 'fork'), reason='needs fork')
+  def test_forked_child_starts_with_an_empty_buffer(self, tmp_path):
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path)
+    trace_lib.complete_event('parent_before', 'stage', 0.0, 1.0)
+    pid = os.fork()
+    if pid == 0:  # the child: one event of its own, written by hand
+      try:
+        trace_lib.complete_event('child', 'stage', 1.0, 2.0)
+        trace_lib.flush()
+      finally:
+        os._exit(0)
+    _, status = os.waitpid(pid, 0)
+    assert status == 0
+    trace_lib.complete_event('parent_after', 'stage', 2.0, 3.0)
+    trace_lib.configure(None)
+    events = summarize_lib.load_trace(path)
+    names = sorted(e['name'] for e in events)
+    assert names == ['child', 'parent_after', 'parent_before']
+    by_name = {e['name']: e for e in events}
+    assert by_name['child']['pid'] == pid
+    assert by_name['parent_before']['pid'] == os.getpid()
+
+
 class TestRecordStage:
 
   def test_feeds_histogram_and_span_same_interval(self, tmp_path):
@@ -302,14 +528,18 @@ class TestSummarize:
     assert s['wall_s'] == pytest.approx(4.0)
     assert s['tiers'] == {1: 'dctpu-run'}
 
-  def test_critical_path_ordering(self):
+  def test_critical_path_orders_by_self_time_and_leaves_waits_out(self):
     s = summarize_lib.summarize(self._pipeline_events())
-    # device_compute spans [1.3, 1.8] U [1.5, 3.5] -> 2.2s coverage,
-    # the largest single-stage coverage -> top of the critical path.
+    # finalize_drain did 0.5 + 1.6 s of work on its thread; the waits
+    # (device_compute 2.5 s, pack_wait) are longer and never enter.
     top = s['critical_path'][0]
-    assert top['stage'] == 'device_compute'
-    assert top['coverage_s'] == pytest.approx(2.2)
-    assert top['fraction_of_wall'] == pytest.approx(2.2 / 4.0, abs=1e-3)
+    assert top['stage'] == 'finalize_drain'
+    assert top['self_s'] == pytest.approx(2.1)
+    assert top['fraction_of_wall'] == pytest.approx(2.1 / 4.0, abs=1e-3)
+    names = [row['stage'] for row in s['critical_path']]
+    assert 'device_compute' not in names and 'pack_wait' not in names
+    assert s['waits']['device_compute'] == {'total_s': 2.5, 'count': 2}
+    assert s['waits']['pack_wait']['count'] == 1
 
   def test_span_overlap_rule(self):
     overlap = summarize_lib.span_overlap(self._pipeline_events())
@@ -344,65 +574,62 @@ class TestSummarize:
     assert overlap['n_overlapped'] == 2  # pack 1 (early launch) + pack 2
     assert overlap['n_direct'] == 1
 
-  def test_device_gaps_fully_transfer_covered(self):
-    """Resident pack loop: each inter-compute gap exactly holds the
-    next pack's H2D -> zero host gap, transfer_only_fraction 1.0."""
-    events = []
-    for k in range(3):
-      events.append(_span('h2d_transfer', 1.1 * k + 1.0, 0.1, pack=k))
-      events.append(_span('device_compute', 1.1 * k, 1.0, pack=k))
-    gaps = summarize_lib.device_gaps(events)
-    assert gaps['n_gaps'] == 2
-    assert gaps['gap_s'] == pytest.approx(0.2)
-    assert gaps['transfer_s'] == pytest.approx(0.2)
-    assert gaps['host_gap_s'] == pytest.approx(0.0, abs=1e-9)
-    assert gaps['transfer_only_fraction'] == 1.0
-
-  def test_device_gaps_partial_coverage_is_host_time(self):
-    """Half of a 1s gap covered by H2D: the other half is host work on
-    the critical path (pack assembly, weight re-transfer, python)."""
-    events = [
-        _span('device_compute', 0.0, 1.0, pack=0),
-        _span('h2d_transfer', 1.2, 0.5, pack=1),
-        _span('device_compute', 2.0, 1.0, pack=1),
+  def _nested_events(self):
+    """One submit of 10 s: stack 2 s, format 3 s, a dispatch of 2 s that
+    holds a cast of 1.5 s, a drain of 1 s; 2 s are the submit's own. A
+    pack_wait of 10 s and a device_compute of 30 s lie over it all."""
+    return [
+        _span('submit', 0.0, 10.0, span=1),
+        _span('stack_windows', 0.0, 2.0, span=2, parent=1),
+        _span('format_rows', 2.0, 3.0, span=3, parent=1),
+        _span('dispatch', 6.0, 2.0, span=4, parent=1),
+        _span('pack_cast', 6.0, 1.5, span=5, parent=4),
+        _span('finalize_drain', 8.5, 1.0, span=6, parent=1),
+        _span('pack_wait', 0.0, 10.0, cat='wait', bucket=100),
+        _span('device_compute', 0.0, 30.0, cat='wait', pack=1),
+        # Same ids in another process: never this submit's children.
+        _span('submit', 0.0, 4.0, pid=2, span=1),
+        _span('format_rows', 1.0, 1.0, pid=2, span=2, parent=1),
+        # Stamped after the fact: no id, its self time is its duration.
+        _span('featurize', 0.0, 5.0),
     ]
-    gaps = summarize_lib.device_gaps(events)
-    assert gaps['n_gaps'] == 1
-    assert gaps['gap_s'] == pytest.approx(1.0)
-    assert gaps['transfer_s'] == pytest.approx(0.5)
-    assert gaps['host_gap_s'] == pytest.approx(0.5)
-    assert gaps['max_host_gap_s'] == pytest.approx(0.5)
-    assert gaps['transfer_only_fraction'] == pytest.approx(0.5)
 
-  def test_device_gaps_clips_transfers_and_isolates_pids(self):
-    """H2D spans clip to the gap they cover (overlap-running transfers
-    don't inflate coverage), and compute on another pid never pairs."""
-    events = [
-        _span('device_compute', 0.0, 1.0, pack=0),
-        # Transfer starts inside compute and runs past the gap start:
-        # only its in-gap portion counts.
-        _span('h2d_transfer', 0.5, 0.7, pack=1),
-        _span('device_compute', 1.5, 1.0, pack=1),
-        _span('device_compute', 5.0, 1.0, pid=2, pack=0),
-    ]
-    gaps = summarize_lib.device_gaps(events)
-    assert gaps['n_gaps'] == 1
-    assert gaps['gap_s'] == pytest.approx(0.5)
-    assert gaps['transfer_s'] == pytest.approx(0.2)
-    assert gaps['host_gap_s'] == pytest.approx(0.3)
+  def test_self_time_through_parent_ids(self):
+    st = summarize_lib.self_times(self._nested_events())
+    assert st['submit'] == {'total_s': 14.0, 'self_s': 2.0 + 3.0,
+                            'count': 2, 'under': ['']}
+    assert st['dispatch']['self_s'] == pytest.approx(0.5)
+    assert st['dispatch']['under'] == ['submit']
+    assert st['pack_cast'] == {'total_s': 1.5, 'self_s': 1.5, 'count': 1,
+                               'under': ['dispatch']}
+    assert st['format_rows']['total_s'] == pytest.approx(4.0)
+    assert st['format_rows']['self_s'] == pytest.approx(4.0)
+    assert st['featurize']['self_s'] == pytest.approx(5.0)
+    assert 'pack_wait' not in st and 'device_compute' not in st
+    # One thread per process: self times add up to the top-level totals.
+    assert sum(r['self_s'] for r in st.values()) == pytest.approx(
+        10.0 + 4.0 + 5.0)
 
-  def test_device_gaps_no_computes(self):
-    gaps = summarize_lib.device_gaps([_span('featurize', 0.0, 1.0)])
-    assert gaps['n_gaps'] == 0
-    assert gaps['gap_s'] == 0.0
-    # No gap time at all = nothing attributable to the host.
-    assert gaps['transfer_only_fraction'] == 1.0
-
-  def test_summary_and_text_include_device_gaps(self):
-    s = summarize_lib.summarize(self._pipeline_events())
-    assert 'device_gaps' in s
+  def test_summary_lists_waits_apart_and_orders_by_self_time(self):
+    s = summarize_lib.summarize(self._nested_events())
+    assert s['waits'] == {'device_compute': {'total_s': 30.0, 'count': 1},
+                          'pack_wait': {'total_s': 10.0, 'count': 1}}
+    order = [row['stage'] for row in s['critical_path']]
+    assert order[:3] == ['featurize', 'submit', 'format_rows']
+    assert 'pack_wait' not in order and 'device_compute' not in order
+    # Totals still cover the waits (bench.py reconciles them with the
+    # histograms); stragglers and overlap still read device_compute.
+    assert s['stage_totals_s']['device_compute'] == pytest.approx(30.0)
+    assert s['stragglers'][0]['pack'] == 1
     text = summarize_lib.format_summary(s)
-    assert 'device gaps' in text
+    assert 'self time per stage' in text
+    assert 'waits (intervals between two events' in text
+    assert 'gaps' not in text and 'coverage' not in text
+    # No gap accounting from host spans, no coverage: the keys are these.
+    assert set(s) == {
+        'n_events', 'n_spans', 'wall_s', 'tiers', 'stage_totals_s',
+        'stage_counts', 'self_time', 'waits', 'critical_path', 'stragglers',
+        'overlap', 'n_traces'}
 
   def test_stragglers_slowest_decile(self):
     events = [

@@ -355,11 +355,12 @@ def test_adversarial_interleave_byte_identity_dp8(real_setup):
 # Residency: trace spans through `dctpu trace --json`
 
 
-def test_traced_ragged_run_reports_device_gaps(real_setup, tmp_path,
-                                               capsys):
+def test_traced_ragged_run_reports_self_time(real_setup, tmp_path,
+                                             capsys):
   """A live traced ragged run drives the full span pipeline: every
-  pack gets an h2d_transfer and a device_compute span at ONE bucket
-  (the slot length), and the summary exposes the device_gaps block."""
+  pack gets a dispatch with its h2d_transfer, and a device_compute wait
+  at ONE bucket (the slot length); the summary gives self time per
+  stage and lists the waits apart."""
   from deepconsensus_tpu import cli
 
   path = str(tmp_path / 'ragged_trace.jsonl')
@@ -374,20 +375,28 @@ def test_traced_ragged_run_reports_device_gaps(real_setup, tmp_path,
   assert payload['stage_counts']['device_compute'] == eng.n_packs
   assert payload['stage_counts']['h2d_transfer'] == eng.n_packs
   assert payload['overlap']['n_packs'] == eng.n_packs
-  gaps = payload['device_gaps']
-  # Pipelined packs overlap their compute spans, so a run can show
-  # FEWER gaps than packs — never more.
-  assert 0 <= gaps['n_gaps'] <= eng.n_packs - 1
-  assert 0.0 <= gaps['transfer_only_fraction'] <= 1.0
+  assert not [key for key in payload if 'gaps' in key]
+  self_time = payload['self_time']
+  assert self_time['dispatch']['count'] == eng.n_packs
+  assert set(self_time['dispatch']['under']) <= {'flush', 'submit'}
+  assert self_time['pack_cast']['under'] == ['dispatch']
+  assert self_time['deliver']['count'] == eng.n_packs
+  assert set(payload['waits']) == {'device_compute', 'pack_wait'}
+  assert not set(payload['waits']) & set(self_time)
+  # submit_formatted of a list: stacked, never formatted.
+  assert 'format_rows' not in self_time
+  for row in self_time.values():
+    assert 0.0 <= row['self_s'] <= row['total_s'] + 1e-9
 
 
-def test_resident_pack_loop_trace_is_transfer_only(tmp_path, capsys):
+def test_resident_pack_loop_trace_counts_overlap_and_waits(tmp_path, capsys):
   """The residency acceptance fixture: a device-resident pack loop's
   trace — back-to-back device_compute spans whose gaps hold only the
   next pack's h2d_transfer, drains batched at end-of-input (so no
-  finalize_drain span per pack). `dctpu trace --json` must attribute
-  every inter-compute gap to transfers and count every drain-free
-  pack's launch as overlapped."""
+  finalize_drain span per pack). `dctpu trace --json` must count every
+  drain-free pack's launch as overlapped, and read a trace written
+  before the `wait` category existed: device_compute is a wait by its
+  name, so the transfers are the only work on the critical path."""
   from deepconsensus_tpu import cli
 
   def span(name, ts_s, dur_s, **args):
@@ -413,9 +422,8 @@ def test_resident_pack_loop_trace_is_transfer_only(tmp_path, capsys):
   assert payload['overlap']['n_packs'] == 4
   assert payload['overlap']['n_overlapped'] == 4
   assert payload['overlap']['span_overlap_fraction'] == 1.0
-  gaps = payload['device_gaps']
-  assert gaps['n_gaps'] == 3
-  assert gaps['gap_s'] == pytest.approx(0.3)
-  assert gaps['transfer_s'] == pytest.approx(0.3)
-  assert gaps['host_gap_s'] == pytest.approx(0.0, abs=1e-9)
-  assert gaps['transfer_only_fraction'] == 1.0
+  assert not [key for key in payload if 'gaps' in key]
+  assert payload['waits'] == {
+      'device_compute': {'total_s': pytest.approx(3.6), 'count': 4}}
+  assert [r['stage'] for r in payload['critical_path']] == ['h2d_transfer']
+  assert payload['self_time']['h2d_transfer']['self_s'] == pytest.approx(0.4)
