@@ -40,10 +40,13 @@ import numpy as np
 from deepconsensus_tpu import faults as faults_lib
 from deepconsensus_tpu import obs as obs_lib
 from deepconsensus_tpu.calibration import lib as calibration_lib
+from deepconsensus_tpu.models import data as data_lib
 from deepconsensus_tpu.preprocess.pileup import row_indices
 from deepconsensus_tpu.utils import phred
 
 Ticket = Any
+# One compact pack on the host: (main_u8 [B, R - 4, L, 1], sn [B, 4]).
+PackBuffer = Tuple[np.ndarray, np.ndarray]
 DeliverFn = Callable[[Ticket, np.ndarray, np.ndarray], None]
 PackFailureFn = Callable[[Sequence[Ticket], int, BaseException], None]
 
@@ -151,6 +154,9 @@ class _PackerBase:
     self.n_oom_bisections = 0
     self.n_device_faults = 0
     self.n_dispatch_timeouts = 0
+    # Pack buffers ever allocated (pool misses); the ragged packer has
+    # no pool and stays at 0.
+    self.n_pack_buffers_allocated = 0
     self.model_wall = 0.0
 
   def poison(self, ticket: Ticket) -> None:
@@ -223,12 +229,25 @@ class _PackerBase:
 class _WindowPacker(_PackerBase):
   """Cross-batch window packer feeding the fixed-shape compiled forward.
 
-  Formatted model-input rows accumulate across submissions; full
-  batch_size packs are cut and dispatched as soon as they exist, so in
-  steady state the forward never runs padded and the dispatch pipeline
-  never drains at submission seams (only the end-of-input tail pads).
-  Up to dispatch_depth packs stay in flight; draining the oldest hands
-  its (ids, quals) rows to deliver(), one call per ticket.
+  The packer owns batch-sized compact pack buffers (`main_u8`
+  [batch, total_rows - 4, L, 1] uint8 and `sn` [batch, 4] float32: what
+  the device receives, models/data.py). Every submitted window is
+  written once, at its final row of the pack being filled, clipped and
+  cast on the way (data.fill_pack); a pack is cut and dispatched the
+  moment it is full, so in steady state the forward never runs padded
+  and the dispatch pipeline never drains at submission seams. A partial
+  pack stays in its buffer until the next submission; only flush()
+  dispatches one, its unfilled rows zeroed. Up to dispatch_depth packs
+  stay in flight; draining the oldest hands its (ids, quals) rows to
+  deliver(), one call per ticket.
+
+  Buffers come from a free list and go back to it only when their pack
+  has been drained and delivered, or failed and routed: the transfer is
+  asynchronous (and may alias host memory on the CPU backend), and
+  under on_device_error=degrade a device fault bisects or resubmits the
+  pack from its buffer. At most dispatch_depth + 1 buffers are alive at
+  once (dispatch_depth in flight and the one being filled);
+  n_pack_buffers_allocated counts them.
 
   A pack that fails to dispatch or finalize is routed to
   on_pack_failure(tickets, pack_seq, error) — ticket bookkeeping plus
@@ -236,9 +255,7 @@ class _WindowPacker(_PackerBase):
   on_device_error=degrade, typed device faults are absorbed first:
   RESOURCE_EXHAUSTED bisects the pack (retry at half batch), a
   lost/halted device rebuilds the mesh one dp step down and resubmits
-  everything that was in flight, in featurize order. Degrade mode
-  retains each in-flight pack's host rows to make that resubmission
-  possible (up to dispatch_depth packs of extra host memory).
+  everything that was in flight, in featurize order.
   """
 
   def __init__(self, runner, options, timing_rows: List[Dict[str, Any]],
@@ -249,21 +266,46 @@ class _WindowPacker(_PackerBase):
                      deliver, poisoned, pack_clock)
     self._batch = options.batch_size
     self._degrade = getattr(options, 'on_device_error', 'fail') == 'degrade'
-    self._rows: List[np.ndarray] = []
+    # The pack being filled, (main_u8, sn): rows [:_buffered] are
+    # written, for the tickets in _tickets.
+    self._buf: Optional[PackBuffer] = None
     self._tickets: List[Ticket] = []
+    self._free: List[PackBuffer] = []
     # Clock reading when the current buffered tail started waiting.
     self._starve_mark = 0
 
-  def add(self, rows: np.ndarray, tickets: Sequence[Ticket]) -> None:
-    """Buffers one submission's formatted model rows ([k, R, L, 1],
-    aligned with tickets) and dispatches every full pack now cuttable."""
-    if not self._buffered:
-      self._starve_mark = self._pack_clock[0]
-      self._t_buf_start = time.time()
-    self._rows.append(rows)
-    self._tickets.extend(tickets)
-    self._buffered += len(rows)
-    self._cut_packs(flush=False)
+  def add(self, windows, tickets: Sequence[Ticket],
+          layout: 'data_lib.PackLayout') -> None:
+    """Writes one submission's windows (a list of [H, L, 1] tensors or a
+    [k, H, L, 1] array, one width, aligned with tickets) into the pack
+    being filled, dispatching every pack the moment it is full."""
+    n = len(windows)
+    done = 0
+    while done < n:
+      if not self._buffered:
+        self._starve_mark = self._pack_clock[0]
+        self._t_buf_start = time.time()
+      take = min(n - done, self._batch - self._buffered)
+      with obs_lib.stage(self._obs, obs_lib.trace.STAGE_FORMAT) as st:
+        if self._buf is None:
+          self._buf = self._take_buffer(layout.n_main, windows[0].shape[1:])
+        main_u8, sn = self._buf
+        data_lib.fill_pack(windows[done:done + take], layout, main_u8, sn,
+                           at=self._buffered)
+        st.set(n_rows=take,
+               bytes=take * (main_u8[0].nbytes + sn[0].nbytes),
+               bytes_read=take * windows[0].nbytes)
+      self._tickets.extend(tickets[done:done + take])
+      self._buffered += take
+      done += take
+      self._cut_packs(flush=False)
+
+  def _take_buffer(self, n_main: int, window_shape) -> PackBuffer:
+    if self._free:
+      return self._free.pop()
+    self.n_pack_buffers_allocated += 1
+    return (np.zeros((self._batch, n_main) + tuple(window_shape), np.uint8),
+            np.zeros((self._batch, data_lib.SN_ROWS), np.float32))
 
   def maybe_flush_starved(self, limit: int) -> None:
     """Bucket starvation flush: if this packer's partial tail has sat
@@ -282,71 +324,72 @@ class _WindowPacker(_PackerBase):
       self._cut_packs(flush=True)
 
   def _cut_packs(self, flush: bool) -> None:
-    while self._buffered >= self._batch or (flush and self._buffered):
-      with obs_lib.stage(self._obs, obs_lib.trace.STAGE_PACK_CUT) as st:
-        copied = 0
-        if len(self._rows) > 1:
-          # The carried tail and the new rows, copied into one array.
-          self._rows = [np.concatenate(self._rows)]
-          copied = self._rows[0].nbytes
-        buf = self._rows[0]
-        n = min(self._batch, self._buffered)
-        pack, rest = buf[:n], buf[n:]
-        self._rows = [rest] if len(rest) else []
-        tickets = self._tickets[:n]
-        del self._tickets[:n]
-        self._buffered -= n
-        st.set(n_rows=n, bytes_concatenated=copied)
-      self._dispatch(pack, tickets)
+    """Cuts the pack being filled if it is full (or, on flush, begun):
+    the buffer and its tickets leave the packer whole, nothing is
+    copied. A short pack's unfilled rows are zeroed, since a reused
+    buffer holds an earlier pack there."""
+    if self._buffered < self._batch and not (flush and self._buffered):
+      return
+    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_PACK_CUT) as st:
+      n = self._buffered
+      buf, self._buf = self._buf, None
+      if n < self._batch:
+        for plane in buf:
+          plane[n:] = 0
+      tickets, self._tickets = self._tickets, []
+      self._buffered = 0
+      st.set(n_rows=n, bytes_concatenated=0)
+    self._dispatch(buf, n, tickets)
 
-  def _dispatch(self, pack: np.ndarray, tickets: List[Ticket]) -> None:
+  def _dispatch(self, buf: PackBuffer, n: int,
+                tickets: List[Ticket]) -> None:
     seq = self.n_packs
     self.n_packs += 1
     self._pack_clock[0] += 1
     self._starve_mark = self._pack_clock[0]
-    self._stamp_pack_wait(int(pack.shape[2]), len(pack))
-    self.n_pack_rows += len(pack)
-    self.n_pad_rows += self._batch - len(pack)
+    self._stamp_pack_wait(int(buf[0].shape[2]), n)
+    self.n_pack_rows += n
+    self.n_pad_rows += self._batch - n
     try:
       self._raise_if_poisoned(tickets, f'pack {seq}')
-      handle = self._runner.dispatch(pack)
+      handle = self._runner.dispatch_pack(*buf, n_rows=n)
     except Exception as e:
-      self._handle_pack_fault(pack if self._degrade else None,
-                              tickets, seq, e)
+      self._handle_pack_fault(buf, n, tickets, seq, e)
+      self._free.append(buf)
       return
-    # Degrade mode keeps the host rows so a device fault can bisect or
-    # resubmit the pack; fail mode drops them (steady-state memory).
-    self._in_flight.append(
-        (handle, tickets, seq, pack if self._degrade else None))
+    # The entry holds the buffer until the pack is drained or routed: the
+    # transfer may still be reading it, and degrade mode retries from it.
+    self._in_flight.append((handle, tickets, seq, buf, n))
     while len(self._in_flight) > self._depth:
       self._drain_one()
 
   def _drain_one(self) -> None:
-    handle, tickets, seq, pack = self._in_flight.popleft()
+    handle, tickets, seq, buf, n = self._in_flight.popleft()
     t0 = time.time()
     try:
       pred_ids, quality = self._runner.finalize(handle)
     except Exception as e:
-      self._handle_pack_fault(pack, tickets, seq, e)
-      return
-    self._deliver_pack(tickets, pred_ids, quality, t0)
+      self._handle_pack_fault(buf, n, tickets, seq, e)
+    else:
+      self._deliver_pack(tickets, pred_ids, quality, t0)
+    self._free.append(buf)
 
   def _deliver_rows(self, tickets: List[Ticket], ids_u8: np.ndarray,
                     quals_u8: np.ndarray) -> None:
     for ticket, row_ids, row_quals in zip(tickets, ids_u8, quals_u8):
       self._deliver(ticket, row_ids, row_quals)
 
-  def _handle_pack_fault(self, pack: Optional[np.ndarray],
+  def _handle_pack_fault(self, pack: PackBuffer, n: int,
                          tickets: List[Ticket], seq: int,
                          error: BaseException,
                          batch_size: Optional[int] = None) -> None:
-    """Device-fault policy for one failed pack.
+    """Device-fault policy for one failed pack (`pack`: its buffer, or
+    a bisected slice of it, the first `n` rows written).
 
     Classifies the error into the DeviceFault family; under
-    on_device_error=degrade (and with the pack's host rows retained)
-    OOM bisects and a lost device degrades the mesh. Anything
-    unrecovered routes to on_pack_failure with the classified error,
-    so dead-letters carry the device-fault kind.
+    on_device_error=degrade OOM bisects and a lost device degrades the
+    mesh. Anything unrecovered routes to on_pack_failure with the
+    classified error, so dead-letters carry the device-fault kind.
     """
     error = faults_lib.classify_device_error(error)
     if isinstance(error, faults_lib.DeviceFault):
@@ -355,18 +398,18 @@ class _WindowPacker(_PackerBase):
         # The watchdog already bounded the loss; retrying a hung
         # device at the same (or any) shape would hang again.
         self.n_dispatch_timeouts += 1
-      elif self._degrade and pack is not None:
+      elif self._degrade:
         if isinstance(error, faults_lib.DeviceOomError):
-          if self._bisect(pack, tickets, seq,
+          if self._bisect(pack, n, tickets, seq,
                           batch_size or self._batch):
             return
         elif isinstance(error, faults_lib.DeviceLostError):
-          if self._try_degrade(pack, tickets, seq):
+          if self._try_degrade(pack, n, tickets, seq):
             return
     self._on_pack_failure(tickets, seq, error)
 
-  def _bisect(self, pack: np.ndarray, tickets: List[Ticket], seq: int,
-              batch_size: int) -> bool:
+  def _bisect(self, pack: PackBuffer, n: int, tickets: List[Ticket],
+              seq: int, batch_size: int) -> bool:
     """OOM bisection: retry the pack as halves at half batch shape.
 
     Floors at mesh-dp divisibility (the compiled batch must still
@@ -378,12 +421,12 @@ class _WindowPacker(_PackerBase):
     if half < 1 or half % dp:
       return False
     self.n_oom_bisections += 1
-    for lo in range(0, len(pack), half):
-      self._run_pack_at(pack[lo:lo + half], tickets[lo:lo + half],
-                        seq, half)
+    for lo in range(0, n, half):
+      self._run_pack_at(tuple(plane[lo:lo + half] for plane in pack),
+                        min(half, n - lo), tickets[lo:lo + half], seq, half)
     return True
 
-  def _try_degrade(self, pack: np.ndarray, tickets: List[Ticket],
+  def _try_degrade(self, pack: PackBuffer, n: int, tickets: List[Ticket],
                    seq: int) -> bool:
     """Mesh degradation: rebuild at the next lower dp and resubmit the
     failed pack plus everything else in flight (launched on the dead
@@ -391,25 +434,28 @@ class _WindowPacker(_PackerBase):
     degrade = getattr(self._runner, 'degrade_mesh', None)
     if degrade is None or not degrade():
       return False
-    pending = [(pack, tickets, seq)]
+    pending = [(pack, n, tickets, seq)]
     while self._in_flight:
-      _handle, ts, s, p = self._in_flight.popleft()
-      pending.append((p, ts, s))
-    for p, ts, s in sorted(pending, key=lambda entry: entry[2]):
-      self._run_pack_at(p, ts, s, self._batch)
+      _handle, ts, s, p, rows = self._in_flight.popleft()
+      pending.append((p, rows, ts, s))
+      # Resubmitted synchronously below; free for the next fill after.
+      self._free.append(p)
+    for p, rows, ts, s in sorted(pending, key=lambda entry: entry[3]):
+      self._run_pack_at(p, rows, ts, s, self._batch)
     return True
 
-  def _run_pack_at(self, pack: np.ndarray, tickets: List[Ticket],
+  def _run_pack_at(self, pack: PackBuffer, n: int, tickets: List[Ticket],
                    seq: int, batch_size: int) -> None:
     """Synchronous retry of one (possibly bisected) pack at an explicit
     batch shape. Further faults recurse through _handle_pack_fault, so
     a bisected half can bisect again down to the dp floor."""
     t0 = time.time()
     try:
-      handle = self._runner.dispatch(pack, batch_size=batch_size)
+      handle = self._runner.dispatch_pack(*pack, n_rows=n,
+                                          batch_size=batch_size)
       pred_ids, quality = self._runner.finalize(handle)
     except Exception as e:
-      self._handle_pack_fault(pack, tickets, seq, e,
+      self._handle_pack_fault(pack, n, tickets, seq, e,
                               batch_size=batch_size)
       return
     self._deliver_pack(tickets, pred_ids, quality, t0)
@@ -694,12 +740,10 @@ class ConsensusEngine:
     return self._buckets
 
   def _packer_for(self, width: int):
+    """The packer that takes windows of this width, made on first use;
+    WindowBucketError for a width outside the configured buckets."""
+    data_lib.check_window_bucket(width, self._buckets)
     if self._ragged:
-      if width not in self._buckets:
-        # dclint: allow=typed-faults (caller shape contract: windows
-        # must arrive pre-padded to a configured bucket)
-        raise ValueError(
-            f'window width {width} not in window buckets {self._buckets}')
       if self._ragged_packer is None:
         self._ragged_packer = _RaggedPacker(
             self.runner, self.options, self._buckets, self.timing_rows,
@@ -709,11 +753,6 @@ class ConsensusEngine:
       return self._ragged_packer
     packer = self._packers.get(width)
     if packer is None:
-      if width not in self._buckets:
-        # dclint: allow=typed-faults (caller shape contract: windows
-        # must arrive pre-padded to a configured bucket)
-        raise ValueError(
-            f'window width {width} not in window buckets {self._buckets}')
       packer = _WindowPacker(
           self.runner, self.options, self.timing_rows,
           # Indirection so predict_windows can swap the deliver sink
@@ -723,12 +762,6 @@ class ConsensusEngine:
           poisoned=self._poisoned, pack_clock=self._pack_clock)
       self._packers[width] = packer
     return packer
-
-  def _add_rows(self, rows: np.ndarray, tickets: List[Ticket]) -> None:
-    width = int(rows.shape[2])
-    self._n_windows_by_bucket[width] = (
-        self._n_windows_by_bucket.get(width, 0) + len(rows))
-    self._packer_for(width).add(rows, tickets)
 
   def _all_packers(self) -> List[Any]:
     """Every live packer: the per-bucket fleet, or the one ragged
@@ -809,9 +842,10 @@ class ConsensusEngine:
   def _submit(self, windows, tickets: Sequence[Ticket],
               formatted: bool) -> None:
     """Both submits: one `submit` stage on the caller's thread, and
-    under it `stack_windows` (what taking a list and not an array
-    costs: the grouping, then each np.stack), `format_rows`, and the
-    packer's `pack_cut` / `dispatch` / `finalize_drain` / `deliver`."""
+    under it `stack_windows` (the grouping by width that a list costs),
+    `format_rows` (each fill of a pack buffer) and the packer's
+    `pack_cut` / `dispatch` / `finalize_drain` / `deliver`. Nothing of
+    `windows` is referenced once this returns."""
     if len(windows) != len(tickets):
       # dclint: allow=typed-faults (caller API misuse guard, not a
       # data-plane fault: both args come from the same client code)
@@ -823,33 +857,37 @@ class ConsensusEngine:
     with obs_lib.stage(self._obs, obs_lib.trace.STAGE_SUBMIT,
                        n_windows=len(tickets), formatted=int(formatted)):
       if isinstance(windows, np.ndarray) and windows.dtype != object:
-        self._format_and_add(np.asarray(windows), list(tickets), formatted)
+        groups = [(int(windows.shape[-2]), (windows, list(tickets)))]
       else:
         with obs_lib.stage(self._obs, obs_lib.trace.STAGE_STACK,
                            n_rows=len(tickets), bytes=0):
           groups = sorted(self._group_by_width(windows, tickets).items())
-        for _width, (ws, ts) in groups:
-          # No name for the stacked rows here: format_rows_batch's result
-          # replaces them, and they are freed there, not after the add.
-          self._format_and_add(self._stack(ws), ts, formatted)
+      # Every width is checked before any window is written.
+      packers = [self._packer_for(width) for width, _ in groups]
+      for packer, (width, (ws, ts)) in zip(packers, groups):
+        self._n_windows_by_bucket[width] = (
+            self._n_windows_by_bucket.get(width, 0) + len(ws))
+        if self._ragged:
+          packer.add(self._ragged_rows(ws, formatted), ts)
+        else:
+          packer.add(ws, ts, data_lib.pack_layout(
+              ws[0].shape[0], self.runner.params, formatted))
       self._flush_starved()
 
-  def _stack(self, windows: list) -> np.ndarray:
-    with obs_lib.stage(self._obs, obs_lib.trace.STAGE_STACK) as st:
-      rows = np.stack(windows)
-      st.set(n_rows=len(rows), bytes=rows.nbytes)
-    return rows
-
-  def _format_and_add(self, rows: np.ndarray, tickets: List[Ticket],
-                      formatted: bool) -> None:
+  def _ragged_rows(self, windows, formatted: bool) -> np.ndarray:
+    """One width group as the ragged packer queues it, float32 rows:
+    stacked when they came as a list, formatted when they came raw."""
+    rows = windows
+    if not isinstance(rows, np.ndarray):
+      with obs_lib.stage(self._obs, obs_lib.trace.STAGE_STACK) as st:
+        rows = np.stack(rows)
+        st.set(n_rows=len(rows), bytes=rows.nbytes)
     if not formatted:
-      from deepconsensus_tpu.models import data as data_lib
-
       with obs_lib.stage(self._obs, obs_lib.trace.STAGE_FORMAT) as st:
         rows = data_lib.format_rows_batch(
             rows, self.runner.params, window_buckets=self._buckets)
         st.set(n_rows=len(rows), bytes=rows.nbytes)
-    self._add_rows(rows, tickets)
+    return rows
 
   def flush(self, drain: bool = True) -> None:
     """Cuts every bucket's buffered tail as a padded pack; with drain,
@@ -952,6 +990,8 @@ class ConsensusEngine:
         'n_oom_bisections': self.n_oom_bisections,
         'n_device_faults': self.n_device_faults,
         'n_dispatch_timeouts': self.n_dispatch_timeouts,
+        # All buckets together: at most dispatch_depth + 1 a bucket.
+        'n_pack_buffers_allocated': self._agg('n_pack_buffers_allocated'),
     }
     # Sharded-dispatch / transfer-overlap counters (stub runners in
     # tests may not implement the full dispatch contract).

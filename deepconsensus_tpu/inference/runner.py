@@ -188,7 +188,7 @@ class InferenceOptions:
   )
 
 
-_SN_ROWS = 4  # trailing rows: per-window SN constants (layout: pileup.py)
+_SN_ROWS = data_lib.SN_ROWS  # trailing rows: per-window SN constants
 
 
 def _assemble_rows(main_u8: jnp.ndarray, sn: jnp.ndarray,
@@ -817,41 +817,60 @@ class ModelRunner:
 
   def dispatch(self, rows: np.ndarray,
                batch_size: Optional[int] = None) -> _DispatchHandle:
-    """Async sharded dispatch: rows [B, R, L, 1] -> _DispatchHandle.
+    """dispatch_pack() for float rows [B, R, L, 1] already through
+    data.format_rows_batch: casts them to the compact pack first. The
+    entry of callers that hold float rows (predict, the per-batch
+    pipeline, benches); the engine fills compact packs itself
+    (data.fill_pack) and calls dispatch_pack directly."""
+    return self.dispatch_pack(
+        self._cast_main_u8(rows),
+        np.ascontiguousarray(rows[:, -_SN_ROWS:, 0, 0].astype(np.float32)),
+        batch_size=batch_size)
 
-    Pads to the fixed compiled batch shape, places the compact pack on
-    the device(s) with an async `jax.device_put` (dp-sharded over the
-    mesh data axis when a mesh is configured), and returns a handle
-    holding the in-flight transfer slot. The matching forward is
-    double-buffered: it launches when the NEXT pack dispatches — so
-    this pack's compute overlaps that pack's host->device transfer —
-    or on demand in finalize(). The forward donates the input buffers,
-    so steady state reuses device memory.
+  def dispatch_pack(self, main_u8: np.ndarray, sn: np.ndarray,
+                    n_rows: Optional[int] = None,
+                    batch_size: Optional[int] = None) -> _DispatchHandle:
+    """Async sharded dispatch of one compact pack -> _DispatchHandle.
 
     Transfer is compact: every non-SN row holds clip-bounded integers
     (bases/ccs 0-4, pw/ip <= PW_MAX/IP_MAX = 255, strand 0-2, ccs_bq
     -1..93 shipped biased by +1), and the 4 SN rows are per-window
-    constants, so the batch ships as uint8 rows + [B, 4] float SN
-    scalars (~4x less than f32 rows over PCIe) and reassembles
-    losslessly on device (_assemble_rows undoes the ccs_bq bias).
+    constants, so the batch ships as uint8 rows `main_u8` [B, R - 4, L,
+    1] + `sn` [B, 4] float SN scalars (~4x less than f32 rows over
+    PCIe) and reassembles losslessly on device (_assemble_rows undoes
+    the ccs_bq bias).
 
+    Pads to the fixed compiled batch shape if the pack is shorter,
+    places it on the device(s) with an async `jax.device_put`
+    (dp-sharded over the mesh data axis when a mesh is configured), and
+    returns a handle holding the in-flight transfer slot. The matching
+    forward is double-buffered: it launches when the NEXT pack
+    dispatches — so this pack's compute overlaps that pack's
+    host->device transfer — or on demand in finalize(). The forward
+    donates the input buffers, so steady state reuses device memory.
+    The transfer may still be reading the host arrays when this
+    returns: the caller keeps them unchanged until finalize().
+
+    n_rows: how many leading rows are windows (the rest is zero
+    padding the caller already wrote); default all of them.
     batch_size overrides the compiled batch shape for this pack only
     (OOM bisection retries at half batch; jit's per-shape cache keeps
     one executable per distinct size).
     """
-    n = rows.shape[0]
+    n = len(main_u8) if n_rows is None else n_rows
     batch = batch_size or self.options.batch_size
-    width = int(rows.shape[2])
+    width = int(main_u8.shape[2])
     with obs_lib.stage(self.obs, obs_lib.trace.STAGE_DISPATCH,
                        pack=self._n_dispatched + 1, bucket=width, n_rows=n):
+      # What is left of the cast: the pad of a pack that came short.
       with obs_lib.stage(self.obs, obs_lib.trace.STAGE_PACK_CAST) as st:
-        bytes_in = rows.nbytes
-        if n < batch:
-          pad = np.zeros((batch - n,) + rows.shape[1:], rows.dtype)
-          rows = np.concatenate([rows, pad])
-        main_u8 = self._cast_main_u8(rows)
-        sn = np.ascontiguousarray(
-            rows[:, -_SN_ROWS:, 0, 0].astype(np.float32))
+        bytes_in = main_u8.nbytes + sn.nbytes
+        short = batch - len(main_u8)
+        if short > 0:
+          main_u8, sn = (
+              np.concatenate(
+                  [plane, np.zeros((short,) + plane.shape[1:], plane.dtype)])
+              for plane in (main_u8, sn))
         st.set(bytes_in=bytes_in, bytes_out=main_u8.nbytes + sn.nbytes)
       # Per-bucket compile-once accounting: jit keeps one executable per
       # distinct (batch, L); the set is the compile count.

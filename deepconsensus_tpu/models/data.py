@@ -10,7 +10,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import logging
-from typing import Dict, Iterator, List, Sequence, Union
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 import ml_collections
 import numpy as np
@@ -78,21 +78,155 @@ def format_rows_batch(
     features.append(rows_of(ccs_bq_r))
   features.append(np.clip(rows_of(sn_r), 0, params.SN_MAX))
   rows = np.concatenate(features, axis=1)
-  buckets = (tuple(window_buckets) if window_buckets
-             else config.resolve_window_buckets(params))
   width = rows.shape[2]
-  if width not in buckets:
-    who = ''
-    if len(names):
-      shown = [str(n) for n in list(names)[:3]]
-      who = f' (window id(s) {shown}{"..." if len(names) > 3 else ""})'
-    raise WindowBucketError(
-        f'window width {width} not in window buckets {buckets}{who}; '
-        f'triage the window into a bucket (pad) or run with '
-        f'--on_shard_error=skip to quarantine it (n_width_rejected)')
+  check_window_bucket(
+      width, window_buckets or config.resolve_window_buckets(params), names)
   expected = (len(subreads), params.total_rows, width, 1)
   assert rows.shape == expected, rows.shape
   return rows
+
+
+def check_window_bucket(width: int, buckets: Sequence[int],
+                        names: Sequence = ()) -> None:
+  """Raises WindowBucketError for a window width outside `buckets`."""
+  buckets = tuple(buckets)
+  if width in buckets:
+    return
+  who = ''
+  if len(names):
+    shown = [str(n) for n in list(names)[:3]]
+    who = f' (window id(s) {shown}{"..." if len(names) > 3 else ""})'
+  raise WindowBucketError(
+      f'window width {width} not in window buckets {buckets}{who}; '
+      f'triage the window into a bucket (pad) or run with '
+      f'--on_shard_error=skip to quarantine it (n_width_rejected)')
+
+
+# ----------------------------------------------------------------------
+# The compact pack: what the device receives for a batch of windows.
+# `main_u8` [B, total_rows - SN_ROWS, L, 1] uint8 holds every non-SN row
+# (clip-bounded integers; ccs_bq biased by +1 because its spaced values
+# include -1 sentinels), `sn` [B, SN_ROWS] float32 the per-window SN
+# constants. ModelRunner._assemble_rows is the device-side inverse.
+
+SN_ROWS = 4
+
+# Float32 scratch of one fill chunk: a few tens of windows, so that the
+# clip and the cast read what the gather just wrote from the cache.
+_FILL_SCRATCH_BYTES = 1 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class PackLayout:
+  """Which rows of a window go where in a compact pack: format_rows_batch
+  and the runner's uint8 cast as one row map."""
+
+  height: int  # rows of a source window
+  # (dst_lo, dst_hi, src_lo): runs of source rows cast to main_u8 rows.
+  runs: Tuple[Tuple[int, int, int], ...]
+  # (src_lo, src_hi, max): source rows clipped to [0, max] before the cast.
+  clips: Tuple[Tuple[int, int, float], ...]
+  bq_src: Optional[int]  # source row biased by +1 before the cast
+  sn_src: int  # first of the SN_ROWS source rows, read at column 0
+  sn_max: float
+
+  @property
+  def n_main(self) -> int:
+    return self.runs[-1][1]
+
+
+def pack_layout(height: int, params: ml_collections.ConfigDict,
+                formatted: bool = False) -> PackLayout:
+  """The row map for windows of `height` rows: raw examples (passes
+  cropped to params.max_passes when the example carries more) or rows
+  already through format_rows_batch (the identity map; clipping again
+  changes nothing)."""
+  keep = params.max_passes
+  if formatted:
+    have = keep
+    if height != params.total_rows:
+      # dclint: allow=typed-faults (caller shape contract: formatted
+      # rows come from format_rows_batch under the same params)
+      raise ValueError(
+          f'formatted rows have {height} rows, the model takes '
+          f'{params.total_rows}')
+  else:
+    have = layout_from_shape((height, 0, 1), params.use_ccs_bq).max_passes
+    if have < keep:
+      # dclint: allow=typed-faults (configuration contract: the
+      # featurizer's max_passes is set from the model's params)
+      raise ValueError(
+          f'windows carry {have} passes, the model takes {keep}')
+  src = row_indices(have, params.use_ccs_bq)
+  dst = row_indices(keep, params.use_ccs_bq)
+  runs: List[List[int]] = []
+  for (s_lo, _), (d_lo, d_hi) in zip(src[:6], dst[:6]):
+    if d_hi == d_lo:
+      continue
+    if runs and runs[-1][1] == d_lo and (
+        runs[-1][2] + runs[-1][1] - runs[-1][0] == s_lo):
+      runs[-1][1] = d_hi
+    else:
+      runs.append([d_lo, d_hi, s_lo])
+  pw, ip, bq = src[1], src[2], src[5]
+  return PackLayout(
+      height=height,
+      runs=tuple(tuple(r) for r in runs),
+      clips=((pw[0], pw[0] + keep, params.PW_MAX),
+             (ip[0], ip[0] + keep, params.IP_MAX)),
+      bq_src=bq[0] if params.use_ccs_bq else None,
+      sn_src=src[6][0],
+      sn_max=params.SN_MAX)
+
+
+def fill_pack(windows, layout: PackLayout, main_u8: np.ndarray,
+              sn: np.ndarray, at: int = 0) -> None:
+  """Writes `windows` (a list of [height, L, 1] tensors, strided views or
+  not, or one [k, height, L, 1] array) into rows at..at+k of a compact
+  pack, each window read once and written once: to the bit what
+  np.stack -> format_rows_batch -> the runner's uint8 cast and SN gather
+  give, with no float32 intermediate beyond the scratch of one chunk.
+  Nothing of `windows` is referenced afterwards."""
+  k = len(windows)
+  if not k:
+    return
+  shape = windows[0].shape
+  if (shape[0], layout.n_main) + shape[1:] != (
+      layout.height,) + main_u8.shape[1:]:
+    # dclint: allow=typed-faults (caller shape contract: the layout and
+    # the pack buffer are made by the same packer for this width)
+    raise ValueError(
+        f'window shape {shape} does not fit a pack of {main_u8.shape[1:]} '
+        f'from {layout.height} rows')
+  chunk = max(1, _FILL_SCRATCH_BYTES // (4 * int(np.prod(shape))))
+  scratch = np.empty((min(chunk, k),) + shape, np.float32)
+  is_array = isinstance(windows, np.ndarray)
+  for lo in range(0, k, chunk):
+    hi = min(k, lo + chunk)
+    s = scratch[:hi - lo]
+    if is_array:
+      s[...] = windows[lo:hi]
+    else:
+      for j in range(hi - lo):
+        window = windows[lo + j]
+        if window.shape != shape:  # a [1, L, 1] would broadcast silently
+          # dclint: allow=typed-faults (caller shape contract, what
+          # np.stack raised for a list of unequal windows)
+          raise ValueError(
+              f'window {lo + j} has shape {window.shape}, not {shape}')
+        s[j] = window
+    for c_lo, c_hi, c_max in layout.clips:
+      block = s[:, c_lo:c_hi]
+      np.maximum(block, 0, out=block)
+      np.minimum(block, c_max, out=block)
+    if layout.bq_src is not None:
+      s[:, layout.bq_src] += 1.0
+    dst = main_u8[at + lo:at + hi]
+    for d_lo, d_hi, s_lo in layout.runs:
+      np.copyto(dst[:, d_lo:d_hi], s[:, s_lo:s_lo + d_hi - d_lo],
+                casting='unsafe')
+    np.clip(s[:, layout.sn_src:layout.sn_src + SN_ROWS, 0, 0], 0,
+            layout.sn_max, out=sn[at + lo:at + hi])
 
 
 def parse_example(

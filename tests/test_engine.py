@@ -44,18 +44,20 @@ def _stub_runner(params, batch_size=BATCH, fail_packs=()):
   mp = params.max_passes
   seq = [0]
 
-  def dispatch(rows):
+  def dispatch_pack(main_u8, sn, n_rows=None, batch_size=None):
+    # A view into the engine's pack buffer: finalize reads it only when
+    # the pack drains, so a buffer handed out again too early shows.
     pack = seq[0]
     seq[0] += 1
     if pack in fail_packs:
       raise RuntimeError(f'stub failure in pack {pack}')
-    return rows
+    return main_u8[:n_rows]
 
   def finalize(rows):
     ids = rows[:, 4 * mp, :, 0].astype(np.int32)
     return ids, np.full(ids.shape, STUB_QUAL, np.int32)
 
-  runner.dispatch = dispatch
+  runner.dispatch_pack = dispatch_pack
   runner.finalize = finalize
   return runner, options
 

@@ -110,9 +110,10 @@ def test_list_submit_cutting_two_packs_emits_the_chain(tmp_path, params):
   assert spans['submit'][0]['args']['n_windows'] == 2 * BATCH + 3
   assert spans['submit'][0]['args']['formatted'] == 0
   assert 'parent' not in spans['submit'][0]['args']
-  # The grouping by width, then one np.stack (one width).
-  assert len(spans['stack_windows']) == 2
-  assert len(spans['format_rows']) == 1
+  # The grouping by width; then one fill per pack buffer written to (two
+  # full packs and the three windows that begin the third).
+  assert len(spans['stack_windows']) == 1
+  assert len(spans['format_rows']) == 3
   assert len(spans['pack_cut']) == 2
   assert len(spans['dispatch']) == 2
   for name in ('stack_windows', 'format_rows', 'pack_cut', 'dispatch'):
@@ -133,20 +134,28 @@ def test_list_submit_cutting_two_packs_emits_the_chain(tmp_path, params):
     assert e['cat'] == 'wait'
     assert 'span' not in e['args'] and 'parent' not in e['args']
   # Counts in args.
-  stacked = spans['stack_windows'][1]['args']
-  assert stacked['n_rows'] == 2 * BATCH + 3
-  assert stacked['bytes'] == windows[0].nbytes * (2 * BATCH + 3)
-  assert spans['format_rows'][0]['args']['n_rows'] == 2 * BATCH + 3
+  grouped = spans['stack_windows'][0]['args']
+  assert grouped['n_rows'] == 2 * BATCH + 3 and grouped['bytes'] == 0
+  fills = [e['args'] for e in spans['format_rows']]
+  assert [a['n_rows'] for a in fills] == [BATCH, BATCH, 3]
+  # A window is read once as float32 and written once as uint8 + 4 SN.
+  row_u8 = (params.total_rows - 4) * params.max_length + 4 * 4
+  assert [a['bytes'] for a in fills] == [
+      BATCH * row_u8, BATCH * row_u8, 3 * row_u8]
+  assert [a['bytes_read'] for a in fills] == [
+      windows[0].nbytes * k for k in (BATCH, BATCH, 3)]
   assert [e['args']['n_rows'] for e in spans['pack_cut']] == [BATCH, BATCH]
   assert [e['args']['bytes_concatenated'] for e in spans['pack_cut']] == [0, 0]
+  # A full pack leaves the engine compact: nothing to cast or pad.
   cast = spans['pack_cast'][0]['args']
-  assert cast['bytes_in'] > cast['bytes_out'] > 0
+  assert cast['bytes_in'] == cast['bytes_out'] == BATCH * row_u8
   assert [e['args']['pack'] for e in spans['dispatch']] == [1, 2]
   assert spans['h2d_transfer'][0]['args']['bytes'] == cast['bytes_out']
   assert not delivered
 
 
-def test_second_submit_recopies_the_tail_and_flush_drains(tmp_path, params):
+def test_second_submit_fills_the_begun_pack_and_flush_drains(
+    tmp_path, params):
   engine, delivered = _engine(params)
   n = BATCH + 3
 
@@ -161,11 +170,17 @@ def test_second_submit_recopies_the_tail_and_flush_drains(tmp_path, params):
   cuts = spans['pack_cut']
   assert [_parent_name(events, e) for e in cuts] == [
       'submit', 'submit', 'flush']
-  # First cut carried nothing; the second re-copies tail + new rows.
-  assert cuts[0]['args']['bytes_concatenated'] == 0
-  row_bytes = cuts[1]['args']['bytes_concatenated'] // (3 + n)
-  assert cuts[1]['args']['bytes_concatenated'] == row_bytes * (3 + n) > 0
-  assert cuts[2]['args']['n_rows'] == 2 * n - 2 * BATCH
+  # The second submit goes on in the buffer the first one began: no cut
+  # copies anything, whatever tail it found.
+  assert [e['args']['bytes_concatenated'] for e in cuts] == [0, 0, 0]
+  assert [e['args']['n_rows'] for e in cuts] == [BATCH, BATCH, 2 * n - 2 * BATCH]
+  assert [e['args']['n_rows'] for e in spans['format_rows']] == [
+      BATCH, 3, BATCH - 3, n - (BATCH - 3)]
+  # Only the flushed pack came short; it is padded in its own buffer, so
+  # the runner's `pack_cast` has nothing left to do on any pack.
+  for e in spans['pack_cast']:
+    assert e['args']['bytes_in'] == e['args']['bytes_out']
+  assert engine.stats()['n_pack_buffers_allocated'] == 3
   # flush drains all three packs: drain and deliver under `flush`, and the
   # last pack's forward is launched directly, inside its drain.
   assert len(spans['finalize_drain']) == len(spans['deliver']) == 3
@@ -233,7 +248,7 @@ def test_event_count_does_not_depend_on_the_number_of_windows(
   assert sum(counts[100].values()) < 20
 
 
-def test_submit_of_an_array_skips_the_stack_and_formatted_skips_format(
+def test_submit_of_an_array_skips_the_stack_and_formatted_rows_take_the_fill(
     tmp_path, params):
   engine, delivered = _engine(params)
   raw = _raw(params, BATCH)
@@ -250,10 +265,55 @@ def test_submit_of_an_array_skips_the_stack_and_formatted_skips_format(
       lambda: (engine.submit_formatted(rows, list(range(BATCH, 2 * BATCH))),
                engine.flush()))
   spans = _by_name(events)
-  assert 'stack_windows' not in spans and 'format_rows' not in spans
+  # Formatted rows are written into the pack by the same fill.
+  assert 'stack_windows' not in spans and len(spans['format_rows']) == 1
   assert spans['submit'][0]['args']['formatted'] == 1
   assert len(spans['dispatch']) == 1
   assert len(delivered) == 2 * BATCH
+
+
+def test_leaves_and_h2d_cover_submit_and_flush_but_for_the_remainder(
+    tmp_path, params):
+  """The four stage sites survive the in-place fill, each under the
+  parent the table in docs/observability.md names, and the leaves still
+  account for `submit` + `flush` up to the self time of `submit`,
+  `flush` and `dispatch` (host_unattributed_ms_per_pack)."""
+  engine, delivered = _engine(params, batch_size=4)
+  n = 11
+
+  def run():
+    for step in range(3):
+      engine.submit(list(_raw(params, n, seed=step)),
+                    list(range(step * n, (step + 1) * n)))
+    engine.flush()
+
+  events = [e for e in _traced(tmp_path, run) if e['cat'] == 'stage']
+  spans = _by_name(events)
+  assert len(delivered) == 3 * n
+  for name, parents in (
+      ('stack_windows', {'submit'}), ('format_rows', {'submit'}),
+      ('pack_cut', {'submit', 'flush'}), ('dispatch', {'submit', 'flush'}),
+      ('pack_cast', {'dispatch'}), ('h2d_transfer', {'dispatch'})):
+    assert spans[name], name
+    assert {_parent_name(events, e) for e in spans[name]} == parents, name
+  assert len(spans['pack_cut']) == len(spans['pack_cast']) == engine.n_packs
+  assert {e['args']['bytes_concatenated'] for e in spans['pack_cut']} == {0}
+  owners = ('submit', 'flush', 'dispatch')
+  covered = collections.defaultdict(float)
+  for e in events:
+    if 'parent' in e['args']:
+      covered[e['args']['parent']] += e['dur']
+  remainder = sum(e['dur'] - covered[e['args']['span']]
+                  for name in owners for e in spans[name])
+  # A leaf is a stage with no child of its own but for a drain's direct
+  # launch, which lies inside it.
+  leaves = sum(
+      e['dur'] for e in events
+      if e['name'] not in owners
+      and _parent_name(events, e) != 'finalize_drain')
+  top = sum(e['dur'] for name in ('submit', 'flush') for e in spans[name])
+  assert remainder >= 0
+  assert leaves + remainder == pytest.approx(top, abs=10 * EPS_US)
 
 
 def test_ragged_packer_speaks_the_same_vocabulary(tmp_path, params):
@@ -321,7 +381,7 @@ def test_tracing_off_builds_no_event_and_changes_no_byte(
     assert plain[t][1].tobytes() == traced[t][1].tobytes()
   histograms = engine.runner.obs.snapshot()['histograms']
   for name, count in (
-      ('submit', 1), ('flush', 1), ('stack_windows', 2), ('format_rows', 1),
+      ('submit', 1), ('flush', 1), ('stack_windows', 1), ('format_rows', 3),
       ('pack_cut', 3), ('dispatch', 3), ('pack_cast', 3),
       ('forward_launch', 3), ('h2d_transfer', 3), ('finalize_drain', 3),
       ('deliver', 3), ('pack_wait', 3), ('device_compute', 3)):

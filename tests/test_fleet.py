@@ -91,7 +91,8 @@ def _stub_runner(params):
     ids = rows[:, 4 * mp, :, 0].astype(np.int32)
     return ids, np.full(ids.shape, STUB_QUAL, np.int32)
 
-  runner.dispatch = lambda rows: rows
+  runner.dispatch_pack = (
+      lambda main_u8, sn, n_rows=None, batch_size=None: main_u8[:n_rows])
   runner.finalize = finalize
   return runner, options
 

@@ -56,16 +56,16 @@ def _stub_runner(params, control=None):
   mp = params.max_passes
   control = control or _StubControl()
 
-  def dispatch(rows):
+  def dispatch_pack(main_u8, sn, n_rows=None, batch_size=None):
     if control.dispatch_delay:
       time.sleep(control.dispatch_delay)
-    return rows
+    return main_u8[:n_rows]
 
   def finalize(rows):
     ids = rows[:, 4 * mp, :, 0].astype(np.int32)
     return ids, np.full(ids.shape, STUB_QUAL, np.int32)
 
-  runner.dispatch = dispatch
+  runner.dispatch_pack = dispatch_pack
   runner.finalize = finalize
   return runner, options, control
 

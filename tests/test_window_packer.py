@@ -39,16 +39,16 @@ def _stub_model(runner, params, fail=False):
   pack -> scatter -> stitch path verifiable without weights."""
   mp = params.max_passes
 
-  def dispatch(rows):
+  def dispatch_pack(main_u8, sn, n_rows=None, batch_size=None):
     if fail:
       raise RuntimeError('stub model pack failure')
-    return rows
+    return main_u8[:n_rows]
 
   def finalize(rows):
     ids = rows[:, 4 * mp, :, 0].astype(np.int32)
     return ids, np.full(ids.shape, STUB_QUAL, np.int32)
 
-  runner.dispatch = dispatch
+  runner.dispatch_pack = dispatch_pack
   runner.finalize = finalize
 
 
