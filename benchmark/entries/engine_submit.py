@@ -146,7 +146,7 @@ class Entry:
     take = min(int(ctx.traffic['compare_windows']), len(served))
     sample = np.sort(rng.choice(served, size=take, replace=False))
     windows = np.stack([self.views[i] for i in sample])
-    logits = compare.reference_logits(params, windows, ctx.shape)
-    yard = compare.reference_logits(params, windows, ctx.shape, 'bfloat16')
+    logits = ctx.family.reference_logits(params, windows, ctx.shape)
+    yard = ctx.family.reference_logits(params, windows, ctx.shape, 'bfloat16')
     return compare.numbers(logits, self.out_ids[sample],
                            self.out_quals[sample], yard)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.lib.weights import key_from_seed
+from benchmark.lib.seeds import key_from_seed
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -2,7 +2,8 @@
 
 What the timed path delivered for a sample of windows (uint8 base ids and
 Phred qualities per position) is held against the plain float32 reference
-run once over the same windows:
+of the configuration's family (`reference_logits` of
+benchmark/families/<family>.py) run once over the same windows:
 
   id_gap_mean        mean gap by which a served base's reference logit
                      lies below the reference's best, over all positions
@@ -32,13 +33,8 @@ from __future__ import annotations
 
 import numpy as np
 
+# The Phred epilogue, which every family with the 5-way head shares.
 from benchmark.reference import forward as ref
-
-
-def geometry(shape: dict) -> dict:
-  return dict(max_passes=shape['max_passes'],
-              num_layers=shape['num_hidden_layers'],
-              num_heads=shape['num_heads'], band=shape['attn_win_size'])
 
 
 def _softmax(z: np.ndarray) -> np.ndarray:
@@ -82,21 +78,6 @@ def _absolute(ref_logits, ids, quals):
       'qual_diff_mean': float(dq.mean()),
       'qual_diff_max': float(dq.max()),
   }
-
-
-def reference_logits(params, windows: np.ndarray, shape: dict,
-                     precision: str = 'float32', block: int = 256):
-  """windows [S, R, L, 1] as generated -> reference logits [S, L, 5]."""
-  rows = np.asarray(windows, np.float32)[..., 0]
-  # The published input pipeline clips kinetics and SN to the embedding
-  # tables' ranges before the model sees them.
-  p = shape['max_passes']
-  rows = rows.copy()
-  rows[:, p:2 * p] = np.clip(rows[:, p:2 * p], 0, shape['PW_MAX'])
-  rows[:, 2 * p:3 * p] = np.clip(rows[:, 2 * p:3 * p], 0, shape['IP_MAX'])
-  rows[:, 4 * p + 1:] = np.clip(rows[:, 4 * p + 1:], 0, shape['SN_MAX'])
-  return ref.forward_blocks(params, rows, geometry=geometry(shape),
-                            precision=precision, block=block)
 
 
 def judge(values: dict, limits: dict):

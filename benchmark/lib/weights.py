@@ -22,12 +22,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-
-def key_from_seed(seed: int):
-  """A PRNG key from any non-negative whole number (seeds pass 2**31)."""
-  seed = int(seed)
-  key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
-  return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
+from benchmark.lib.seeds import key_from_seed
 
 
 def leaf_specs(shape: dict):

@@ -62,10 +62,15 @@ def bytes_per_pack(shape: dict, batch: int) -> dict:
   return parts
 
 
-def least_seconds_per_pack(shape: dict, batch: int, peaks: dict) -> dict:
+def least_seconds(flops: float, n_bytes: float, peaks: dict) -> dict:
   """The roofline: the larger of FLOPs/peak and bytes/HBM rate, and which."""
-  t_flops = flops_per_window(shape)['total'] * batch / peaks['bf16_flops_per_s']
-  t_bytes = bytes_per_pack(shape, batch)['total'] / peaks['hbm_bytes_per_s']
+  t_flops = flops / peaks['bf16_flops_per_s']
+  t_bytes = n_bytes / peaks['hbm_bytes_per_s']
   return {'seconds': max(t_flops, t_bytes),
           'bound': 'compute' if t_flops >= t_bytes else 'memory',
           'flops_seconds': t_flops, 'bytes_seconds': t_bytes}
+
+
+def least_seconds_per_pack(shape: dict, batch: int, peaks: dict) -> dict:
+  return least_seconds(flops_per_window(shape)['total'] * batch,
+                       bytes_per_pack(shape, batch)['total'], peaks)

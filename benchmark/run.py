@@ -8,7 +8,10 @@ for. Everything that belongs to one cell, configuration, traffic mix,
 entry, generator or per-layer metric is a file of its own, found by the
 name in BENCHMARK.json:
 
-  benchmark/configs/<config>.json      sizes as run, preset, batch size
+  benchmark/configs/<config>.json      sizes as run, preset, batch size,
+                                       `family` (default gap_aware_encoder)
+  benchmark/families/<family>.py       shape_of / stated / make_params /
+                                       work / reference_logits
   benchmark/traffic/<traffic>.json     parameters, generator, entry
   benchmark/generators/<generator>.py  make(shape, traffic, seed)
   benchmark/entries/<entry>.py         Entry: prepare / window / compare
@@ -37,6 +40,10 @@ COMPILE_EVENTS = ('/jax/core/compile/backend_compile_duration',
                   '/jax/compilation_cache/cache_retrieval_time_sec')
 WINDOW_ANNOTATION = 'bench_window'
 SYNC_ANNOTATION = 'bench_clock_sync'
+DEFAULT_FAMILY = 'gap_aware_encoder'
+FAMILY_FUNCTIONS = ('shape_of', 'stated', 'make_params', 'flops_per_window',
+                    'bytes_per_pack', 'param_count', 'least_seconds_per_pack',
+                    'reference_logits')
 
 
 def log(*parts):
@@ -71,6 +78,7 @@ def load_cell(bench_path: str, workload: str):
   return types.SimpleNamespace(
       bench=bench, cell=cell, config=config, traffic=traffic,
       limits=limits.get('limits', {}), bench_dir=bench_dir,
+      family=load_family(bench_dir, config.get('family', DEFAULT_FAMILY)),
       end_to_end=[m for m in bench['end_to_end'] if applies(m)],
       per_layer=[m for m in bench['per_layer'] if applies(m)])
 
@@ -90,15 +98,17 @@ def load_by_name(bench_dir: str, kind: str, name: str):
   raise FileNotFoundError(f'no {kind}/{name}.py under {bench_dir}')
 
 
-def shape_of(config: dict) -> dict:
-  keys = ('num_hidden_layers', 'hidden_size', 'filter_size', 'num_heads',
-          'attn_win_size', 'max_passes', 'max_length', 'total_rows',
-          'condense_input_size', 'embedding', 'PW_MAX', 'IP_MAX',
-          'STRAND_MAX', 'SN_MAX')
-  return {k: config[k] for k in keys}
+def load_family(bench_dir: str, name: str):
+  """The family module a configuration names: everything that belongs to
+  one model architecture (sizes, seeded tree, work, plain reference)."""
+  family = load_by_name(bench_dir, 'families', name)
+  for function in FAMILY_FUNCTIONS:
+    if not callable(getattr(family, function, None)):
+      raise SystemExit(f'families/{name}.py lacks {function}()')
+  return family
 
 
-def program_params(config: dict):
+def program_params(config: dict, family):
   """The program's own config for this configuration, checked against the
   sizes the file states: the file holds the configuration as it is run."""
   from deepconsensus_tpu.models import config as config_lib
@@ -108,27 +118,7 @@ def program_params(config: dict):
     for key, value in config.get('overrides', {}).items():
       params[key] = value
   config_lib.finalize_params(params, is_training=False)
-  stated = {
-      'num_hidden_layers': params.num_hidden_layers,
-      'hidden_size': params.hidden_size,
-      'filter_size': params.filter_size,
-      'num_heads': params.num_heads,
-      'attn_win_size': params.attn_win_size,
-      'max_passes': params.max_passes,
-      'max_length': params.max_length,
-      'total_rows': params.total_rows,
-      'use_ccs_bq': params.use_ccs_bq,
-      'PW_MAX': params.PW_MAX, 'IP_MAX': params.IP_MAX,
-      'STRAND_MAX': params.STRAND_MAX, 'SN_MAX': params.SN_MAX,
-      'dtype': params.dtype,
-      'rezero': params.rezero,
-      'use_fused_hotpath': params.use_fused_hotpath,
-      'embedding': {
-          'bases': params.per_base_hidden_size, 'pw': params.pw_hidden_size,
-          'ip': params.ip_hidden_size, 'strand': params.strand_hidden_size,
-          'sn': params.sn_hidden_size},
-  }
-  wrong = {k: (config.get(k), v) for k, v in stated.items()
+  wrong = {k: (config.get(k), v) for k, v in family.stated(params).items()
            if config.get(k) != v}
   if wrong:
     raise SystemExit(f'configuration file and program disagree: {wrong}')
@@ -200,15 +190,14 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
   from deepconsensus_tpu.inference import runner as runner_lib
   from benchmark.lib import peaks as peaks_lib
   from benchmark.lib import spans as spans_lib
-  from benchmark.lib import weights as weights_lib
-  from benchmark.lib import work as work_lib
   from benchmark.lib import xplane as xplane_lib
   from benchmark.lib import compare as compare_lib
 
   t_imports = time.time()
-  shape = shape_of(config)
-  params = program_params(config)
-  variables = {'params': weights_lib.make_params(shape, seed)}
+  family = loaded.family
+  shape = family.shape_of(config)
+  params = program_params(config, family)
+  variables = {'params': family.make_params(shape, seed)}
   jax.block_until_ready(variables)
   t_weights = time.time()
   options = runner_lib.InferenceOptions(
@@ -218,6 +207,7 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
   options.use_ccs_bq = params.use_ccs_bq
   ctx = types.SimpleNamespace(
       seed=int(seed), cell=cell, config=config, traffic=traffic, shape=shape,
+      family=family,
       batch=int(config['batch_size']), options=options, out_dir=out_dir,
       runner=runner_lib.ModelRunner(params, variables, options),
       generator=load_by_name(loaded.bench_dir, 'generators',
@@ -311,7 +301,7 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
         result=result, window_s=result['window_s'], spans=spans,
         span_window=result['wall'], planes=planes, trace_window=(lo, hi),
         shape=shape, batch=ctx.batch, peaks=chip_peaks, chips=len(devices),
-        memory_peak_bytes=peak, work=work_lib, xplane=xplane_lib,
+        memory_peak_bytes=peak, work=family, xplane=xplane_lib,
         spans_lib=spans_lib, on_chip=devices[0].platform == 'tpu', log=log)
     for m in loaded.per_layer:
       value = load_by_name(loaded.bench_dir, 'metrics', m['name']).read(reading)

@@ -22,12 +22,6 @@ UNIT = re.compile(r'^[A-Za-z0-9_/%.\-]{1,16}$')
 SOURCES = {'device_trace', 'program_span', 'program_counter', 'host_clock'}
 
 
-@pytest.fixture(scope='module')
-def no_cache():
-  import jax
-  jax.config.update('jax_enable_compilation_cache', False)
-
-
 def toy_run(tmp_path, trace, seed=2**31 + 11, **kw):
   from benchmark import run
   return run.run_cell(TOY, 'toy_polish', seed, 0.3, trace,
@@ -56,11 +50,16 @@ def test_toy_rehearsal_end_to_end(tmp_path, no_cache, trace):
 
 def test_cell_config_traffic_and_metric_are_added_by_files_only(
     tmp_path, no_cache):
-  """The toy cell, its configuration, its traffic mix and the metric
-  `toy_packs` exist only as fixture files and entries of the fixture's
-  BENCHMARK file; nothing under benchmark/ names them."""
+  """The toy cells, their configurations, their traffic mix, the metric
+  `toy_packs` and the second model family `toy_fc` exist only as fixture
+  files and entries of the fixture's BENCHMARK file; nothing under
+  benchmark/ names them."""
   result = toy_run(tmp_path, True)
   assert result['metrics']['toy_packs']['value'] >= 1
+  from benchmark import run
+  second = run.run_cell(TOY, 'toy_fc_polish', 2**31 + 11, 0.3, False,
+                        require_chip=False, out_dir=str(tmp_path))
+  assert second['correct'] is True and second['attempted'] > 0
   for dirpath, _dirs, files in os.walk(os.path.join(ROOT, 'benchmark')):
     if 'tests' in dirpath.split(os.sep):
       continue
@@ -69,6 +68,7 @@ def test_cell_config_traffic_and_metric_are_added_by_files_only(
         with open(os.path.join(dirpath, name)) as f:
           text = f.read()
         assert 'toy_packs' not in text and 'toy_stream' not in text
+        assert 'toy_fc' not in text
 
 
 def test_entry_submits_as_run_inference_does(tmp_path, no_cache, monkeypatch):
@@ -169,20 +169,20 @@ def test_weights_tree_is_the_programs_and_seeded():
   import jax
   import jax.numpy as jnp
   from benchmark import run
-  from benchmark.lib import weights
   from deepconsensus_tpu.models import model as model_lib
 
-  config = run.load_cell(TOY, 'toy_polish').config
-  params = run.program_params(config)
+  loaded = run.load_cell(TOY, 'toy_polish')
+  config, family = loaded.config, loaded.family
+  params = run.program_params(config, family)
   model = model_lib.get_model(params)
   want = jax.eval_shape(
       lambda k: model.init(k, jnp.zeros((1, 25, 20, 1))),
       jax.random.PRNGKey(0))['params']
-  got = weights.make_params(run.shape_of(config), 2**31 + 3)
+  got = family.make_params(family.shape_of(config), 2**31 + 3)
   shapes = lambda t: jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), t)
   assert shapes(got) == shapes(want)
-  again = weights.make_params(run.shape_of(config), 2**31 + 3)
-  other = weights.make_params(run.shape_of(config), 3)
+  again = family.make_params(family.shape_of(config), 2**31 + 3)
+  other = family.make_params(family.shape_of(config), 3)
   leaves = jax.tree_util.tree_leaves
   assert all(np.array_equal(x, y) for x, y in zip(leaves(got), leaves(again)))
   assert not all(np.array_equal(x, y)
@@ -193,18 +193,25 @@ def test_weights_tree_is_the_programs_and_seeded():
 
 # ------------------------------------------------------------------- work
 
-def real_shape(name):
+def real_cell(name):
+  """(family module, shape) of one of the benchmark's own cells."""
   from benchmark import run
-  return run.shape_of(run.load_cell(BENCH, name).config)
+  loaded = run.load_cell(BENCH, name)
+  return loaded.family, loaded.family.shape_of(loaded.config)
 
 
-def test_work_against_a_hand_count_at_toy_widths():
+@pytest.mark.parametrize('through', ['lib', 'family'])
+def test_work_against_a_hand_count_at_toy_widths(through):
   from benchmark.lib import work
+  if through == 'family':  # what the metrics are handed as `reading.work`
+    work_of = real_cell('teacher_polish')[0]
+  else:
+    work_of = work
   shape = dict(max_length=4, hidden_size=6, filter_size=10,
                num_hidden_layers=2, attn_win_size=1, condense_input_size=8)
   # Pairs with |i-j| <= 1 in 4 positions: 2 + 3 + 3 + 2.
   assert work.band_pairs(4, 1) == 10
-  parts = work.flops_per_window(shape)
+  parts = work_of.flops_per_window(shape)
   assert parts['condense'] == 2 * 4 * 8 * 6
   assert parts['qkvo'] == 2 * 4 * (2 * 4 * 6 * 6)
   assert parts['band_scores'] == 2 * (2 * 10 * 6) == parts['band_values']
@@ -219,8 +226,7 @@ def test_work_against_a_hand_count_at_toy_widths():
 def test_work_against_the_compiled_programs_count(cell, xla_flops, n_params):
   """XLA counts the full 100x100 attention and elementwise work too, so
   the algorithm's matmul count lies a few percent below it."""
-  from benchmark.lib import work
-  shape = real_shape(cell)
+  work, shape = real_cell(cell)
   total = work.flops_per_window(shape)['total']
   assert 0.95 * xla_flops < total < xla_flops
   assert work.param_count(shape) == n_params
@@ -243,21 +249,21 @@ def test_reference_agrees_with_the_flax_model_in_float32(no_cache):
   import jax
   from benchmark import run
   from benchmark.generators import pileup_windows as gen
-  from benchmark.lib import compare, weights
   from deepconsensus_tpu.models import model as model_lib
 
   loaded = run.load_cell(TOY, 'toy_polish')
-  shape = run.shape_of(loaded.config)
-  params = run.program_params(loaded.config)
-  tree = weights.make_params(shape, 17)
+  family = loaded.family
+  shape = family.shape_of(loaded.config)
+  params = run.program_params(loaded.config, family)
+  tree = family.make_params(shape, 17)
   windows = gen.make(shape, loaded.traffic, 17)[:32]
   out = model_lib.get_model(params).apply(
       {'params': tree}, windows, method='apply_with_intermediates')
   with jax.default_matmul_precision('highest'):
-    ref = compare.reference_logits(tree, windows, shape, block=32)
+    ref = family.reference_logits(tree, windows, shape, block=32)
   assert np.abs(np.asarray(out['logits']) - ref).max() < 1e-4
   # And the lower precisions really are lower.
-  low = compare.reference_logits(tree, windows, shape, 'fp8', block=32)
+  low = family.reference_logits(tree, windows, shape, 'fp8', block=32)
   assert np.abs(low - ref).max() > 1e-2
 
 
@@ -350,6 +356,112 @@ def test_reduction_on_the_recorded_chip_trace():
   assert xplane.top_ops(planes, lo, hi)[0][0] == want['top_op']
 
 
+def test_scope_seconds_on_a_hand_made_trace():
+  """Device seconds of the operations whose scope matches: a union, so an
+  operation nested in another of the same scope is not counted twice; the
+  mean over the device planes; nothing without the side table."""
+  from benchmark.lib import xplane
+  ops = [('fusion.1', 1e9, 2e9), ('dot.2', 1.5e9, 1e9), ('copy.3', 4e9, 1e9),
+         ('fusion.4', 6e9, 1e9)]
+  planes = xplane.Planes({
+      '/device:TPU:0': {'XLA Ops': list(ops)},
+      '/device:TPU:1': {'XLA Ops': list(ops[:1])},
+      '/host:CPU': {'python': [('bench_window', 0.0, 10e9)]}})
+  planes.scopes['/device:TPU:0'] = [
+      'jit(f)/encoder/ffn_0/dot_general', 'jit(f)/encoder/ffn_0/add', '',
+      'jit(f)/encoder/attention_1/dot_general']
+  planes.scopes['/device:TPU:1'] = ['jit(f)/encoder/ffn_0/dot_general']
+  lo, hi = xplane.window_of(planes, 'bench_window')
+  # ffn_0: [1, 3) and the nested [1.5, 2.5) on device 0, [1, 3) on device 1.
+  assert xplane.scope_seconds(planes, lo, hi, 'ffn_0') == pytest.approx(2.0)
+  assert xplane.scope_seconds(planes, lo, hi, r'attention_\d') == (
+      pytest.approx(0.5))
+  assert xplane.scope_seconds(planes, lo, hi, '^$') == pytest.approx(0.5)
+  assert xplane.scope_seconds(planes, lo, hi, 'no_such_scope') == 0.0
+  # Every operation: what `busy_seconds` reads; clipped to the window.
+  assert xplane.scope_seconds(planes, lo, hi, '') == pytest.approx(
+      xplane.busy_seconds(planes, lo, hi)) == pytest.approx(3.0)
+  assert xplane.scope_seconds(planes, 2e9, 6.5e9, 'encoder') == (
+      pytest.approx((1.0 + 0.5 + 1.0) / 2))
+  plain = {k: v for k, v in planes.items()}
+  assert xplane.scope_seconds(plain, lo, hi, '') == 0.0
+  assert any('scopes | 4 distinct' in line for line in xplane.describe(planes))
+  # The side table survives a recording, and an Event stays a 3-tuple.
+  back = xplane.from_recording(json.loads(json.dumps(
+      xplane.to_recording(planes))))
+  assert back.scopes == planes.scopes and back == planes
+  assert all(len(e) == 3 for e in back['/device:TPU:0']['XLA Ops'])
+
+
+def _put(number, payload):
+  """One length-delimited protobuf field."""
+  assert number < 16 and len(payload) < 128 * 128
+  size = len(payload)
+  head = bytes([size]) if size < 128 else bytes([size & 0x7F | 0x80, size >> 7])
+  return bytes([number << 3 | 2]) + head + payload
+
+
+def test_op_scopes_reads_the_event_metadata_of_device_planes(tmp_path):
+  """The wire reader on a hand-made XSpace: the scope is the `tf_op` stat
+  of an event's metadata, as a string or as a reference to a stat
+  metadata's name; lines are skipped; host planes are left out."""
+  from benchmark.lib import xplane
+  varint = lambda number, value: bytes([number << 3, value])
+  stat_meta = lambda i, name: _put(5, varint(1, i) + _put(
+      2, varint(1, i) + _put(2, name)))
+  event_meta = lambda i, name, stat: _put(4, varint(1, i) + _put(
+      2, varint(1, i) + _put(2, name) + _put(5, stat)))
+  line = _put(3, _put(2, b'XLA Ops') + _put(4, varint(1, 7) + varint(3, 9)))
+  plane = (_put(2, b'/device:TPU:0') + line
+           + stat_meta(1, b'tf_op') + stat_meta(2, b'source')
+           + stat_meta(3, b'jit(f)/encoder/ffn_0/add:')
+           + event_meta(7, b'%fusion.1 = bf16[8]', varint(1, 1) + _put(
+               5, b'jit(f)/encoder/ffn_0/dot_general:'))
+           + event_meta(8, b'%add.2 = bf16[8]', varint(1, 1) + varint(7, 3))
+           + event_meta(9, b'%copy.3 = bf16[8]', varint(1, 2) + _put(
+               5, b'model.py:92')))
+  host = _put(2, b'/host:CPU') + stat_meta(1, b'tf_op') + event_meta(
+      7, b'main', varint(1, 1) + _put(5, b'not a device'))
+  path = tmp_path / 't.xplane.pb'
+  path.write_bytes(_put(1, plane) + _put(1, host))
+  assert xplane.op_scopes(str(path)) == {'/device:TPU:0': {
+      '%fusion.1 = bf16[8]': 'jit(f)/encoder/ffn_0/dot_general',
+      '%add.2 = bf16[8]': 'jit(f)/encoder/ffn_0/add',
+      '%copy.3 = bf16[8]': ''}}
+
+
+RECORDED_SCOPES = os.path.join(HERE, 'fixtures',
+                               'recorded_trace_v5e_scopes.json')
+
+
+def test_scope_seconds_on_the_recorded_chip_traces():
+  """The recording of PR 27 carries the side table; the one of PR 24 was
+  made before it, keeps loading, and reads 0.0."""
+  from benchmark.lib import xplane
+  with open(RECORDED) as f:
+    old = xplane.from_recording(json.load(f))
+  lo, hi = xplane.window_of(old, 'bench_window')
+  assert old.scopes == {} and xplane.busy_seconds(old, lo, hi) > 0
+  assert xplane.scope_seconds(old, lo, hi, '') == 0.0
+  with open(RECORDED_SCOPES) as f:
+    rec = json.load(f)
+  planes = xplane.from_recording(rec)
+  lo, hi = xplane.window_of(planes, 'bench_window')
+  want = rec['expected']
+  assert xplane.busy_seconds(planes, lo, hi) == pytest.approx(want['busy_s'])
+  assert len(want['scope_s']) >= 3
+  for pattern, seconds in want['scope_s'].items():
+    assert xplane.scope_seconds(planes, lo, hi, pattern) == pytest.approx(
+        seconds)
+  # Every operation is what busy_seconds reads; a layer is a part of it.
+  assert want['scope_s'][''] == pytest.approx(want['busy_s'], rel=0.01)
+  parts = [s for p, s in want['scope_s'].items() if p]
+  assert all(0 < s < want['busy_s'] for s in parts)
+  durations = xplane.module_durations(planes, 'jit_forward', lo, hi)
+  assert len(durations) == want['n_forward']
+  assert xplane.top_ops(planes, lo, hi)[0][0] == want['top_op']
+
+
 # ------------------------------------------------------ BENCHMARK.json
 
 def test_benchmark_json_keeps_to_the_contract():
@@ -411,17 +523,18 @@ def test_control_fp8_reference_fails_the_cells_limits(cell, no_cache):
   must come out as not correct under the cell's own limits."""
   from benchmark import run
   from benchmark.generators import pileup_windows as gen
-  from benchmark.lib import compare, weights
+  from benchmark.lib import compare
 
   loaded = run.load_cell(BENCH, cell)
-  shape = run.shape_of(loaded.config)
-  tree = weights.make_params(shape, 5)
+  family = loaded.family
+  shape = family.shape_of(loaded.config)
+  tree = family.make_params(shape, 5)
   windows = gen.make_windows(
       64, seed=5, max_passes=shape['max_passes'], length=shape['max_length'],
       **loaded.traffic['generator_params'])
-  ref = compare.reference_logits(tree, windows, shape, block=32)
-  yard = compare.reference_logits(tree, windows, shape, 'bfloat16', block=32)
-  low = compare.reference_logits(tree, windows, shape, 'fp8', block=32)
+  ref = family.reference_logits(tree, windows, shape, block=32)
+  yard = family.reference_logits(tree, windows, shape, 'bfloat16', block=32)
+  low = family.reference_logits(tree, windows, shape, 'fp8', block=32)
   judged = compare.judge(
       compare.numbers(ref, *compare.served_from_logits(low), yard),
       loaded.limits)

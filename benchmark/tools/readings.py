@@ -29,12 +29,12 @@ def control_numbers(bench_path: str, workload: str, seed: int,
   import numpy as np
 
   from benchmark import run
-  from benchmark.lib import compare, weights
+  from benchmark.lib import compare
 
   loaded = run.load_cell(bench_path, workload)
-  shape = run.shape_of(loaded.config)
-  traffic = loaded.traffic
-  params = weights.make_params(shape, seed)
+  family, traffic = loaded.family, loaded.traffic
+  shape = family.shape_of(loaded.config)
+  params = family.make_params(shape, seed)
   generator = run.load_by_name(loaded.bench_dir, 'generators',
                                traffic['generator'])
   pool = generator.make(shape, traffic, seed)
@@ -42,11 +42,11 @@ def control_numbers(bench_path: str, workload: str, seed: int,
   take = min(int(traffic['compare_windows']), len(pool))
   sample = np.sort(rng.choice(len(pool), size=take, replace=False))
   windows = pool[sample]
-  ref_logits = compare.reference_logits(params, windows, shape)
-  yard = compare.reference_logits(params, windows, shape, 'bfloat16')
+  ref_logits = family.reference_logits(params, windows, shape)
+  yard = family.reference_logits(params, windows, shape, 'bfloat16')
   out = {}
   for precision in precisions:
-    low = compare.reference_logits(params, windows, shape, precision)
+    low = family.reference_logits(params, windows, shape, precision)
     out[precision] = compare.numbers(
         ref_logits, *compare.served_from_logits(low), yard)
   return out

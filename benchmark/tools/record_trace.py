@@ -5,8 +5,10 @@
 
 Runs the cell traced for about two seconds, then writes the events the
 reduction reads (device `XLA Modules` and `XLA Ops` lines, the harness's
-host annotations) as JSON, operation names cut to their short form, with
-the numbers the reduction gave on the spot as `expected`.
+host annotations) as JSON, operation names cut to their short form, and
+the side table of the operations' scopes (`xplane.to_recording`), with the
+numbers the reduction gave on the spot as `expected`: among them the device
+seconds under each pattern of `--scopes` (`scope_seconds`).
 """
 from __future__ import annotations
 
@@ -26,6 +28,8 @@ def main(argv=None):
   parser.add_argument('--workload', required=True)
   parser.add_argument('--out', required=True)
   parser.add_argument('--seconds', type=float, default=2.0)
+  parser.add_argument('--scopes', default='',
+                      help="comma-separated patterns; '' is always read")
   args = parser.parse_args(argv)
   from benchmark import run
   from benchmark.lib import xplane
@@ -35,14 +39,6 @@ def main(argv=None):
   trace_dir = os.path.join(ROOT, 'bench_out', f'trace.{args.workload}')
   names = ('bench_window', 'bench_submit', 'bench_flush')
   planes = xplane.load(xplane.find_trace(trace_dir), names)
-  keep = {}
-  for plane, lines in planes.items():
-    for line, events in lines.items():
-      if plane == xplane.HOST_PLANE or line in (xplane.MODULE_LINE,
-                                                xplane.OP_LINE):
-        keep.setdefault(plane, {})[line] = [
-            [xplane.short_op_name(n) if line == xplane.OP_LINE else n, s, d]
-            for n, s, d in events]
   lo, hi = xplane.window_of(planes, 'bench_window')
   durations = xplane.module_durations(planes, xplane.FORWARD_MODULE_PREFIX, lo, hi)
   expected = {
@@ -50,10 +46,13 @@ def main(argv=None):
       'n_forward': len(durations),
       'forward_median_s': xplane.median(durations),
       'top_op': xplane.top_ops(planes, lo, hi)[0][0],
+      'scope_s': {pattern: xplane.scope_seconds(planes, lo, hi, pattern)
+                  for pattern in [''] + [p for p in args.scopes.split(',')
+                                         if p]},
   }
   with open(args.out, 'w') as f:
     json.dump({'device': 'TPU v5 lite', 'workload': args.workload,
-               'planes': keep, 'expected': expected}, f)
+               **xplane.to_recording(planes), 'expected': expected}, f)
   print(json.dumps(expected))
   return 0
 
