@@ -603,6 +603,20 @@ class ModelRunner:
     # batch driver all observe into this same registry so /metricz and
     # the run sidecar read one coherent view (obs/metrics.py).
     self.obs = obs_lib.MetricsRegistry()
+    # What the forward holds and computes, for the forward_launch span
+    # and the registry: the encoder block kind (the model family where
+    # there is no encoder), and the resident parameter bytes by leaf
+    # dtype (0 for an exported artifact, whose weights are baked in). A
+    # second copy or an upcast of the weights shows here.
+    self._block_kind = (
+        model_lib.block_kind_of(self.params)
+        if 'transformer' in self.params.model_name
+        else str(self.params.model_name))
+    self._weight_bytes = sum(
+        int(leaf.nbytes)
+        for leaf in jax.tree_util.tree_leaves(self.variables))
+    self.obs.set_gauge('model_weight_bytes', self._weight_bytes)
+    self._n_forward_positions = self.obs.counter('n_forward_positions')
     # dclint: lock-free (single transfer slot: the model-loop thread
     # is the sole device owner — dispatch/finalize are never called
     # concurrently, per the engine's single-thread contract)
@@ -992,10 +1006,15 @@ class ModelRunner:
     # signal dctpu trace reconciles against the counters.
     handle.t_launch = time.time()
     fwd = self._ragged_forward if handle.ragged else self._forward
+    # Positions the forward computes: the compiled pack's rows x width.
+    n_positions = inputs[0].shape[0] * inputs[0].shape[2]
+    self._n_forward_positions.inc(n_positions)
     # Host time to enqueue the forward: where a full runtime queue
     # would block.
     with obs_lib.stage(self.obs, obs_lib.trace.STAGE_LAUNCH,
-                       pack=handle.seq):
+                       pack=handle.seq, block_kind=self._block_kind,
+                       n_positions=n_positions,
+                       weight_bytes=self._weight_bytes):
       try:
         faults.injected_device_fault(handle.seq)
         handle.hang_s = faults.injected_device_hang(handle.seq)
@@ -1040,6 +1059,9 @@ class ModelRunner:
         'n_epilogue_packs': self._n_epilogue_packs,
         'd2h_bytes_per_pack': self._d2h_bytes_per_pack,
         'n_forward_shapes': len(self._forward_shapes),
+        'block_kind': self._block_kind,
+        'model_weight_bytes': self._weight_bytes,
+        'n_forward_positions': self._n_forward_positions.value,
         'n_dispatched_by_bucket': {
             w: self._n_dispatched_by_bucket[w]
             for w in sorted(self._n_dispatched_by_bucket)},

@@ -99,6 +99,22 @@ def bucket_for(width: int, buckets):
   return None
 
 
+# Encoder block kinds, named by mechanism. EncoderStack builds the block
+# its configuration names (models/model.py::_block_modules):
+#   banded_softmax_relu     banded softmax self-attention over full heads
+#                           + ReLU feed-forward, ReZero or pre-LayerNorm
+#                           residuals, final LayerNorm (the published
+#                           DeepConsensus block).
+#   power_retention_swiglu  gated power retention (a linear-attention
+#                           layer, run in its two-direction quadratic
+#                           form: ops/power_retention.py) over grouped
+#                           heads with per-head q/k RMSNorm and rotary
+#                           positions + SwiGLU feed-forward, pre-RMSNorm
+#                           residuals, final RMSNorm.
+BLOCK_BANDED_SOFTMAX = 'banded_softmax_relu'
+BLOCK_POWER_RETENTION = 'power_retention_swiglu'
+BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION)
+
 # Transformer size presets (reference: transformer_basic_params.py).
 TRANSFORMER_SIZE_PARAMS = {
     'tiny': dict(
@@ -121,6 +137,7 @@ TRANSFORMER_SIZE_PARAMS = {
 
 def _set_base_transformer_hparams(params):
   params.model_name = 'transformer'
+  params.block_kind = BLOCK_BANDED_SOFTMAX
   params.add_pos_encoding = True
   params.num_heads = 2
   params.layer_norm = False
@@ -186,6 +203,40 @@ def _set_transformer_learned_embeddings_distill_hparams(params):
   params.student_alpha = 1.0
   params.temperature = 1.0
   params.logit_loss_identifier = 'mean_squared_error'
+
+
+def _set_transformer_learned_embeddings_retention_hparams(params):
+  """A second encoder block kind at the widths of a public 14B
+  linear-attention language model (every attention layer a gated power
+  retention layer; 40 layers, hidden 5120, 40 query / 8 key-value heads
+  of 128, SwiGLU 17408, RMSNorm 1e-6, rotary base 1e6), behind this
+  system's pile-up embedding and 5-way head. Served in bfloat16; at these
+  widths one chip holds 8 of the 40 layers (--set num_hidden_layers=8)
+  and a pack of 256 windows fills it (docs/inference.md)."""
+  _set_transformer_learned_embeddings_hparams(params)
+  params.model_name = 'transformer_learn_values_retention'
+  params.block_kind = BLOCK_POWER_RETENTION
+  params.transformer_input_size = 5120
+  params.num_hidden_layers = 40
+  params.num_heads = 40
+  params.num_kv_heads = 8
+  params.head_dim = 128
+  params.filter_size = 17408
+  params.rope_theta = 1.0e6
+  params.rms_norm_eps = 1.0e-6
+  params.retention_degree = 2
+  # Rotary positions take the sinusoidal encoding's place, and the
+  # pre-RMSNorm residual the ReZero one's.
+  params.add_pos_encoding = False
+  params.rezero = False
+  params.attn_win_size = 0
+  # The published model has no dropout.
+  params.layer_postprocess_dropout = 0.0
+  params.attention_dropout = 0.0
+  params.relu_dropout = 0.0
+  params.dtype = 'bfloat16'
+  params.inference_dtype = 'bfloat16'
+  params.use_fused_hotpath = False
 
 
 def _set_base_fc_hparams(params):
@@ -434,6 +485,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
     _set_transformer_learned_embeddings_hparams(params)
   elif model_config_name == 'transformer_learn_values_distill':
     _set_transformer_learned_embeddings_distill_hparams(params)
+  elif model_config_name == 'transformer_learn_values_retention':
+    _set_transformer_learned_embeddings_retention_hparams(params)
   else:
     raise ValueError(f'Unknown model_config_name: {model_config_name}')
 

@@ -25,6 +25,7 @@ import numpy as np
 
 from deepconsensus_tpu import constants
 from deepconsensus_tpu.models import config as config_lib
+from deepconsensus_tpu.ops import power_retention
 from deepconsensus_tpu.parallel import ring_attention as ring_lib
 from deepconsensus_tpu.preprocess.pileup import row_indices
 
@@ -278,19 +279,136 @@ class FeedForward(nn.Module):
     return nn.Dense(self.hidden_size, dtype=self.dtype, name='output_layer')(h)
 
 
+class RMSNorm(nn.Module):
+  """x / rms(x) * scale over the last axis, reckoned in float32 and
+  returned in `dtype`."""
+
+  epsilon: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    scale = self.param('scale', nn.initializers.ones, (x.shape[-1],),
+                       jnp.float32)
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+    return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+def rotary_tables(length: int, head_dim: int, theta: float):
+  """(cos, sin) [L, head_dim] float32 of the rotate-half rotary position
+  embedding: frequencies theta**(-2i/head_dim) over the first half of the
+  head, repeated over the second."""
+  inv = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+  angles = np.arange(length, dtype=np.float64)[:, None] * inv[None, :]
+  angles = np.concatenate([angles, angles], axis=1)
+  return (np.cos(angles).astype(np.float32),
+          np.sin(angles).astype(np.float32))
+
+
+def apply_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+  """x [B, L, N, D] float32, positions 0..L-1 -> x rotated."""
+  cos, sin = rotary_tables(x.shape[1], x.shape[3], theta)
+  half = x.shape[3] // 2
+  rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+  return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
+
+
+class _GateProjection(nn.Module):
+  """x [B, L, H] -> x W + b [B, L, features] in float32: the retention
+  gate's logits are summed along the window, so they leave the matmul
+  unrounded."""
+
+  features: int
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    kernel = self.param('kernel', nn.initializers.lecun_normal(),
+                        (x.shape[-1], self.features), jnp.float32)
+    bias = self.param('bias', nn.initializers.zeros, (self.features,),
+                      jnp.float32)
+    out = jnp.einsum('blh,hk->blk', x, kernel.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    return out + bias.astype(jnp.float32)
+
+
+class PowerRetentionAttention(nn.Module):
+  """Gated power retention over grouped heads, two directions
+  (ops/power_retention.py): bias-free q/k/v projections, q and k each
+  RMSNorm'd over the head and given rotary positions, one gate per
+  key-value head, bias-free output projection."""
+
+  hidden_size: int
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  rope_theta: float
+  rms_norm_eps: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+    del deterministic  # the published layer has no dropout
+    dense = lambda name, heads: nn.DenseGeneral(
+        features=(heads, self.head_dim), axis=-1, use_bias=False,
+        dtype=self.dtype, kernel_init=nn.initializers.lecun_normal(),
+        name=name)
+    head_norm = lambda name: RMSNorm(self.rms_norm_eps, name=name)
+    query = head_norm('query_norm')(dense('query', self.num_heads)(x))
+    key = head_norm('key_norm')(dense('key', self.num_kv_heads)(x))
+    value = dense('value', self.num_kv_heads)(x)
+    # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+    # after norm and rotation in float32)
+    query = apply_rotary(query, self.rope_theta).astype(self.dtype)
+    # dclint: allow=dtype-downcast (as above)
+    key = apply_rotary(key, self.rope_theta).astype(self.dtype)
+    log_gate = jax.nn.log_sigmoid(
+        _GateProjection(self.num_kv_heads, name='gate')(x))
+    with jax.named_scope('retention'):
+      out = power_retention.power_retention_bidirectional(
+          query, key, value, log_gate)
+    return nn.DenseGeneral(
+        features=self.hidden_size, axis=(-2, -1), use_bias=False,
+        dtype=self.dtype, kernel_init=nn.initializers.lecun_normal(),
+        name='output_transform')(out)
+
+
+class GatedFeedForward(nn.Module):
+  """SwiGLU: (silu(x W_gate) * (x W_up)) W_down, no biases."""
+
+  hidden_size: int
+  filter_size: int
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+    del deterministic
+    dense = lambda width, name: nn.Dense(
+        width, use_bias=False, dtype=self.dtype,
+        kernel_init=nn.initializers.lecun_normal(), name=name)
+    h = nn.silu(dense(self.filter_size, 'gate_layer')(x))
+    h = h * dense(self.filter_size, 'up_layer')(x)
+    return dense(self.hidden_size, 'output_layer')(h)
+
+
 class ResidualWrapper(nn.Module):
-  """ReZero (x + alpha*f(x), alpha init 0) or pre-LN residual
-  (reference PrePostProcessingWrapper: encoder_stack.py:43-93)."""
+  """ReZero (x + alpha*f(x), alpha init 0), pre-LN residual
+  (reference PrePostProcessingWrapper: encoder_stack.py:43-93) or, with
+  `rms_norm_eps`, pre-RMSNorm residual in the stream's own type."""
 
   sublayer: nn.Module
   rezero: bool
   dropout_rate: float
+  rms_norm_eps: Optional[float] = None
 
   @nn.compact
   def __call__(self, x: jnp.ndarray, deterministic: bool,
                **sublayer_kwargs) -> jnp.ndarray:
     if self.rezero:
       y = x
+    elif self.rms_norm_eps is not None:
+      y = RMSNorm(self.rms_norm_eps, dtype=x.dtype, name='rms_norm')(x)
     else:
       y = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32, name='layer_norm')(x)
     y = self.sublayer(y, deterministic=deterministic, **sublayer_kwargs)
@@ -301,9 +419,82 @@ class ResidualWrapper(nn.Module):
     return x + y
 
 
+def block_kind_of(p) -> str:
+  """The encoder block kind a configuration names (params.json files
+  from before the key existed mean the one block there was)."""
+  kind = p.get('block_kind', None) or config_lib.BLOCK_BANDED_SOFTMAX
+  if kind not in config_lib.BLOCK_KINDS:
+    raise ValueError(
+        f'unknown block_kind {kind!r}; have {config_lib.BLOCK_KINDS}')
+  return kind
+
+
+def _block_modules(p, n: int, dtype):
+  """(attention, feed-forward, wrap) of encoder layer `n` for the
+  configuration's block kind: the one place that knows the kinds.
+  `wrap(sublayer, name)` gives the kind's residual form. Called inside
+  EncoderStack's compact method, so the modules are its children."""
+  kind = block_kind_of(p)
+  if kind == config_lib.BLOCK_POWER_RETENTION:
+    if p.retention_degree != power_retention.DEGREE:
+      raise ValueError(
+          f'retention_degree {p.retention_degree} is not served; '
+          f'ops/power_retention.py computes degree {power_retention.DEGREE}')
+    attn = PowerRetentionAttention(
+        hidden_size=p.hidden_size,
+        num_heads=p.num_heads,
+        num_kv_heads=p.num_kv_heads,
+        head_dim=p.head_dim,
+        rope_theta=p.rope_theta,
+        rms_norm_eps=p.rms_norm_eps,
+        dtype=dtype,
+        name=f'self_attention_{n}',
+    )
+    ffn = GatedFeedForward(
+        hidden_size=p.hidden_size,
+        filter_size=p.filter_size,
+        dtype=dtype,
+        name=f'ffn_{n}',
+    )
+    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
+  else:
+    attn = BandedSelfAttention(
+        hidden_size=p.hidden_size,
+        num_heads=p.num_heads,
+        dropout_rate=p.attention_dropout,
+        attn_win_size=p.attn_win_size,
+        dtype=dtype,
+        use_pallas=p.get('use_pallas_attention', False),
+        softmax_dtype=jnp.dtype(
+            p.get('attn_softmax_dtype', None) or 'float32'),
+        name=f'self_attention_{n}',
+    )
+    ffn = FeedForward(
+        hidden_size=p.hidden_size,
+        filter_size=p.filter_size,
+        dropout_rate=p.relu_dropout,
+        dtype=dtype,
+        name=f'ffn_{n}',
+    )
+    residual = dict(rezero=p.rezero)
+  wrap = lambda sublayer, name: ResidualWrapper(
+      sublayer, dropout_rate=p.layer_postprocess_dropout, name=name,
+      **residual)
+  return attn, ffn, wrap
+
+
+def _output_norm(p):
+  """The stack's final normalization, by block kind (float32 out)."""
+  if block_kind_of(p) == config_lib.BLOCK_POWER_RETENTION:
+    return RMSNorm(p.rms_norm_eps, name='output_normalization')
+  return nn.LayerNorm(
+      epsilon=1e-6, dtype=jnp.float32, name='output_normalization')
+
+
 class EncoderStack(nn.Module):
-  """N x (banded self-attention + FFN), final LayerNorm
-  (reference encoder_stack.py:96-198)."""
+  """N x (self-attention + FFN) of the configuration's block kind
+  (config.BLOCK_KINDS), then the kind's final normalization
+  (reference encoder_stack.py:96-198 for the published block)."""
 
   params: ml_collections.FrozenConfigDict
   dtype: Any = jnp.float32
@@ -321,9 +512,7 @@ class EncoderStack(nn.Module):
       # every attention/FFN block including the ReZero residuals; only
       # the final normalization remains. Init never takes this branch,
       # so the param tree is created identically.
-      return nn.LayerNorm(
-          epsilon=1e-6, dtype=jnp.float32, name='output_normalization'
-      )(x)
+      return _output_norm(p)(x)
 
     # Optional rematerialization: drop each residual block's
     # activations and recompute them in the backward pass, trading
@@ -344,6 +533,7 @@ class EncoderStack(nn.Module):
                          ragged_buckets=ragged_buckets)
 
     for n in range(p.num_hidden_layers):
+      attn, ffn, wrap = _block_modules(p, n, self.dtype)
       if skip_first_attention and n == 0:
         # The fused hot path (ops/fused_window_attention.py) already
         # applied attention_wrapper_0's block including the residual;
@@ -351,44 +541,12 @@ class EncoderStack(nn.Module):
         # unchanged (init never takes this branch).
         pass
       else:
-        attn = BandedSelfAttention(
-            hidden_size=p.hidden_size,
-            num_heads=p.num_heads,
-            dropout_rate=p.attention_dropout,
-            attn_win_size=p.attn_win_size,
-            dtype=self.dtype,
-            use_pallas=p.get('use_pallas_attention', False),
-            softmax_dtype=jnp.dtype(
-                p.get('attn_softmax_dtype', None) or 'float32'),
-            name=f'self_attention_{n}',
-        )
-        x = run_block(
-            ResidualWrapper(
-                attn, rezero=p.rezero,
-                dropout_rate=p.layer_postprocess_dropout,
-                name=f'attention_wrapper_{n}',
-            ),
-            x,
-            **attn_kwargs,
-        )
-      ffn = FeedForward(
-          hidden_size=p.hidden_size,
-          filter_size=p.filter_size,
-          dropout_rate=p.relu_dropout,
-          dtype=self.dtype,
-          name=f'ffn_{n}',
-      )
-      x = run_block(
-          ResidualWrapper(
-              ffn, rezero=p.rezero,
-              dropout_rate=p.layer_postprocess_dropout,
-              name=f'ffn_wrapper_{n}',
-          ),
-          x,
-      )
-    return nn.LayerNorm(
-        epsilon=1e-6, dtype=jnp.float32, name='output_normalization'
-    )(x)
+        with jax.named_scope('attention'):
+          x = run_block(wrap(attn, f'attention_wrapper_{n}'), x,
+                        **attn_kwargs)
+      with jax.named_scope('ffn'):
+        x = run_block(wrap(ffn, f'ffn_wrapper_{n}'), x)
+    return _output_norm(p)(x)
 
 
 class DeepConsensusModel(nn.Module):
@@ -488,8 +646,9 @@ class DeepConsensusModel(nn.Module):
     """True when this apply can route through the batch-major fused
     embed->condense->attention kernel. Init always runs the XLA path so
     the param tree is created identically; training needs gradients and
-    dropout the kernel doesn't serve; the kernel assumes the condensed
-    learn-values input, a ReZero residual for layer 0, and a window
+    dropout the kernel doesn't serve; the kernel computes the banded
+    softmax block only (any other block kind declines), and assumes the
+    condensed learn-values input, a ReZero residual for layer 0, and a window
     short enough for whole-L score blocks. rows.shape is static under
     trace, so with window buckets the routing is per bucket: each
     bucket's compiled forward independently picks fused
@@ -499,6 +658,7 @@ class DeepConsensusModel(nn.Module):
     p = self.params
     return bool(
         p.get('use_fused_hotpath', False)
+        and block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
         and not train
         and not self.is_initializing()
         and self.learn_values
@@ -593,6 +753,7 @@ class DeepConsensusModel(nn.Module):
     p = self.params
     return bool(
         p.get('use_fused_hotpath', False)
+        and block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
         and not self.is_initializing()
         and self.learn_values
         and p.condense_transformer_input
@@ -721,29 +882,34 @@ class DeepConsensusModel(nn.Module):
       logits = self.logits_layer(encoded.astype(jnp.float32))
       preds = jax.nn.softmax(logits, axis=-1)
       return {'final_output': encoded, 'logits': logits, 'preds': preds}
-    if self.learn_values:
-      x = self._embed_rows(rows)
-      if p.condense_transformer_input:
-        x = self.condenser(x)
-    else:
-      # Raw per-position feature vectors [B, L, total_rows], zero-padded
-      # to an even width for the positional encoding
-      # (reference: networks.py:266-306).
-      # dclint: allow=dtype-downcast (model entry point: inputs adopt
-      # the configured compute dtype once, here)
-      x = jnp.transpose(rows, (0, 2, 1)).astype(self.compute_dtype)
-      if p.add_pos_encoding and x.shape[-1] % 2 != 0:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, 1)))
-    if p.add_pos_encoding:
-      pos = sinusoidal_position_encoding(x.shape[1], x.shape[2])
-      x = x + jnp.asarray(pos, x.dtype)
+    # Scope names (`embed`, `attention` with `retention` inside, `ffn`,
+    # `head`) are kept as a promise to whoever reads the device trace by
+    # scope (docs/observability.md); they are metadata and change no HLO.
+    with jax.named_scope('embed'):
+      if self.learn_values:
+        x = self._embed_rows(rows)
+        if p.condense_transformer_input:
+          x = self.condenser(x)
+      else:
+        # Raw per-position feature vectors [B, L, total_rows], zero-padded
+        # to an even width for the positional encoding
+        # (reference: networks.py:266-306).
+        # dclint: allow=dtype-downcast (model entry point: inputs adopt
+        # the configured compute dtype once, here)
+        x = jnp.transpose(rows, (0, 2, 1)).astype(self.compute_dtype)
+        if p.add_pos_encoding and x.shape[-1] % 2 != 0:
+          x = jnp.pad(x, ((0, 0), (0, 0), (0, 1)))
+      if p.add_pos_encoding:
+        pos = sinusoidal_position_encoding(x.shape[1], x.shape[2])
+        x = x + jnp.asarray(pos, x.dtype)
     if train and p.layer_postprocess_dropout > 0:
       x = nn.Dropout(rate=p.layer_postprocess_dropout, name='input_dropout')(
           x, deterministic=deterministic
       )
     encoded = self.encoder(x, deterministic=deterministic)
-    logits = self.logits_layer(encoded.astype(jnp.float32))
-    preds = jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope('head'):
+      logits = self.logits_layer(encoded.astype(jnp.float32))
+      preds = jax.nn.softmax(logits, axis=-1)
     return {'final_output': encoded, 'logits': logits, 'preds': preds}
 
 

@@ -1,7 +1,9 @@
 """Load-time quantization levers for inference.
 
 Two independent levers, both applied ONCE at checkpoint load (before
-any device placement, so sharded transfers ship the shrunken bytes):
+any device placement, so sharded transfers ship the shrunken bytes; a
+leaf that already has the requested type, on the host or on the device,
+passes through as the same array):
 
 * `params.inference_dtype = 'bfloat16'`: cast every float param leaf
   to bf16. The model's compute dtype follows (runner sets params.dtype
@@ -113,11 +115,17 @@ def cast_params(variables: Dict[str, Any], dtype: Any) -> Dict[str, Any]:
   leaving every other collection (int8 values, f32 scales) untouched."""
   variables = dict(variables)
   dtype = jnp.dtype(dtype)
+
+  def cast(x):
+    have = jnp.result_type(x)
+    if have == dtype or not jnp.issubdtype(have, jnp.floating):
+      # Already there (gigabytes of bfloat16 leaves resident on the
+      # device stay the one copy), or not a float.
+      return x
+    return x.astype(dtype)
+
   variables['params'] = jax.tree_util.tree_map(
-      lambda x: x.astype(dtype)
-      if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x,
-      _as_mutable(variables['params']),
-  )
+      cast, _as_mutable(variables['params']))
   return variables
 
 

@@ -21,6 +21,8 @@ fleet processes appending to one file) and derives:
   sum to at most 1.0);
 * straggler packs — the slowest decile of device_compute spans with
   their bucket / dp / row-count context;
+* what the forward held and computed — from the `forward_launch`
+  spans' args: block kind, positions launched, resident weight bytes;
 * a span-derived transfer-overlap fraction that must agree with the
   counter-derived ``transfer_overlap_fraction``: a pack's forward
   launch (the device_compute span start) happening strictly BEFORE its
@@ -252,6 +254,17 @@ def summarize(events: List[Dict[str, Any]],
       })
     stragglers.sort(key=lambda row: -row['dur_s'])
 
+  launches = [e.get('args') or {} for e in spans
+              if e.get('name') == trace_lib.STAGE_LAUNCH]
+  forward = {
+      'n_launches': len(launches),
+      'block_kinds': sorted({str(a['block_kind']) for a in launches
+                             if a.get('block_kind')}),
+      'n_positions': sum(int(a.get('n_positions') or 0) for a in launches),
+      'weight_bytes': max(
+          (int(a.get('weight_bytes') or 0) for a in launches), default=0),
+  }
+
   return {
       'n_events': len(events),
       'n_spans': len(spans),
@@ -266,6 +279,7 @@ def summarize(events: List[Dict[str, Any]],
                 for name, row in sorted(waits.items())},
       'critical_path': critical_path,
       'stragglers': stragglers,
+      'forward': forward,
       'overlap': span_overlap(events),
       'n_traces': len(trace_groups(events)),
   }
@@ -298,6 +312,13 @@ def format_summary(summary: Dict[str, Any]) -> str:
     for name, row in summary['waits'].items():
       lines.append(f'  {name:<16} total {row["total_s"]:>10.4f}s  '
                    f'n={row["count"]}')
+  forward = summary.get('forward') or {}
+  if forward.get('n_launches'):
+    lines.append(
+        f'forward: {forward["n_launches"]} launches of '
+        f'{", ".join(forward["block_kinds"]) or "?"}, '
+        f'{forward["n_positions"]} positions, '
+        f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
   overlap = summary['overlap']
   lines.append(
       f'transfer overlap (span-derived): '

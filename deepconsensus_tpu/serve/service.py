@@ -657,6 +657,10 @@ class ConsensusService:
     counters.setdefault('n_starvation_flushes', 0)
     counters.setdefault('flush_padding_fraction', 0.0)
     counters.setdefault('use_ragged_kernel', 0)
+    # What the forward holds and computes (runner.dispatch_stats):
+    # resident parameter bytes and positions launched.
+    counters.setdefault('model_weight_bytes', 0)
+    counters.setdefault('n_forward_positions', 0)
     with self._lock:
       outstanding = len(self._outstanding)
     engine_stats = self.engine.stats()

@@ -439,6 +439,117 @@ def test_dctpu_trace_prints_self_time_and_no_gap_accounting(
       r['stage'] for r in payload['critical_path']}
 
 
+def _retention_params():
+  """The second block kind at a tiny size."""
+  p = config_lib.get_config('transformer_learn_values_retention+custom')
+  with p.unlocked():
+    p.max_passes = 5
+    p.transformer_input_size = 64
+    p.num_heads, p.num_kv_heads, p.head_dim = 4, 2, 8
+    p.filter_size = 96
+    p.num_hidden_layers = 2
+    p.dtype = p.inference_dtype = 'float32'
+  config_lib.finalize_params(p, is_training=False)
+  return p
+
+
+def _weighted_engine(p, weights):
+  """_engine() with a parameter tree resident: the stub forward reads
+  none of it, the runner counts all of it."""
+  options = runner_lib.InferenceOptions(batch_size=BATCH)
+  options.max_passes = p.max_passes
+  options.max_length = p.max_length
+  options.use_ccs_bq = p.use_ccs_bq
+  runner = runner_lib.ModelRunner(p, {'params': weights}, options)
+  ccs_row = 4 * p.max_passes
+
+  def forward(_variables, main_u8, _sn):
+    ids = main_u8[:, ccs_row, :, 0]
+    return ids, jnp.full(ids.shape, STUB_QUAL, jnp.uint8)
+
+  runner._forward = forward
+  engine = engine_lib.ConsensusEngine(
+      runner, options, deliver=lambda t, ids, quals: None)
+  return engine, runner, options
+
+
+def _test_params():
+  p = config_lib.get_config('transformer_learn_values+test')
+  config_lib.finalize_params(p, is_training=False)
+  return p
+
+
+KINDS = {
+    config_lib.BLOCK_BANDED_SOFTMAX: _test_params,
+    config_lib.BLOCK_POWER_RETENTION: _retention_params,
+}
+
+
+@pytest.mark.parametrize('kind', sorted(KINDS))
+def test_forward_launch_says_what_the_forward_holds_and_computes(
+    tmp_path, kind, capsys):
+  from deepconsensus_tpu import cli
+
+  p = KINDS[kind]()
+  weights = {'a': jnp.ones((7, 3), jnp.bfloat16),
+             'b': {'c': jnp.ones((5,), jnp.float32)}}
+  engine, runner, _ = _weighted_engine(p, weights)
+  windows = list(_raw(p, 2 * BATCH + 3))
+  path = str(tmp_path / 'spans.jsonl')
+  trace_lib.configure(path, tier='run')
+  try:
+    engine.submit(windows, list(range(len(windows))))
+    engine.flush()
+  finally:
+    trace_lib.configure(None)
+  events = [e for e in summarize_lib.load_trace(path) if e.get('ph') == 'X']
+  launches = _by_name(events)['forward_launch']
+  assert len(launches) == 3
+  for e in launches:
+    assert e['args']['block_kind'] == kind
+    # The compiled pack's rows x width, the tail pack's padding included.
+    assert e['args']['n_positions'] == BATCH * p.max_length
+    assert e['args']['weight_bytes'] == 7 * 3 * 2 + 5 * 4
+  stats = engine.stats()
+  assert stats['block_kind'] == kind
+  assert stats['model_weight_bytes'] == 62
+  assert stats['n_forward_positions'] == 3 * BATCH * p.max_length
+  snapshot = runner.obs.snapshot()
+  assert snapshot['gauges']['model_weight_bytes'] == 62
+  assert snapshot['counters']['n_forward_positions'] == (
+      3 * BATCH * p.max_length)
+  # And `dctpu trace` shows it.
+  assert cli.main(['trace', path, '--json']) == 0
+  forward = json.loads(capsys.readouterr().out)['forward']
+  assert forward == {'n_launches': 3, 'block_kinds': [kind],
+                     'n_positions': 3 * BATCH * p.max_length,
+                     'weight_bytes': 62}
+  assert cli.main(['trace', path]) == 0
+  assert f'forward: 3 launches of {kind}' in capsys.readouterr().out
+
+
+@pytest.mark.parametrize('kind', sorted(KINDS))
+def test_metricz_shows_weight_bytes_and_forward_positions(kind):
+  from deepconsensus_tpu.serve.service import ConsensusService, ServeOptions
+
+  p = KINDS[kind]()
+  _, runner, options = _weighted_engine(
+      p, {'w': jnp.ones((11, 2), jnp.bfloat16)})
+  service = ConsensusService(runner, options, ServeOptions())
+  before = service.stats()['counters']
+  assert before['model_weight_bytes'] == 44
+  assert before['n_forward_positions'] == 0
+  service.warmup()  # one pack through the runner
+  stats = service.stats()
+  assert stats['counters']['model_weight_bytes'] == 44
+  assert stats['counters']['n_forward_positions'] == BATCH * p.max_length
+  assert stats['block_kind'] == kind
+  prom = service.prom_text()
+  assert 'dctpu_model_weight_bytes{tier="serve"} 44' in prom
+  assert ('dctpu_n_forward_positions{tier="serve"} '
+          f'{BATCH * p.max_length}') in prom
+
+
 def test_profiler_capture_shows_the_stages_on_the_host_plane(
     tmp_path, params):
   """The profiler bridge: with tracing on (and jax imported), a stage is

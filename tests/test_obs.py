@@ -629,7 +629,12 @@ class TestSummarize:
     assert set(s) == {
         'n_events', 'n_spans', 'wall_s', 'tiers', 'stage_totals_s',
         'stage_counts', 'self_time', 'waits', 'critical_path', 'stragglers',
-        'overlap', 'n_traces'}
+        'forward', 'overlap', 'n_traces'}
+    # No forward_launch span in this trace: the block says so and the
+    # text leaves its line out.
+    assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
+                            'n_positions': 0, 'weight_bytes': 0}
+    assert 'forward:' not in text
 
   def test_stragglers_slowest_decile(self):
     events = [

@@ -4,7 +4,8 @@ The only file in the repo that describes the chip: every kernel and
 jitted forward the main path runs is lowered and compiled for a
 described (not attached) `v5e:2x2` device at production shapes
 (6 layers x hidden 280 x filter 2048, 85 rows x L=100, batch 1024 for
-inference and 256 for the loss). A compile that passes here is not a
+inference and 256 for the loss; two layers of the power-retention block
+kind at hidden 5120, batch 256). A compile that passes here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
 
@@ -110,6 +111,37 @@ def test_xla_forward_b1024(one_chip):
   assert _n_kernels(compiled) == 0
   # Fits one v5e chip (16 GB) with room for the dispatch pipeline.
   assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_power_retention_forward_b256_at_published_widths(one_chip):
+  """The second block kind as it is served: hidden 5120, 40 / 8 heads of
+  128, SwiGLU 17408, bfloat16 leaves, a pack of 256 windows; two of the
+  layers, by shape alone (no array of the 1.3 GB is made)."""
+  p = config_lib.get_config('transformer_learn_values_retention+custom')
+  with p.unlocked():
+    p.num_hidden_layers = 2
+  config_lib.finalize_params(p, is_training=False)
+  model = model_lib.get_model(p)
+  tree = jax.eval_shape(
+      lambda key: model.init(
+          key, jnp.zeros((1, p.total_rows, p.max_length, 1), jnp.float32)),
+      jax.random.PRNGKey(0))
+  variables = jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                     sharding=one_chip), tree)
+  rows = jax.ShapeDtypeStruct(
+      (256, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+  compiled = jax.jit(model.apply).lower(variables, rows).compile()
+  assert _n_kernels(compiled) == 0
+  memory = compiled.memory_analysis()
+  # Two layers of 330,352,904 bfloat16 parameters and what lies outside.
+  assert 2 * 2 * 330_352_904 < memory.argument_size_in_bytes < 1.4e9
+  # The temporaries of a pack do not grow with depth: 8 layers (5.3 GB of
+  # weights) leave the chip's other 10 GB to them.
+  assert memory.temp_size_in_bytes < 3 << 30
+  # Grouped heads: no repeat of k or v to 40 heads is materialised.
+  assert 'bf16[256,100,40,128]' in compiled.as_text()
+  assert 'repeat' not in compiled.as_text()
 
 
 def test_fused_front_end_b1024(one_chip, compiled_kernels):
