@@ -47,27 +47,23 @@ def sinusoidal_position_encoding(
   return np.concatenate([np.sin(scaled), np.cos(scaled)], axis=1)
 
 
-# Vocab bound for the one-hot matmul embedding path: above this the
-# materialized one-hot outweighs any MXU win (pw/ip vocab 256 stay on
-# the gather path even with the flag on).
-_ONEHOT_MAX_VOCAB = 32
-
-
 class MaskedEmbed(nn.Module):
   """Embedding with zero vectors for id 0 and sqrt(dim) output scaling
   (reference ModifiedOnDeviceEmbedding: networks.py:42-63).
 
-  onehot=True routes small-vocab lookups through a one-hot matmul
-  instead of a gather — a candidate MFU lever: gathers run on the
-  scalar/vector units while the matmul rides the MXU and fuses with
-  the downstream condenser. Values are identical (each output row is
-  a single table row either way); the flag exists to A/B on hardware.
+  The lookup is a contraction of a one-hot of the ids with the table,
+  not a gather: on the TPU a gather is bound by the index (1.7 ns each,
+  whatever the width) while XLA fuses the iota-compare into the
+  product's operand, so the one-hot is never written. Scaling and the
+  id-0 mask are applied to the [vocab, features] table, where they
+  commute with selecting a row: each value is bit-identical to
+  `take(table, ids, mode='clip') * sqrt(features) * (ids != 0)` for
+  ids >= 0 (ids above the vocabulary clamp to its last row).
   """
 
   vocab_size: int
   features: int
   dtype: Any = jnp.float32
-  onehot: bool = False
 
   @nn.compact
   def __call__(self, ids: jnp.ndarray) -> jnp.ndarray:
@@ -77,23 +73,25 @@ class MaskedEmbed(nn.Module):
         (self.vocab_size, self.features),
         jnp.float32,
     )
-    if self.onehot and self.vocab_size <= _ONEHOT_MAX_VOCAB:
-      # Clip first to match the gather path's mode='clip' semantics
-      # (one_hot would zero out-of-range rows instead of clamping).
-      ids_c = jnp.clip(ids, 0, self.vocab_size - 1)
-      oh = jax.nn.one_hot(ids_c, self.vocab_size, dtype=self.dtype)
-      # HIGHEST precision: each output row is one table row, and the
-      # default-precision matmul would bf16-round f32 tables, breaking
-      # exact equivalence with the gather path.
-      emb = jnp.matmul(oh, table.astype(self.dtype),
-                       precision=jax.lax.Precision.HIGHEST)
-    else:
-      # clip mode: out-of-range ids (already clipped upstream by
-      # format_rows) clamp instead of producing NaN fill values.
-      emb = jnp.take(table.astype(self.dtype), ids, axis=0, mode='clip')
-    emb = emb * jnp.asarray(self.features**0.5, self.dtype)
-    mask = (ids != 0).astype(self.dtype)
-    return emb * mask[..., None]
+    # The table is rounded to the compute dtype by an operation XLA may not
+    # skip: inside one fusion the TPU compiler drops a float32 -> bfloat16
+    # -> float32 pair (excess precision), and the values would then depend
+    # on whether the leaves were cast at load (inference_dtype) or here.
+    finfo = jnp.finfo(self.dtype)
+    table = jax.lax.reduce_precision(table, finfo.nexp, finfo.nmant)
+    table = table.astype(self.dtype) * jnp.asarray(
+        self.features**0.5, self.dtype)
+    table = table.at[0].set(0)  # id 0 selects a zero row: the mask
+    ids = jnp.clip(ids, 0, self.vocab_size - 1)
+    onehot = ids[..., None] == jnp.arange(self.vocab_size, dtype=ids.dtype)
+    # Each output is 1.0 x one table value plus zeros: exact in one
+    # bfloat16 pass with a float32 accumulator; HIGHEST keeps float32
+    # operands exact too (and is ignored for bfloat16 ones).
+    emb = jnp.einsum(
+        '...v,ve->...e', onehot.astype(self.dtype), table,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return emb.astype(self.dtype)
 
 
 class BandedSelfAttention(nn.Module):
@@ -572,31 +570,25 @@ class DeepConsensusModel(nn.Module):
           constants.SEQ_VOCAB_SIZE, use_bias=True, dtype=jnp.float32,
           kernel_init=nn.initializers.glorot_uniform(), name='logits')
       return
-    onehot = p.get('embed_onehot', False)
     if p.use_bases or p.use_ccs:
       self.bases_embedding = MaskedEmbed(
           constants.SEQ_VOCAB_SIZE, p.per_base_hidden_size, dt,
-          onehot=onehot, name='bases_embedding')
+          name='bases_embedding')
     if p.use_pw:
       self.pw_embedding = MaskedEmbed(
-          p.PW_MAX + 1, p.pw_hidden_size, dt, onehot=onehot,
-          name='pw_embedding')
+          p.PW_MAX + 1, p.pw_hidden_size, dt, name='pw_embedding')
     if p.use_ip:
       self.ip_embedding = MaskedEmbed(
-          p.IP_MAX + 1, p.ip_hidden_size, dt, onehot=onehot,
-          name='ip_embedding')
+          p.IP_MAX + 1, p.ip_hidden_size, dt, name='ip_embedding')
     if p.use_strand:
       self.strand_embedding = MaskedEmbed(
-          p.STRAND_MAX + 1, p.strand_hidden_size, dt, onehot=onehot,
-          name='strand_embedding')
+          p.STRAND_MAX + 1, p.strand_hidden_size, dt, name='strand_embedding')
     if p.use_ccs_bq:
       self.ccs_bq_embedding = MaskedEmbed(
-          p.CCS_BQ_MAX, p.ccs_bq_hidden_size, dt, onehot=onehot,
-          name='ccs_bq_embedding')
+          p.CCS_BQ_MAX, p.ccs_bq_hidden_size, dt, name='ccs_bq_embedding')
     if p.use_sn:
       self.sn_embedding = MaskedEmbed(
-          p.SN_MAX + 1, p.sn_hidden_size, dt, onehot=onehot,
-          name='sn_embedding')
+          p.SN_MAX + 1, p.sn_hidden_size, dt, name='sn_embedding')
     if p.condense_transformer_input:
       self.condenser = nn.Dense(
           p.transformer_input_size, use_bias=False, dtype=dt,
@@ -619,27 +611,29 @@ class DeepConsensusModel(nn.Module):
     )
     blocks = []
 
-    def gather(embedding, row_range, shift: int = 0):
+    def lookup(embedding, row_range, shift: int = 0):
       ids = rows[:, row_range[0]:row_range[1], :].astype(jnp.int32) + shift
-      emb = embedding(ids)  # [B, r, L, E]
-      b, r, l, e = emb.shape
-      return jnp.transpose(emb, (0, 2, 1, 3)).reshape(b, l, r * e)
+      # Ids go in position-major, so the lookup emits [B, L, r, E] and
+      # no [B, r, L, E] array is transposed.
+      emb = embedding(jnp.swapaxes(ids, 1, 2))
+      b, l, r, e = emb.shape
+      return emb.reshape(b, l, r * e)
 
     if p.use_bases:
-      blocks.append(gather(self.bases_embedding, base_r))
+      blocks.append(lookup(self.bases_embedding, base_r))
     if p.use_pw:
-      blocks.append(gather(self.pw_embedding, pw_r))
+      blocks.append(lookup(self.pw_embedding, pw_r))
     if p.use_ip:
-      blocks.append(gather(self.ip_embedding, ip_r))
+      blocks.append(lookup(self.ip_embedding, ip_r))
     if p.use_strand:
-      blocks.append(gather(self.strand_embedding, strand_r))
+      blocks.append(lookup(self.strand_embedding, strand_r))
     if p.use_ccs:
-      blocks.append(gather(self.bases_embedding, ccs_r))
+      blocks.append(lookup(self.bases_embedding, ccs_r))
     if p.use_ccs_bq:
       # Shift -1 (gap) to 0 (networks.py:491-497).
-      blocks.append(gather(self.ccs_bq_embedding, ccs_bq_r, shift=1))
+      blocks.append(lookup(self.ccs_bq_embedding, ccs_bq_r, shift=1))
     if p.use_sn:
-      blocks.append(gather(self.sn_embedding, sn_r))
+      blocks.append(lookup(self.sn_embedding, sn_r))
     return jnp.concatenate(blocks, axis=-1)
 
   def _fused_hotpath_eligible(self, rows: jnp.ndarray, train: bool) -> bool:

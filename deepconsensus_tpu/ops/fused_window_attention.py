@@ -14,11 +14,10 @@ sized crumbs.
 
 Per grid program, for a tile of windows, one VMEM-resident pass:
 
-  1. one-hot feature embedding (the `embed_onehot` MFU lever, done
-     structurally: the one-hot is built in VMEM with an iota compare
-     and immediately matmul'd against the family table — the gather
-     path's scalar-unit traffic and the [B, R, L, E] HBM intermediate
-     both disappear);
+  1. one-hot feature embedding (the one-hot is built in VMEM with an
+     iota compare and immediately matmul'd against the family table:
+     no gather and no [B, R, L, E] HBM intermediate, as on the XLA
+     path's MaskedEmbed);
   2. the condenser projection (`condense_transformer_input`), fused
      per row-chunk as a two-axis contraction so the 560-wide concat
      never materializes anywhere;

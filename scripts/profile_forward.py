@@ -3,7 +3,7 @@
 The measured forward MFU is 0.21 at b1024; this script attributes
 wall-clock across the forward's stages without parsing profiler traces
 (same strategy as bench_train_stages.py):
-cumulative ablations of the real model — embed gathers alone, +
+cumulative ablations of the real model — the embedding lookups alone, +
 condenser, + encoder, + logits/softmax — timed back-to-back in one
 process, plus standalone same-shape modules (one attention block, one
 FFN block) for the within-encoder split, plus compiled-flops MFU for
@@ -52,7 +52,7 @@ def main():
                   'forward (inspect offline with tensorboard/xprof)')
   ap.add_argument('--set', action='append', default=[], dest='overrides',
                   metavar='KEY=VALUE',
-                  help='config override (e.g. embed_onehot=true, '
+                  help='config override (e.g. '
                   'attn_softmax_dtype=bfloat16) for lever A/Bs')
   ap.add_argument('--config', default='transformer_learn_values+test',
                   help='config preset; use '

@@ -99,17 +99,16 @@ def kernel_args(params, variables, rows):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize('embed_onehot', [False, True])
-def test_fused_matches_xla_on_golden_production_windows(embed_onehot):
+def test_fused_matches_xla_on_golden_production_windows():
   """L=100, condensed, ReZero: the acceptance-criteria golden. Batch 11
   with the default tile of 8 also exercises the batch-padding path."""
-  params = make_params(embed_onehot=embed_onehot)
+  params = make_params()
   assert params.max_length == 100 and params.condense_transformer_input
   model, variables, rows = init_pair(params, batch=11, seed=7)
   ref = model.apply(variables, rows, False,
                     method='apply_with_intermediates')
 
-  params_f = make_params(embed_onehot=embed_onehot, use_fused_hotpath=True)
+  params_f = make_params(use_fused_hotpath=True)
   model_f = model_lib.get_model(params_f)
   got = model_f.apply(variables, rows, False,
                       method='apply_with_intermediates')

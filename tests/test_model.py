@@ -55,24 +55,75 @@ def test_forward_shapes_and_softmax():
   )
 
 
-def test_embed_onehot_matches_gather():
-  """The one-hot-matmul embedding lever (embed_onehot) must be a pure
-  execution-strategy change: identical predictions with the SAME
-  variables as the default gather path (each output row is a single
-  table row either way)."""
+def _take_embed(table, ids, dtype):
+  """MaskedEmbed as a gather: the form the contraction replaces."""
+  emb = jnp.take(table.astype(dtype), ids, axis=0, mode='clip')
+  emb = emb * jnp.asarray(table.shape[1] ** 0.5, dtype)
+  return emb * (ids != 0).astype(dtype)[..., None]
+
+
+def _edge_ids(vocab, shape=(3, 7, 11), seed=0):
+  """Ids over the whole vocabulary, with 0, the maximum and values beyond
+  the vocabulary (which clip to its last row) certain to occur."""
+  ids = np.random.default_rng(seed).integers(0, vocab + 40, size=shape)
+  ids.flat[:4] = [0, vocab - 1, vocab, vocab + 1000]
+  return jnp.asarray(ids, jnp.int32)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('vocab', [3, 5, 256, 501])
+def test_masked_embed_matches_take(vocab, dtype):
+  """The one-hot contraction selects single table values: bit-equal to
+  take * sqrt * mask at every vocabulary the model has."""
+  dtype = jnp.dtype(dtype)
+  emb = model_lib.MaskedEmbed(vocab_size=vocab, features=8, dtype=dtype)
+  ids = _edge_ids(vocab, seed=vocab)
+  variables = emb.init(jax.random.PRNGKey(vocab), ids)
+  got = emb.apply(variables, ids)
+  want = _take_embed(variables['params']['embedding'], ids, dtype)
+  assert got.dtype == dtype and got.shape == ids.shape + (8,)
+  np.testing.assert_array_equal(
+      np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+def test_masked_embed_table_gradient_matches_take():
+  """Training: the table's gradient through the contraction (a product
+  with the one-hot) is the gather form's scatter-add, row 0 included."""
+  emb = model_lib.MaskedEmbed(vocab_size=256, features=8)
+  ids = _edge_ids(256, shape=(4, 20, 50), seed=1)
+  table = emb.init(jax.random.PRNGKey(1), ids)['params']['embedding']
+  weights = jax.random.normal(jax.random.PRNGKey(2), ids.shape + (8,))
+  got = jax.grad(lambda t: jnp.sum(
+      emb.apply({'params': {'embedding': t}}, ids) * weights))(table)
+  want = jax.grad(lambda t: jnp.sum(
+      _take_embed(t, ids, jnp.float32) * weights))(table)
+  assert not np.asarray(got[0]).any()
+  np.testing.assert_allclose(
+      np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_embed_rows_concat_order_is_the_per_row_takes():
+  """_embed_rows' [B, L, 560] is, bit for bit, the per-row lookups in the
+  order the condenser's weight and every checkpoint were trained on:
+  bases, pw, ip, strand rows (pass by pass), then ccs, then the SN rows."""
   params = make_params()
-  rows = fake_rows(params, batch=3, seed=7)
+  rows = fake_rows(params, batch=3, seed=5)
   model = model_lib.get_model(params)
   variables = model.init(jax.random.PRNGKey(0), rows)
-  base = model.apply(variables, rows)
-  params_oh = make_params(embed_onehot=True)
-  model_oh = model_lib.get_model(params_oh)
-  got = model_oh.apply(variables, rows)
-  np.testing.assert_allclose(np.asarray(got), np.asarray(base),
-                             rtol=1e-6, atol=1e-6)
-  # Large-vocab families (pw/ip 256, sn 501) must stay on the gather
-  # path regardless of the flag (one-hot materialization cost).
-  assert model_lib._ONEHOT_MAX_VOCAB < 256
+  got = model.apply(
+      variables, rows[..., 0], method=lambda m, r: m._embed_rows(r))
+  tables = {k[:-len('_embedding')]: v['embedding']
+            for k, v in variables['params'].items() if 'embedding' in k}
+  mp = params.max_passes
+  families = (['bases'] * mp + ['pw'] * mp + ['ip'] * mp + ['strand'] * mp
+              + ['bases'] + ['sn'] * 4)
+  assert len(families) == params.total_rows
+  want = jnp.concatenate([
+      _take_embed(tables[family], rows[:, i, :, 0].astype(jnp.int32),
+                  jnp.float32)
+      for i, family in enumerate(families)], axis=-1)
+  assert got.shape == (3, params.max_length, 560)
+  np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_attn_softmax_dtype_lever():

@@ -16,6 +16,7 @@ persistent compile cache is off around the module (a described-device
 executable cannot be read back without a chip).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,11 +107,33 @@ def _n_kernels(compiled):
   return compiled.as_text().count('tpu_custom_call')
 
 
+def _entry_computation(text):
+  """The instructions whose results are buffers of the program: what a
+  fused computation holds inside is never written to memory."""
+  return text[text.index('\nENTRY '):].splitlines()
+
+
 def test_xla_forward_b1024(one_chip):
   compiled = _compile_forward(_params(), one_chip)
   assert _n_kernels(compiled) == 0
-  # Fits one v5e chip (16 GB) with room for the dispatch pipeline.
-  assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+  # Every embedding table is looked up by a product with a one-hot that
+  # XLA fuses into the product's operand: no gather is left (the parent of
+  # PR 29 had six, bound by the index at 1.7 ns each on the chip), and no
+  # one-hot over a vocabulary (256 for pw and ip, 501 for sn) is written.
+  text = compiled.as_text()
+  assert not re.search(r'\bgather\(', text)
+  assert 'iota_compare_fusion' in text
+  # Each of the five tables is rounded to bfloat16 by an operation the
+  # compiler keeps: without it the chip read one rounding fewer than the
+  # CPU and than the gather form did (PERF.md section 6, PR 29).
+  assert text.count('reduce-precision(') == 5
+  one_hots = [line for line in _entry_computation(text)
+              if re.search(r'= \(?\w+\[[0-9,]*,(256|501)\]', line)]
+  assert not one_hots, one_hots
+  # The temporaries of a pack of 1,024: 671,083,008 bytes with the gathers
+  # (their [2048000, 8] outputs, the transposing copies, the int32 indices),
+  # 216,762,880 with the products, which write into the concat in place.
+  assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20
 
 
 def test_power_retention_forward_b256_at_published_widths(one_chip):
