@@ -145,10 +145,6 @@ def _add_run(sub):
                  help='Featurize batches buffered between the model '
                  'stage and the stitch/emit worker before the model '
                  'stage blocks.')
-  p.add_argument('--no_cross_batch_packing', action='store_true',
-                 help='Pad out each featurize batch\'s model tail '
-                 'instead of packing windows across batches into full '
-                 'fixed-shape model batches (debug/compat).')
   p.add_argument('--max_record_bytes', type=int, default=64 << 20,
                  help='Per-record allocation cap for the BAM decoders: '
                  'a record claiming more than this many bytes is '
@@ -1234,7 +1230,6 @@ def _dispatch(args) -> int:
         device_epilogue=args.device_epilogue,
         window_buckets=args.window_buckets,
         use_ragged_kernel=args.use_ragged_kernel,
-        pack_across_batches=not args.no_cross_batch_packing,
         max_record_bytes=args.max_record_bytes,
         dc_calibration_values=calibration_lib.parse_calibration_string(
             dc_cal

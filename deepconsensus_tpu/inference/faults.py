@@ -115,17 +115,6 @@ log = logging.getLogger(__name__)
 # deepconsensus_tpu/faults.py)
 
 
-class FaultStage:
-  """Pipeline stage where a fault surfaced."""
-
-  DECODE = 'decode'        # BAM/BGZF stream decoding (feeder)
-  FEATURIZE = 'featurize'  # alignment expansion / pileup / windows
-  MODEL = 'model'          # device dispatch / forward pass
-  STITCH = 'stitch'        # window stitching / output formatting
-
-  ALL = (DECODE, FEATURIZE, MODEL, STITCH)
-
-
 class OnZmwError:
   """--on-zmw-error policy values."""
 

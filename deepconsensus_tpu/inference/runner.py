@@ -56,7 +56,6 @@ from deepconsensus_tpu.preprocess import (
     reads_to_pileup,
 )
 from deepconsensus_tpu.preprocess.pileup import row_indices
-from deepconsensus_tpu.utils import phred
 
 log = logging.getLogger(__name__)
 
@@ -113,11 +112,6 @@ class InferenceOptions:
   # i+1..i+k with the compute of batch i. Device-side cost per in-flight batch is one
   # uint8 input buffer (~21 MB at b1024) + tiny outputs.
   dispatch_depth: int = 8
-  # Cross-batch window packing: model batches are cut from a window
-  # buffer spanning featurize batches, so the compiled forward runs
-  # full except for one end-of-input tail (False reverts to per-
-  # featurize-batch packs, each padded to batch_size).
-  pack_across_batches: bool = True
   # Bounded hand-off queue between the model stage and the stitch/emit
   # worker thread, in featurize batches. Deeper absorbs longer emit
   # stalls (slow disk) before the device pipeline feels them; each
@@ -523,21 +517,12 @@ class ModelRunner:
     bq_row = self._bq_row
     self._configure_epilogue()
     thresholds = self._epilogue_thresholds
-    # The Pallas epilogue rides the fused hot path (appended after the
-    # last fused encoder block's output); under a mesh the XLA epilogue
-    # shards trivially with the existing out_shardings instead.
-    pallas_epilogue = (
-        thresholds is not None
-        and bool(params.get('use_fused_hotpath', False))
-        and mesh is None
-    )
 
     def forward(variables, main_u8, sn):
       rows = _assemble_rows(main_u8, sn, bq_row)
       preds = model.apply(variables, rows)
       if thresholds is not None:
-        return output_plane.phred_epilogue(
-            preds, thresholds, use_pallas=pallas_epilogue)
+        return output_plane.phred_epilogue(preds, thresholds)
       pred_ids = jnp.argmax(preds, axis=-1).astype(jnp.int32)
       max_prob = jnp.max(preds, axis=-1)
       return pred_ids, max_prob
@@ -546,8 +531,7 @@ class ModelRunner:
       rows = _assemble_rows_ragged(main_u8, sn_w, lengths, bq_row)
       preds = model.apply(variables, rows, window_lengths=lengths)
       if thresholds is not None:
-        return output_plane.phred_epilogue(
-            preds, thresholds, use_pallas=pallas_epilogue)
+        return output_plane.phred_epilogue(preds, thresholds)
       pred_ids = jnp.argmax(preds, axis=-1).astype(jnp.int32)
       max_prob = jnp.max(preds, axis=-1)
       return pred_ids, max_prob
@@ -1360,33 +1344,6 @@ def _features_from_shm(result):
   return features, counter, shm
 
 
-def process_skipped_window(
-    feature_dict: Dict[str, Any], options: InferenceOptions
-) -> stitch.DCModelOutput:
-  """Adopts the CCS bases/qualities for a skipped window
-  (reference: quick_inference.py:567-594)."""
-  rows = feature_dict['subreads']
-  ccs_range = row_indices(options.max_passes, options.use_ccs_bq)[4]
-  ccs = rows[ccs_range[0], :, 0]
-  ccs_seq = phred.encoded_sequence_to_string(ccs)
-  quals = np.asarray(feature_dict['ccs_base_quality_scores'])
-  if options.ccs_calibration_values.enabled:
-    quals = calibration_lib.calibrate_quality_scores(
-        quals, options.ccs_calibration_values
-    )
-  quals = np.minimum(quals, options.max_base_quality).astype(np.int32)
-  return stitch.DCModelOutput(
-      window_pos=feature_dict['window_pos'],
-      molecule_name=feature_dict['name'],
-      sequence=ccs_seq,
-      quality_string=phred.quality_scores_to_string(np.maximum(quals, 0)),
-      ec=feature_dict['ec'],
-      np_num_passes=feature_dict['np_num_passes'],
-      rq=feature_dict['rq'],
-      rg=feature_dict['rg'],
-  )
-
-
 # The model stage (triage -> pack -> dispatch -> finalize) lives in
 # inference/engine.py as ConsensusEngine; this pipeline is one of its
 # thin clients (the serve daemon is the other). Aliases keep the
@@ -1485,52 +1442,6 @@ class _BatchState:
   @property
   def complete(self) -> bool:
     return self.featurized and self.pending == 0
-
-
-def run_model_on_windows(
-    feature_dicts: List[Dict[str, Any]],
-    runner: ModelRunner,
-    params,
-    options: InferenceOptions,
-) -> List[stitch.DCModelOutput]:
-  """Formats, batches, and runs windows through the model
-  (reference: quick_inference.py:341-415)."""
-  outputs: List[stitch.DCModelOutput] = []
-
-  # Pipelined: keep up to options.dispatch_depth batches in flight so
-  # host-side stacking/quality math and per-dispatch transfer latency
-  # overlap device compute; drain in order.
-  pending: List[Tuple[List, Any]] = []
-  depth = max(1, options.dispatch_depth)
-
-  def drain(entry):
-    chunk, dispatched = entry
-    pred_ids, quality = runner.finalize(dispatched)
-    for c, ids, quals in zip(chunk, pred_ids, quality):
-      outputs.append(
-          stitch.DCModelOutput(
-              window_pos=c['window_pos'],
-              molecule_name=c['name'] if isinstance(c['name'], str)
-              else c['name'].decode(),
-              sequence=phred.encoded_sequence_to_string(ids),
-              quality_string=phred.quality_scores_to_string(quals),
-              ec=c['ec'],
-              np_num_passes=c['np_num_passes'],
-              rq=c['rq'],
-              rg=c['rg'],
-          )
-      )
-
-  for start in range(0, len(feature_dicts), options.batch_size):
-    chunk = feature_dicts[start : start + options.batch_size]
-    raw = np.stack([c['subreads'] for c in chunk])
-    rows = data_lib.format_rows_batch(raw, params)
-    pending.append((chunk, runner.dispatch(rows)))
-    if len(pending) > depth:
-      drain(pending.pop(0))
-  while pending:
-    drain(pending.pop(0))
-  return outputs
 
 
 def run_inference(
@@ -2045,10 +1956,6 @@ def run_inference(
           # A list (not a stacked array): widths may mix across buckets;
           # the engine groups per bucket preserving featurize order.
           engine.submit([fd['subreads'] for fd in to_model], slots)
-          if not options.pack_across_batches:
-            # Compat/debug mode: pad out this batch's tail instead of
-            # carrying it into the next featurize batch's pack.
-            engine.flush(drain=False)
         feat['windows'] = None
         state.featurized = True
         states.append(state)

@@ -129,37 +129,11 @@ class BamRecord:
   def is_secondary(self) -> bool:
     return bool(self.flag & FSECONDARY)
 
-  @property
-  def cigartuples(self) -> List[Tuple[int, int]]:
-    return list(zip(self.cigar_ops.tolist(), self.cigar_lens.tolist()))
-
   def get_tag(self, name: str):
     return self.tags[name]
 
   def has_tag(self, name: str) -> bool:
     return name in self.tags
-
-  @property
-  def query_alignment_start(self) -> int:
-    """Index of the first non-soft-clipped base of seq."""
-    start = 0
-    for op, ln in zip(self.cigar_ops, self.cigar_lens):
-      if op == constants.Cigar.SOFT_CLIP:
-        start += int(ln)
-      elif op != constants.Cigar.HARD_CLIP:
-        break
-    return start
-
-  @property
-  def query_alignment_end(self) -> int:
-    """One past the last non-soft-clipped base of seq."""
-    end = len(self.seq)
-    for op, ln in zip(self.cigar_ops[::-1], self.cigar_lens[::-1]):
-      if op == constants.Cigar.SOFT_CLIP:
-        end -= int(ln)
-      elif op != constants.Cigar.HARD_CLIP:
-        break
-    return end
 
   def expanded_cigar(self) -> np.ndarray:
     """Per-position cigar ops (uint8), hard clips excluded."""

@@ -394,9 +394,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
 
   # TPU-native execution knobs (not in the reference).
   params.dtype = 'bfloat16'          # compute dtype; params stay float32
-  # MFU A/B lever (see scripts/profile_forward.py): the attention
-  # softmax accumulation dtype on the XLA path (None/'float32' =
-  # reference-matching default).
+  # The attention softmax accumulation dtype on the XLA path
+  # (None/'float32' = reference-matching default).
   params.attn_softmax_dtype = ml_collections.config_dict.placeholder(str)
   params.use_pallas_attention = False
   # Batch-major fused embed->condense->layer-0-attention Pallas kernel

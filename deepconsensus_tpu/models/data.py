@@ -229,39 +229,6 @@ def fill_pack(windows, layout: PackLayout, main_u8: np.ndarray,
             layout.sn_max, out=sn[at + lo:at + hi])
 
 
-def parse_example(
-    raw: bytes,
-    params: ml_collections.ConfigDict,
-    inference: bool = False,
-) -> Dict[str, np.ndarray]:
-  """Parses one serialized example into formatted features
-  (reference process_input: data_providers.py:249-297)."""
-  ex = Example.parse(raw)
-  shape = ex['subreads/shape']
-  subreads = np.frombuffer(
-      ex['subreads/encoded'][0], dtype=constants.NP_DATA_TYPE
-  ).reshape(shape)
-  out = {
-      'rows': format_rows(subreads, params),
-      'num_passes': np.asarray(
-          ex['subreads/num_passes'][0], dtype=constants.NP_DATA_TYPE
-      ),
-      'window_pos': np.asarray(ex['window_pos'][0], dtype=np.int64),
-      'name': ex['name'][0],
-      'ccs_base_quality_scores': np.asarray(
-          ex['ccs_base_quality_scores'], dtype=np.int64
-      ),
-  }
-  if not inference:
-    label = np.frombuffer(
-        ex['label/encoded'][0], dtype=constants.NP_DATA_TYPE
-    ).reshape(ex['label/shape'])
-    if params.remove_label_gaps:
-      label = phred.left_shift_seq(label)
-    out['label'] = label
-  return out
-
-
 # The only proto fields the training batch path needs; everything else
 # (notably the 100-varint ccs_base_quality_scores walk) is skipped.
 _MINIMAL_FIELDS = frozenset({
@@ -415,25 +382,6 @@ def _batch_from_minimal(
       label = phred.left_shift(label)
     batch['label'] = label
   return batch
-
-
-def process_feature_dict(
-    features: Dict, params: ml_collections.ConfigDict
-) -> Dict:
-  """Formats an in-memory inference feature dict
-  (reference: data_providers.py:187-223)."""
-  return {
-      'rows': format_rows(features['subreads'], params),
-      'label': np.empty(0, dtype=constants.NP_DATA_TYPE),
-      'num_passes': features['subreads/num_passes'],
-      'window_pos': features['window_pos'],
-      'name': features['name'],
-      'ccs_base_quality_scores': features['ccs_base_quality_scores'],
-      'ec': features['ec'],
-      'np_num_passes': features['np_num_passes'],
-      'rq': features['rq'],
-      'rg': features['rg'],
-  }
 
 
 @dataclasses.dataclass

@@ -49,10 +49,7 @@ def export_model(
   dc_calibration / max_base_quality, which are baked into the program
   and recorded in the metadata (from_exported refuses a load whose
   quality knobs disagree). Without it, the serving call returns
-  softmax preds and the host computes qualities, as before. The XLA
-  epilogue is used unconditionally here — a Pallas call would pin the
-  artifact to one backend's custom-call ABI; StableHLO keeps it
-  portable.
+  softmax preds and the host computes qualities, as before.
 
   polymorphic_batch exports the batch dimension symbolically, so the
   artifact serves ANY batch size (the reference's SavedModel does

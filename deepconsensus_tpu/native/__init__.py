@@ -46,9 +46,9 @@ def _build() -> bool:
 def get_lib() -> Optional[ctypes.CDLL]:
   """Loads (building if needed) the native library, or None.
 
-  DC_TPU_NO_NATIVE=1 disables it (emergency off-switch + the
-  native-vs-Python A/B knob for bench_loader.py; checked per call so
-  spawn-based worker processes honor it too)."""
+  DC_TPU_NO_NATIVE=1 disables it (emergency off-switch to the
+  pure-Python decoders; checked per call so spawn-based worker
+  processes honor it too)."""
   if os.environ.get('DC_TPU_NO_NATIVE') == '1':
     return None
   global _lib, _build_failed

@@ -270,13 +270,6 @@ class MetricsRegistry:
         'histograms': {m.name: m.snapshot() for m in histograms},
     }
 
-  def latency_summary(self) -> Dict[str, Dict[str, Any]]:
-    """Per-histogram nearest-rank percentiles (the /metricz `latency`
-    nesting)."""
-    with self._lock:
-      histograms = list(self._histograms.values())
-    return {m.name: m.percentiles() for m in histograms}
-
   def to_prom(self, tier: Optional[str] = None) -> str:
     """Prometheus text exposition (v0.0.4) of the whole registry."""
     tier = tier if tier is not None else self.tier

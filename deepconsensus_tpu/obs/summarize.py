@@ -34,7 +34,7 @@ fleet processes appending to one file) and derives:
 
 The per-stage totals here and the ``stage_*_s`` histogram sums in
 /metricz come from the same measured intervals (obs.record_stage), so
-they reconcile within float rounding — bench.py asserts within 1%.
+they reconcile within float rounding (tests/test_obs.py).
 """
 from __future__ import annotations
 

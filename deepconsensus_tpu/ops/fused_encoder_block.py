@@ -6,7 +6,7 @@ embed->condense->pos->layer-0 attention; this one covers everything
 after it — for each remaining encoder block, banded multi-head
 attention, the relu FFN, and both ReZero residuals run as ONE grid
 program per tile of windows, with the same batch-major tiling
-(DC_TPU_FUSED_TILE windows per program, every projection an MXU-shaped
+(DEFAULT_TILE_WINDOWS windows per program, every projection an MXU-shaped
 [tile*L, K] x [K, N] matmul).
 
 One pallas_call per encoder block, not one for the whole stack: five

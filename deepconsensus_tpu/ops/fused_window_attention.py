@@ -43,7 +43,6 @@ params.use_fused_hotpath is set and the config is eligible
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -68,8 +67,8 @@ MAX_WINDOW_LEN = config_lib.FUSED_MAX_WINDOW_LEN
 
 # Windows per grid program. 8 keeps the peak VMEM footprint (one-hot
 # chunk + live q/k/v/x values + weights) near 11 MB at the production
-# shape; override for sweeps without a code change.
-DEFAULT_TILE_WINDOWS = int(os.environ.get('DC_TPU_FUSED_TILE', '8'))
+# shape.
+DEFAULT_TILE_WINDOWS = 8
 
 # VMEM budget for one transient one-hot block [tile, chunk, L, V] f32;
 # bounds how many rows of a family are one-hot-encoded at once.

@@ -18,23 +18,19 @@ step function of max_prob with at most max_base_quality steps, so on
 device a quality is just a count of thresholds <= max_prob: pure IEEE
 comparisons, bit-exact on every backend by construction.
 
-Two device implementations share the thresholds: a plain-XLA epilogue
-(compare + sum) and a Pallas kernel that fuses argmax + threshold
-count into one VMEM pass appended after the last fused encoder block.
-Both emit two uint8 planes — base ids and Phred qualities — shrinking
-D2H per pack from 8 bytes/position (int32 ids + f32 max_prob) to 2.
+The device epilogue (plain XLA: compare + sum) emits two uint8 planes
+— base ids and Phred qualities — shrinking D2H per pack from
+8 bytes/position (int32 ids + f32 max_prob) to 2.
 """
 from __future__ import annotations
 
 import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from deepconsensus_tpu.calibration import lib as calibration_lib
-from deepconsensus_tpu.ops import pallas_util
 
 # The host epilogue's error-probability floor (runner._finalize_sync).
 MIN_ERROR_PROB = 1e-12
@@ -158,23 +154,14 @@ def _verify_thresholds(thresholds: np.ndarray, oracle) -> bool:
   return bool(np.array_equal(counted, oracle(p)))
 
 
-def d2h_bytes_per_position(device_epilogue: bool) -> int:
-  """Bytes/position the finalize drain pulls over D2H: two uint8
-  planes with the device epilogue, int32 ids + f32 max_prob without."""
-  return 2 if device_epilogue else 8
-
-
 # ---------------------------------------------------------------------------
-# Device epilogues (XLA + Pallas) — same thresholds, same outputs.
+# Device epilogue.
 # ---------------------------------------------------------------------------
 
 
 def phred_epilogue(
     preds: jnp.ndarray,
     thresholds: np.ndarray,
-    *,
-    use_pallas: bool = False,
-    interpret: Optional[bool] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
   """Softmax preds [B, L, V] -> (ids uint8 [B, L], quals uint8 [B, L]).
 
@@ -183,75 +170,10 @@ def phred_epilogue(
   prob clears — exactly host_quality_reference, with no device
   transcendentals (see module docstring).
   """
-  if use_pallas:
-    return phred_epilogue_pallas(preds, thresholds, interpret=interpret)
   thr = jnp.asarray(thresholds, jnp.float32)
   ids = jnp.argmax(preds, axis=-1).astype(jnp.uint8)
   max_prob = jnp.max(preds, axis=-1)
   quals = jnp.sum(
       max_prob[..., None] >= thr[None, None, :], axis=-1
   ).astype(jnp.uint8)
-  return ids, quals
-
-
-def _epilogue_kernel(preds_ref, thr_ref, ids_ref, quals_ref):
-  """One VMEM pass per window tile: argmax + threshold count."""
-  preds = preds_ref[...]
-  ids_ref[...] = jnp.argmax(preds, axis=-1).astype(jnp.uint8)
-  max_prob = jnp.max(preds, axis=-1)
-  thr = thr_ref[...]
-  quals_ref[...] = jnp.sum(
-      max_prob[:, :, None] >= thr[0][None, None, :], axis=-1
-  ).astype(jnp.uint8)
-
-
-def _pick_tile(batch: int, want: int = 8) -> int:
-  while want > 1 and batch % want:
-    want //= 2
-  return max(1, want)
-
-
-def phred_epilogue_pallas(
-    preds: jnp.ndarray,
-    thresholds: np.ndarray,
-    *,
-    interpret: Optional[bool] = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-  """Pallas twin of phred_epilogue: the output-plane epilogue appended
-  after the last fused encoder block, tiled batch-major like the block
-  kernels. Thresholds ride in as one f32 lane row padded with +inf
-  (padding can never count: p >= inf is false)."""
-  from jax.experimental import pallas as pl
-  from jax.experimental.pallas import tpu as pltpu
-
-  interpret = pallas_util.resolve_interpret(interpret)
-  b, length, vocab = preds.shape
-  lane = 128
-  k = int(np.asarray(thresholds).size)
-  k_pad = max(lane, ((k + lane - 1) // lane) * lane)
-  thr = np.full((1, k_pad), np.inf, np.float32)
-  thr[0, :k] = np.asarray(thresholds, np.float32)
-  tile = _pick_tile(b)
-  grid = (b // tile,)
-  ids, quals = pl.pallas_call(
-      _epilogue_kernel,
-      grid=grid,
-      in_specs=[
-          pl.BlockSpec((tile, length, vocab), lambda i: (i, 0, 0),
-                       memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, k_pad), lambda i: (0, 0),
-                       memory_space=pltpu.VMEM),
-      ],
-      out_specs=[
-          pl.BlockSpec((tile, length), lambda i: (i, 0),
-                       memory_space=pltpu.VMEM),
-          pl.BlockSpec((tile, length), lambda i: (i, 0),
-                       memory_space=pltpu.VMEM),
-      ],
-      out_shape=[
-          jax.ShapeDtypeStruct((b, length), jnp.uint8),
-          jax.ShapeDtypeStruct((b, length), jnp.uint8),
-      ],
-      interpret=interpret,
-  )(preds, jnp.asarray(thr))
   return ids, quals

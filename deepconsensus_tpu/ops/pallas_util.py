@@ -40,8 +40,7 @@ def execution_report() -> Dict[str, Any]:
 # ragged front end, fused encoder block). The compiler's default scope
 # is 16 MiB; at the production 280/2048 shape and tile=8 the f32
 # encoder block asks for 20.66 MiB and the 200-wide ragged front end
-# for more than 16. 48 MiB leaves room for a DC_TPU_FUSED_TILE=16
-# sweep and stays far under the 128 MiB a v5e core has.
+# for more than 16. 48 MiB stays far under the 128 MiB a v5e core has.
 BATCH_TILE_VMEM_LIMIT_BYTES = 48 << 20
 
 

@@ -23,12 +23,8 @@ Array = jnp.ndarray
 # Scan unroll factor. Measured on TPU v5e at batch 256: unroll=4 makes
 # the differentiated loss scan ~5x faster per step, but inflates the
 # full train-step XLA compile from ~4 min to >9 min on this stack, so
-# the default stays 1. Set DC_TPU_SCAN_UNROLL for long production runs
-# where the persistent compilation cache
-# (utils/compile_cache.py) amortizes the one-time cost.
-import os as _os
-
-SCAN_UNROLL = int(_os.environ.get('DC_TPU_SCAN_UNROLL', '1'))
+# the value stays 1.
+SCAN_UNROLL = 1
 
 
 def wavefrontify(t: Array) -> Array:

@@ -44,11 +44,8 @@ Array = jnp.ndarray
 # (Pallas double-buffers the streamed [unroll, B, m]/[B, m+1] blocks,
 # and emit_rows streams an [unroll, B, m+1] output block too), so the
 # effective unroll is capped per call by _auto_unroll to keep streamed
-# blocks inside a VMEM budget. Override the max via
-# DC_TPU_PALLAS_UNROLL (1 disables unrolling).
-import os as _os
-
-PALLAS_UNROLL = int(_os.environ.get('DC_TPU_PALLAS_UNROLL', '8'))
+# blocks inside a VMEM budget.
+PALLAS_UNROLL = 8
 
 # Streamed-block VMEM budget (bytes). ~16 MB/core total; leave room
 # for the three [B, m+1] scratch rows and the non-streamed operands.

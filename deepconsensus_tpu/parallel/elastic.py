@@ -87,7 +87,10 @@ _POLL_S = 0.01
 
 
 def _atomic_write_bytes(path: str, payload: bytes) -> None:
-  tmp = f'{path}.tmp.{os.getpid()}'
+  # The thread id keeps the heartbeat thread and the training thread of
+  # one host (both write hb/<host>.json) off each other's temp file:
+  # sharing it, the slower rename found the file already moved.
+  tmp = f'{path}.tmp.{os.getpid()}.{threading.get_ident()}'
   with open(tmp, 'wb') as f:
     f.write(payload)
     f.flush()

@@ -66,10 +66,6 @@ class AlignedRead:
   def zmw(self) -> int:
     return int(self.name.split('/')[1])
 
-  @property
-  def avg_base_quality_score(self) -> float:
-    return phred.avg_phred(self.base_quality_scores)
-
   def __len__(self) -> int:
     return len(self.bases)
 

@@ -18,7 +18,6 @@ import numpy as np
 from deepconsensus_tpu import constants
 from deepconsensus_tpu.io.example_proto import Example
 from deepconsensus_tpu.preprocess.alignment import AlignedRead
-from deepconsensus_tpu.utils import phred
 
 
 class FeatureLayout:
@@ -192,15 +191,6 @@ class Pileup:
   @property
   def is_empty(self) -> bool:
     return not (self.ccs.ccs_idx >= 0).any()
-
-  @property
-  def ccs_matches_label(self) -> bool:
-    ccs = phred.left_shift_seq(self.ccs.bases)
-    label = phred.left_shift_seq(self.label.bases)
-    n = max(len(ccs), len(label))
-    ccs = np.pad(ccs, (0, n - len(ccs)))
-    label = np.pad(label, (0, n - len(label)))
-    return bool(np.array_equal(ccs, label))
 
   # ------------------------------------------------------------------
   def window_slice(self, r_slice: slice) -> 'Pileup':

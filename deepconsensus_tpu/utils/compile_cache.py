@@ -1,9 +1,9 @@
 """One place that decides where JAX's persistent compilation cache lives.
 
 Every entry point that jits (`dctpu run/serve/train/distill/...`,
-bench.py, chip_smoke.py's children) calls `enable()` before its first
-compile. The directory is part of the cache key's surroundings, so it
-must not move between processes that are meant to share compiles.
+chip_smoke.py's children) calls `enable()` before its first compile.
+The directory is part of the cache key's surroundings, so it must not
+move between processes that are meant to share compiles.
 """
 from __future__ import annotations
 

@@ -823,8 +823,7 @@ def main():
   # synthetic BAMs (the fault-injection helper) — QC numbers are
   # meaningless there, but the soak verdict is about pipeline-level
   # properties (throughput flatness, RSS growth, shm leaks), which the
-  # synthetic stream exercises identically. Same fallback bench.py's
-  # e2e stage uses.
+  # synthetic stream exercises identically.
   synthetic = not os.path.isdir(TESTDATA)
   if synthetic:
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -861,8 +860,8 @@ def main():
   random_init = not os.path.exists(args.checkpoint)
   if random_init:
     # No servable checkpoint on this host: run the pipeline with
-    # randomly initialized weights (bench.py's e2e stage does the
-    # same). Output qualities are garbage; pipeline dynamics are real.
+    # randomly initialized weights. Output qualities are garbage;
+    # pipeline dynamics are real.
     child_code = (
         'import jax, sys\n'
         "jax.config.update('jax_platforms', 'cpu')\n"

@@ -150,10 +150,9 @@ def test_predict_identity_with_calibration(calibration, maxq):
   np.testing.assert_array_equal(quals_on, quals_off)
 
 
-def test_fused_hotpath_identity_uses_pallas_epilogue():
-  """On the fused hot path the Pallas epilogue kernel (appended after
-  the last fused encoder block) carries the output plane; same
-  identity bar."""
+def test_fused_hotpath_identity():
+  """The fused hot path hands its predictions to the same device
+  epilogue; same identity bar."""
   params = _params()
   variables = _init_variables(params, seed=10)
   rows = _rows(params, 8, seed=11)

@@ -162,6 +162,40 @@ class _shared_trace:
     return False
 
 
+class _deadline_only_at_step:
+  """Context manager: of all the drill's collectives only the one the
+  victim never answers, the step barrier it dies in front of, keeps
+  elastic_host's 5 s deadline; every other wait gets LOADED_DEADLINE_S.
+
+  Which barrier expires must follow from the step count, not from the
+  clock: the first steps of a process compile and open the summary
+  writer, and on a loaded machine (six test workers) either outlasts
+  5 s, so a live peer was declared lost at step 1 or 2, the pod split
+  before the injected death, a step was logged twice and the epoch
+  bumped once more than the drill expects."""
+
+  LOADED_DEADLINE_S = 120.0
+
+  def __init__(self, step):
+    self.name = f'r0-step-{step}'
+
+  def __enter__(self):
+    self._orig = orig = elastic_lib.ElasticPod._collect
+    kill_barrier, floor = self.name, self.LOADED_DEADLINE_S
+
+    def collect(pod, epoch, name, expected, timeout_s):
+      if name != kill_barrier:
+        timeout_s = max(timeout_s, floor)
+      return orig(pod, epoch, name, expected, timeout_s)
+
+    elastic_lib.ElasticPod._collect = collect
+    return self
+
+  def __exit__(self, *exc):
+    elastic_lib.ElasticPod._collect = self._orig
+    return False
+
+
 def assert_params_close(out_a, out_b):
   la = jax.tree_util.tree_leaves(final_checkpoint_params(out_a))
   lb = jax.tree_util.tree_leaves(final_checkpoint_params(out_b))
@@ -195,6 +229,31 @@ def test_bounded_call_deadline_is_bounded_and_typed():
 
 # ----------------------------------------------------------------------
 # Pod protocol units (no training loop)
+
+
+def test_atomic_write_survives_two_writers_of_one_path(tmp_path):
+  """The heartbeat thread and the training thread of one host both
+  publish hb/<host>.json (rebuild and boot write a beat themselves):
+  neither may trip over the other's temp file."""
+  path = str(tmp_path / 'beat.json')
+  errors = []
+
+  def writer(tag):
+    try:
+      for i in range(300):
+        elastic_lib._atomic_write_bytes(path, b'%s %d' % (tag, i))
+    except OSError as e:
+      errors.append(e)
+
+  threads = [threading.Thread(target=writer, args=(tag,))
+             for tag in (b'beat', b'main')]
+  for t in threads:
+    t.start()
+  for t in threads:
+    t.join(timeout=120)
+  assert not errors, errors
+  with open(path, 'rb') as f:
+    assert f.read().split()[0] in (b'beat', b'main')
 
 
 def test_pod_geometry_and_timeout_validation(tmp_path):
@@ -371,7 +430,8 @@ def kill_drill(shards, tmp_path_factory):
   os.environ['DCTPU_FAULT_HOST_LOST_MODE'] = 'drop'
   results = {}
   try:
-    with _shared_trace(os.path.join(out, 'trace.jsonl')):
+    with _shared_trace(os.path.join(out, 'trace.jsonl')), \
+        _deadline_only_at_step(3):
       threads = [
           threading.Thread(target=elastic_host,
                            args=(shards, out, i, 2, 1, results))
@@ -472,7 +532,8 @@ def rejoin_drill(shards, tmp_path_factory):
   os.environ['DCTPU_FAULT_HOST_LOST_MODE'] = 'drop'
   results = {}
   try:
-    with _shared_trace(os.path.join(out, 'trace.jsonl')):
+    with _shared_trace(os.path.join(out, 'trace.jsonl')), \
+        _deadline_only_at_step(2):
       threads = [
           threading.Thread(target=elastic_host,
                            args=(shards, out, i, 2, 2, results))

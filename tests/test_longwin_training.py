@@ -116,9 +116,9 @@ def train_losses(out_dir):
 
 
 def curve_digest(losses, decimals):
-  """The quantized curve digest bench_train_scaling.py reports per dp
-  point (same construction as test_train_parallel.py's
-  curve_digest_1e4, with the quantization step explicit)."""
+  """The quantized curve digest (same construction as
+  test_train_parallel.py's curve_digest_1e4, with the quantization
+  step explicit)."""
   import hashlib
 
   return hashlib.sha256(

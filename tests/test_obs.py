@@ -10,8 +10,8 @@ Covers the four obs subsystems in isolation plus their contracts:
     many writers share the file, atomic one-line appends), the
     tracing-off fast path, thread-local trace-id stamping, and the
     record_stage / stage contract that feeds the SAME measured interval
-    to both the histogram and the span (the reconciliation guarantee
-    bench.py asserts end to end), the per-thread stage stack behind
+    to both the histogram and the span (the reconciliation
+    guarantee), the per-thread stage stack behind
     `args.span` / `args.parent`, and the buffered writer (nothing
     before the threshold, everything at close, whole lines, an empty
     buffer after a fork);
@@ -617,7 +617,7 @@ class TestSummarize:
     order = [row['stage'] for row in s['critical_path']]
     assert order[:3] == ['featurize', 'submit', 'format_rows']
     assert 'pack_wait' not in order and 'device_compute' not in order
-    # Totals still cover the waits (bench.py reconciles them with the
+    # Totals still cover the waits (they reconcile with the
     # histograms); stragglers and overlap still read device_compute.
     assert s['stage_totals_s']['device_compute'] == pytest.approx(30.0)
     assert s['stragglers'][0]['pack'] == 1

@@ -218,19 +218,6 @@ def test_ragged_forward_b512(one_chip, compiled_kernels):
   assert _n_kernels(compiled) == 1 + p.num_hidden_layers
 
 
-def test_phred_epilogue_pallas_b1024(one_chip, compiled_kernels):
-  from deepconsensus_tpu.ops import output_plane
-
-  thresholds = output_plane.quality_thresholds(
-      calibration_lib.parse_calibration_string('skip'), 93)
-  preds = jax.ShapeDtypeStruct((BATCH, 100, 5), jnp.float32,
-                               sharding=one_chip)
-  compiled = jax.jit(
-      lambda x: output_plane.phred_epilogue_pallas(x, thresholds)
-  ).lower(preds).compile()
-  assert _n_kernels(compiled) == 1
-
-
 @pytest.mark.parametrize('grad', [False, True], ids=['forward', 'grad'])
 def test_wavefront_loss_b256(one_chip, compiled_kernels, grad):
   """What `dctpu train` takes by itself on a TPU backend

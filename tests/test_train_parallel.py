@@ -2,9 +2,9 @@
 prefetch-overlapped transfers, and the training degradation ladder.
 
 All multichip drills run over the 8 forced host-platform CPU devices
-from conftest.py; real-chip scaling is not measured
-(scripts/bench_train_scaling.py is the tool; chip_smoke.py --chips 4
-proves dp x tp trains on four chips).
+from conftest.py; real-chip scaling is not measured (no benchmark
+cell trains yet; chip_smoke.py --chips 4 proves dp x tp trains on
+four chips).
 
 Cross-dp identity, precisely: at equal global batch and seed the
 dp=8 run consumes byte-identical batches in the same order as dp=1
@@ -13,8 +13,7 @@ curves agree to all-reduce reduction order — empirically ~1e-6
 relative on CPU, NOT bitwise, because sharding the batch changes the
 summation order of the cross-device mean. The tests below pin that
 contract two ways: np.allclose at rtol=1e-4 on the raw curves, and
-equality of the 1e-4-quantized digest that bench_train_scaling.py
-reports per dp point.
+equality of the 1e-4-quantized digest of the curve per dp point.
 """
 import json
 import os

@@ -133,19 +133,20 @@ def test_molecules_span_pack_boundaries(tmp_path, synthetic_bams, params):
 
 def test_cross_batch_packing_output_invariance(tmp_path, synthetic_bams,
                                                params):
-  """Packing windows across featurize batches must not change a single
-  output byte vs per-batch padded dispatch — only the pad accounting."""
+  """Where a pack is cut must not change a single output byte: at
+  batch 8 packs straddle the 12-window featurize batches and the tail
+  pads; batch 4 divides every featurize batch, so no pack crosses one
+  and none pads."""
   packed, c_packed, _ = _run(tmp_path, synthetic_bams, params, 'packed',
-                             batch_size=8, pack_across_batches=True)
-  padded, c_padded, _ = _run(tmp_path, synthetic_bams, params, 'padded',
-                             batch_size=8, pack_across_batches=False)
-  with open(packed, 'rb') as a, open(padded, 'rb') as b:
+                             batch_size=8)
+  aligned, c_aligned, _ = _run(tmp_path, synthetic_bams, params,
+                               'aligned', batch_size=4)
+  with open(packed, 'rb') as a, open(aligned, 'rb') as b:
     assert a.read() == b.read()
-  # Without cross-batch packing every 12-window featurize batch cuts
-  # its own 8 + 4-pad packs.
+  assert c_packed['n_model_packs'] == 5
   assert c_packed['n_model_pad_rows'] == 4
-  assert c_padded['n_model_packs'] == 6
-  assert c_padded['n_model_pad_rows'] == 12
+  assert c_aligned['n_model_packs'] == 9
+  assert c_aligned['n_model_pad_rows'] == 0
 
 
 def test_pack_failure_attributes_member_molecules(tmp_path,

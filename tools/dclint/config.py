@@ -145,7 +145,7 @@ DEVICE_SOURCE_CALLS = frozenset({
     'dispatch_ragged',
     # Output-plane epilogues (ops/output_plane.py): their uint8 planes
     # are device values until the finalize drain.
-    'phred_epilogue', 'phred_epilogue_pallas',
+    'phred_epilogue',
 })
 
 # Function parameters known to carry device values (the engine hands
@@ -171,7 +171,7 @@ HOST_SYNC_CALLS = frozenset({'float', 'int', 'bool', 'asarray', 'array'})
 # transfer/compute overlap (jit-hazards double-buffer rule).
 FORWARD_CALLS = frozenset({'_forward', '_ragged_forward',
                            'ragged_forward', 'phred_epilogue',
-                           'phred_epilogue_pallas', 'train_step'})
+                           'train_step'})
 
 # dtype-downcast sub-rule: modules where an unannotated cast to a
 # reduced-precision dtype is flagged.  With bf16 inference live, a
