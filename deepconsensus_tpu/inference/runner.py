@@ -518,14 +518,23 @@ class ModelRunner:
     self._configure_epilogue()
     thresholds = self._epilogue_thresholds
 
-    def forward(variables, main_u8, sn):
-      rows = _assemble_rows(main_u8, sn, bq_row)
-      preds = model.apply(variables, rows)
-      if thresholds is not None:
-        return output_plane.phred_epilogue(preds, thresholds)
-      pred_ids = jnp.argmax(preds, axis=-1).astype(jnp.int32)
-      max_prob = jnp.max(preds, axis=-1)
-      return pred_ids, max_prob
+    def forward_for(single_device):
+      # A function named `forward`: the compiled program is found in the
+      # device trace as `jit_forward`.
+      def forward(variables, main_u8, sn):
+        rows = _assemble_rows(main_u8, sn, bq_row)
+        # Without a mesh the program is inference for one device, and
+        # the model may take kernels on its own
+        # (model_lib.attention_path).
+        with pallas_util.single_device_inference(single_device):
+          preds = model.apply(variables, rows)
+        if thresholds is not None:
+          return output_plane.phred_epilogue(preds, thresholds)
+        pred_ids = jnp.argmax(preds, axis=-1).astype(jnp.int32)
+        max_prob = jnp.max(preds, axis=-1)
+        return pred_ids, max_prob
+
+      return forward
 
     def ragged_forward(variables, main_u8, sn_w, lengths):
       rows = _assemble_rows_ragged(main_u8, sn_w, lengths, bq_row)
@@ -538,7 +547,8 @@ class ModelRunner:
 
     # Retained so degrade_mesh() can recompile the same forward for a
     # rebuilt (smaller) mesh.
-    self._make_forward = lambda m: self._jit_forward(forward, m)
+    self._make_forward = lambda m: self._jit_forward(
+        forward_for(m is None), m)
     self._forward = self._make_forward(mesh)
     # The ragged forward compiles lazily at its first dispatch_ragged,
     # so wiring it up always costs nothing when use_ragged_kernel is
@@ -596,6 +606,11 @@ class ModelRunner:
         model_lib.block_kind_of(self.params)
         if 'transformer' in self.params.model_name
         else str(self.params.model_name))
+    # Whether the jitted forward was traced as inference for one device
+    # (checkpoint runners without a mesh): the model's rule for the
+    # attention sublayer kernel reads it, and so does the span.
+    self._single_device = (
+        mesh is None and getattr(self, 'variables', None) is not None)
     self._weight_bytes = sum(
         int(leaf.nbytes)
         for leaf in jax.tree_util.tree_leaves(self.variables))
@@ -997,6 +1012,8 @@ class ModelRunner:
     # would block.
     with obs_lib.stage(self.obs, obs_lib.trace.STAGE_LAUNCH,
                        pack=handle.seq, block_kind=self._block_kind,
+                       attention_path=self._attention_path(
+                           inputs[0].shape[2], handle.ragged),
                        n_positions=n_positions,
                        weight_bytes=self._weight_bytes):
       try:
@@ -1008,6 +1025,16 @@ class ModelRunner:
       # pack-failure routing can attribute it to the right tickets)
       except Exception as e:
         handle.error = faults.classify_device_error(e)
+
+  def _attention_path(self, length: int, ragged: bool) -> str:
+    """How the compiled forward of this width runs its attention
+    sublayers: the model's own rule, asked as the forward's trace asks
+    it."""
+    if 'transformer' not in self.params.model_name:
+      return model_lib.ATTENTION_XLA
+    with pallas_util.single_device_inference(self._single_device):
+      return model_lib.attention_path(
+          self.params, length=length, ragged=ragged)
 
   def raw_outputs(self, dispatched: _DispatchHandle):
     """Device arrays (pred_ids, max_prob, n) for a dispatch handle —

@@ -25,6 +25,7 @@ import numpy as np
 
 from deepconsensus_tpu import constants
 from deepconsensus_tpu.models import config as config_lib
+from deepconsensus_tpu.ops import pallas_util
 from deepconsensus_tpu.ops import power_retention
 from deepconsensus_tpu.parallel import ring_attention as ring_lib
 from deepconsensus_tpu.preprocess.pileup import row_indices
@@ -427,6 +428,52 @@ def block_kind_of(p) -> str:
   return kind
 
 
+# How a layer's attention sublayer runs (`forward_launch`'s
+# `attention_path`, docs/observability.md).
+ATTENTION_FUSED_SUBLAYER = 'fused_sublayer'
+ATTENTION_XLA = 'xla'
+
+
+def attention_path(p, *, length: int, deterministic: bool = True,
+                   initializing: bool = False, ragged: bool = False,
+                   sow_intermediates: bool = False) -> str:
+  """The one rule by which a forward takes the fused attention sublayer
+  kernel (ops/fused_encoder_block.py::fused_attention_sublayer) in place
+  of ResidualWrapper(BandedSelfAttention); no option asks for it. Every
+  term is read where the forward is traced:
+
+  * the banded-softmax block with its ReZero residual, and no attention
+    kernel asked for by option (`use_pallas_attention`);
+  * inference: deterministic, not initialising (init runs the modules,
+    so the parameter tree is theirs either way), no `intermediates`
+    collection to `sow` attention maps into (the kernel writes none);
+  * a window of at most FUSED_MAX_WINDOW_LEN positions, no ragged slots;
+  * bfloat16 compute: at float32 XLA's single-pass product is the faster
+    and the stated arithmetic, a Mosaic float32 product takes 3-6 passes;
+  * a TPU, in a trace its caller declared inference for one device
+    (pallas_util.single_device_inference: ModelRunner without a mesh).
+    Under a mesh, in `dctpu export`, in training and evaluation steps
+    nobody declares it, and the modules run as they always have.
+  """
+  fused = (
+      block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
+      and p.rezero
+      and not p.get('use_pallas_attention', False)
+      and deterministic
+      and not initializing
+      and not sow_intermediates
+      and not ragged
+      and length <= config_lib.FUSED_MAX_WINDOW_LEN
+      and jnp.dtype(p.get('dtype', 'float32')) == jnp.bfloat16
+      and pallas_util.may_choose_kernels()
+  )
+  return ATTENTION_FUSED_SUBLAYER if fused else ATTENTION_XLA
+
+
+def _attn_softmax_dtype(p):
+  return jnp.dtype(p.get('attn_softmax_dtype', None) or 'float32')
+
+
 def _block_modules(p, n: int, dtype):
   """(attention, feed-forward, wrap) of encoder layer `n` for the
   configuration's block kind: the one place that knows the kinds.
@@ -463,8 +510,7 @@ def _block_modules(p, n: int, dtype):
         attn_win_size=p.attn_win_size,
         dtype=dtype,
         use_pallas=p.get('use_pallas_attention', False),
-        softmax_dtype=jnp.dtype(
-            p.get('attn_softmax_dtype', None) or 'float32'),
+        softmax_dtype=_attn_softmax_dtype(p),
         name=f'self_attention_{n}',
     )
     ffn = FeedForward(
@@ -492,7 +538,10 @@ def _output_norm(p):
 class EncoderStack(nn.Module):
   """N x (self-attention + FFN) of the configuration's block kind
   (config.BLOCK_KINDS), then the kind's final normalization
-  (reference encoder_stack.py:96-198 for the published block)."""
+  (reference encoder_stack.py:96-198 for the published block).
+
+  [B, L, H] in; [B, L, H] out, or the same rows flat, [B*L, H], where
+  the stack took the attention sublayer kernel (`attention_path`)."""
 
   params: ml_collections.FrozenConfigDict
   dtype: Any = jnp.float32
@@ -530,6 +579,14 @@ class EncoderStack(nn.Module):
       attn_kwargs = dict(ragged_widths=ragged_widths,
                          ragged_buckets=ragged_buckets)
 
+    fused = attention_path(
+        p, length=x.shape[1], deterministic=deterministic,
+        initializing=self.is_initializing(),
+        ragged=ragged_widths is not None,
+        sow_intermediates=self.is_mutable_collection('intermediates'),
+    ) == ATTENTION_FUSED_SUBLAYER
+    batch, length, hidden = x.shape
+
     for n in range(p.num_hidden_layers):
       attn, ffn, wrap = _block_modules(p, n, self.dtype)
       if skip_first_attention and n == 0:
@@ -540,11 +597,46 @@ class EncoderStack(nn.Module):
         pass
       else:
         with jax.named_scope('attention'):
-          x = run_block(wrap(attn, f'attention_wrapper_{n}'), x,
-                        **attn_kwargs)
+          if fused:
+            # The kernel's blocks are row ranges of the flat [B*L, H]
+            # stream, and everything after it is position-wise: the
+            # stream is flattened once, here, and stays flat to the
+            # caller, so no layer pays a [B, L, H] <-> [B*L, H] copy (at
+            # L=100 that is no bitcast on the chip's 8-row tiles).
+            x = self._fused_attention_sublayer(
+                n, x.reshape(batch * length, hidden), length)
+          else:
+            x = run_block(wrap(attn, f'attention_wrapper_{n}'), x,
+                          **attn_kwargs)
       with jax.named_scope('ffn'):
         x = run_block(wrap(ffn, f'ffn_wrapper_{n}'), x)
     return _output_norm(p)(x)
+
+  def _fused_attention_sublayer(self, n: int, x2: jnp.ndarray,
+                                length: int) -> jnp.ndarray:
+    """Layer n's `attention_wrapper_n(self_attention_n)` on the flat
+    stream through the sublayer kernel, reading the leaves the modules
+    read (the effective values where models/quantize.py replaced them).
+    Sublayers are constructed outside ResidualWrapper, so Flax names
+    them as siblings of their wrapper inside this scope."""
+    from deepconsensus_tpu.ops import fused_encoder_block as feb
+
+    p = self.params
+    params = self.variables['params']
+    attn = params[f'self_attention_{n}']
+    h = p.hidden_size
+    return feb.fused_attention_sublayer(
+        x2,
+        attn['query']['kernel'].reshape(h, h),
+        attn['key']['kernel'].reshape(h, h),
+        attn['value']['kernel'].reshape(h, h),
+        attn['output_transform']['kernel'].reshape(h, h),
+        params[f'attention_wrapper_{n}']['alpha'],
+        length=length,
+        num_heads=p.num_heads,
+        attn_win_size=p.attn_win_size or None,
+        softmax_dtype=_attn_softmax_dtype(p),
+    )
 
 
 class DeepConsensusModel(nn.Module):
@@ -697,8 +789,7 @@ class DeepConsensusModel(nn.Module):
         table_keys=table_keys,
         num_heads=p.num_heads,
         attn_win_size=p.attn_win_size or None,
-        softmax_dtype=jnp.dtype(p.get('attn_softmax_dtype', None)
-                                or 'float32'),
+        softmax_dtype=_attn_softmax_dtype(p),
         compute_dtype=self.compute_dtype,
     )
     alpha = wrap0['alpha']
@@ -731,8 +822,7 @@ class DeepConsensusModel(nn.Module):
         blocks,
         num_heads=p.num_heads,
         attn_win_size=p.attn_win_size or None,
-        softmax_dtype=jnp.dtype(p.get('attn_softmax_dtype', None)
-                                or 'float32'),
+        softmax_dtype=_attn_softmax_dtype(p),
         compute_dtype=self.compute_dtype,
         lengths=lengths,
     )
@@ -792,8 +882,7 @@ class DeepConsensusModel(nn.Module):
         table_keys=table_keys,
         num_heads=p.num_heads,
         attn_win_size=p.attn_win_size or None,
-        softmax_dtype=jnp.dtype(p.get('attn_softmax_dtype', None)
-                                or 'float32'),
+        softmax_dtype=_attn_softmax_dtype(p),
         compute_dtype=self.compute_dtype,
     )
     alpha = wrap0['alpha']
@@ -904,6 +993,11 @@ class DeepConsensusModel(nn.Module):
     with jax.named_scope('head'):
       logits = self.logits_layer(encoded.astype(jnp.float32))
       preds = jax.nn.softmax(logits, axis=-1)
+    if encoded.ndim == 2:
+      # The stack ran on the flat stream and the head is position-wise:
+      # windows come back here, where a position is 5 values wide.
+      windows = lambda a: a.reshape(x.shape[0], x.shape[1], a.shape[-1])
+      encoded, logits, preds = windows(encoded), windows(logits), windows(preds)
     return {'final_output': encoded, 'logits': logits, 'preds': preds}
 
 

@@ -260,6 +260,8 @@ def summarize(events: List[Dict[str, Any]],
       'n_launches': len(launches),
       'block_kinds': sorted({str(a['block_kind']) for a in launches
                              if a.get('block_kind')}),
+      'attention_paths': sorted({str(a['attention_path']) for a in launches
+                                 if a.get('attention_path')}),
       'n_positions': sum(int(a.get('n_positions') or 0) for a in launches),
       'weight_bytes': max(
           (int(a.get('weight_bytes') or 0) for a in launches), default=0),
@@ -316,7 +318,8 @@ def format_summary(summary: Dict[str, Any]) -> str:
   if forward.get('n_launches'):
     lines.append(
         f'forward: {forward["n_launches"]} launches of '
-        f'{", ".join(forward["block_kinds"]) or "?"}, '
+        f'{", ".join(forward["block_kinds"]) or "?"} '
+        f'(attention: {", ".join(forward["attention_paths"]) or "?"}), '
         f'{forward["n_positions"]} positions, '
         f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
   overlap = summary['overlap']

@@ -28,6 +28,15 @@ transfer shrinks 4x. ReZero alphas are passed as (1, 1) SMEM scalars
 — NOT folded into the weights — so quantization and the residual stay
 independent and the op order matches the XLA model exactly.
 
+The attention half is also callable alone: `fused_attention_sublayer`
+runs one layer's `x + alpha * attention(x)` on the flat [B*L, H] stream
+in the compute dtype (bfloat16 MXU operands, float32 accumulators and
+softmax), which is what the default XLA forward takes for its attention
+sublayers at L<=128 bfloat16 inference on one TPU
+(models/model.py::attention_path; no option asks for it). The block
+kernel hands the same `_attention` float32 activations, so its products
+stay float32.
+
 Semantics are defined by `reference_encoder_stack` (pure jnp, shares
 the math helpers below); the kernel is validated against it per block
 and against the full XLA model in interpret mode on CPU
@@ -42,6 +51,7 @@ from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -51,6 +61,13 @@ from deepconsensus_tpu.ops import pallas_util
 Array = jnp.ndarray
 
 _NEG = -1e9
+
+# Windows a grid program of the attention sublayer kernel. tile*L must be
+# a multiple of the bfloat16 sublane tile (16) for the flat block to need
+# no re-layout: any multiple of 4 at L=100. Picked on the chip (PERF.md,
+# PR 31): 8, 16 and 32 ran within 1% of each other at a pack of 8,192,
+# and the kernel unrolls over its tile, so 8 also compiles fastest.
+SUBLAYER_TILE_WINDOWS = 8
 
 
 class QuantizedWeight(NamedTuple):
@@ -88,12 +105,14 @@ class EncoderBlockWeights(NamedTuple):
 def _dequant_matmul(x2: Array, values: Array, scale: Optional[Array]) -> Array:
   """[M, K] x QuantizedWeight -> [M, N] f32, dequant in the epilogue.
 
-  The per-output-channel scale commutes with the contraction, so
-  (x @ q) * scale equals x @ (q * scale) up to f32 rounding; with
-  scale=None (or exact ones) this is the plain f32 matmul.
+  The MXU operands are in x2's dtype (the block kernel hands float32
+  activations, the attention sublayer the compute dtype), the
+  accumulator is float32. The per-output-channel scale commutes with
+  the contraction, so (x @ q) * scale equals x @ (q * scale) up to f32
+  rounding; with scale=None (or exact ones) this is the plain matmul.
   """
   out = jax.lax.dot_general(
-      x2, values.astype(jnp.float32), (((1,), (0,)), ((), ())),
+      x2, values.astype(x2.dtype), (((1,), (0,)), ((), ())),
       preferred_element_type=jnp.float32,
   )
   if scale is not None:
@@ -101,52 +120,77 @@ def _dequant_matmul(x2: Array, values: Array, scale: Optional[Array]) -> Array:
   return out
 
 
-def _attention(x, wq, wk, wv, wo, *, num_heads, qscale, attn_win_size,
-               length, softmax_dtype, mask=None):
-  """Banded MHA on a [tile, L, H] f32 block with quant-aware
-  projections; mirrors fused_window_attention._attention (same band
-  mask, same softmax_dtype lever, same op order). Each w is a
-  (values, scale_row_or_None) pair. mask (ragged slots): a
-  [tile, L, L] bool mask that REPLACES the static band — it already
-  ANDs the band with the lengths-derived same-window/valid tests
-  (ragged_window_attention.ragged_attention_mask). Shared with the
-  jnp reference."""
-  tile, _, hidden = x.shape
+def _rounded_product(a: Array, scalar, dtype) -> Array:
+  """a * scalar as the XLA modules compute it in `dtype`: a rounded to
+  dtype, the product rounded to dtype. `scalar` is already a value of
+  dtype held in float32; the arithmetic between the roundings is
+  float32 (the v5e VPU has no bfloat16 multiply). All no-ops for
+  float32."""
+  return (a.astype(dtype).astype(jnp.float32) * scalar).astype(dtype)
+
+
+def _attention(x2, wqkv, wo, *, tile, length, num_heads, attn_win_size,
+               softmax_dtype, mask=None):
+  """Banded MHA on `tile` windows of `length` rows, flat: x2
+  [tile*L, H] -> the output product's float32 accumulator [tile*L, H].
+
+  The compute dtype is x2's: MXU operands in it, float32 accumulation,
+  and q, k, v, the softmax weights and the heads' output rounded to it
+  where BandedSelfAttention rounds them (DenseGeneral(dtype=...),
+  query * head_dim**-0.5, .astype(dtype) after the softmax, the value
+  einsum); the logits stay float32 where XLA rounds them. With float32
+  activations every rounding is a no-op. wqkv: three (values,
+  scale_row_or_None) pairs of [H, H], or one fused [H, 3H] pair (one
+  pass over x2); wo: one pair. mask (ragged slots): a [tile, L, L] bool
+  mask that REPLACES the static band — it already ANDs the band with
+  the lengths-derived same-window/valid tests
+  (ragged_window_attention.ragged_attention_mask). Shared by the block
+  kernel, the sublayer kernel and the jnp reference."""
+  cd = x2.dtype
+  hidden = x2.shape[1]
   head_dim = hidden // num_heads
-  x2 = x.reshape(tile * length, hidden)
-
-  def proj(w):
-    return _dequant_matmul(x2, w[0], w[1]).reshape(tile, length, hidden)
-
-  q = proj(wq) * qscale
-  k = proj(wk)
-  v = proj(wv)
-  # Heads are lane slices of the [tile, L, H] projections: Mosaic has
-  # no shape cast that splits the lane dimension into (heads, depth).
-  head = lambda t, h: t[:, :, h * head_dim:(h + 1) * head_dim]
-  band = mask
-  if band is None and attn_win_size is not None:
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tile, length, length), 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, length, length), 2)
+  qkv = [_dequant_matmul(x2, *w) for w in wqkv]
+  if len(qkv) == 1:
+    qkv = [qkv[0][:, i * hidden:(i + 1) * hidden] for i in range(3)]
+  # head_dim**-0.5 rounded to the compute dtype, as the weakly typed
+  # Python scalar is in `query_raw * (head_dim**-0.5)`.
+  qscale = float(np.asarray(head_dim ** -0.5, dtype=cd))
+  q = _rounded_product(qkv[0], qscale, cd)
+  k = qkv[1].astype(cd)
+  v = qkv[2].astype(cd)
+  band = None
+  if mask is None and attn_win_size is not None:
+    rows = jax.lax.broadcasted_iota(jnp.int32, (length, length), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (length, length), 1)
     band = jnp.abs(rows - cols) <= attn_win_size
-  outs = []
+  # A window is a row slice and a head a lane slice of the flat
+  # projections: Mosaic has no shape cast that splits the lane
+  # dimension into (heads, depth), and a [tile*L, .] -> [tile, L, .]
+  # reshape at L=100 unrolls into the same shifted copies.
+  heads = []
   for h in range(num_heads):
-    s = jax.lax.dot_general(
-        head(q, h), head(k, h), (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # [tile, L, L]
-    if band is not None:
-      s = jnp.where(band, s, _NEG)
-    sd = s.astype(softmax_dtype)
-    m = jnp.max(sd, axis=2, keepdims=True)
-    p = jnp.exp(sd - m)
-    w = (p / jnp.sum(p, axis=2, keepdims=True)).astype(jnp.float32)
-    outs.append(jax.lax.dot_general(
-        w, head(v, h), (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ))
-  o = jnp.concatenate(outs, axis=-1).reshape(tile * length, hidden)
-  return _dequant_matmul(o, wo[0], wo[1]).reshape(tile, length, hidden)
+    outs = []
+    for t in range(tile):
+      part = lambda a: a[t * length:(t + 1) * length,
+                         h * head_dim:(h + 1) * head_dim]
+      s = jax.lax.dot_general(
+          part(q), part(k), (((1,), (1,)), ((), ())),
+          preferred_element_type=jnp.float32,
+      )  # [L, L]
+      keep = band if mask is None else mask[t]
+      if keep is not None:
+        s = jnp.where(keep, s, _NEG)
+      sd = s.astype(softmax_dtype)
+      m = jnp.max(sd, axis=1, keepdims=True)
+      p = jnp.exp(sd - m)
+      w = (p / jnp.sum(p, axis=1, keepdims=True)).astype(cd)
+      outs.append(jax.lax.dot_general(
+          w, part(v), (((1,), (0,)), ((), ())),
+          preferred_element_type=jnp.float32,
+      ).astype(cd))
+    heads.append(jnp.concatenate(outs, axis=0))
+  o = jnp.concatenate(heads, axis=-1)
+  return _dequant_matmul(o, *wo)
 
 
 def _ffn(x, w_filter, b_filter, w_output, b_output, *, length, hidden):
@@ -161,23 +205,24 @@ def _ffn(x, w_filter, b_filter, w_output, b_output, *, length, hidden):
   return out.reshape(tile, length, hidden)
 
 
-def _block_body(x, attn, ffn, attn_alpha, ffn_alpha, *, num_heads, qscale,
+def _block_body(x, attn, ffn, attn_alpha, ffn_alpha, *, num_heads,
                 attn_win_size, length, hidden, softmax_dtype, mask=None):
   """One encoder block on a [tile, L, H] f32 block: optional attention
   residual, then FFN residual, both ReZero (x + alpha * y)."""
   if attn is not None:
+    tile = x.shape[0]
     y = _attention(
-        x, *attn, num_heads=num_heads, qscale=qscale,
-        attn_win_size=attn_win_size, length=length,
+        x.reshape(tile * length, hidden), attn[:3], attn[3], tile=tile,
+        length=length, num_heads=num_heads, attn_win_size=attn_win_size,
         softmax_dtype=softmax_dtype, mask=mask,
     )
-    x = x + attn_alpha * y
+    x = x + attn_alpha * y.reshape(tile, length, hidden)
   y = _ffn(x, *ffn, length=length, hidden=hidden)
   return x + ffn_alpha * y
 
 
-def _kernel(*refs, has_attn, has_lengths, num_heads, qscale, attn_win_size,
-            length, hidden, softmax_dtype):
+def _kernel(*refs, has_attn, has_lengths, num_heads, attn_win_size, length,
+            hidden, softmax_dtype):
   it = iter(refs)
   x_ref = next(it)
   mask = None
@@ -199,7 +244,7 @@ def _kernel(*refs, has_attn, has_lengths, num_heads, qscale, attn_win_size,
   x = x_ref[:].astype(jnp.float32)
   x = _block_body(
       x, attn, ffn, attn_alpha, ffn_alpha, num_heads=num_heads,
-      qscale=qscale, attn_win_size=attn_win_size, length=length,
+      attn_win_size=attn_win_size, length=length,
       hidden=hidden, softmax_dtype=softmax_dtype, mask=mask,
   )
   out_ref[:] = x.astype(out_ref.dtype)
@@ -232,7 +277,6 @@ def _block_call(xp: Array, block: EncoderBlockWeights, *, num_heads,
                 interpret, lengths: Optional[Array] = None) -> Array:
   """One pallas_call over an already tile-padded [B', L, H] batch."""
   bp, length, hidden = xp.shape
-  head_dim = hidden // num_heads
   n_tiles = bp // tile
   has_attn = block.wq is not None
   has_lengths = has_attn and lengths is not None
@@ -270,8 +314,7 @@ def _block_call(xp: Array, block: EncoderBlockWeights, *, num_heads,
   return pl.pallas_call(
       functools.partial(
           _kernel, has_attn=has_attn, has_lengths=has_lengths,
-          num_heads=num_heads,
-          qscale=head_dim ** -0.5, attn_win_size=attn_win_size,
+          num_heads=num_heads, attn_win_size=attn_win_size,
           length=length, hidden=hidden,
           softmax_dtype=jnp.dtype(softmax_dtype),
       ),
@@ -358,6 +401,91 @@ def fused_encoder_stack(
   return xp[:b]
 
 
+def _sublayer_kernel(x_ref, wqkv_ref, wo_ref, alpha_ref, out_ref, *, tile,
+                     length, num_heads, attn_win_size, softmax_dtype):
+  """x + alpha * attention(x) on one tile of windows, in x's dtype."""
+  cd = x_ref.dtype
+  x2 = x_ref[:]
+  y = _attention(
+      x2, ((wqkv_ref[:], None),), (wo_ref[:], None), tile=tile,
+      length=length, num_heads=num_heads, attn_win_size=attn_win_size,
+      softmax_dtype=softmax_dtype,
+  )
+  # ResidualWrapper: x + alpha.astype(dtype) * y, each step in dtype.
+  y = _rounded_product(y, alpha_ref[0, 0], cd)
+  out_ref[:] = (x2.astype(jnp.float32) + y.astype(jnp.float32)).astype(cd)
+
+
+def fused_attention_sublayer(
+    x2: Array,
+    wq: Array,
+    wk: Array,
+    wv: Array,
+    wo: Array,
+    alpha: Array,
+    *,
+    length: int,
+    num_heads: int,
+    attn_win_size: Optional[int],
+    softmax_dtype: Any = jnp.float32,
+    tile_windows: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> Array:
+  """The banded attention sublayer of one encoder layer with its ReZero
+  residual, `x + alpha * attention(x)`, as one pallas_call over tiles of
+  windows: q, k, v, the scores and the softmax weights live in VMEM only.
+
+  x2: the window batch flat, [B*L, H], in the compute dtype; the result
+  has its shape and dtype. wq/wk/wv/wo: the [H, H] matmul forms of the
+  layer's DenseGeneral kernels, alpha its ReZero scalar; all are cast to
+  x2's dtype here, as the modules cast them. The arithmetic is the
+  modules' own (`_attention`): operands in the compute dtype, float32
+  accumulators, softmax in softmax_dtype.
+
+  The flat form is the kernel's own: its blocks are [tile*L, H] row
+  ranges that need no re-layout on the way in, where a [tile, L, H]
+  block pads every window's L=100 rows to the 16-row tile.
+  """
+  rows, hidden = x2.shape
+  if hidden % num_heads:
+    raise ValueError('hidden size must divide num_heads')
+  if rows % length:
+    raise ValueError(f'{rows} rows are no whole number of L={length} windows')
+  cd = x2.dtype
+  b = rows // length
+  tile = tile_windows or SUBLAYER_TILE_WINDOWS
+  tile = max(1, min(tile, b))
+  pad = (-b) % tile
+  xp = jnp.pad(x2, ((0, pad * length), (0, 0))) if pad else x2
+  block = pl.BlockSpec((tile * length, hidden), lambda i: (i, 0),
+                       memory_space=pltpu.VMEM)
+  full = lambda a: pl.BlockSpec(
+      a.shape, lambda i: (0,) * a.ndim, memory_space=pltpu.VMEM)
+  # dclint: allow=dtype-downcast (the modules cast their float32 kernels
+  # and alpha to the compute dtype the same way: DenseGeneral(dtype=...),
+  # alpha.astype(y.dtype))
+  wqkv = jnp.concatenate([wq, wk, wv], axis=1).astype(cd)
+  wo = wo.astype(cd)
+  alpha = jnp.asarray(alpha, jnp.float32).astype(cd).astype(
+      jnp.float32).reshape(1, 1)
+  out = pl.pallas_call(
+      functools.partial(
+          _sublayer_kernel, tile=tile, length=length, num_heads=num_heads,
+          attn_win_size=attn_win_size,
+          softmax_dtype=jnp.dtype(softmax_dtype)),
+      grid=((b + pad) // tile,),
+      in_specs=[
+          block, full(wqkv), full(wo),
+          pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
+      ],
+      out_specs=block,
+      out_shape=jax.ShapeDtypeStruct(xp.shape, cd),
+      compiler_params=pallas_util.batch_tile_compiler_params(),
+      interpret=pallas_util.resolve_interpret(interpret),
+  )(xp, wqkv, wo, alpha)
+  return out[:rows]
+
+
 def _reference_pair(qw: QuantizedWeight) -> Tuple[Array, Optional[Array]]:
   values, scale = qw
   if scale is None:
@@ -378,7 +506,6 @@ def reference_encoder_block(
   """Pure-jnp semantics of one fused block (same helpers, no Pallas):
   the per-block parity oracle for unit tests."""
   _, length, hidden = x.shape
-  head_dim = hidden // num_heads
   attn = None
   mask = None
   if block.wq is not None:
@@ -398,7 +525,7 @@ def reference_encoder_block(
       None if block.attn_alpha is None else jnp.asarray(
           block.attn_alpha, jnp.float32),
       jnp.asarray(block.ffn_alpha, jnp.float32),
-      num_heads=num_heads, qscale=head_dim ** -0.5,
+      num_heads=num_heads,
       attn_win_size=attn_win_size, length=length, hidden=hidden,
       softmax_dtype=jnp.dtype(softmax_dtype), mask=mask,
   )

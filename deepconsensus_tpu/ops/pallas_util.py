@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import collections
+import contextlib
+import threading
 from typing import Any, Dict, Optional
 
 import jax
@@ -13,13 +15,45 @@ import jax
 _resolved: collections.Counter = collections.Counter()
 
 
+def on_tpu() -> bool:
+  return jax.default_backend() == 'tpu'
+
+
 def resolve_interpret(interpret: Optional[bool]) -> bool:
   """None -> interpret everywhere but real TPU, so the same flag runs
   the kernels under CPU tests and the virtual mesh."""
   if interpret is None:
-    interpret = jax.default_backend() != 'tpu'
+    interpret = not on_tpu()
   _resolved['interpret' if interpret else 'compiled'] += 1
   return bool(interpret)
+
+
+# Set while a caller traces a forward-only program that one device runs
+# whole. Nothing else may take a kernel on its own initiative: XLA cannot
+# partition a Mosaic custom call over a mesh, an exported artifact must
+# not carry one, and the kernels chosen by the code have no gradient.
+_tracing = threading.local()
+
+
+@contextlib.contextmanager
+def single_device_inference(holds: bool = True):
+  """Declares, for the trace inside the block, that the program is
+  inference for one device: no mesh, no export, no gradient. The model
+  reads it through `may_choose_kernels()`; `holds=False` is the
+  explicit "no" of a caller that knows its mesh."""
+  before = getattr(_tracing, 'single_device_inference', False)
+  _tracing.single_device_inference = bool(holds)
+  try:
+    yield
+  finally:
+    _tracing.single_device_inference = before
+
+
+def may_choose_kernels() -> bool:
+  """True where code may route to a Mosaic kernel no option asked for:
+  on a TPU, inside a trace declared `single_device_inference`."""
+  return bool(
+      getattr(_tracing, 'single_device_inference', False) and on_tpu())
 
 
 def execution_report() -> Dict[str, Any]:
@@ -30,7 +64,7 @@ def execution_report() -> Dict[str, Any]:
       'platform': devices[0].platform,
       'device_kind': devices[0].device_kind,
       'device_count': len(devices),
-      'pallas_interpret_default': int(jax.default_backend() != 'tpu'),
+      'pallas_interpret_default': int(not on_tpu()),
       'n_pallas_calls_compiled': _resolved['compiled'],
       'n_pallas_calls_interpret': _resolved['interpret'],
   }

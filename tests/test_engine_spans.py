@@ -507,6 +507,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert len(launches) == 3
   for e in launches:
     assert e['args']['block_kind'] == kind
+    # The CPU takes no kernel on its own (model_lib.attention_path).
+    assert e['args']['attention_path'] == 'xla'
     # The compiled pack's rows x width, the tail pack's padding included.
     assert e['args']['n_positions'] == BATCH * p.max_length
     assert e['args']['weight_bytes'] == 7 * 3 * 2 + 5 * 4
@@ -522,10 +524,12 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert cli.main(['trace', path, '--json']) == 0
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
+                     'attention_paths': ['xla'],
                      'n_positions': 3 * BATCH * p.max_length,
                      'weight_bytes': 62}
   assert cli.main(['trace', path]) == 0
-  assert f'forward: 3 launches of {kind}' in capsys.readouterr().out
+  assert (f'forward: 3 launches of {kind} (attention: xla)'
+          in capsys.readouterr().out)
 
 
 @pytest.mark.parametrize('kind', sorted(KINDS))

@@ -4,7 +4,8 @@ The only file in the repo that describes the chip: every kernel and
 jitted forward the main path runs is lowered and compiled for a
 described (not attached) `v5e:2x2` device at production shapes
 (6 layers x hidden 280 x filter 2048, 85 rows x L=100, batch 1024 for
-inference and 256 for the loss; two layers of the power-retention block
+inference, the attention sublayer kernel the bfloat16 forward takes by
+itself among them, and 256 for the loss; two layers of the power-retention block
 kind at hidden 5120, batch 256). A compile that passes here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
@@ -191,6 +192,43 @@ def test_fused_front_end_b1024(one_chip, compiled_kernels):
       sds((h, h)), sds((h, h)), sds((h, h)), sds((h, h)),
       sds((p.max_length, h))).compile()
   assert _n_kernels(compiled) == 1
+
+
+def test_attention_sublayer_forward_b1024(one_chip, compiled_kernels,
+                                          monkeypatch):
+  """The teacher's bfloat16 forward as a ModelRunner without a mesh
+  traces it on a TPU: every layer's attention sublayer is one Mosaic
+  call under scope `attention` (model_lib.attention_path; no option asks
+  for it), and no score tensor is left in the program. Mosaic refusing
+  the kernel at the served widths fails here, before any chip time."""
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+  p = _params(dtype='bfloat16')
+  model = model_lib.get_model(p)
+
+  def forward(variables, rows):
+    with pallas_util.single_device_inference():
+      return model.apply(variables, rows)
+
+  variables = _abstract(_variables(p), one_chip)
+  rows = jax.ShapeDtypeStruct(
+      (BATCH, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+  text = jax.jit(forward).lower(variables, rows).compile().as_text()
+  calls = [line for line in _entry_computation(text)
+           if 'tpu_custom_call' in line]
+  assert len(calls) == p.num_hidden_layers == 6
+  for line in calls:
+    assert re.search(r'op_name="[^"]*/attention/[^"]*pallas_call"', line)
+    # The flat stream in the compute dtype, in and out.
+    assert f'bf16[{BATCH * p.max_length},{p.hidden_size}]' in line
+  # q, k, v, scores and weights never reach memory: the modules' program
+  # holds [B,2,100,100] scores and [B,100,2,140] heads, this one neither.
+  assert f'[{BATCH},{p.num_heads},100,100]' not in text
+  assert f'[{BATCH},100,{p.num_heads},140]' not in text
+  # The same forward, undeclared (a mesh, an export, a training step's
+  # evaluation): the modules, no kernel.
+  plain = jax.jit(model.apply).lower(variables, rows).compile().as_text()
+  assert 'tpu_custom_call' not in plain
+  assert f'[{BATCH},{p.num_heads},100,100]' in plain
 
 
 @pytest.mark.parametrize('levers', [
