@@ -351,6 +351,35 @@ def _check_exported_epilogue(meta, options: 'InferenceOptions',
     )
 
 
+def _holds_sparse_experts(params) -> bool:
+  """Whether the forward of this configuration routes tokens to experts
+  and returns their per-pack counts beside the predictions."""
+  return ('transformer' in params.model_name and model_lib.block_kind_of(
+      params) == config_lib.BLOCK_GATED_DELTA_MOE)
+
+
+def _check_sparse_experts_served(params, mesh) -> None:
+  """What the sparse-experts block kind cannot run yet, refused by name
+  before anything is placed (ROADMAP R-a)."""
+  kind = config_lib.BLOCK_GATED_DELTA_MOE
+  if params.get('quantize_matmuls', None) not in (None, 'none'):
+    # dclint: allow=typed-faults (model-config validation at startup,
+    # surfaced as operator error by the CLI, not a data-plane fault)
+    raise ValueError(
+        f'block kind {kind!r} is not served with '
+        f'quantize_matmuls={params.quantize_matmuls!r}: models/quantize.py '
+        'has no per-expert scales')
+  if mesh is not None:
+    from deepconsensus_tpu.parallel import mesh as mesh_lib
+
+    if int(mesh.shape.get(mesh_lib.MODEL_AXIS, 1)) > 1:
+      # dclint: allow=typed-faults (startup config validation: the
+      # operator asked for a mesh axis the kind cannot be split over)
+      raise ValueError(
+          f'block kind {kind!r} is not served with --tp: '
+          'parallel/partition_rules.py has no expert axis')
+
+
 def _check_dp_divisible(options: 'InferenceOptions', mesh) -> int:
   """The compiled batch splits evenly over the mesh data axis; returns
   the data-axis size."""
@@ -473,6 +502,9 @@ class ModelRunner:
   def __init__(self, params, variables, options: InferenceOptions,
                mesh=None):
     self.params = params
+    sparse_experts = _holds_sparse_experts(params)
+    if sparse_experts:
+      _check_sparse_experts_served(params, mesh)
     # Quantize/cast once on the host BEFORE any device placement, so
     # the weight transfer below ships the shrunken bf16/int8 bytes
     # (and degrade_mesh()'s re-placement keeps shipping them).
@@ -523,16 +555,25 @@ class ModelRunner:
       # device trace as `jit_forward`.
       def forward(variables, main_u8, sn):
         rows = _assemble_rows(main_u8, sn, bq_row)
+        counts = ()
         # Without a mesh the program is inference for one device, and
         # the model may take kernels on its own
         # (model_lib.attention_path).
         with pallas_util.single_device_inference(single_device):
-          preds = model.apply(variables, rows)
+          if sparse_experts:
+            # Beside the predictions, the assignments every held expert
+            # took this pack, [layers, experts held] (finalize counts
+            # them).
+            preds, sown = model.apply(variables, rows,
+                                      mutable=['moe_counts'])
+            counts = (model_lib.expert_assignments(sown['moe_counts']),)
+          else:
+            preds = model.apply(variables, rows)
         if thresholds is not None:
-          return output_plane.phred_epilogue(preds, thresholds)
+          return output_plane.phred_epilogue(preds, thresholds) + counts
         pred_ids = jnp.argmax(preds, axis=-1).astype(jnp.int32)
         max_prob = jnp.max(preds, axis=-1)
-        return pred_ids, max_prob
+        return (pred_ids, max_prob) + counts
 
       return forward
 
@@ -548,7 +589,7 @@ class ModelRunner:
     # Retained so degrade_mesh() can recompile the same forward for a
     # rebuilt (smaller) mesh.
     self._make_forward = lambda m: self._jit_forward(
-        forward_for(m is None), m)
+        forward_for(m is None), m, n_replicated_outputs=int(sparse_experts))
     self._forward = self._make_forward(mesh)
     # The ragged forward compiles lazily at its first dispatch_ragged,
     # so wiring it up always costs nothing when use_ragged_kernel is
@@ -616,6 +657,25 @@ class ModelRunner:
         for leaf in jax.tree_util.tree_leaves(self.variables))
     self.obs.set_gauge('model_weight_bytes', self._weight_bytes)
     self._n_forward_positions = self.obs.counter('n_forward_positions')
+    # What `forward_launch` says of the stack: one letter a layer
+    # (config.layer_pattern) and, for sparse experts, the share held.
+    self._launch_fields = {}
+    if 'transformer' in self.params.model_name:
+      self._launch_fields['layer_pattern'] = config_lib.layer_pattern(
+          self.params)
+    self._sparse_experts = _holds_sparse_experts(self.params)
+    if self._sparse_experts:
+      first = int(self.params.experts_held_first)
+      self._launch_fields.update(
+          experts_held=[first, first + int(self.params.experts_held_count)],
+          experts_published=int(self.params.num_experts))
+      # Assignments the router made (positions x k x layers), those that
+      # fell on held experts, and the most any one held expert took in a
+      # pack of one layer.
+      self._moe_total = self.obs.counter('moe_assignments_total')
+      self._moe_held = self.obs.counter('moe_assignments_held')
+      self._moe_load_max = 0
+      self.obs.set_gauge('moe_expert_load_max', 0)
     # dclint: lock-free (single transfer slot: the model-loop thread
     # is the sole device owner — dispatch/finalize are never called
     # concurrently, per the engine's single-thread contract)
@@ -666,7 +726,7 @@ class ModelRunner:
     self._make_ragged_forward = getattr(self, '_make_ragged_forward', None)
 
   @staticmethod
-  def _jit_forward(forward, mesh):
+  def _jit_forward(forward, mesh, n_replicated_outputs: int = 0):
     # donate_argnums: the uint8 pack and SN buffers are dead after the
     # forward (finalize only touches the outputs), so steady state
     # reuses their device memory instead of growing the arena by one
@@ -681,7 +741,10 @@ class ModelRunner:
         # Variables keep the placement __init__ gave them (replicated,
         # or model-axis sharded under tp>1).
         in_shardings=(None, batch_sh, batch_sh),
-        out_shardings=(batch_sh, batch_sh),
+        # The two planes by window; what follows them (a pack's counts)
+        # whole on every device.
+        out_shardings=(batch_sh, batch_sh) + (
+            mesh_lib.replicated(mesh),) * n_replicated_outputs,
         donate_argnums=(1, 2),
     )
 
@@ -1015,7 +1078,8 @@ class ModelRunner:
                        attention_path=self._attention_path(
                            inputs[0].shape[2], handle.ragged),
                        n_positions=n_positions,
-                       weight_bytes=self._weight_bytes):
+                       weight_bytes=self._weight_bytes,
+                       **self._launch_fields):
       try:
         faults.injected_device_fault(handle.seq)
         handle.hang_s = faults.injected_device_hang(handle.seq)
@@ -1048,7 +1112,7 @@ class ModelRunner:
       self._n_direct_launches += 1
     if handle.error is not None:
       raise handle.error
-    pred_ids, max_prob = handle.outputs
+    pred_ids, max_prob = handle.outputs[:2]
     return pred_ids, max_prob, handle.n
 
   def dispatch_stats(self) -> Dict[str, Any]:
@@ -1073,10 +1137,18 @@ class ModelRunner:
         'block_kind': self._block_kind,
         'model_weight_bytes': self._weight_bytes,
         'n_forward_positions': self._n_forward_positions.value,
+        **self._expert_stats(),
         'n_dispatched_by_bucket': {
             w: self._n_dispatched_by_bucket[w]
             for w in sorted(self._n_dispatched_by_bucket)},
     }
+
+  def _expert_stats(self) -> Dict[str, int]:
+    if not self._sparse_experts:
+      return {}
+    return {'moe_assignments_total': self._moe_total.value,
+            'moe_assignments_held': self._moe_held.value,
+            'moe_expert_load_max': self._moe_load_max}
 
   @property
   def mesh_dp(self) -> int:
@@ -1163,7 +1235,8 @@ class ModelRunner:
         try:
           return self._drain_sync(handle)
         finally:
-          st.set(bytes=self._d2h_bytes_per_pack)
+          st.set(bytes=self._d2h_bytes_per_pack,
+                 **self._count_expert_assignments(handle))
     finally:
       if handle.t_launch:
         # A wait, not work: launch to the end of the drain on the host's
@@ -1174,6 +1247,29 @@ class ModelRunner:
             handle.t_launch, time.time(), cat=obs_lib.trace.CAT_WAIT,
             pack=handle.seq, bucket=handle.bucket, dp=self.mesh_dp,
             n_rows=handle.n)
+
+  def _count_expert_assignments(self, handle) -> Dict[str, int]:
+    """Adds a drained pack's per-expert assignment counts (the forward's
+    third output, [layers, experts held]) to the registry; returns what
+    the pack's `finalize_drain` span says of them. Nothing for a forward
+    without sparse experts, or one that failed."""
+    if not self._sparse_experts or handle.error is not None or (
+        handle.outputs is None):
+      return {}
+    # dclint: allow=jit-hazards (finalize IS the sync point, and the
+    # planes of this pack have just been drained)
+    counts = np.asarray(handle.outputs[2])
+    # Every position of the compiled pack is routed, padding included.
+    total = (handle.outputs[0].size * int(self.params.num_experts_per_tok)
+             * counts.shape[0])
+    held, load_max = int(counts.sum()), int(counts.max())
+    self._moe_total.inc(total)
+    self._moe_held.inc(held)
+    self._moe_load_max = max(self._moe_load_max, load_max)
+    self.obs.set_gauge('moe_expert_load_max', self._moe_load_max)
+    return {'moe_assignments_total': total, 'moe_assignments_held': held,
+            'moe_expert_load_max': load_max,
+            'moe_expert_load_min': int(counts.min())}
 
   def _drain_sync(self, dispatched) -> Tuple[np.ndarray, np.ndarray]:
     """The blocking half of finalize: device sync, plus host quality

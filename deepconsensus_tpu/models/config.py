@@ -111,9 +111,44 @@ def bucket_for(width: int, buckets):
 #                           heads with per-head q/k RMSNorm and rotary
 #                           positions + SwiGLU feed-forward, pre-RMSNorm
 #                           residuals, final RMSNorm.
+#   gated_delta_hybrid_moe  a stack whose layers are not alike: layer n
+#                           is gated softmax attention over grouped heads
+#                           where (n + 1) % full_attention_interval == 0
+#                           and a Gated DeltaNet mixer otherwise (the
+#                           gated delta rule in chunked form, two
+#                           directions: ops/gated_delta.py); every layer's
+#                           feed-forward is sparse experts (router at its
+#                           full width, top-k, the products of the experts
+#                           this process holds: ops/moe.py) plus a gated
+#                           shared expert; zero-centred pre-RMSNorm
+#                           residuals, final RMSNorm.
 BLOCK_BANDED_SOFTMAX = 'banded_softmax_relu'
 BLOCK_POWER_RETENTION = 'power_retention_swiglu'
-BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION)
+BLOCK_GATED_DELTA_MOE = 'gated_delta_hybrid_moe'
+BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION,
+               BLOCK_GATED_DELTA_MOE)
+
+# A layer's attention, one letter a layer in `layer_pattern` (the
+# `forward_launch` span, docs/observability.md).
+LAYER_BANDED_SOFTMAX = 'B'
+LAYER_POWER_RETENTION = 'R'
+LAYER_GATED_DELTA = 'G'
+LAYER_GATED_SOFTMAX = 'S'
+
+
+def layer_pattern(params) -> str:
+  """The attention of every layer of the stack, in order: the one place
+  a per-layer pattern is derived from what the configuration states."""
+  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
+  layers = range(params.num_hidden_layers)
+  if kind == BLOCK_GATED_DELTA_MOE:
+    interval = params.full_attention_interval
+    return ''.join(
+        LAYER_GATED_SOFTMAX if (n + 1) % interval == 0 else LAYER_GATED_DELTA
+        for n in layers)
+  letter = {BLOCK_BANDED_SOFTMAX: LAYER_BANDED_SOFTMAX,
+            BLOCK_POWER_RETENTION: LAYER_POWER_RETENTION}[kind]
+  return letter * len(layers)
 
 # Transformer size presets (reference: transformer_basic_params.py).
 TRANSFORMER_SIZE_PARAMS = {
@@ -227,6 +262,66 @@ def _set_transformer_learned_embeddings_retention_hparams(params):
   params.retention_degree = 2
   # Rotary positions take the sinusoidal encoding's place, and the
   # pre-RMSNorm residual the ReZero one's.
+  params.add_pos_encoding = False
+  params.rezero = False
+  params.attn_win_size = 0
+  # The published model has no dropout.
+  params.layer_postprocess_dropout = 0.0
+  params.attention_dropout = 0.0
+  params.relu_dropout = 0.0
+  params.dtype = 'bfloat16'
+  params.inference_dtype = 'bfloat16'
+  params.use_fused_hotpath = False
+
+
+def _set_transformer_learned_embeddings_gdn_moe_hparams(params):
+  """A third encoder block kind at the widths of a public 80B
+  sparse-expert language model with 3B active parameters: hidden 2048, 48
+  layers of which every fourth is gated softmax attention (16 query / 2
+  key-value heads of 256, rotary on a quarter of the head at base 1e7)
+  and the rest Gated DeltaNet mixers (16 key / 32 value heads of 128,
+  short convolution of 4), each followed by 512 routed experts of width
+  512, 10 a token, and a gated shared expert of width 512. Behind this
+  system's pile-up embedding and 5-way head, served in bfloat16.
+
+  Which experts this process holds is a size of the configuration:
+  `experts_held_first` and `experts_held_count` of the `num_experts`
+  published. The router keeps its full width and its top-k; what the
+  experts held elsewhere would add is added elsewhere (the exchange
+  between sharing chips is not in this program). One v5e chip holds one
+  period of the pattern with half of each layer's experts
+  (--set num_hidden_layers=4 --set experts_held_count=256) and a pack of
+  512 windows (docs/inference.md)."""
+  _set_transformer_learned_embeddings_hparams(params)
+  params.model_name = 'transformer_learn_values_gdn_moe'
+  params.block_kind = BLOCK_GATED_DELTA_MOE
+  params.transformer_input_size = 2048
+  params.num_hidden_layers = 48
+  params.full_attention_interval = 4
+  # Gated DeltaNet mixer.
+  params.linear_num_key_heads = 16
+  params.linear_num_value_heads = 32
+  params.linear_key_head_dim = 128
+  params.linear_value_head_dim = 128
+  params.linear_conv_kernel_dim = 4
+  # Gated softmax attention.
+  params.num_heads = 16
+  params.num_kv_heads = 2
+  params.head_dim = 256
+  params.partial_rotary_factor = 0.25
+  params.rope_theta = 1.0e7
+  params.rms_norm_eps = 1.0e-6
+  # Sparse experts; filter_size is one expert's width.
+  params.num_experts = 512
+  params.num_experts_per_tok = 10
+  params.moe_intermediate_size = 512
+  params.filter_size = 512
+  params.shared_expert_intermediate_size = 512
+  params.norm_topk_prob = True
+  params.experts_held_first = 0
+  params.experts_held_count = 512
+  # Rotary positions and the short convolution take the sinusoidal
+  # encoding's place, and the pre-RMSNorm residual the ReZero one's.
   params.add_pos_encoding = False
   params.rezero = False
   params.attn_win_size = 0
@@ -484,6 +579,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
     _set_transformer_learned_embeddings_distill_hparams(params)
   elif model_config_name == 'transformer_learn_values_retention':
     _set_transformer_learned_embeddings_retention_hparams(params)
+  elif model_config_name == 'transformer_learn_values_gdn_moe':
+    _set_transformer_learned_embeddings_gdn_moe_hparams(params)
   else:
     raise ValueError(f'Unknown model_config_name: {model_config_name}')
 

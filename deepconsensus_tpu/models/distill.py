@@ -99,6 +99,8 @@ def run_distillation(
   but a HostLostError propagates to the caller (the flywheel's stage
   retry degrades the pod) instead of an in-place rebuild.
   """
+  for side in (params, teacher_params_cfg):
+    model_lib.refuse_inference_only_kind(side, 'distill')
   train_patterns = train_patterns or list(params.train_path)
   eval_patterns = eval_patterns or list(params.eval_path)
   num_epochs = num_epochs or params.num_epochs

@@ -69,6 +69,7 @@ def export_model(
   if params is None:
     params = config_lib.read_params_from_json(checkpoint_path)
     config_lib.finalize_params(params, is_training=False)
+  model_lib.refuse_inference_only_kind(params, 'export')
   if inference_dtype or (quantize_matmuls and quantize_matmuls != 'none'):
     with params.unlocked():
       if inference_dtype:

@@ -25,6 +25,8 @@ import numpy as np
 
 from deepconsensus_tpu import constants
 from deepconsensus_tpu.models import config as config_lib
+from deepconsensus_tpu.ops import gated_delta
+from deepconsensus_tpu.ops import moe
 from deepconsensus_tpu.ops import pallas_util
 from deepconsensus_tpu.ops import power_retention
 from deepconsensus_tpu.parallel import ring_attention as ring_lib
@@ -280,19 +282,25 @@ class FeedForward(nn.Module):
 
 class RMSNorm(nn.Module):
   """x / rms(x) * scale over the last axis, reckoned in float32 and
-  returned in `dtype`."""
+  returned in `dtype`; `zero_centred` weights start at 0 and multiply as
+  1 + scale."""
 
   epsilon: float
   dtype: Any = jnp.float32
+  zero_centred: bool = False
 
   @nn.compact
   def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-    scale = self.param('scale', nn.initializers.ones, (x.shape[-1],),
-                       jnp.float32)
+    scale = self.param(
+        'scale',
+        nn.initializers.zeros if self.zero_centred else nn.initializers.ones,
+        (x.shape[-1],), jnp.float32).astype(jnp.float32)
+    if self.zero_centred:
+      scale = 1.0 + scale
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(
         jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
-    return (y * scale.astype(jnp.float32)).astype(self.dtype)
+    return (y * scale).astype(self.dtype)
 
 
 def rotary_tables(length: int, head_dim: int, theta: float):
@@ -306,8 +314,15 @@ def rotary_tables(length: int, head_dim: int, theta: float):
           np.sin(angles).astype(np.float32))
 
 
-def apply_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
-  """x [B, L, N, D] float32, positions 0..L-1 -> x rotated."""
+def apply_rotary(x: jnp.ndarray, theta: float,
+                 rotary_dim: Optional[int] = None) -> jnp.ndarray:
+  """x [B, L, N, D] float32, positions 0..L-1 -> x rotated; with
+  `rotary_dim`, the first `rotary_dim` of the D alone (at frequencies
+  theta**(-2i/rotary_dim)), the rest as they are."""
+  if rotary_dim is not None and rotary_dim != x.shape[3]:
+    return jnp.concatenate(
+        [apply_rotary(x[..., :rotary_dim], theta), x[..., rotary_dim:]],
+        axis=-1)
   cos, sin = rotary_tables(x.shape[1], x.shape[3], theta)
   half = x.shape[3] // 2
   rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
@@ -315,20 +330,24 @@ def apply_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
 
 
 class _GateProjection(nn.Module):
-  """x [B, L, H] -> x W + b [B, L, features] in float32: the retention
-  gate's logits are summed along the window, so they leave the matmul
+  """x [B, L, H] -> x W (+ b) [B, L, features] in float32: logits that
+  are summed along the window (the retention gate), exponentiated (the
+  delta rule's decay) or ranked (the router) leave the matmul
   unrounded."""
 
   features: int
+  use_bias: bool = True
 
   @nn.compact
   def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
     kernel = self.param('kernel', nn.initializers.lecun_normal(),
                         (x.shape[-1], self.features), jnp.float32)
-    bias = self.param('bias', nn.initializers.zeros, (self.features,),
-                      jnp.float32)
     out = jnp.einsum('blh,hk->blk', x, kernel.astype(x.dtype),
                      preferred_element_type=jnp.float32)
+    if not self.use_bias:
+      return out
+    bias = self.param('bias', nn.initializers.zeros, (self.features,),
+                      jnp.float32)
     return out + bias.astype(jnp.float32)
 
 
@@ -373,6 +392,151 @@ class PowerRetentionAttention(nn.Module):
         name='output_transform')(out)
 
 
+class GatedDeltaNetMixer(nn.Module):
+  """Gated DeltaNet mixer, two directions (ops/gated_delta.py): one
+  bias-free projection to [q | k | v | z] and one, float32, to [b | a];
+  a causal depthwise convolution of `conv_kernel` positions and silu over
+  concat(q, k, v), run from the window's start and from its end with the
+  same weights; q and k L2-normalised over the head, q scaled by Dk^-1/2;
+  beta = sigmoid(b), log decay g = -exp(A_log) softplus(a + dt_bias); the
+  two directions' outputs added, then RMSNorm over each value head (plain
+  weight) gated by silu(z), and a bias-free output projection."""
+
+  hidden_size: int
+  num_key_heads: int
+  num_value_heads: int
+  key_head_dim: int
+  value_head_dim: int
+  conv_kernel: int
+  rms_norm_eps: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+    del deterministic  # the published layer has no dropout
+    hk, hv = self.num_key_heads, self.num_value_heads
+    dk, dv = self.key_head_dim, self.value_head_dim
+    key_dim, value_dim = hk * dk, hv * dv
+    batch, length, _ = x.shape
+    dense = lambda width, name: nn.Dense(
+        width, use_bias=False, dtype=self.dtype,
+        kernel_init=nn.initializers.lecun_normal(), name=name)
+    qkvz = dense(2 * key_dim + 2 * value_dim, 'in_proj_qkvz')(x)
+    mixed, z = qkvz[..., :2 * key_dim + value_dim], qkvz[..., -value_dim:]
+    b, a = jnp.split(
+        _GateProjection(2 * hv, use_bias=False, name='in_proj_ba')(x), 2,
+        axis=-1)
+    conv = self.param('conv_kernel', nn.initializers.lecun_normal(),
+                      (self.conv_kernel, mixed.shape[-1]), jnp.float32)
+    a_log = self.param('A_log', nn.initializers.zeros, (hv,), jnp.float32)
+    dt_bias = self.param('dt_bias', nn.initializers.ones, (hv,), jnp.float32)
+    norm_scale = self.param('norm_scale', nn.initializers.ones, (dv,),
+                            jnp.float32)
+    beta = jax.nn.sigmoid(b)
+    log_decay = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+        a + dt_bias.astype(jnp.float32))
+
+    # Index 0 the run from the window's start, whose convolution reads
+    # positions t - (kernel - 1) ... t; index 1 the run from its end,
+    # which reads t + (kernel - 1) ... t with the same weights. One pass
+    # over `mixed`, float32 inside, the compute dtype out.
+    reach = self.conv_kernel - 1
+    padded = jnp.pad(mixed, ((0, 0), (reach, reach), (0, 0)))
+    taps = lambda starts: jax.nn.silu(sum(
+        padded[:, start:start + length].astype(jnp.float32)
+        * conv[i].astype(jnp.float32) for i, start in enumerate(starts)))
+    # dclint: allow=dtype-downcast (the convolution's output is the
+    # compute dtype, as a bfloat16 model's is)
+    both = jnp.stack([
+        taps(range(self.conv_kernel)),
+        taps(range(2 * reach, reach - 1, -1))]).astype(self.dtype)
+    heads = lambda t, n, d: t.reshape(2, batch, length, n, d)
+    def unit(t):
+      t = t.astype(jnp.float32)
+      return t * jax.lax.rsqrt(
+          jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+    # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+    # normalised in float32)
+    query = (unit(heads(both[..., :key_dim], hk, dk)) * dk ** -0.5).astype(
+        self.dtype)
+    # dclint: allow=dtype-downcast (as above)
+    key = unit(heads(both[..., key_dim:2 * key_dim], hk, dk)).astype(
+        self.dtype)
+    value = heads(both[..., 2 * key_dim:], hv, dv)
+    with jax.named_scope('gdn'):
+      out = gated_delta.gated_delta_two_directions(
+          query, key, value, log_decay, beta)
+    out = out * jax.lax.rsqrt(
+        jnp.mean(jnp.square(out), axis=-1, keepdims=True) + self.rms_norm_eps)
+    out = out * norm_scale.astype(jnp.float32) * jax.nn.silu(
+        z.reshape(batch, length, hv, dv).astype(jnp.float32))
+    # dclint: allow=dtype-downcast (the gated norm is float32; the stream
+    # is the compute dtype)
+    out = out.astype(self.dtype).reshape(batch, length, value_dim)
+    return dense(self.hidden_size, 'out_proj')(out)
+
+
+class GatedSoftmaxAttention(nn.Module):
+  """Softmax attention over the whole window with grouped heads and an
+  output gate: the query projection yields, per head, the query and a
+  gate of the same size; q and k are RMSNorm'd over the head (zero-centred
+  weights) and rotated on the first `rotary_dim` of the head; query head
+  h reads key-value head h // (heads // kv heads); the attention's
+  output is multiplied by sigmoid(gate) before the output projection. No
+  biases."""
+
+  hidden_size: int
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  rotary_dim: int
+  rope_theta: float
+  rms_norm_eps: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+    del deterministic
+    n_q, n_kv, d = self.num_heads, self.num_kv_heads, self.head_dim
+    if n_q % n_kv:
+      raise ValueError(f'{n_q} query heads do not group over {n_kv} '
+                       'key-value heads')
+    batch, length, _ = x.shape
+    dense = lambda name, heads, width: nn.DenseGeneral(
+        features=(heads, width), axis=-1, use_bias=False, dtype=self.dtype,
+        kernel_init=nn.initializers.lecun_normal(), name=name)
+    head_norm = lambda name: RMSNorm(self.rms_norm_eps, zero_centred=True,
+                                     name=name)
+    query, gate = jnp.split(dense('query', n_q, 2 * d)(x), 2, axis=-1)
+    query = head_norm('query_norm')(query)
+    key = head_norm('key_norm')(dense('key', n_kv, d)(x))
+    value = dense('value', n_kv, d)(x)
+    # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+    # after norm and rotation in float32)
+    query = apply_rotary(query, self.rope_theta, self.rotary_dim).astype(
+        self.dtype)
+    # dclint: allow=dtype-downcast (as above)
+    key = apply_rotary(key, self.rope_theta, self.rotary_dim).astype(
+        self.dtype)
+    with jax.named_scope('softmax'):
+      grouped = query.reshape(batch, length, n_kv, n_q // n_kv, d)
+      scores = jnp.einsum('blkgd,bmkd->bkglm', grouped, key,
+                          preferred_element_type=jnp.float32)
+      weights = jax.nn.softmax(scores * jnp.float32(d ** -0.5), axis=-1)
+      out = jnp.einsum('bkglm,bmkd->blkgd', weights.astype(self.dtype), value,
+                       preferred_element_type=jnp.float32)
+    out = out.reshape(batch, length, n_q, d) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))
+    # dclint: allow=dtype-downcast (the gate is float32; the stream is the
+    # compute dtype)
+    out = out.astype(self.dtype)
+    return nn.DenseGeneral(
+        features=self.hidden_size, axis=(-2, -1), use_bias=False,
+        dtype=self.dtype, kernel_init=nn.initializers.lecun_normal(),
+        name='output_transform')(out)
+
+
 class GatedFeedForward(nn.Module):
   """SwiGLU: (silu(x W_gate) * (x W_up)) W_down, no biases."""
 
@@ -391,15 +555,76 @@ class GatedFeedForward(nn.Module):
     return dense(self.hidden_size, 'output_layer')(h)
 
 
+class SparseExpertsFeedForward(nn.Module):
+  """Routed experts plus a gated shared expert (ops/moe.py): a float32
+  router over all `num_experts`, the `experts_per_token` largest kept
+  (renormalised where `norm_topk`), the products of the experts
+  `held_first` ... `held_first + held_count - 1` alone, each a SwiGLU of
+  `expert_width`; plus sigmoid(x w_s) times a SwiGLU of `shared_width`.
+  The assignments each held expert took are sown as `assignments` in the
+  `moe_counts` collection, for whoever asks for it."""
+
+  hidden_size: int
+  num_experts: int
+  experts_per_token: int
+  expert_width: int
+  shared_width: int
+  norm_topk: bool
+  held_first: int
+  held_count: int
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+    if not 0 <= self.held_first <= self.held_first + self.held_count <= (
+        self.num_experts) or not self.held_count:
+      raise ValueError(
+          f'experts held [{self.held_first}, '
+          f'{self.held_first + self.held_count}) are not a share of '
+          f'{self.num_experts}')
+    batch, length, h = x.shape
+    expert_init = nn.initializers.variance_scaling(
+        1.0, 'fan_in', 'truncated_normal', batch_axis=(0,))
+    w_gate, w_up = (
+        self.param(name, expert_init,
+                   (self.held_count, h, self.expert_width), jnp.float32)
+        for name in ('experts_gate', 'experts_up'))
+    w_down = self.param('experts_down', expert_init,
+                        (self.held_count, self.expert_width, h), jnp.float32)
+    with jax.named_scope('moe'):
+      with jax.named_scope('router'):
+        logits = _GateProjection(self.num_experts, use_bias=False,
+                                 name='router')(x)
+        weights, experts = moe.route_top_k(
+            logits.reshape(batch * length, self.num_experts),
+            self.experts_per_token, self.norm_topk)
+      routed, counts = moe.held_experts(
+          x.reshape(batch * length, h), weights, experts, w_gate, w_up,
+          w_down, self.held_first)
+    self.sow('moe_counts', 'assignments', counts)
+    with jax.named_scope('shared_expert'):
+      shared = GatedFeedForward(
+          hidden_size=h, filter_size=self.shared_width, dtype=self.dtype,
+          name='shared_expert')(x, deterministic=deterministic)
+      share = jax.nn.sigmoid(_GateProjection(
+          1, use_bias=False, name='shared_expert_gate')(x))
+      # dclint: allow=dtype-downcast (the gate is float32; the stream is
+      # the compute dtype)
+      shared = (share * shared.astype(jnp.float32)).astype(self.dtype)
+    return routed.reshape(batch, length, h) + shared
+
+
 class ResidualWrapper(nn.Module):
   """ReZero (x + alpha*f(x), alpha init 0), pre-LN residual
   (reference PrePostProcessingWrapper: encoder_stack.py:43-93) or, with
-  `rms_norm_eps`, pre-RMSNorm residual in the stream's own type."""
+  `rms_norm_eps`, pre-RMSNorm residual in the stream's own type
+  (`rms_norm_zero_centred`: weights that multiply as 1 + w)."""
 
   sublayer: nn.Module
   rezero: bool
   dropout_rate: float
   rms_norm_eps: Optional[float] = None
+  rms_norm_zero_centred: bool = False
 
   @nn.compact
   def __call__(self, x: jnp.ndarray, deterministic: bool,
@@ -407,7 +632,8 @@ class ResidualWrapper(nn.Module):
     if self.rezero:
       y = x
     elif self.rms_norm_eps is not None:
-      y = RMSNorm(self.rms_norm_eps, dtype=x.dtype, name='rms_norm')(x)
+      y = RMSNorm(self.rms_norm_eps, dtype=x.dtype,
+                  zero_centred=self.rms_norm_zero_centred, name='rms_norm')(x)
     else:
       y = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32, name='layer_norm')(x)
     y = self.sublayer(y, deterministic=deterministic, **sublayer_kwargs)
@@ -426,6 +652,20 @@ def block_kind_of(p) -> str:
     raise ValueError(
         f'unknown block_kind {kind!r}; have {config_lib.BLOCK_KINDS}')
   return kind
+
+
+def refuse_inference_only_kind(p, command: str) -> None:
+  """`dctpu train`, `distill` and `export` of a block kind that runs at
+  inference alone, refused by name before anything is built."""
+  if 'transformer' not in str(p.model_name):
+    return
+  kind = block_kind_of(p)
+  if kind == config_lib.BLOCK_GATED_DELTA_MOE:
+    raise ValueError(
+        f'block kind {kind!r} is not served by `dctpu {command}`: routed '
+        'experts have no training step here (no balancing loss, no '
+        'gradient through the grouped products) and no exported form; '
+        'the kind runs through `dctpu run` and `dctpu serve`')
 
 
 # How a layer's attention sublayer runs (`forward_launch`'s
@@ -476,11 +716,52 @@ def _attn_softmax_dtype(p):
 
 def _block_modules(p, n: int, dtype):
   """(attention, feed-forward, wrap) of encoder layer `n` for the
-  configuration's block kind: the one place that knows the kinds.
-  `wrap(sublayer, name)` gives the kind's residual form. Called inside
-  EncoderStack's compact method, so the modules are its children."""
+  configuration's block kind and, where the kind's layers are not alike,
+  the layer's place in the pattern (config.layer_pattern): the one place
+  that knows the kinds. `wrap(sublayer, name)` gives the kind's residual
+  form. Called inside EncoderStack's compact method, so the modules are
+  its children."""
   kind = block_kind_of(p)
-  if kind == config_lib.BLOCK_POWER_RETENTION:
+  if kind == config_lib.BLOCK_GATED_DELTA_MOE:
+    if config_lib.layer_pattern(p)[n] == config_lib.LAYER_GATED_SOFTMAX:
+      attn = GatedSoftmaxAttention(
+          hidden_size=p.hidden_size,
+          num_heads=p.num_heads,
+          num_kv_heads=p.num_kv_heads,
+          head_dim=p.head_dim,
+          rotary_dim=int(p.head_dim * p.partial_rotary_factor),
+          rope_theta=p.rope_theta,
+          rms_norm_eps=p.rms_norm_eps,
+          dtype=dtype,
+          name=f'gated_attention_{n}',
+      )
+    else:
+      attn = GatedDeltaNetMixer(
+          hidden_size=p.hidden_size,
+          num_key_heads=p.linear_num_key_heads,
+          num_value_heads=p.linear_num_value_heads,
+          key_head_dim=p.linear_key_head_dim,
+          value_head_dim=p.linear_value_head_dim,
+          conv_kernel=p.linear_conv_kernel_dim,
+          rms_norm_eps=p.rms_norm_eps,
+          dtype=dtype,
+          name=f'gdn_{n}',
+      )
+    ffn = SparseExpertsFeedForward(
+        hidden_size=p.hidden_size,
+        num_experts=p.num_experts,
+        experts_per_token=p.num_experts_per_tok,
+        expert_width=p.moe_intermediate_size,
+        shared_width=p.shared_expert_intermediate_size,
+        norm_topk=p.norm_topk_prob,
+        held_first=p.experts_held_first,
+        held_count=p.experts_held_count,
+        dtype=dtype,
+        name=f'moe_{n}',
+    )
+    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps,
+                    rms_norm_zero_centred=True)
+  elif kind == config_lib.BLOCK_POWER_RETENTION:
     if p.retention_degree != power_retention.DEGREE:
       raise ValueError(
           f'retention_degree {p.retention_degree} is not served; '
@@ -527,10 +808,22 @@ def _block_modules(p, n: int, dtype):
   return attn, ffn, wrap
 
 
+def expert_assignments(sown) -> jnp.ndarray:
+  """What the stack's SparseExpertsFeedForward layers sowed in one apply
+  (the `moe_counts` collection) as [layers, experts held] int32, in layer
+  order."""
+  layers = sown['encoder']
+  return jnp.stack([
+      layers[f'moe_{n}']['assignments'][0] for n in range(len(layers))])
+
+
 def _output_norm(p):
   """The stack's final normalization, by block kind (float32 out)."""
-  if block_kind_of(p) == config_lib.BLOCK_POWER_RETENTION:
-    return RMSNorm(p.rms_norm_eps, name='output_normalization')
+  kind = block_kind_of(p)
+  if kind != config_lib.BLOCK_BANDED_SOFTMAX:
+    return RMSNorm(p.rms_norm_eps,
+                   zero_centred=kind == config_lib.BLOCK_GATED_DELTA_MOE,
+                   name='output_normalization')
   return nn.LayerNorm(
       epsilon=1e-6, dtype=jnp.float32, name='output_normalization')
 
@@ -965,7 +1258,8 @@ class DeepConsensusModel(nn.Module):
       logits = self.logits_layer(encoded.astype(jnp.float32))
       preds = jax.nn.softmax(logits, axis=-1)
       return {'final_output': encoded, 'logits': logits, 'preds': preds}
-    # Scope names (`embed`, `attention` with `retention` inside, `ffn`,
+    # Scope names (`embed`, `attention` with `retention`, `gdn` or
+    # `softmax` inside, `ffn` with `moe` and `shared_expert` inside,
     # `head`) are kept as a promise to whoever reads the device trace by
     # scope (docs/observability.md); they are metadata and change no HLO.
     with jax.named_scope('embed'):

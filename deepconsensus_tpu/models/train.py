@@ -157,6 +157,7 @@ class Trainer:
   pod_slices_batches: bool = True
 
   def __post_init__(self):
+    model_lib.refuse_inference_only_kind(self.params, 'train')
     # Bucketed training compiles one pjit step per bucket width over a
     # single param tree, so the bucket SET must be valid at
     # construction (strictly ascending, smallest == max_length — the

@@ -22,7 +22,8 @@ fleet processes appending to one file) and derives:
 * straggler packs — the slowest decile of device_compute spans with
   their bucket / dp / row-count context;
 * what the forward held and computed — from the `forward_launch`
-  spans' args: block kind, positions launched, resident weight bytes;
+  spans' args: block kind, layer pattern, the share of the experts held,
+  positions launched, resident weight bytes;
 * a span-derived transfer-overlap fraction that must agree with the
   counter-derived ``transfer_overlap_fraction``: a pack's forward
   launch (the device_compute span start) happening strictly BEFORE its
@@ -262,6 +263,11 @@ def summarize(events: List[Dict[str, Any]],
                              if a.get('block_kind')}),
       'attention_paths': sorted({str(a['attention_path']) for a in launches
                                  if a.get('attention_path')}),
+      'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
+                                if a.get('layer_pattern')}),
+      'experts_held': sorted(
+          {(*a['experts_held'], a.get('experts_published'))
+           for a in launches if a.get('experts_held')}),
       'n_positions': sum(int(a.get('n_positions') or 0) for a in launches),
       'weight_bytes': max(
           (int(a.get('weight_bytes') or 0) for a in launches), default=0),
@@ -322,6 +328,11 @@ def format_summary(summary: Dict[str, Any]) -> str:
         f'(attention: {", ".join(forward["attention_paths"]) or "?"}), '
         f'{forward["n_positions"]} positions, '
         f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
+    if forward.get('layer_patterns'):
+      lines.append(
+          f'  layers: {", ".join(forward["layer_patterns"])}' + ''.join(
+              f'; experts {lo}-{hi - 1} of {published} held'
+              for lo, hi, published in forward.get('experts_held', ())))
   overlap = summary['overlap']
   lines.append(
       f'transfer overlap (span-derived): '

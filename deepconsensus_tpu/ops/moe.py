@@ -1,0 +1,116 @@
+"""Sparse experts: top-k routing and the products of the experts held here.
+
+A token's feed-forward is the weighted sum of k of E small gated
+feed-forwards, chosen by a router:
+
+  p = softmax(n W_r) over all E;  top = the k largest, renormalised
+  moe(n) = sum_{e in top} p_e (silu(n W_gate_e) * (n W_up_e)) W_down_e
+
+A process holds a contiguous share [first, first + held) of the experts.
+The router keeps its E outputs and its top-k; of a token's k assignments
+those that fall on held experts are computed here and the others are
+left to the process that holds them (shares add up: tests/
+test_moe_share.py). There is no capacity and no dropped token: the (token,
+expert) assignments are sorted by expert, the held ones first, and the
+three products run as grouped products over `held` ragged groups of rows
+(`jax.lax.ragged_dot`, which the TPU compiler lowers to one grouped
+matrix-multiply kernel that visits only the rows its groups cover, not
+to a masked dense product a group). Rows behind the last held group are
+never computed and never read.
+
+Tokens are taken MAX_ROWS assignments at a time (jax.lax.map): the sorted
+copy of the tokens and the experts' output are [tokens x k, hidden] each,
+4 kB a row at hidden 2048.
+"""
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+MAX_ROWS = 1 << 18
+
+
+def route_top_k(logits: jnp.ndarray, k: int,
+                renormalise: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+  """Router logits [N, E] -> (weights [N, k] float32, experts [N, k]
+  int32): softmax over all E in float32, the k largest, made to sum to
+  one where the model renormalises."""
+  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+  weights, experts = jax.lax.top_k(probs, k)
+  if renormalise:
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+  return weights, experts.astype(jnp.int32)
+
+
+def _held_experts(x, weights, experts, w_gate, w_up, w_down, first: int):
+  """One turn of `held_experts`: every row of x at once."""
+  n, k = experts.shape
+  held = w_gate.shape[0]
+  with jax.named_scope('dispatch'):
+    local = experts - first
+    mine = (local >= 0) & (local < held)
+    # Assignments by held expert, the others behind them all.
+    group = jnp.where(mine, local, held).reshape(n * k)
+    # The routing weights ride along with the sort: no gather of scalars.
+    group_sorted, order, row_weight = jax.lax.sort(
+        (group, jnp.arange(n * k, dtype=jnp.int32), weights.reshape(n * k)),
+        num_keys=1)
+    bounds = jnp.searchsorted(
+        group_sorted, jnp.arange(held + 1, dtype=jnp.int32), side='left')
+    counts = jnp.diff(bounds).astype(jnp.int32)
+    # Every index below is a position of a permutation: `clip` spares the
+    # bounds check and the pass that fills what it would reject.
+    rows = jnp.take(x, order // k, axis=0, mode='clip')  # [n * k, hidden]
+  with jax.named_scope('experts'):
+    # Each product leaves its kernel in x's type, from a float32
+    # accumulator: a float32 copy of [n * k, hidden] is never written. The
+    # routing weight multiplies the row before the last product, which is
+    # linear, so the combine only adds.
+    grouped = lambda a, w: jax.lax.ragged_dot(
+        a, w.astype(a.dtype), counts, preferred_element_type=a.dtype)
+    hidden = jax.nn.silu(grouped(rows, w_gate).astype(jnp.float32))
+    hidden = hidden * grouped(rows, w_up).astype(jnp.float32)
+    out = grouped((hidden * row_weight[:, None]).astype(x.dtype), w_down)
+  with jax.named_scope('combine'):
+    # Where each assignment's row went: the inverse of the sort. The rows
+    # come back one assignment of every token after another ([k, n, H]: a
+    # token's k rows are then k planes to add, and no [n, k, H] array is
+    # laid out anew).
+    _, place = jax.lax.sort((order, jnp.arange(n * k, dtype=jnp.int32)),
+                            num_keys=1)
+    mine_t = mine.T  # [k, n]
+    # An assignment held elsewhere reads row 0 (any row: it is masked
+    # below), so that half the reads do not wander over rows nobody wrote.
+    back = jnp.take(out, jnp.where(mine_t, place.reshape(n, k).T, 0).reshape(
+        n * k), axis=0, mode='clip').reshape(k, n, -1)
+    # A `where`, not a product by zero: rows past the held groups hold
+    # whatever the buffer held. Summed in float32 as the rows are read.
+    y = jnp.sum(jnp.where(mine_t[..., None], back, jnp.zeros((), back.dtype)),
+                axis=0, dtype=jnp.float32)
+  return y.astype(x.dtype), counts
+
+
+def held_experts(x: jnp.ndarray, weights: jnp.ndarray, experts: jnp.ndarray,
+                 w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray,
+                 first: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+  """What the held experts add to every token.
+
+  x [N, H] tokens; weights, experts [N, k] from `route_top_k`; w_gate,
+  w_up [held, H, F] and w_down [held, F, H] the experts first ...
+  first + held - 1. -> (y [N, H] in x's type, counts [held] int32: the
+  assignments each held expert took). Products take x's type with a
+  float32 accumulator and leave it in x's type; the gate, the routing
+  weight and the combine's sum are float32."""
+  n, k = experts.shape
+  turns = 1
+  while (n // turns) * k > MAX_ROWS and n % (turns * 2) == 0:
+    turns *= 2
+  if turns == 1:
+    return _held_experts(x, weights, experts, w_gate, w_up, w_down, first)
+  split = lambda a: a.reshape((turns, n // turns) + a.shape[1:])
+  y, counts = jax.lax.map(
+      lambda xs: _held_experts(*xs, w_gate, w_up, w_down, first),
+      (split(x), split(weights), split(experts)))
+  return y.reshape(n, -1), jnp.sum(counts, axis=0)

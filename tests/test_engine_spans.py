@@ -512,6 +512,11 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     # The compiled pack's rows x width, the tail pack's padding included.
     assert e['args']['n_positions'] == BATCH * p.max_length
     assert e['args']['weight_bytes'] == 7 * 3 * 2 + 5 * 4
+    # One letter a layer; only sparse experts state a held share.
+    assert e['args']['layer_pattern'] == (
+        {config_lib.BLOCK_BANDED_SOFTMAX: 'B',
+         config_lib.BLOCK_POWER_RETENTION: 'R'}[kind] * p.num_hidden_layers)
+    assert 'experts_held' not in e['args']
   stats = engine.stats()
   assert stats['block_kind'] == kind
   assert stats['model_weight_bytes'] == 62
@@ -525,6 +530,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'],
+                     'layer_patterns': [config_lib.layer_pattern(p)],
+                     'experts_held': [],
                      'n_positions': 3 * BATCH * p.max_length,
                      'weight_bytes': 62}
   assert cli.main(['trace', path]) == 0

@@ -1,0 +1,217 @@
+"""The third encoder block kind (config.BLOCK_GATED_DELTA_MOE) on the
+normal path: through ModelRunner and ConsensusEngine from submit to
+delivery against the test-local plain reference, what the path says of the
+kind (spans, counters, `dctpu trace`) and counts of it, which hot paths
+decline it, and what the kind refuses by name (--tp, int8, train, distill,
+export). Sizes, seeded weights and reference are tests/test_gdn_moe_block.py's
+(a file of its own so that the two halves run on two workers).
+"""
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepconsensus_tpu.inference import engine as engine_lib
+from deepconsensus_tpu.models import config as config_lib
+from deepconsensus_tpu.models import model as model_lib
+from deepconsensus_tpu.obs import summarize as summarize_lib
+from deepconsensus_tpu.obs import trace as trace_lib
+from tests.test_gdn_moe_block import (KIND, LENGTHS, TOP_K, _runner,
+                                      reference, seeded_variables,
+                                      tiny_params)
+from tests.test_power_retention import pileup_rows
+
+# ------------------------------------------------------------ the normal path
+
+@pytest.mark.parametrize('length', LENGTHS)
+def test_engine_submit_to_delivery_serves_the_reference_bases(length,
+                                                              tmp_path):
+  p = tiny_params(length)
+  model = model_lib.get_model(p)
+  variables = seeded_variables(model, p, seed=1)
+  runner, options = _runner(p, variables)
+  delivered = {}
+  engine = engine_lib.ConsensusEngine(
+      runner, options,
+      deliver=lambda t, ids, quals: delivered.__setitem__(
+          t, (ids.copy(), quals.copy())))
+  rows = pileup_rows(p, 19, seed=2)
+  path = str(tmp_path / 'spans.jsonl')
+  trace_lib.configure(path, tier='run')
+  try:
+    engine.submit(list(rows), list(range(len(rows))))
+    engine.flush()
+  finally:
+    trace_lib.configure(None)
+  assert sorted(delivered) == list(range(19))
+  want, want_counts = reference(variables, rows, p)
+  ids = np.stack([delivered[t][0] for t in range(19)])
+  quals = np.stack([delivered[t][1] for t in range(19)])
+  # Where the reference's top two logits are not a rounding apart.
+  top = np.sort(want, axis=-1)
+  clear = (top[..., -1] - top[..., -2]) > 1e-3
+  assert clear.mean() > 0.95
+  assert np.array_equal(ids[clear], want.argmax(-1)[clear])
+  assert quals.min() >= 0 and len(np.unique(quals)) > 3
+
+  # What the normal path says of the kind, and what it counts of it.
+  stats = engine.stats()
+  assert stats['block_kind'] == KIND
+  assert stats['n_forward_positions'] == 3 * 8 * length
+  assert stats['n_forward_shapes'] == 1
+  # Three packs of 8 (the tail's 5 padding windows are routed too), 4
+  # layers, 4 experts a position.
+  assert stats['moe_assignments_total'] == 3 * 8 * length * 4 * TOP_K
+  counters = runner.obs.snapshot()
+  assert counters['counters']['moe_assignments_total'] == (
+      stats['moe_assignments_total'])
+  assert counters['counters']['moe_assignments_held'] == (
+      stats['moe_assignments_held'])
+  assert counters['gauges']['moe_expert_load_max'] == (
+      stats['moe_expert_load_max'])
+  events = [e for e in summarize_lib.load_trace(path) if e.get('ph') == 'X']
+  launches = [e['args'] for e in events if e['name'] == 'forward_launch']
+  drains = [e['args'] for e in events if e['name'] == 'finalize_drain']
+  assert len(launches) == len(drains) == 3
+  for args in launches:
+    assert args['block_kind'] == KIND and args['attention_path'] == 'xla'
+    assert args['layer_pattern'] == 'GGGS'
+    assert args['experts_held'] == [8, 16]
+    assert args['experts_published'] == 16
+  assert sum(a['moe_assignments_held'] for a in drains) == (
+      stats['moe_assignments_held'])
+  assert max(a['moe_expert_load_max'] for a in drains) == (
+      stats['moe_expert_load_max'])
+  # The two full packs hold windows 0 ... 15: their counts are the
+  # reference's for those windows.
+  # To within a couple of near-ties of the last expert kept: the program
+  # and the reference sum in two orders, and 25,600 assignments are routed.
+  _, first_two = reference(variables, rows[:16], p)
+  assert abs(drains[0]['moe_assignments_held']
+             + drains[1]['moe_assignments_held'] - first_two.sum()) <= 2
+  share = stats['moe_assignments_held'] / stats['moe_assignments_total']
+  assert 0.3 < share < 0.7  # half the experts are held
+  assert want_counts.sum() <= stats['moe_assignments_held']
+
+
+def test_dctpu_trace_shows_the_pattern_and_the_held_share(tmp_path, capsys):
+  from deepconsensus_tpu import cli
+
+  p = tiny_params(12)
+  variables = seeded_variables(model_lib.get_model(p), p, seed=6)
+  runner, _ = _runner(p, variables)
+  path = str(tmp_path / 'spans.jsonl')
+  trace_lib.configure(path, tier='run')
+  try:
+    runner.predict(pileup_rows(p, 8, seed=6))
+  finally:
+    trace_lib.configure(None)
+  assert cli.main(['trace', path, '--json']) == 0
+  forward = json.loads(capsys.readouterr().out)['forward']
+  assert forward['block_kinds'] == [KIND]
+  assert forward['attention_paths'] == ['xla']
+  assert forward['layer_patterns'] == ['GGGS']
+  assert forward['experts_held'] == [[8, 16, 16]]
+  assert cli.main(['trace', path]) == 0
+  assert 'layers: GGGS; experts 8-15 of 16 held' in capsys.readouterr().out
+
+
+def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
+  from deepconsensus_tpu.ops import pallas_util
+
+  p = tiny_params(100, dtype='bfloat16')
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+  with pallas_util.single_device_inference():
+    assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
+    banded = config_lib.get_config('transformer_learn_values+custom')
+    with banded.unlocked():
+      banded.dtype = 'bfloat16'
+    config_lib.finalize_params(banded, is_training=False)
+    assert model_lib.attention_path(banded, length=100) == (
+        model_lib.ATTENTION_FUSED_SUBLAYER)
+
+
+@pytest.mark.parametrize('flag', ['fused', 'ragged'])
+def test_fused_and_ragged_hot_paths_decline_the_kind(flag):
+  import flax.linen as nn
+
+  p = tiny_params(use_fused_hotpath=True)
+  model = model_lib.get_model(p)
+  rows = jnp.zeros((2, 25, 12))
+
+  def eligible(m):
+    if flag == 'fused':
+      return m._fused_hotpath_eligible(rows, False)
+    return m._ragged_hotpath_eligible(rows)
+
+  assert nn.apply(eligible, model)({'params': {}}) is False
+
+
+# ------------------------------------------------- what the kind refuses
+
+def test_tp_is_refused_by_name_and_dp_is_served():
+  from deepconsensus_tpu.parallel import mesh as mesh_lib
+
+  p = tiny_params(12)
+  variables = seeded_variables(model_lib.get_model(p), p, seed=7)
+  with pytest.raises(ValueError, match=r'not served with --tp: '
+                     r'parallel/partition_rules.py has no expert axis'):
+    _runner(p, variables, mesh=mesh_lib.make_mesh(
+        dp=2, tp=2, devices=jax.devices()[:4]))
+  rows = pileup_rows(p, 8, seed=7)
+  alone, _ = _runner(p, variables)
+  sharded, _ = _runner(p, variables, mesh=mesh_lib.make_mesh(
+      dp=2, tp=1, devices=jax.devices()[:2]))
+  ids, quals = alone.predict(rows)
+  ids_dp, quals_dp = sharded.predict(rows)
+  assert np.array_equal(np.asarray(ids), np.asarray(ids_dp))
+  assert np.abs(np.asarray(quals, np.int32)
+                - np.asarray(quals_dp, np.int32)).max() <= 1
+  assert sharded.dispatch_stats()['moe_assignments_held'] == (
+      alone.dispatch_stats()['moe_assignments_held'])
+
+
+def test_int8_is_refused_by_name():
+  p = tiny_params(12, quantize_matmuls='int8')
+  variables = seeded_variables(model_lib.get_model(p), p, seed=8)
+  with pytest.raises(ValueError, match=r"not served with "
+                     r"quantize_matmuls='int8': models/quantize.py has no "
+                     r"per-expert scales"):
+    _runner(p, variables)
+
+
+@pytest.mark.parametrize('command', ['train', 'distill', 'export'])
+def test_training_and_export_of_the_kind_are_refused_by_name(command,
+                                                             tmp_path):
+  from deepconsensus_tpu.models import distill as distill_lib
+  from deepconsensus_tpu.models import export as export_lib
+  from deepconsensus_tpu.models import train as train_lib
+
+  p = tiny_params(12)
+  match = rf"'{KIND}' is not served by `dctpu {command}`"
+  with pytest.raises(ValueError, match=match):
+    if command == 'train':
+      train_lib.Trainer(params=p, out_dir=str(tmp_path))
+    elif command == 'distill':
+      student = config_lib.get_config('transformer_learn_values_distill+test')
+      config_lib.finalize_params(student, is_training=False)
+      distill_lib.run_distillation(student, p, {}, str(tmp_path),
+                                   train_patterns=['x'], eval_patterns=['x'])
+    else:
+      export_lib.export_model('unused', str(tmp_path), params=p,
+                              variables={'params': {}})
+  # The kinds that train are not in the way of the check.
+  for preset in ('transformer_learn_values+test', 'fc+test'):
+    other = config_lib.get_config(preset)
+    config_lib.finalize_params(other, is_training=False)
+    model_lib.refuse_inference_only_kind(other, command)
+
+
+def test_a_full_attention_interval_is_stated_not_defaulted():
+  p = tiny_params(12)
+  with p.unlocked():
+    del p['full_attention_interval']
+  with pytest.raises((AttributeError, KeyError)):
+    config_lib.layer_pattern(p)

@@ -1,0 +1,206 @@
+"""ops/moe.py and SparseExpertsFeedForward: the share adds up.
+
+A process holds a share of a layer's experts; the router keeps its full
+width. At a small size on the CPU: the routed parts that the shares
+[0, E/2) and [E/2, E) compute, plus the shared expert counted once, equal
+what the test-local plain reference (tests/gdn_moe_reference.py) gives for
+the uncut layer; no assignment is lost or doubled when one expert takes
+every token and when an expert takes none; the counts that come back sum
+to positions x k.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepconsensus_tpu.models import model as model_lib
+from deepconsensus_tpu.ops import moe
+from tests import gdn_moe_reference as ref
+
+H, E, K, F = 32, 16, 4, 24
+
+
+def layer(first=0, count=E, dtype=jnp.float32, norm_topk=True):
+  return model_lib.SparseExpertsFeedForward(
+      hidden_size=H, num_experts=E, experts_per_token=K, expert_width=F,
+      shared_width=F, norm_topk=norm_topk, held_first=first, held_count=count,
+      dtype=dtype)
+
+
+def whole_layer_weights(seed=0):
+  """The uncut layer's leaves, drawn so that every part counts."""
+  rng = np.random.default_rng(seed)
+  draw = lambda *shape: jnp.asarray(
+      rng.normal(0, shape[-2] ** -0.5, shape), jnp.float32)
+  return {
+      'router': {'kernel': draw(H, E) * 3.0},
+      'experts_gate': draw(E, H, F), 'experts_up': draw(E, H, F),
+      'experts_down': draw(E, F, H),
+      'shared_expert': {'gate_layer': {'kernel': draw(H, F)},
+                        'up_layer': {'kernel': draw(H, F)},
+                        'output_layer': {'kernel': draw(F, H)}},
+      'shared_expert_gate': {'kernel': draw(H, 1)},
+  }
+
+
+def share_of(weights, first, count):
+  """What the process holding experts first ... first + count - 1 has."""
+  cut = dict(weights)
+  for name in ('experts_gate', 'experts_up', 'experts_down'):
+    cut[name] = weights[name][first:first + count]
+  return cut
+
+
+def tokens(batch=3, length=20, seed=1):
+  return jnp.asarray(
+      np.random.default_rng(seed).normal(size=(batch, length, H)), jnp.float32)
+
+
+def apply(first, count, weights, x, **kwargs):
+  out, sown = layer(first, count, **kwargs).apply(
+      {'params': share_of(weights, first, count)}, x, deterministic=True,
+      mutable=['moe_counts'])
+  return out, np.asarray(sown['moe_counts']['assignments'][0])
+
+
+def shared_part(weights, x):
+  flat = x.reshape(-1, H)
+  s = weights['shared_expert']
+  out = jax.nn.sigmoid(
+      flat @ weights['shared_expert_gate']['kernel']) * ref.swiglu(
+          flat, s['gate_layer']['kernel'], s['up_layer']['kernel'],
+          s['output_layer']['kernel'])
+  return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize('cuts', [((0, 8), (8, 8)), ((0, 4), (4, 4), (8, 8)),
+                                  ((0, 1), (1, 15))],
+                         ids=['halves', 'three_shares', 'one_and_the_rest'])
+def test_shares_and_the_shared_expert_once_are_the_uncut_layer(cuts):
+  weights, x = whole_layer_weights(), tokens()
+  want, want_counts = ref.routed_experts(weights, x.reshape(-1, H), top_k=K)
+  shared = shared_part(weights, x)
+  total, counts = 0.0, []
+  for first, count in cuts:
+    out, took = apply(first, count, weights, x)
+    total = total + (out - shared)  # the routed part of this share
+    counts.append(took)
+  total = total + shared  # what every chip computes alike, once
+  np.testing.assert_allclose(np.asarray(total).reshape(-1, H),
+                             np.asarray(want), atol=2e-5)
+  assert np.array_equal(np.concatenate(counts), want_counts)
+  assert want_counts.sum() == x.shape[0] * x.shape[1] * K
+
+
+@pytest.mark.parametrize('first,count', [(0, 16), (0, 8), (8, 8), (5, 3)])
+def test_a_share_is_the_references_routed_part_for_that_share(first, count):
+  weights, x = whole_layer_weights(seed=3), tokens(seed=4)
+  got, took = apply(first, count, weights, x)
+  want, want_counts = ref.routed_experts(
+      share_of(weights, first, count), x.reshape(-1, H), top_k=K,
+      first=first)
+  np.testing.assert_allclose(np.asarray(got).reshape(-1, H),
+                             np.asarray(want), atol=2e-5)
+  assert np.array_equal(took, want_counts)
+
+
+def routed(logits, first, count, weights, x):
+  w, e = moe.route_top_k(logits, K, True)
+  cut = share_of(weights, first, count)
+  return moe.held_experts(x, w, e, cut['experts_gate'], cut['experts_up'],
+                          cut['experts_down'], first)
+
+
+@pytest.mark.parametrize('first,count', [(0, 8), (8, 8), (0, 16)])
+def test_one_expert_taking_every_token_and_one_taking_none(first, count):
+  """Expert 3 is in every token's top-k and expert 9 in no token's: the
+  fullest group holds every token once, the empty one nothing, and what
+  comes back is still the plain loop's sum."""
+  weights = whole_layer_weights(seed=5)
+  x = tokens(seed=6).reshape(-1, H)
+  logits = jnp.asarray(np.random.default_rng(7).normal(size=(len(x), E)),
+                       jnp.float32)
+  logits = logits.at[:, 3].set(20.0).at[:, 9].set(-20.0)
+  got, counts = routed(logits, first, count, weights, x)
+  counts = np.asarray(counts)
+  if first <= 3 < first + count:
+    assert counts[3 - first] == len(x)
+  if first <= 9 < first + count:
+    assert counts[9 - first] == 0
+  top_p, top_e = (np.asarray(a) for a in moe.route_top_k(logits, K, True))
+  want = np.zeros((len(x), H), np.float32)
+  for e in range(first, first + count):
+    token, slot = np.nonzero(top_e == e)
+    assert counts[e - first] == len(token)
+    want[token] += top_p[token, slot][:, None] * np.asarray(ref.swiglu(
+        x[token], weights['experts_gate'][e], weights['experts_up'][e],
+        weights['experts_down'][e]))
+  np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+  if count == E:
+    assert counts.sum() == len(x) * K
+
+
+def test_every_token_on_experts_held_elsewhere_adds_nothing():
+  weights = whole_layer_weights(seed=8)
+  x = tokens(seed=9).reshape(-1, H)
+  logits = jnp.zeros((len(x), E)).at[:, 8:12].set(10.0)
+  got, counts = routed(logits, 0, 8, weights, x)
+  assert np.asarray(counts).sum() == 0
+  assert np.abs(np.asarray(got)).max() == 0.0
+  _got, counts = routed(logits, 8, 8, weights, x)
+  assert np.array_equal(np.asarray(counts), [len(x)] * 4 + [0] * 4)
+
+
+def test_more_assignments_than_one_go_holds_are_taken_in_turn(monkeypatch):
+  weights, x = whole_layer_weights(seed=10), tokens(batch=4, seed=11)
+  whole, counts = apply(4, 8, weights, x)
+  monkeypatch.setattr(moe, 'MAX_ROWS', 20 * K)  # one window a turn
+  in_turn, counts_in_turn = apply(4, 8, weights, x)
+  np.testing.assert_allclose(np.asarray(in_turn), np.asarray(whole),
+                             atol=1e-6)
+  assert np.array_equal(counts, counts_in_turn)
+
+
+@pytest.mark.parametrize('renormalise', [True, False])
+def test_router_keeps_the_k_largest_of_a_float32_softmax(renormalise):
+  logits = jnp.asarray(np.random.default_rng(12).normal(size=(50, E)) * 2,
+                       jnp.bfloat16)
+  weights, experts = moe.route_top_k(logits, K, renormalise)
+  assert weights.dtype == jnp.float32 and experts.dtype == jnp.int32
+  probs = np.asarray(jax.nn.softmax(logits.astype(jnp.float32), axis=-1))
+  order = np.argsort(-probs, axis=-1)[:, :K]
+  assert np.array_equal(np.sort(np.asarray(experts)), np.sort(order))
+  kept = np.take_along_axis(probs, np.asarray(experts), axis=-1)
+  if renormalise:
+    kept = kept / kept.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, atol=1e-6)
+  np.testing.assert_allclose(np.asarray(weights), kept, atol=1e-6)
+
+
+def test_top_k_weights_left_unnormalised_are_a_different_layer():
+  weights, x = whole_layer_weights(seed=13), tokens(seed=14)
+  a, _ = apply(0, 16, weights, x)
+  b, _ = apply(0, 16, weights, x, norm_topk=False)
+  assert np.abs(np.asarray(a - b)).max() > 0.01
+
+
+def test_stream_in_bfloat16_keeps_router_and_combine_in_float32():
+  weights, x = whole_layer_weights(seed=15), tokens(seed=16)
+  low = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), weights)
+  got, took = apply(0, 8, low, x.astype(jnp.bfloat16), dtype=jnp.bfloat16)
+  assert got.dtype == jnp.bfloat16
+  rounded = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), low)
+  want, want_took = apply(
+      0, 8, rounded, x.astype(jnp.bfloat16).astype(jnp.float32))
+  # The same routing unless two experts tie within bfloat16's rounding of
+  # the stream; the sums agree to bfloat16's 8 bits of the largest term.
+  assert np.abs(took - want_took).sum() <= 4
+  assert np.abs(np.asarray(got, np.float32) - np.asarray(want)).max() < (
+      0.05 * np.abs(np.asarray(want)).max())
+
+
+@pytest.mark.parametrize('first,count', [(8, 9), (0, 0), (-1, 4)])
+def test_a_share_that_is_no_share_of_the_experts_is_refused(first, count):
+  with pytest.raises(ValueError, match='are not a share of 16'):
+    layer(first, count).init(jax.random.PRNGKey(0), tokens(),
+                             deterministic=True)

@@ -6,7 +6,8 @@ described (not attached) `v5e:2x2` device at production shapes
 (6 layers x hidden 280 x filter 2048, 85 rows x L=100, batch 1024 for
 inference, the attention sublayer kernel the bfloat16 forward takes by
 itself among them, and 256 for the loss; two layers of the power-retention block
-kind at hidden 5120, batch 256). A compile that passes here is not a
+kind at hidden 5120, batch 256; one period of the gated-delta and
+sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512). A compile that passes here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
 
@@ -166,6 +167,55 @@ def test_power_retention_forward_b256_at_published_widths(one_chip):
   # Grouped heads: no repeat of k or v to 40 heads is materialised.
   assert 'bf16[256,100,40,128]' in compiled.as_text()
   assert 'repeat' not in compiled.as_text()
+
+
+def test_gated_delta_hybrid_forward_b512_at_published_widths(
+    one_chip, compiled_kernels, monkeypatch):
+  """The third block kind as it is served on one chip: one period of the
+  pattern (three Gated DeltaNet layers, one gated softmax layer), experts
+  0-255 of 512 in each, bfloat16 leaves, a pack of 512 windows, by shape
+  alone (no array of the 6.3 GiB is made). As ModelRunner traces it
+  without a mesh: the delta rule takes its window kernel, the grouped
+  products the compiler's own."""
+  p = config_lib.get_config('transformer_learn_values_gdn_moe+custom')
+  with p.unlocked():
+    p.num_hidden_layers = 4
+    p.experts_held_count = 256
+  config_lib.finalize_params(p, is_training=False)
+  model = model_lib.get_model(p)
+  tree = jax.eval_shape(
+      lambda key: model.init(
+          key, jnp.zeros((1, p.total_rows, p.max_length, 1), jnp.float32)),
+      jax.random.PRNGKey(0))['params']
+  variables = {'params': jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                     sharding=one_chip), tree)}
+  rows = jax.ShapeDtypeStruct(
+      (512, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+
+  def forward(variables, rows):
+    with pallas_util.single_device_inference():
+      return model.apply(variables, rows, mutable=['moe_counts'])
+
+  compiled = jax.jit(forward).lower(variables, rows).compile()
+  text = compiled.as_text()
+  # One window kernel a DeltaNet layer, and in every layer the three
+  # grouped products with the metadata call that sizes their groups: no
+  # masked dense product a group.
+  assert text.count('gated_delta_window') >= 3
+  assert text.count('ragged-dot') >= 4 * 3
+  assert _n_kernels(compiled) >= 3 + 4 * 3
+  memory = compiled.memory_analysis()
+  # 3,366,446,144 block parameters and what lies outside, 2 bytes each.
+  assert 2 * 3_366_446_144 < memory.argument_size_in_bytes < 6.8e9
+  # With the weights, a pack's temporaries have to leave room on a chip of
+  # 15.75 GiB: the delta rule's q, k, v of both directions and one turn of
+  # the experts' sorted rows are the largest.
+  assert memory.temp_size_in_bytes < 7 << 30
+  # The experts' sorted rows are one turn of 25,600 tokens in bfloat16
+  # (the temporaries above would not hold the pack's 512,000 at once).
+  assert 'bf16[256000,2048]' in text
 
 
 def test_fused_front_end_b1024(one_chip, compiled_kernels):
