@@ -1075,10 +1075,10 @@ class ModelRunner:
     # would block.
     with obs_lib.stage(self.obs, obs_lib.trace.STAGE_LAUNCH,
                        pack=handle.seq, block_kind=self._block_kind,
-                       attention_path=self._attention_path(
-                           inputs[0].shape[2], handle.ragged),
                        n_positions=n_positions,
                        weight_bytes=self._weight_bytes,
+                       **self._kernel_paths(inputs[0].shape[2],
+                                            handle.ragged),
                        **self._launch_fields):
       try:
         faults.injected_device_fault(handle.seq)
@@ -1090,15 +1090,20 @@ class ModelRunner:
       except Exception as e:
         handle.error = faults.classify_device_error(e)
 
-  def _attention_path(self, length: int, ragged: bool) -> str:
-    """How the compiled forward of this width runs its attention
-    sublayers: the model's own rule, asked as the forward's trace asks
-    it."""
+  def _kernel_paths(self, length: int, ragged: bool) -> Dict[str, str]:
+    """What the compiled forward of this width takes of the kernels the
+    model chooses by itself: `attention_path` for its attention sublayers
+    and, where it has Gated DeltaNet mixers, `delta_rule_path`; the
+    model's own rules, asked as the forward's trace asks them."""
     if 'transformer' not in self.params.model_name:
-      return model_lib.ATTENTION_XLA
+      return {'attention_path': model_lib.ATTENTION_XLA}
     with pallas_util.single_device_inference(self._single_device):
-      return model_lib.attention_path(
-          self.params, length=length, ragged=ragged)
+      paths = {'attention_path': model_lib.attention_path(
+          self.params, length=length, ragged=ragged)}
+      delta_rule = model_lib.delta_rule_path(self.params, length=length)
+    if delta_rule is not None:
+      paths['delta_rule_path'] = delta_rule
+    return paths
 
   def raw_outputs(self, dispatched: _DispatchHandle):
     """Device arrays (pred_ids, max_prob, n) for a dispatch handle —

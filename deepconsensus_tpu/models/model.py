@@ -415,9 +415,8 @@ class GatedDeltaNetMixer(nn.Module):
   def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
     del deterministic  # the published layer has no dropout
     hk, hv = self.num_key_heads, self.num_value_heads
-    dk, dv = self.key_head_dim, self.value_head_dim
-    key_dim, value_dim = hk * dk, hv * dv
-    batch, length, _ = x.shape
+    key_dim, value_dim = hk * self.key_head_dim, hv * self.value_head_dim
+    length = x.shape[1]
     dense = lambda width, name: nn.Dense(
         width, use_bias=False, dtype=self.dtype,
         kernel_init=nn.initializers.lecun_normal(), name=name)
@@ -430,50 +429,35 @@ class GatedDeltaNetMixer(nn.Module):
                       (self.conv_kernel, mixed.shape[-1]), jnp.float32)
     a_log = self.param('A_log', nn.initializers.zeros, (hv,), jnp.float32)
     dt_bias = self.param('dt_bias', nn.initializers.ones, (hv,), jnp.float32)
-    norm_scale = self.param('norm_scale', nn.initializers.ones, (dv,),
-                            jnp.float32)
+    norm_scale = self.param('norm_scale', nn.initializers.ones,
+                            (self.value_head_dim,), jnp.float32)
     beta = jax.nn.sigmoid(b)
     log_decay = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
         a + dt_bias.astype(jnp.float32))
 
-    # Index 0 the run from the window's start, whose convolution reads
-    # positions t - (kernel - 1) ... t; index 1 the run from its end,
-    # which reads t + (kernel - 1) ... t with the same weights. One pass
-    # over `mixed`, float32 inside, the compute dtype out.
+    # The run from the window's start, whose convolution reads positions
+    # t - (kernel - 1) ... t, and the run from its end, which reads
+    # t + (kernel - 1) ... t with the same weights. One pass over `mixed`,
+    # float32 inside, the compute dtype out.
     reach = self.conv_kernel - 1
     padded = jnp.pad(mixed, ((0, 0), (reach, reach), (0, 0)))
-    taps = lambda starts: jax.nn.silu(sum(
-        padded[:, start:start + length].astype(jnp.float32)
-        * conv[i].astype(jnp.float32) for i, start in enumerate(starts)))
     # dclint: allow=dtype-downcast (the convolution's output is the
     # compute dtype, as a bfloat16 model's is)
-    both = jnp.stack([
-        taps(range(self.conv_kernel)),
-        taps(range(2 * reach, reach - 1, -1))]).astype(self.dtype)
-    heads = lambda t, n, d: t.reshape(2, batch, length, n, d)
-    def unit(t):
-      t = t.astype(jnp.float32)
-      return t * jax.lax.rsqrt(
-          jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-
-    # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
-    # normalised in float32)
-    query = (unit(heads(both[..., :key_dim], hk, dk)) * dk ** -0.5).astype(
-        self.dtype)
-    # dclint: allow=dtype-downcast (as above)
-    key = unit(heads(both[..., key_dim:2 * key_dim], hk, dk)).astype(
-        self.dtype)
-    value = heads(both[..., 2 * key_dim:], hv, dv)
+    taps = lambda starts: jax.nn.silu(sum(
+        padded[:, start:start + length].astype(jnp.float32)
+        * conv[i].astype(jnp.float32)
+        for i, start in enumerate(starts))).astype(self.dtype)
+    streams = (taps(range(self.conv_kernel)),
+               taps(range(2 * reach, reach - 1, -1)))
+    # Between the convolution and the output projection the stream stays
+    # flat, heads along the lanes: the L2 norm of q and k, the rule in
+    # two directions and the gated norm are one operator
+    # (gated_delta.gated_delta_window), one Pallas call a window where
+    # its rule says so.
     with jax.named_scope('gdn'):
-      out = gated_delta.gated_delta_two_directions(
-          query, key, value, log_decay, beta)
-    out = out * jax.lax.rsqrt(
-        jnp.mean(jnp.square(out), axis=-1, keepdims=True) + self.rms_norm_eps)
-    out = out * norm_scale.astype(jnp.float32) * jax.nn.silu(
-        z.reshape(batch, length, hv, dv).astype(jnp.float32))
-    # dclint: allow=dtype-downcast (the gated norm is float32; the stream
-    # is the compute dtype)
-    out = out.astype(self.dtype).reshape(batch, length, value_dim)
+      out = gated_delta.gated_delta_window(
+          streams, z, log_decay, beta, norm_scale, num_key_heads=hk,
+          num_value_heads=hv, epsilon=self.rms_norm_eps)
     return dense(self.hidden_size, 'out_proj')(out)
 
 
@@ -708,6 +692,23 @@ def attention_path(p, *, length: int, deterministic: bool = True,
       and pallas_util.may_choose_kernels()
   )
   return ATTENTION_FUSED_SUBLAYER if fused else ATTENTION_XLA
+
+
+def delta_rule_path(p, *, length: int) -> Optional[str]:
+  """How a forward of this width runs the delta rule of its Gated DeltaNet
+  mixers (`forward_launch`'s `delta_rule_path`): `window_kernel`, one
+  Pallas call a window with the mixer's two norms inside it, or `plain`,
+  the same arithmetic by heads as XLA compiles it; None for a block kind
+  without such a layer. The rule is ops/gated_delta.py::delta_rule_path,
+  the one `gated_delta_window` asks where the forward is traced; no option
+  asks for the kernel."""
+  if block_kind_of(p) != config_lib.BLOCK_GATED_DELTA_MOE:
+    return None
+  return gated_delta.delta_rule_path(
+      key_head_dim=p.linear_key_head_dim,
+      value_head_dim=p.linear_value_head_dim,
+      num_key_heads=p.linear_num_key_heads,
+      num_value_heads=p.linear_num_value_heads, length=length)
 
 
 def _attn_softmax_dtype(p):

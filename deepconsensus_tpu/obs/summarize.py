@@ -263,6 +263,8 @@ def summarize(events: List[Dict[str, Any]],
                              if a.get('block_kind')}),
       'attention_paths': sorted({str(a['attention_path']) for a in launches
                                  if a.get('attention_path')}),
+      'delta_rule_paths': sorted({str(a['delta_rule_path']) for a in launches
+                                  if a.get('delta_rule_path')}),
       'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
                                 if a.get('layer_pattern')}),
       'experts_held': sorted(
@@ -329,8 +331,10 @@ def format_summary(summary: Dict[str, Any]) -> str:
         f'{forward["n_positions"]} positions, '
         f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
     if forward.get('layer_patterns'):
+      delta_rule = ', '.join(forward.get('delta_rule_paths', ()))
       lines.append(
-          f'  layers: {", ".join(forward["layer_patterns"])}' + ''.join(
+          f'  layers: {", ".join(forward["layer_patterns"])}'
+          + (f' (delta rule: {delta_rule})' if delta_rule else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'
               for lo, hi, published in forward.get('experts_held', ())))
   overlap = summary['overlap']

@@ -37,17 +37,34 @@ Matrix products take their operands in q's type (bfloat16 as served,
 float32 in the tests) with a float32 accumulator; G, the decay, beta and
 every mask are float32.
 
-Two forms of the same arithmetic. Plain jnp, as XLA compiles it, runs on
-the CPU and under a mesh; there the [L, L] matrices of every problem
+Two forms of the same arithmetic, behind one entry for a mixer
+(`gated_delta_window`: the flat stream [B, L, q | k | v] of each direction
+as the convolution leaves it, the L2 norm of every q and k head, the rule in two
+directions, the gated RMS norm of every value head, the compute dtype
+out) and one rule that chooses between them (`delta_rule_path`). Plain
+jnp, as XLA compiles it, runs on the CPU and under a mesh: the stream is
+reshaped to heads, normalised, and the [L, L] matrices of every problem
 (window x direction x value head) go through device memory between
 products, which is what bounds it. On one TPU device at inference
 (`pallas_util.may_choose_kernels`, the rule every kernel the code chooses
-by itself obeys) heads of 128 take one Pallas call a window instead: both
-directions and all heads of the window in VMEM, the system inverted from
-its diagonal blocks outwards (the same finite product on blocks of 16,
-then pairs of inverted blocks merged up to the whole padded window: all
-products of 128 x 128), and only q, k, v in and o out cross device
-memory.
+by itself obeys) heads of 128 take one Pallas call a window instead. What
+crosses device memory there: the convolution's output once in (bfloat16,
+a head one lane tile of it, the window's own positions: the kernel's
+blocks reach past them and it zeroes what lies behind), the gate z, G and
+beta, and the normed, gated output once out in the compute dtype; no
+padded, re-laid or float32 copy of q, k, v or o. In VMEM, for the
+problems of two key heads at a time (direction x key head x value head of
+its group: eight at the published sizes), side by side so that every step
+is as many independent products: the L2 norm as prologue; q k^T and k k^T
+as one product with k stationary; the system inverted from its diagonal
+blocks outwards (the same finite product on blocks of 16: six products;
+then pairs of inverted blocks merged up to the whole padded window, two
+products a doubling: six at 128 positions), one product to apply the
+inverse and one to read: 14 products of 128 x 128 x 128 a problem and one
+of 256 x 128 x 128 a key head and direction; the gated norm as epilogue.
+Both forms take the two norms' arithmetic from `unit_over_head` and
+`gated_norm_over_head`, so that q and k are rounded to the compute dtype
+at the same place.
 """
 from __future__ import annotations
 
@@ -175,125 +192,268 @@ def gated_delta_causal(q, k, v, g, beta, block: int = BLOCK) -> jnp.ndarray:
   return out[:, :length]
 
 
-def _window_kernel(q_ref, k_ref, v_ref, cum_ref, cum_t_ref, beta_ref, o_ref):
-  """One window, both directions, every head, positions in the window's
-  order throughout. q_ref, k_ref [2, 1, Lp, Hk * D] and v_ref
-  [2, 1, Lp, Hv * D] (index 1 what the reversed run reads); cum_ref
-  [2, 1, Lp, Hv] the log decay summed from the window's start up to each
-  position (index 0) and from each position to its end (index 1),
-  cum_t_ref [2, 1, Hv, Lp] the same with positions along the lanes;
-  beta_ref [1, Lp, Hv]; o_ref [1, Lp, Hv * D] float32. The reversed run
-  is the same system with `j after t` for `j before t`: its matrices are
-  upper triangular, and nothing is turned round."""
-  d = KERNEL_HEAD_DIM
-  lp = q_ref.shape[2]
-  hk, hv = q_ref.shape[3] // d, v_ref.shape[3] // d
-  group = hv // hk
-  dtype = q_ref.dtype
+# Which form of the rule a mixer's forward runs (`forward_launch`'s
+# `delta_rule_path`, docs/observability.md).
+DELTA_RULE_WINDOW_KERNEL = 'window_kernel'
+DELTA_RULE_PLAIN = 'plain'
+L2_EPSILON = 1e-6
+
+
+def delta_rule_path(*, key_head_dim: int, value_head_dim: int,
+                    num_key_heads: int, num_value_heads: int,
+                    length: int) -> str:
+  """The one rule by which `gated_delta_window` takes the Pallas call in
+  place of the plain form around its two norms; no option asks for it.
+  Heads of KERNEL_HEAD_DIM on both sides (a head is then one lane tile of
+  the flat stream), value heads grouped over key heads, a window of one
+  chunk, and a TPU in a trace its caller declared inference for one
+  device (pallas_util.may_choose_kernels: ModelRunner without a mesh)."""
+  kernel = (
+      key_head_dim == value_head_dim == KERNEL_HEAD_DIM
+      and num_value_heads % num_key_heads == 0
+      and length <= MAX_WINDOW_LEN
+      and pallas_util.may_choose_kernels())
+  return DELTA_RULE_WINDOW_KERNEL if kernel else DELTA_RULE_PLAIN
+
+
+def unit_over_head(x: jnp.ndarray, scale=None) -> jnp.ndarray:
+  """x [..., D] -> x / |x| over the last axis (times `scale`), float32:
+  the L2 norm of q and k, in the mixer's modules and in the kernel's
+  prologue alike, so that both round the same number."""
+  x = x.astype(jnp.float32)
+  unit = x * jax.lax.rsqrt(
+      jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPSILON)
+  return unit if scale is None else unit * scale
+
+
+def gated_norm_over_head(out: jnp.ndarray, z: jnp.ndarray,
+                         weight: jnp.ndarray, epsilon: float) -> jnp.ndarray:
+  """out [..., D] float32 -> out / rms(out) * weight * silu(z) over the
+  last axis, float32: the mixer's gated RMS norm, in its modules and in
+  the kernel's epilogue alike."""
+  out = out * jax.lax.rsqrt(
+      jnp.mean(jnp.square(out), axis=-1, keepdims=True) + epsilon)
+  return out * weight.astype(jnp.float32) * jax.nn.silu(
+      z.astype(jnp.float32))
+
+
+def _block_masks(lp: int):
+  """Over an [lp, lp] matrix: the identity (float32); where row and column
+  lie in the same diagonal block of KERNEL_BLOCK; and, for each doubling
+  of the block up to the whole padded window, where they lie in the same
+  doubled block but not in the same half of it."""
   row = jax.lax.broadcasted_iota(jnp.int32, (lp, lp), 0)
   col = jax.lax.broadcasted_iota(jnp.int32, (lp, lp), 1)
-  eye = (row == col).astype(jnp.float32)
-  reach = ((row >= col, row > col), (row <= col, row < col))
-  # Positions in the same diagonal block of KERNEL_BLOCK, of twice that,
-  # ... of the whole padded window.
   sizes = [KERNEL_BLOCK]
   while sizes[-1] < lp:
     sizes.append(sizes[-1] * 2)
   together = [row // size == col // size for size in sizes]
   between = [around & ~inside for inside, around in zip(together,
                                                          together[1:])]
+  return (row == col).astype(jnp.float32), together[0], between
+
+
+def _solved_and_read(systems, reads, rhs, dtype, masks):
+  """For every problem of the lists (none depends on another): D solving
+  (I + system) D = rhs, then read D -> [Lp, D] float32. Products take
+  their operands in `dtype`; every step is written over all the problems
+  before the next, so that it issues as many independent products.
+
+  (I + system)^-1 is built from the diagonal blocks of KERNEL_BLOCK
+  outwards (`masks`: _block_masks). A block's inverse is the finite
+  product of its powers (a strictly triangular block of n has n-th power
+  zero); two inverted blocks and what lies between them,
+  [[M11, 0], [M21, M22]], give [[T11, 0], [-T22 M21 T11, T22]]
+  = T - T Off T (the transpose of it for the upper triangular systems of
+  the reversed run: the same formula). Squaring in bfloat16 doubles a
+  power's relative error each time, so only the small blocks are inverted
+  by powers: the whole window's would lose every digit where keys are
+  alike (k_t . k_j near 1)."""
+  eye, in_block, between = masks
+  dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+  low = lambda xs: [x.astype(dtype) for x in xs]
+  powers = [jnp.where(in_block, -system, 0.0) for system in systems]
+  inverses = [eye + power for power in powers]
+  size = 2
+  while size < KERNEL_BLOCK:
+    powers = low(dot(x, x) for x in low(powers))
+    inverses = [inverse + dot(x, power) for inverse, x, power in zip(
+        inverses, low(inverses), powers)]
+    size *= 2
+  # Off T: T is block diagonal, so the product of the whole system with
+  # it, kept where row and column lie in two halves of one doubled block,
+  # sums the same terms.
+  systems = low(systems)
+  for outside in between:
+    lows = low(inverses)
+    overs = [jnp.where(outside, dot(system, x), 0.0)
+             for system, x in zip(systems, lows)]
+    inverses = [inverse - dot(x, over) for inverse, x, over in zip(
+        inverses, lows, low(overs))]
+  corrections = [dot(x, y) for x, y in zip(low(inverses), low(rhs))]
+  return [dot(x, y) for x, y in zip(low(reads), low(corrections))]
+
+
+def _normalised_query_and_key(stream_ref, key_head, key_dim, in_rows, dtype):
+  """The kernel's prologue for one key head of one direction: q and k
+  [Lp, D] read from that direction's flat stream [1, Lp, C],
+  L2-normalised over the head in float32 (q times D^-1/2) and rounded to
+  `dtype` where the mixer's modules round them; zero behind the window
+  (`in_rows` [Lp, 1])."""
+  d = KERNEL_HEAD_DIM
+  at = lambda first: pl.ds(pl.multiple_of(first + key_head * d, d), d)
+  # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+  # normalised in float32)
+  q = jnp.where(in_rows, unit_over_head(
+      stream_ref[0, :, at(0)], d ** -0.5), 0.0).astype(dtype)
+  # dclint: allow=dtype-downcast (as above)
+  k = jnp.where(in_rows, unit_over_head(
+      stream_ref[0, :, at(key_dim)]), 0.0).astype(dtype)
+  return q, k
+
+
+def _window_kernel(ahead_ref, back_ref, z_ref, cum_ref, cum_t_ref, beta_ref,
+                   weight_ref, o_ref, *, length: int, hk: int, hv: int,
+                   pack: int, epsilon: float):
+  """One window, both directions, every head, positions in the window's
+  order throughout, on the flat stream. ahead_ref and back_ref [1, Lp, C]
+  hold [q | k | v] with heads along the lanes as the convolution of each
+  direction left them (back_ref what the reversed run reads); z_ref
+  [1, Lp, Hv * D] the output gate; cum_ref [2, 1, Lp, Hv] the log decay
+  summed from the window's start up to each position (index 0) and from
+  each position to its end (index 1), cum_t_ref [2, 1, Hv, Lp] the same
+  with positions along the lanes; beta_ref [1, Lp, Hv]; weight_ref [1, D]
+  the gated norm's weight; o_ref [1, Lp, Hv * D] in the stream's type.
+  The blocks reach past the window's `length` positions to Lp, and what
+  they hold there is not defined: every operand is zeroed behind the
+  window as it is read (g = 0, beta = 0, k = 0 correct nothing, and no
+  position of the window reads them), and the rows of o_ref behind it are
+  never written back.
+
+  The reversed run is the same system with `j after t` for `j before t`:
+  its matrices are upper triangular, and nothing is turned round. The
+  2 * pack * (Hv // Hk) problems of `pack` key heads (direction x key
+  head x value head of its group) do not depend on each other and go
+  through the inversion step by step, side by side: every step is that
+  many products none of which waits for another."""
+  d = KERNEL_HEAD_DIM
+  lp = ahead_ref.shape[1]
+  group = hv // hk
+  key_dim = hk * d
+  dtype = ahead_ref.dtype
+  row = jax.lax.broadcasted_iota(jnp.int32, (lp, lp), 0)
+  col = jax.lax.broadcasted_iota(jnp.int32, (lp, lp), 1)
+  reach = ((row >= col, row > col), (row <= col, row < col))
+  masks = _block_masks(lp)
   lane = jax.lax.broadcasted_iota(jnp.int32, (lp, hv), 1)
   sublane = jax.lax.broadcasted_iota(jnp.int32, (hv, lp), 0)
-  dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+  in_rows = jax.lax.broadcasted_iota(jnp.int32, (lp, 1), 0) < length
+  in_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, lp), 1) < length
   pairs = lambda a, b: jax.lax.dot_general(  # a b^T
       a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
   beta = beta_ref[0]
+  weight = weight_ref[...]
 
-  def key_head(h, carry):
-    at = pl.ds(pl.multiple_of(h * d, d), d)
-    totals = [0.0] * group
+  def key_heads(step, carry):
+    # Per problem (direction x key head x value head of its group): the
+    # direction's masks, the key head's products, the value head's decay,
+    # beta and right-hand side.
+    column = lambda a, head: jnp.where(in_rows, jnp.sum(
+        jnp.where(lane == head, a, 0.0), axis=1, keepdims=True), 0.0)
+    heads = [(step * pack + h) * group + i
+             for h in range(pack) for i in range(group)]
+    beta_cols = [column(beta, head) for head in heads]
+    systems, reads, rhs = [], [], []
     for direction, (upto, before) in enumerate(reach):
-      q, k = q_ref[direction, 0, :, at], k_ref[direction, 0, :, at]
-      key_key, query_key = pairs(k, k), pairs(q, k)
+      stream_ref = (ahead_ref, back_ref)[direction]
       cum, cum_t = cum_ref[direction, 0], cum_t_ref[direction, 0]
-      for i in range(group):
-        head = h * group + i
-        column = lambda a: jnp.sum(jnp.where(lane == head, a, 0.0), axis=1,
-                                   keepdims=True)
-        cum_row = jnp.sum(jnp.where(sublane == head, cum_t, 0.0), axis=0,
-                          keepdims=True)
-        beta_col = column(beta)
-        decay = jnp.where(
-            upto, jnp.exp(jnp.minimum(column(cum) - cum_row, 0.0)), 0.0)
-        system = jnp.where(before, beta_col * decay * key_key, 0.0)
-        # (I + system)^-1, from the diagonal blocks of KERNEL_BLOCK
-        # outwards. A block's inverse is the finite product of its powers
-        # (a strictly triangular block of n has n-th power zero); two
-        # inverted blocks and what lies between them,
-        # [[M11, 0], [M21, M22]], give [[T11, 0], [-T22 M21 T11, T22]]
-        # = T - T Off T. Squaring in bfloat16 doubles a power's relative
-        # error each time, so only the small blocks are inverted by
-        # powers: the whole window's would lose every digit where keys
-        # are alike (k_t . k_j near 1).
-        power = jnp.where(together[0], -system, 0.0)
-        inverse = eye + power
-        step = 2
-        while step < KERNEL_BLOCK:
-          low = power.astype(dtype)
-          power = dot(low, low)
-          inverse = inverse + dot(inverse.astype(dtype), power.astype(dtype))
-          step *= 2
-        for outside in between:
-          low = inverse.astype(dtype)
-          reach_over = jnp.where(outside, system, 0.0).astype(dtype)
-          inverse = inverse - dot(low, dot(reach_over, low).astype(dtype))
-        value = v_ref[direction, 0, :, pl.ds(pl.multiple_of(head * d, d), d)]
-        corrections = dot(inverse.astype(dtype),
-                          (beta_col * value.astype(jnp.float32)).astype(dtype))
-        totals[i] = totals[i] + dot((decay * query_key).astype(dtype),
-                                    corrections.astype(dtype))
-    for i in range(group):
-      o_ref[0, :, pl.ds(pl.multiple_of((h * group + i) * d, d), d)] = totals[i]
+      for h in range(pack):
+        q, k = _normalised_query_and_key(stream_ref, step * pack + h,
+                                         key_dim, in_rows, dtype)
+        # One product for both, k the stationary operand.
+        both_key = pairs(jnp.concatenate([q, k], axis=0), k)
+        query_key, key_key = both_key[:lp], both_key[lp:]
+        for n in range(h * group, (h + 1) * group):
+          head, beta_col = heads[n], beta_cols[n]
+          cum_row = jnp.where(in_lanes, jnp.sum(
+              jnp.where(sublane == head, cum_t, 0.0), axis=0, keepdims=True),
+                              0.0)
+          decay = jnp.where(upto, jnp.exp(jnp.minimum(
+              column(cum, head) - cum_row, 0.0)), 0.0)
+          value = stream_ref[0, :, pl.ds(
+              pl.multiple_of(2 * key_dim + head * d, d), d)]
+          systems.append(jnp.where(before, beta_col * decay * key_key, 0.0))
+          reads.append(decay * query_key)
+          rhs.append(jnp.where(
+              in_rows, beta_col * value.astype(jnp.float32), 0.0))
+    outs = _solved_and_read(systems, reads, rhs, dtype, masks)
+    # The two runs' outputs added, then the gated norm over each value
+    # head, written once in the stream's type.
+    for head, ahead, back in zip(heads, outs, outs[len(heads):]):
+      at = pl.ds(pl.multiple_of(head * d, d), d)
+      # dclint: allow=dtype-downcast (the gated norm is float32; the
+      # stream is the compute dtype)
+      o_ref[0, :, at] = gated_norm_over_head(
+          ahead + back, z_ref[0, :, at], weight, epsilon).astype(o_ref.dtype)
     return carry
 
-  jax.lax.fori_loop(0, hk, key_head, 0)
+  jax.lax.fori_loop(0, hk // pack, key_heads, 0)
 
 
-def _two_directions_kernel(q, k, v, g, beta, interpret=None) -> jnp.ndarray:
-  """`gated_delta_two_directions` as one Pallas call a window."""
-  _, batch, length, hk, d = q.shape
-  hv = v.shape[3]
+# Problems the kernel takes through the inversion side by side at a padded
+# window of 128 positions, and fewer as their [Lp, Lp] matrices grow:
+# enough that no MXU waits for a product's cast, few enough that a step's
+# matrices stay in VMEM (my chip runs, PR 33, 512 windows of the published
+# heads: one by one 62.1 ms a layer in the model; alone 33.7 ms at 4
+# abreast, 25.0 at 8, 23.2 at 16, 32 no better than 16; in the model 16.1
+# at 8 and 14.7 at 16).
+KERNEL_PROBLEMS_ABREAST = 16
+
+
+def _window_kernel_call(streams, z, g, beta, weight, *, num_key_heads: int,
+                        num_value_heads: int, epsilon: float,
+                        interpret=None) -> jnp.ndarray:
+  """`gated_delta_window` as one Pallas call a window."""
+  batch, length, channels = streams[0].shape
+  hk, hv, d = num_key_heads, num_value_heads, KERNEL_HEAD_DIM
   lp = -(-length // 128) * 128
-  # Positions added behind the window have g = 0, beta = 0 and k = 0:
-  # they correct nothing, and no position of the window reads them.
-  pad = lambda x, axis: jnp.pad(
-      x, [(0, lp - length) if a == axis else (0, 0) for a in range(x.ndim)])
-  flat = lambda x: pad(x, 2).reshape(2, batch, lp, -1)
-  g = pad(g.astype(jnp.float32), 1)
+  # Key heads a step: each brings the problems of its two directions and
+  # its group of value heads.
+  abreast = KERNEL_PROBLEMS_ABREAST * 128 * 128 // (lp * lp)
+  pack = max(1, abreast // (2 * (hv // hk)))
+  while hk % pack:
+    pack -= 1
+  # The window's blocks, twice each for the pipeline, are Lp x 40 KiB at
+  # the published heads: past one lane tile of positions they and a
+  # step's matrices need more than the batch-tiled kernels' usual scope
+  # (of a v5e core's 128 MiB).
+  vmem_limit = pallas_util.BATCH_TILE_VMEM_LIMIT_BYTES * (2 if lp > 128 else 1)
+  g = g.astype(jnp.float32)
   from_start = jnp.cumsum(g, axis=1)
   cum = jnp.stack([from_start, from_start[:, -1:] - from_start + g])
-  by_window = lambda *shape: pl.BlockSpec(
+  by_window = lambda *shape: pl.BlockSpec((1,) + shape, lambda i: (i, 0, 0))
+  both_ways = lambda *shape: pl.BlockSpec(
       (2, 1) + shape, lambda i: (0, i, 0, 0))
-  out = pl.pallas_call(
-      _window_kernel,
+  return pl.pallas_call(
+      functools.partial(_window_kernel, length=length, hk=hk, hv=hv,
+                        pack=pack, epsilon=epsilon),
       grid=(batch,),
-      in_specs=[by_window(lp, hk * d), by_window(lp, hk * d),
-                by_window(lp, hv * d), by_window(lp, hv),
-                by_window(hv, lp),
-                pl.BlockSpec((1, lp, hv), lambda i: (i, 0, 0))],
-      out_specs=pl.BlockSpec((1, lp, hv * d), lambda i: (i, 0, 0)),
-      out_shape=jax.ShapeDtypeStruct((batch, lp, hv * d), jnp.float32),
-      compiler_params=pallas_util.batch_tile_compiler_params(),
+      in_specs=[by_window(lp, channels), by_window(lp, channels),
+                by_window(lp, hv * d), both_ways(lp, hv), both_ways(hv, lp),
+                by_window(lp, hv), pl.BlockSpec((1, d), lambda i: (0, 0))],
+      out_specs=by_window(lp, hv * d),
+      out_shape=jax.ShapeDtypeStruct((batch, length, hv * d), z.dtype),
+      compiler_params=pallas_util.batch_tile_compiler_params(vmem_limit),
       interpret=pallas_util.resolve_interpret(interpret),
       name='gated_delta_window',
-  )(flat(q), flat(k), flat(v), cum, jnp.swapaxes(cum, 2, 3),
-    pad(beta.astype(jnp.float32), 1))
-  return out[:, :length].reshape(batch, length, hv, d)
+  )(*streams, z, cum, jnp.swapaxes(cum, 2, 3), beta.astype(jnp.float32),
+    weight.astype(jnp.float32).reshape(1, d))
 
 
 def gated_delta_two_directions(q, k, v, g, beta,
                                block: int = BLOCK) -> jnp.ndarray:
-  """The rule over the window plus the rule over the window reversed.
+  """The rule over the window plus the rule over the window reversed, in
+  the plain form.
 
   q, k [2, B, L, Hk, Dk] and v [2, B, L, Hv, Dv]: index 0 holds what the
   run from the window's start reads and index 1 what the run from its end
@@ -301,10 +461,6 @@ def gated_delta_two_directions(q, k, v, g, beta,
   direction, so the caller brings both), each at the window's own
   positions. g, beta [B, L, Hv]. -> o [B, L, Hv, Dv] float32, the two
   runs' outputs added position by position."""
-  if (pallas_util.may_choose_kernels()
-      and q.shape[-1] == v.shape[-1] == KERNEL_HEAD_DIM
-      and v.shape[3] % q.shape[3] == 0 and q.shape[2] <= MAX_WINDOW_LEN):
-    return _two_directions_kernel(q, k, v, g, beta)
   # The run from the end is the causal rule over the window turned round.
   turned = lambda x: jnp.concatenate([x[0], jnp.flip(x[1], axis=1)], axis=0)
   both = lambda x: jnp.concatenate([x, jnp.flip(x, axis=1)], axis=0)
@@ -312,3 +468,46 @@ def gated_delta_two_directions(q, k, v, g, beta,
                            both(beta), block)
   forward, backward = jnp.split(out, 2, axis=0)
   return forward + jnp.flip(backward, axis=1)
+
+
+def gated_delta_window(streams, z, g, beta, weight, *, num_key_heads: int,
+                       num_value_heads: int, epsilon: float) -> jnp.ndarray:
+  """A Gated DeltaNet mixer between its convolution and its output
+  projection, on the flat stream: the L2 norm of every q and k head (q
+  times Dk^-1/2), the rule in two directions, the gated RMS norm of every
+  value head.
+
+  streams: two arrays [B, L, Hk * Dk + Hk * Dk + Hv * Dv] that hold
+  [q | k | v] as the convolution of each direction left them (the first
+  what the run from the window's start reads, the second what the run
+  from its end reads, each at the window's own positions); z
+  [B, L, Hv * Dv] the output gate; all in the compute dtype. g, beta
+  [B, L, Hv]; weight [Dv] -> [B, L, Hv * Dv] in the compute dtype. Where
+  `delta_rule_path` says so, one Pallas call a window with the two norms
+  as its prologue and epilogue; elsewhere the same arithmetic by heads
+  around the plain form."""
+  batch, length, channels = streams[0].shape
+  hk, hv = num_key_heads, num_value_heads
+  value_dim = z.shape[-1]
+  key_dim = (channels - value_dim) // 2
+  dk, dv = key_dim // hk, value_dim // hv
+  if delta_rule_path(key_head_dim=dk, value_head_dim=dv, num_key_heads=hk,
+                     num_value_heads=hv,
+                     length=length) == DELTA_RULE_WINDOW_KERNEL:
+    return _window_kernel_call(streams, z, g, beta, weight, num_key_heads=hk,
+                               num_value_heads=hv, epsilon=epsilon)
+  both = jnp.stack(streams)
+  heads = lambda t, n: t.reshape(t.shape[:-1] + (n, t.shape[-1] // n))
+  # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+  # normalised in float32)
+  query = unit_over_head(heads(both[..., :key_dim], hk), dk ** -0.5).astype(
+      both.dtype)
+  # dclint: allow=dtype-downcast (as above)
+  key = unit_over_head(heads(both[..., key_dim:2 * key_dim], hk)).astype(
+      both.dtype)
+  out = gated_delta_two_directions(
+      query, key, heads(both[..., 2 * key_dim:], hv), g, beta)
+  # dclint: allow=dtype-downcast (the gated norm is float32; the stream
+  # is the compute dtype)
+  return gated_norm_over_head(out, heads(z, hv), weight, epsilon).astype(
+      both.dtype).reshape(batch, length, value_dim)

@@ -78,11 +78,12 @@ def execution_report() -> Dict[str, Any]:
 BATCH_TILE_VMEM_LIMIT_BYTES = 48 << 20
 
 
-def batch_tile_compiler_params():
+def batch_tile_compiler_params(
+    vmem_limit_bytes: int = BATCH_TILE_VMEM_LIMIT_BYTES):
   """Mosaic parameters for a kernel whose 1-D grid walks independent
   tiles of windows."""
   from jax.experimental.pallas import tpu as pltpu
 
   return pltpu.CompilerParams(
       dimension_semantics=('parallel',),
-      vmem_limit_bytes=BATCH_TILE_VMEM_LIMIT_BYTES)
+      vmem_limit_bytes=vmem_limit_bytes)

@@ -517,6 +517,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
         {config_lib.BLOCK_BANDED_SOFTMAX: 'B',
          config_lib.BLOCK_POWER_RETENTION: 'R'}[kind] * p.num_hidden_layers)
     assert 'experts_held' not in e['args']
+    # Nor a delta rule: these kinds have no Gated DeltaNet mixer.
+    assert 'delta_rule_path' not in e['args']
   stats = engine.stats()
   assert stats['block_kind'] == kind
   assert stats['model_weight_bytes'] == 62
@@ -529,7 +531,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert cli.main(['trace', path, '--json']) == 0
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
-                     'attention_paths': ['xla'],
+                     'attention_paths': ['xla'], 'delta_rule_paths': [],
                      'layer_patterns': [config_lib.layer_pattern(p)],
                      'experts_held': [],
                      'n_positions': 3 * BATCH * p.max_length,

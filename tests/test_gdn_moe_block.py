@@ -269,3 +269,45 @@ def test_a_period_at_the_published_widths_has_the_hand_counted_parameters():
   assert count('moe_0') - experts == 4_196_352
   block = sum(leaf.size for leaf in jax.tree_util.tree_leaves(tree)) - 2048
   assert block == 3_366_446_144
+
+
+@pytest.mark.parametrize('length,group', [(20, 2), (100, 1)])
+def test_mixer_through_the_window_kernel_is_the_mixer_through_the_modules(
+    length, group, monkeypatch):
+  """GatedDeltaNetMixer at heads of 128, float32: where the code takes the
+  window kernel (one TPU device at inference; here the interpreter stands
+  in for the chip) the flat stream goes in and comes out with the two
+  norms inside the call, and the mixer's output is what the modules around
+  the plain form give, and what the reference's mixer gives."""
+  from deepconsensus_tpu.ops import gated_delta, pallas_util
+  hk, hv, d = 1, group, 128
+  mixer = model_lib.GatedDeltaNetMixer(
+      hidden_size=64, num_key_heads=hk, num_value_heads=hv, key_head_dim=d,
+      value_head_dim=d, conv_kernel=4, rms_norm_eps=1e-6)
+  rng = np.random.default_rng(length)
+  x = jnp.asarray(rng.normal(size=(2, length, 64)), jnp.float32)
+  variables = jax.jit(lambda k: mixer.init(k, x, True))(jax.random.PRNGKey(0))
+  params = dict(variables['params'])
+  params['A_log'] = jnp.asarray(rng.uniform(np.log(0.1), np.log(0.4), hv),
+                                jnp.float32)
+  params['norm_scale'] = jnp.asarray(rng.uniform(0.5, 1.5, d), jnp.float32)
+  apply = lambda: jax.jit(lambda v: mixer.apply(v, x, True))(
+      {'params': params})
+  with jax.default_matmul_precision('highest'):
+    modules = apply()
+    want = ref.gdn_mixer(params, x, hk=hk, hv=hv, dk=d, dv=d, eps=1e-6)
+    taken = []
+    kernel_call = gated_delta._window_kernel_call
+    monkeypatch.setattr(
+        gated_delta, '_window_kernel_call',
+        lambda *args, **sizes: taken.append(args[0][0].shape) or kernel_call(
+            *args, **sizes))
+    monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+    monkeypatch.setattr(pallas_util, 'resolve_interpret', lambda _: True)
+    with pallas_util.single_device_inference():
+      kernel = apply()
+  assert taken == [(2, length, (2 * hk + hv) * d)]
+  np.testing.assert_allclose(np.asarray(kernel), np.asarray(modules),
+                             atol=2e-5)
+  np.testing.assert_allclose(np.asarray(kernel), np.asarray(want), atol=1e-4)
+  assert np.abs(np.asarray(want)).max() > 0.05

@@ -77,6 +77,8 @@ def test_engine_submit_to_delivery_serves_the_reference_bases(length,
   assert len(launches) == len(drains) == 3
   for args in launches:
     assert args['block_kind'] == KIND and args['attention_path'] == 'xla'
+    # The CPU takes no kernel on its own; nor do heads of 8 anywhere.
+    assert args['delta_rule_path'] == 'plain'
     assert args['layer_pattern'] == 'GGGS'
     assert args['experts_held'] == [8, 16]
     assert args['experts_published'] == 16
@@ -112,10 +114,12 @@ def test_dctpu_trace_shows_the_pattern_and_the_held_share(tmp_path, capsys):
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward['block_kinds'] == [KIND]
   assert forward['attention_paths'] == ['xla']
+  assert forward['delta_rule_paths'] == ['plain']
   assert forward['layer_patterns'] == ['GGGS']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
-  assert 'layers: GGGS; experts 8-15 of 16 held' in capsys.readouterr().out
+  assert ('layers: GGGS (delta rule: plain); experts 8-15 of 16 held'
+          in capsys.readouterr().out)
 
 
 def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
@@ -131,6 +135,31 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
     config_lib.finalize_params(banded, is_training=False)
     assert model_lib.attention_path(banded, length=100) == (
         model_lib.ATTENTION_FUSED_SUBLAYER)
+
+
+@pytest.mark.parametrize('where', ['cpu', 'tpu', 'tpu_mesh', 'tpu_heads_of_8',
+                                   'tpu_long_window', 'other_kind'])
+def test_delta_rule_path_is_the_kernel_on_one_tpu_at_heads_of_128(
+    where, monkeypatch):
+  """`forward_launch`'s `delta_rule_path`: the model's rule asked as the
+  runner asks it. Heads of 128 (the published size) on one TPU device at
+  inference take the window kernel; the CPU, a mesh, other head sizes and
+  a window over one chunk take the plain form; a kind without the layer
+  says nothing."""
+  from deepconsensus_tpu.ops import pallas_util
+
+  sizes = {} if where == 'tpu_heads_of_8' else dict(
+      linear_key_head_dim=128, linear_value_head_dim=128)
+  p = tiny_params(100, **sizes)
+  if where == 'other_kind':
+    p = config_lib.get_config('transformer_learn_values+custom')
+    config_lib.finalize_params(p, is_training=False)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: where != 'cpu')
+  length = 600 if where == 'tpu_long_window' else 100
+  with pallas_util.single_device_inference(where != 'tpu_mesh'):
+    got = model_lib.delta_rule_path(p, length=length)
+  assert got == {'tpu': 'window_kernel', 'other_kind': None}.get(
+      where, 'plain')
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

@@ -633,7 +633,8 @@ class TestSummarize:
     # No forward_launch span in this trace: the block says so and the
     # text leaves its line out.
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
-                            'attention_paths': [], 'layer_patterns': [],
+                            'attention_paths': [], 'delta_rule_paths': [],
+                            'layer_patterns': [],
                             'experts_held': [],
                             'n_positions': 0, 'weight_bytes': 0}
     assert 'forward:' not in text

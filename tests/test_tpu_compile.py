@@ -17,6 +17,7 @@ import/collection time; every compile happens in this process; the
 persistent compile cache is off around the module (a described-device
 executable cannot be read back without a chip).
 """
+import functools
 import os
 import re
 
@@ -204,18 +205,53 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   # grouped products with the metadata call that sizes their groups: no
   # masked dense product a group.
   assert text.count('gated_delta_window') >= 3
+  # The kernel takes the flat stream of each direction as the convolution
+  # leaves it and writes the gated norm's output in the stream's type: no
+  # copy of q, k, v padded to 128 positions, stacked or re-laid by heads,
+  # no float32 copy of the rule's output.
+  assert 'bf16[2,512,128,' not in text
+  assert 'bf16[2,512,100,' not in text
+  assert 'f32[512,128,4096]' not in text
+  assert 'f32[512,100,4096]' not in text
   assert text.count('ragged-dot') >= 4 * 3
   assert _n_kernels(compiled) >= 3 + 4 * 3
   memory = compiled.memory_analysis()
   # 3,366,446,144 block parameters and what lies outside, 2 bytes each.
   assert 2 * 3_366_446_144 < memory.argument_size_in_bytes < 6.8e9
   # With the weights, a pack's temporaries have to leave room on a chip of
-  # 15.75 GiB: the delta rule's q, k, v of both directions and one turn of
-  # the experts' sorted rows are the largest.
-  assert memory.temp_size_in_bytes < 7 << 30
+  # 15.75 GiB: the convolution's output of both directions (what the
+  # delta rule reads, 0.84 GB each) and one turn of the experts' sorted rows are
+  # the largest. 4.20e9 as compiled (PR 33; 6.2 GiB before it), and a
+  # tenth.
+  assert memory.temp_size_in_bytes < 4.65e9
   # The experts' sorted rows are one turn of 25,600 tokens in bfloat16
   # (the temporaries above would not hold the pack's 512,000 at once).
   assert 'bf16[256000,2048]' in text
+
+
+@pytest.mark.parametrize('length', [130, 512])
+def test_gated_delta_window_kernel_beyond_one_lane_tile_of_positions(
+    one_chip, compiled_kernels, length):
+  """The delta rule's window kernel alone at the published heads (16 key /
+  32 value heads of 128) where the padded window is 256 and 512
+  positions: it takes fewer problems abreast as their matrices grow and
+  asks for the scoped VMEM its blocks need, so every length the rule
+  `delta_rule_path` sends it compiles."""
+  from deepconsensus_tpu.ops import gated_delta
+
+  hk, hv, d, batch = 16, 32, 128, 8
+  sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+      shape, dtype, sharding=one_chip)
+  stream = sds((batch, length, (2 * hk + hv) * d))
+  compiled = jax.jit(functools.partial(
+      gated_delta._window_kernel_call, num_key_heads=hk, num_value_heads=hv,
+      epsilon=1e-6)).lower(
+          (stream, stream), sds((batch, length, hv * d)),
+          sds((batch, length, hv), jnp.float32),
+          sds((batch, length, hv), jnp.float32),
+          sds((d,), jnp.float32)).compile()
+  assert 'gated_delta_window' in compiled.as_text()
+  assert _n_kernels(compiled) == 1
 
 
 def test_fused_front_end_b1024(one_chip, compiled_kernels):
