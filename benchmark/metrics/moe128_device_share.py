@@ -1,0 +1,10 @@
+"""Share of the device's busy time that the routed experts take: device
+seconds in scope `moe` (router, dispatch, grouped products, combine; the
+compiler's `ragged-dot` calls with it, as `moe_roofline` says) / busy
+seconds, in the traced window; `moe_device_share` under the name of the
+cell whose leading layer is dense. Only on a chip."""
+from benchmark.metrics import moe_device_share
+
+
+def read(r):
+  return moe_device_share.read(r)

@@ -355,13 +355,13 @@ def _holds_sparse_experts(params) -> bool:
   """Whether the forward of this configuration routes tokens to experts
   and returns their per-pack counts beside the predictions."""
   return ('transformer' in params.model_name and model_lib.block_kind_of(
-      params) == config_lib.BLOCK_GATED_DELTA_MOE)
+      params) in config_lib.SPARSE_EXPERT_KINDS)
 
 
 def _check_sparse_experts_served(params, mesh) -> None:
-  """What the sparse-experts block kind cannot run yet, refused by name
-  before anything is placed (ROADMAP R-a)."""
-  kind = config_lib.BLOCK_GATED_DELTA_MOE
+  """What a block kind with sparse experts cannot run yet, refused by
+  name before anything is placed (ROADMAP R-a)."""
+  kind = model_lib.block_kind_of(params)
   if params.get('quantize_matmuls', None) not in (None, 'none'):
     # dclint: allow=typed-faults (model-config validation at startup,
     # surfaced as operator error by the CLI, not a data-plane fault)
@@ -562,8 +562,8 @@ class ModelRunner:
         with pallas_util.single_device_inference(single_device):
           if sparse_experts:
             # Beside the predictions, the assignments every held expert
-            # took this pack, [layers, experts held] (finalize counts
-            # them).
+            # took this pack, [expert layers, experts held] (finalize
+            # counts them).
             preds, sown = model.apply(variables, rows,
                                       mutable=['moe_counts'])
             counts = (model_lib.expert_assignments(sown['moe_counts']),)
@@ -657,21 +657,26 @@ class ModelRunner:
         for leaf in jax.tree_util.tree_leaves(self.variables))
     self.obs.set_gauge('model_weight_bytes', self._weight_bytes)
     self._n_forward_positions = self.obs.counter('n_forward_positions')
-    # What `forward_launch` says of the stack: one letter a layer
-    # (config.layer_pattern) and, for sparse experts, the share held.
+    # What `forward_launch` says of the stack: one letter a layer for its
+    # attention and one for its feed-forward (config.layer_pattern,
+    # config.ffn_pattern) and, for sparse experts, the share held and how
+    # the router scores.
     self._launch_fields = {}
     if 'transformer' in self.params.model_name:
-      self._launch_fields['layer_pattern'] = config_lib.layer_pattern(
-          self.params)
+      self._launch_fields.update(
+          layer_pattern=config_lib.layer_pattern(self.params),
+          ffn_pattern=config_lib.ffn_pattern(self.params))
     self._sparse_experts = _holds_sparse_experts(self.params)
     if self._sparse_experts:
       first = int(self.params.experts_held_first)
       self._launch_fields.update(
           experts_held=[first, first + int(self.params.experts_held_count)],
-          experts_published=int(self.params.num_experts))
-      # Assignments the router made (positions x k x layers), those that
-      # fell on held experts, and the most any one held expert took in a
-      # pack of one layer.
+          experts_published=int(self.params.num_experts),
+          router_scoring=str(self.params.router_scoring) + (
+              '_bias' if self.params.router_selection_bias else ''))
+      # Assignments the router made (positions x k x expert layers), those
+      # that fell on held experts, and the most any one held expert took
+      # in a pack of one layer.
       self._moe_total = self.obs.counter('moe_assignments_total')
       self._moe_held = self.obs.counter('moe_assignments_held')
       self._moe_load_max = 0

@@ -122,11 +122,29 @@ def bucket_for(width: int, buckets):
 #                           this process holds: ops/moe.py) plus a gated
 #                           shared expert; zero-centred pre-RMSNorm
 #                           residuals, final RMSNorm.
+#   latent_attention_moe    every layer's attention is multi-head latent
+#                           attention in its whole-window form: keys and
+#                           values come out of one shared low-rank latent
+#                           (RMSNorm'd), a rotary key of its own is shared
+#                           by all heads, query/key heads are wider than
+#                           value heads (ops/latent_attention.py); the
+#                           feed-forward is chosen per layer
+#                           (`ffn_pattern`): SwiGLU in the first
+#                           `first_k_dense_replace` layers, behind them
+#                           sparse experts scored by a sigmoid and chosen
+#                           with a balancing bias (ops/moe.py) plus an
+#                           ungated shared expert; pre-RMSNorm residuals,
+#                           final RMSNorm.
 BLOCK_BANDED_SOFTMAX = 'banded_softmax_relu'
 BLOCK_POWER_RETENTION = 'power_retention_swiglu'
 BLOCK_GATED_DELTA_MOE = 'gated_delta_hybrid_moe'
+BLOCK_LATENT_MOE = 'latent_attention_moe'
 BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION,
-               BLOCK_GATED_DELTA_MOE)
+               BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE)
+# The kinds some of whose layers' feed-forward is sparse experts: what
+# they cannot run yet (--tp, int8, train, distill, export) is refused by
+# name.
+SPARSE_EXPERT_KINDS = (BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE)
 
 # A layer's attention, one letter a layer in `layer_pattern` (the
 # `forward_launch` span, docs/observability.md).
@@ -134,11 +152,18 @@ LAYER_BANDED_SOFTMAX = 'B'
 LAYER_POWER_RETENTION = 'R'
 LAYER_GATED_DELTA = 'G'
 LAYER_GATED_SOFTMAX = 'S'
+LAYER_LATENT = 'L'
+
+# A layer's feed-forward, one letter a layer in `ffn_pattern`: one dense
+# feed-forward of the kind's form, or sparse experts.
+FFN_DENSE = 'D'
+FFN_EXPERTS = 'E'
 
 
 def layer_pattern(params) -> str:
-  """The attention of every layer of the stack, in order: the one place
-  a per-layer pattern is derived from what the configuration states."""
+  """The attention of every layer of the stack, in order. With
+  `ffn_pattern` below, the one place a per-layer pattern is derived from
+  what the configuration states."""
   kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
   layers = range(params.num_hidden_layers)
   if kind == BLOCK_GATED_DELTA_MOE:
@@ -147,8 +172,25 @@ def layer_pattern(params) -> str:
         LAYER_GATED_SOFTMAX if (n + 1) % interval == 0 else LAYER_GATED_DELTA
         for n in layers)
   letter = {BLOCK_BANDED_SOFTMAX: LAYER_BANDED_SOFTMAX,
-            BLOCK_POWER_RETENTION: LAYER_POWER_RETENTION}[kind]
+            BLOCK_POWER_RETENTION: LAYER_POWER_RETENTION,
+            BLOCK_LATENT_MOE: LAYER_LATENT}[kind]
   return letter * len(layers)
+
+
+def ffn_pattern(params) -> str:
+  """The feed-forward of every layer of the stack, in order: sparse
+  experts in every layer of the gated-delta kind; in the latent-attention
+  kind dense in the first `first_k_dense_replace` layers and sparse
+  experts behind them; dense everywhere else."""
+  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
+  layers = range(params.num_hidden_layers)
+  if kind == BLOCK_GATED_DELTA_MOE:
+    return FFN_EXPERTS * len(layers)
+  if kind == BLOCK_LATENT_MOE:
+    leading = params.first_k_dense_replace
+    return ''.join(FFN_DENSE if n < leading else FFN_EXPERTS for n in layers)
+  return FFN_DENSE * len(layers)
+
 
 # Transformer size presets (reference: transformer_basic_params.py).
 TRANSFORMER_SIZE_PARAMS = {
@@ -318,10 +360,77 @@ def _set_transformer_learned_embeddings_gdn_moe_hparams(params):
   params.filter_size = 512
   params.shared_expert_intermediate_size = 512
   params.norm_topk_prob = True
+  # A softmax router without a selection bias or a scaling factor, and a
+  # sigmoid gate on the shared expert.
+  params.router_scoring = 'softmax'
+  params.router_selection_bias = False
+  params.routed_scaling_factor = 1.0
+  params.shared_expert_gated = True
   params.experts_held_first = 0
   params.experts_held_count = 512
   # Rotary positions and the short convolution take the sinusoidal
   # encoding's place, and the pre-RMSNorm residual the ReZero one's.
+  params.add_pos_encoding = False
+  params.rezero = False
+  params.attn_win_size = 0
+  # The published model has no dropout.
+  params.layer_postprocess_dropout = 0.0
+  params.attention_dropout = 0.0
+  params.relu_dropout = 0.0
+  params.dtype = 'bfloat16'
+  params.inference_dtype = 'bfloat16'
+  params.use_fused_hotpath = False
+
+
+def _set_transformer_learned_embeddings_mla_moe_hparams(params):
+  """A fourth encoder block kind at the widths of a public 30B
+  sparse-expert language model with 3B active parameters: hidden 2048, 48
+  layers, each with multi-head latent attention (32 heads; keys and
+  values out of one latent of 512 behind an RMSNorm, no query latent;
+  query/key heads of 128 + a rotary part of 64 whose key all heads share,
+  value heads of 128; rotary base 1e6) and a feed-forward chosen per
+  layer: SwiGLU 6144 in the one leading layer, behind it 128 routed
+  experts of width 768, 6 a token, scored by a sigmoid and chosen with a
+  balancing bias (one group, so no group limit), weights renormalised and
+  scaled by 2.448, plus an ungated shared expert of 2 x 768. Behind this
+  system's pile-up embedding and 5-way head, served in bfloat16.
+
+  One v5e chip holds the leading dense layer and the seven expert layers
+  behind it with every expert (--set num_hidden_layers=8) and a pack of
+  512 windows (docs/inference.md)."""
+  _set_transformer_learned_embeddings_hparams(params)
+  params.model_name = 'transformer_learn_values_mla_moe'
+  params.block_kind = BLOCK_LATENT_MOE
+  params.transformer_input_size = 2048
+  params.num_hidden_layers = 48
+  # Multi-head latent attention.
+  params.num_heads = 32
+  params.qk_nope_head_dim = 128
+  params.qk_rope_head_dim = 64
+  params.v_head_dim = 128
+  params.kv_lora_rank = 512
+  params.q_lora_rank = None
+  params.rope_theta = 1.0e6
+  params.rms_norm_eps = 1.0e-6
+  # The leading dense layers' SwiGLU; filter_size is its width.
+  params.first_k_dense_replace = 1
+  params.filter_size = 6144
+  # Sparse experts behind them.
+  params.num_experts = 128
+  params.num_experts_per_tok = 6
+  params.moe_intermediate_size = 768
+  params.shared_expert_intermediate_size = 1536
+  params.norm_topk_prob = True
+  params.router_scoring = 'sigmoid'
+  params.router_selection_bias = True
+  params.routed_scaling_factor = 2.448
+  params.shared_expert_gated = False
+  params.n_group = 1
+  params.topk_group = 1
+  params.experts_held_first = 0
+  params.experts_held_count = 128
+  # Rotary positions take the sinusoidal encoding's place, and the
+  # pre-RMSNorm residual the ReZero one's.
   params.add_pos_encoding = False
   params.rezero = False
   params.attn_win_size = 0
@@ -581,6 +690,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
     _set_transformer_learned_embeddings_retention_hparams(params)
   elif model_config_name == 'transformer_learn_values_gdn_moe':
     _set_transformer_learned_embeddings_gdn_moe_hparams(params)
+  elif model_config_name == 'transformer_learn_values_mla_moe':
+    _set_transformer_learned_embeddings_mla_moe_hparams(params)
   else:
     raise ValueError(f'Unknown model_config_name: {model_config_name}')
 

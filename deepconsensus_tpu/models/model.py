@@ -26,6 +26,7 @@ import numpy as np
 from deepconsensus_tpu import constants
 from deepconsensus_tpu.models import config as config_lib
 from deepconsensus_tpu.ops import gated_delta
+from deepconsensus_tpu.ops import latent_attention
 from deepconsensus_tpu.ops import moe
 from deepconsensus_tpu.ops import pallas_util
 from deepconsensus_tpu.ops import power_retention
@@ -521,6 +522,55 @@ class GatedSoftmaxAttention(nn.Module):
         name='output_transform')(out)
 
 
+class LatentAttention(nn.Module):
+  """Multi-head latent attention in its whole-window form
+  (ops/latent_attention.py), no query latent: per head [q_nope | q_rope] =
+  x W_q; [c | k_rope] = x W_kva, c RMSNorm'd over the latent; per head
+  [k_nope | v] = c W_kvb; q_rope and the ONE k_rope that all heads share
+  rotated by position; scores scaled by (nope + rope)^-1/2; bias-free
+  output projection over the heads' values. The rotation is
+  `apply_rotary`'s, over halves: a checkpoint published for interleaved
+  pairs loads with its rotary columns in
+  `latent_attention.halves_from_pairs` order."""
+
+  hidden_size: int
+  num_heads: int
+  qk_nope_head_dim: int
+  qk_rope_head_dim: int
+  v_head_dim: int
+  kv_lora_rank: int
+  rope_theta: float
+  rms_norm_eps: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+    del deterministic  # the published layer has no dropout
+    n, nope, rope = self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim
+    dense = lambda name, features, axis=-1: nn.DenseGeneral(
+        features=features, axis=axis, use_bias=False, dtype=self.dtype,
+        kernel_init=nn.initializers.lecun_normal(), name=name)
+    q_nope, q_rope = jnp.split(dense('query', (n, nope + rope))(x), [nope],
+                               axis=-1)
+    latent, k_rope = jnp.split(dense('kv_a', self.kv_lora_rank + rope)(x),
+                               [self.kv_lora_rank], axis=-1)
+    latent = RMSNorm(self.rms_norm_eps, dtype=self.dtype, name='kv_a_norm')(
+        latent)
+    k_nope, value = jnp.split(dense('kv_b', (n, nope + self.v_head_dim))(
+        latent), [nope], axis=-1)
+    # dclint: allow=dtype-downcast (rotated in float32; q and k meet in
+    # the compute dtype)
+    rotate = lambda t: apply_rotary(t.astype(jnp.float32),
+                                    self.rope_theta).astype(self.dtype)
+    q_rope = rotate(q_rope)
+    k_rope = rotate(k_rope[:, :, None, :])[:, :, 0, :]
+    with jax.named_scope('latent'):
+      out = latent_attention.latent_attention(
+          q_nope, q_rope, k_nope, k_rope, value,
+          scale=(nope + rope) ** -0.5)
+    return dense('output_transform', self.hidden_size, axis=(-2, -1))(out)
+
+
 class GatedFeedForward(nn.Module):
   """SwiGLU: (silu(x W_gate) * (x W_up)) W_down, no biases."""
 
@@ -540,13 +590,16 @@ class GatedFeedForward(nn.Module):
 
 
 class SparseExpertsFeedForward(nn.Module):
-  """Routed experts plus a gated shared expert (ops/moe.py): a float32
-  router over all `num_experts`, the `experts_per_token` largest kept
-  (renormalised where `norm_topk`), the products of the experts
-  `held_first` ... `held_first + held_count - 1` alone, each a SwiGLU of
-  `expert_width`; plus sigmoid(x w_s) times a SwiGLU of `shared_width`.
-  The assignments each held expert took are sown as `assignments` in the
-  `moe_counts` collection, for whoever asks for it."""
+  """Routed experts plus a shared expert (ops/moe.py): a float32 router
+  over all `num_experts`, scored by `scoring` (a softmax, or a sigmoid
+  each), the `experts_per_token` largest kept (of scores +
+  `router_selection_bias` [num_experts] where `selection_bias`: the bias
+  chooses and weighs nothing), renormalised where `norm_topk` and scaled
+  by `routed_scale`; the products of the experts `held_first` ...
+  `held_first + held_count - 1` alone, each a SwiGLU of `expert_width`;
+  plus a SwiGLU of `shared_width`, times sigmoid(x w_s) where
+  `shared_gate`. The assignments each held expert took are sown as
+  `assignments` in the `moe_counts` collection, for whoever asks for it."""
 
   hidden_size: int
   num_experts: int
@@ -556,6 +609,10 @@ class SparseExpertsFeedForward(nn.Module):
   norm_topk: bool
   held_first: int
   held_count: int
+  scoring: str = moe.SCORING_SOFTMAX
+  selection_bias: bool = False
+  routed_scale: float = 1.0
+  shared_gate: bool = True
   dtype: Any = jnp.float32
 
   @nn.compact
@@ -579,9 +636,13 @@ class SparseExpertsFeedForward(nn.Module):
       with jax.named_scope('router'):
         logits = _GateProjection(self.num_experts, use_bias=False,
                                  name='router')(x)
+        bias = self.param(
+            'router_selection_bias', nn.initializers.zeros,
+            (self.num_experts,), jnp.float32) if self.selection_bias else None
         weights, experts = moe.route_top_k(
             logits.reshape(batch * length, self.num_experts),
-            self.experts_per_token, self.norm_topk)
+            self.experts_per_token, self.norm_topk, scoring=self.scoring,
+            bias=bias, scale=self.routed_scale)
       routed, counts = moe.held_experts(
           x.reshape(batch * length, h), weights, experts, w_gate, w_up,
           w_down, self.held_first)
@@ -590,11 +651,12 @@ class SparseExpertsFeedForward(nn.Module):
       shared = GatedFeedForward(
           hidden_size=h, filter_size=self.shared_width, dtype=self.dtype,
           name='shared_expert')(x, deterministic=deterministic)
-      share = jax.nn.sigmoid(_GateProjection(
-          1, use_bias=False, name='shared_expert_gate')(x))
-      # dclint: allow=dtype-downcast (the gate is float32; the stream is
-      # the compute dtype)
-      shared = (share * shared.astype(jnp.float32)).astype(self.dtype)
+      if self.shared_gate:
+        share = jax.nn.sigmoid(_GateProjection(
+            1, use_bias=False, name='shared_expert_gate')(x))
+        # dclint: allow=dtype-downcast (the gate is float32; the stream is
+        # the compute dtype)
+        shared = (share * shared.astype(jnp.float32)).astype(self.dtype)
     return routed.reshape(batch, length, h) + shared
 
 
@@ -644,7 +706,7 @@ def refuse_inference_only_kind(p, command: str) -> None:
   if 'transformer' not in str(p.model_name):
     return
   kind = block_kind_of(p)
-  if kind == config_lib.BLOCK_GATED_DELTA_MOE:
+  if kind in config_lib.SPARSE_EXPERT_KINDS:
     raise ValueError(
         f'block kind {kind!r} is not served by `dctpu {command}`: routed '
         'experts have no training step here (no balancing loss, no '
@@ -715,15 +777,62 @@ def _attn_softmax_dtype(p):
   return jnp.dtype(p.get('attn_softmax_dtype', None) or 'float32')
 
 
+def _sparse_experts(p, n: int, dtype):
+  """Layer n's sparse experts: every size, the router's scoring, bias and
+  factor and the shared expert's gate among them, as the configuration
+  states it."""
+  return SparseExpertsFeedForward(
+      hidden_size=p.hidden_size,
+      num_experts=p.num_experts,
+      experts_per_token=p.num_experts_per_tok,
+      expert_width=p.moe_intermediate_size,
+      shared_width=p.shared_expert_intermediate_size,
+      norm_topk=p.norm_topk_prob,
+      held_first=p.experts_held_first,
+      held_count=p.experts_held_count,
+      scoring=p.router_scoring,
+      selection_bias=p.router_selection_bias,
+      routed_scale=p.routed_scaling_factor,
+      shared_gate=p.shared_expert_gated,
+      dtype=dtype,
+      name=f'moe_{n}',
+  )
+
+
 def _block_modules(p, n: int, dtype):
   """(attention, feed-forward, wrap) of encoder layer `n` for the
   configuration's block kind and, where the kind's layers are not alike,
-  the layer's place in the pattern (config.layer_pattern): the one place
+  the layer's place in the patterns (config.layer_pattern for the
+  attention, config.ffn_pattern for the feed-forward): the one place
   that knows the kinds. `wrap(sublayer, name)` gives the kind's residual
   form. Called inside EncoderStack's compact method, so the modules are
   its children."""
   kind = block_kind_of(p)
-  if kind == config_lib.BLOCK_GATED_DELTA_MOE:
+  gated_ffn = lambda: GatedFeedForward(
+      hidden_size=p.hidden_size, filter_size=p.filter_size, dtype=dtype,
+      name=f'ffn_{n}')
+  if kind == config_lib.BLOCK_LATENT_MOE:
+    if p.q_lora_rank is not None or (p.n_group, p.topk_group) != (1, 1):
+      raise ValueError(
+          f'q_lora_rank {p.q_lora_rank}, n_group {p.n_group} and topk_group '
+          f'{p.topk_group} are not served: LatentAttention has no query '
+          'latent and ops/moe.py::route_top_k chooses over one group')
+    attn = LatentAttention(
+        hidden_size=p.hidden_size,
+        num_heads=p.num_heads,
+        qk_nope_head_dim=p.qk_nope_head_dim,
+        qk_rope_head_dim=p.qk_rope_head_dim,
+        v_head_dim=p.v_head_dim,
+        kv_lora_rank=p.kv_lora_rank,
+        rope_theta=p.rope_theta,
+        rms_norm_eps=p.rms_norm_eps,
+        dtype=dtype,
+        name=f'latent_attention_{n}',
+    )
+    experts = config_lib.ffn_pattern(p)[n] == config_lib.FFN_EXPERTS
+    ffn = _sparse_experts(p, n, dtype) if experts else gated_ffn()
+    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
+  elif kind == config_lib.BLOCK_GATED_DELTA_MOE:
     if config_lib.layer_pattern(p)[n] == config_lib.LAYER_GATED_SOFTMAX:
       attn = GatedSoftmaxAttention(
           hidden_size=p.hidden_size,
@@ -748,18 +857,7 @@ def _block_modules(p, n: int, dtype):
           dtype=dtype,
           name=f'gdn_{n}',
       )
-    ffn = SparseExpertsFeedForward(
-        hidden_size=p.hidden_size,
-        num_experts=p.num_experts,
-        experts_per_token=p.num_experts_per_tok,
-        expert_width=p.moe_intermediate_size,
-        shared_width=p.shared_expert_intermediate_size,
-        norm_topk=p.norm_topk_prob,
-        held_first=p.experts_held_first,
-        held_count=p.experts_held_count,
-        dtype=dtype,
-        name=f'moe_{n}',
-    )
+    ffn = _sparse_experts(p, n, dtype)
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps,
                     rms_norm_zero_centred=True)
   elif kind == config_lib.BLOCK_POWER_RETENTION:
@@ -777,12 +875,7 @@ def _block_modules(p, n: int, dtype):
         dtype=dtype,
         name=f'self_attention_{n}',
     )
-    ffn = GatedFeedForward(
-        hidden_size=p.hidden_size,
-        filter_size=p.filter_size,
-        dtype=dtype,
-        name=f'ffn_{n}',
-    )
+    ffn = gated_ffn()
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
   else:
     attn = BandedSelfAttention(
@@ -811,11 +904,13 @@ def _block_modules(p, n: int, dtype):
 
 def expert_assignments(sown) -> jnp.ndarray:
   """What the stack's SparseExpertsFeedForward layers sowed in one apply
-  (the `moe_counts` collection) as [layers, experts held] int32, in layer
-  order."""
+  (the `moe_counts` collection) as [expert layers, experts held] int32, in
+  layer order; the layers are found by name (`moe_<n>`), so a stack whose
+  leading layers are dense returns a row for each of the others."""
   layers = sown['encoder']
-  return jnp.stack([
-      layers[f'moe_{n}']['assignments'][0] for n in range(len(layers))])
+  numbers = sorted(int(name[len('moe_'):]) for name in layers
+                   if name.startswith('moe_'))
+  return jnp.stack([layers[f'moe_{n}']['assignments'][0] for n in numbers])
 
 
 def _output_norm(p):

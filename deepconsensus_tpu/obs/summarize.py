@@ -267,6 +267,10 @@ def summarize(events: List[Dict[str, Any]],
                                   if a.get('delta_rule_path')}),
       'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
                                 if a.get('layer_pattern')}),
+      'ffn_patterns': sorted({str(a['ffn_pattern']) for a in launches
+                              if a.get('ffn_pattern')}),
+      'router_scorings': sorted({str(a['router_scoring']) for a in launches
+                                 if a.get('router_scoring')}),
       'experts_held': sorted(
           {(*a['experts_held'], a.get('experts_published'))
            for a in launches if a.get('experts_held')}),
@@ -332,11 +336,15 @@ def format_summary(summary: Dict[str, Any]) -> str:
         f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
     if forward.get('layer_patterns'):
       delta_rule = ', '.join(forward.get('delta_rule_paths', ()))
+      ffn = ', '.join(forward.get('ffn_patterns', ()))
+      scoring = ', '.join(forward.get('router_scorings', ()))
       lines.append(
           f'  layers: {", ".join(forward["layer_patterns"])}'
           + (f' (delta rule: {delta_rule})' if delta_rule else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'
-              for lo, hi, published in forward.get('experts_held', ())))
+              for lo, hi, published in forward.get('experts_held', ()))
+          + (f' (router: {scoring})' if scoring else '')
+          + (f'; feed-forward: {ffn}' if ffn else ''))
   overlap = summary['overlap']
   lines.append(
       f'transfer overlap (span-derived): '

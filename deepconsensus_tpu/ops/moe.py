@@ -6,6 +6,13 @@ feed-forwards, chosen by a router:
   p = softmax(n W_r) over all E;  top = the k largest, renormalised
   moe(n) = sum_{e in top} p_e (silu(n W_gate_e) * (n W_up_e)) W_down_e
 
+or, with a sigmoid router and a balancing bias b [E] (`route_top_k`):
+
+  s = sigmoid(n W_r);  top = the k largest of s + b
+  p_e = s_e / (sum_{e' in top} s_e' + 1e-20) * scale
+
+where b chooses and weighs nothing.
+
 A process holds a contiguous share [first, first + held) of the experts.
 The router keeps its E outputs and its top-k; of a token's k assignments
 those that fall on held experts are computed here and the others are
@@ -24,23 +31,47 @@ copy of the tokens and the experts' output are [tokens x k, hidden] each,
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 MAX_ROWS = 1 << 18
 
+SCORING_SOFTMAX = 'softmax'
+SCORING_SIGMOID = 'sigmoid'
 
-def route_top_k(logits: jnp.ndarray, k: int,
-                renormalise: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+
+def route_top_k(logits: jnp.ndarray, k: int, renormalise: bool,
+                scoring: str = SCORING_SOFTMAX,
+                bias: Optional[jnp.ndarray] = None,
+                scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
   """Router logits [N, E] -> (weights [N, k] float32, experts [N, k]
-  int32): softmax over all E in float32, the k largest, made to sum to
-  one where the model renormalises."""
-  probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-  weights, experts = jax.lax.top_k(probs, k)
+  int32). Scores over all E in float32, a softmax or a sigmoid each; the k
+  largest, of `scores + bias` where a selection bias [E] is given (the
+  weights are the scores of the chosen, without it); made to sum to one
+  where the model renormalises (the sigmoid's sum with the published
+  1e-20 beside it); times `scale`."""
+  logits = logits.astype(jnp.float32)
+  if scoring == SCORING_SOFTMAX:
+    scores = jax.nn.softmax(logits, axis=-1)
+  elif scoring == SCORING_SIGMOID:
+    scores = jax.nn.sigmoid(logits)
+  else:
+    raise ValueError(f'unknown router scoring {scoring!r}; have '
+                     f'{(SCORING_SOFTMAX, SCORING_SIGMOID)}')
+  if bias is None:
+    weights, experts = jax.lax.top_k(scores, k)
+  else:
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
   if renormalise:
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    if scoring == SCORING_SIGMOID:
+      total = total + jnp.float32(1e-20)
+    weights = weights / total
+  if scale != 1.0:
+    weights = weights * jnp.float32(scale)
   return weights, experts.astype(jnp.int32)
 
 

@@ -516,7 +516,9 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     assert e['args']['layer_pattern'] == (
         {config_lib.BLOCK_BANDED_SOFTMAX: 'B',
          config_lib.BLOCK_POWER_RETENTION: 'R'}[kind] * p.num_hidden_layers)
+    assert e['args']['ffn_pattern'] == 'D' * p.num_hidden_layers
     assert 'experts_held' not in e['args']
+    assert 'router_scoring' not in e['args']
     # Nor a delta rule: these kinds have no Gated DeltaNet mixer.
     assert 'delta_rule_path' not in e['args']
   stats = engine.stats()
@@ -533,7 +535,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
                      'layer_patterns': [config_lib.layer_pattern(p)],
-                     'experts_held': [],
+                     'ffn_patterns': [config_lib.ffn_pattern(p)],
+                     'router_scorings': [], 'experts_held': [],
                      'n_positions': 3 * BATCH * p.max_length,
                      'weight_bytes': 62}
   assert cli.main(['trace', path]) == 0

@@ -198,6 +198,9 @@ def test_preset_states_the_published_sizes():
   assert (p.num_experts, p.num_experts_per_tok, p.moe_intermediate_size,
           p.shared_expert_intermediate_size, p.norm_topk_prob) == (
               512, 10, 512, 512, True)
+  assert (p.router_scoring, p.router_selection_bias,
+          p.routed_scaling_factor, p.shared_expert_gated) == (
+              'softmax', False, 1.0, True)
   # As published a process holds every expert; a chip's share is a size.
   assert (p.experts_held_first, p.experts_held_count) == (0, 512)
   assert (p.dtype, p.inference_dtype, p.rezero, p.add_pos_encoding) == (

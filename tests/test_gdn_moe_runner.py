@@ -80,6 +80,8 @@ def test_engine_submit_to_delivery_serves_the_reference_bases(length,
     # The CPU takes no kernel on its own; nor do heads of 8 anywhere.
     assert args['delta_rule_path'] == 'plain'
     assert args['layer_pattern'] == 'GGGS'
+    assert args['ffn_pattern'] == 'EEEE'
+    assert args['router_scoring'] == 'softmax'
     assert args['experts_held'] == [8, 16]
     assert args['experts_published'] == 16
   assert sum(a['moe_assignments_held'] for a in drains) == (

@@ -6,7 +6,11 @@ width. At a small size on the CPU: the routed parts that the shares
 what the test-local plain reference (tests/gdn_moe_reference.py) gives for
 the uncut layer; no assignment is lost or doubled when one expert takes
 every token and when an expert takes none; the counts that come back sum
-to positions x k.
+to positions x k. Each for both routers the one class serves: the softmax
+router with a gated shared expert (the third block kind) and the sigmoid
+router with a selection bias, a scaling factor and an ungated shared expert
+(the fourth; reference tests/mla_moe_reference.py), whose bias changes who
+is chosen and never a weight.
 """
 import jax
 import jax.numpy as jnp
@@ -16,23 +20,30 @@ import pytest
 from deepconsensus_tpu.models import model as model_lib
 from deepconsensus_tpu.ops import moe
 from tests import gdn_moe_reference as ref
+from tests import mla_moe_reference as sigmoid_ref
 
 H, E, K, F = 32, 16, 4, 24
+FACTOR = 2.448
+# What the sigmoid router adds to the class's sizes.
+SIGMOID = dict(scoring='sigmoid', selection_bias=True, routed_scale=FACTOR,
+               shared_gate=False)
+ROUTERS = pytest.mark.parametrize('router', ['softmax', 'sigmoid'])
 
 
-def layer(first=0, count=E, dtype=jnp.float32, norm_topk=True):
+def layer(first=0, count=E, dtype=jnp.float32, norm_topk=True,
+          router='softmax'):
   return model_lib.SparseExpertsFeedForward(
       hidden_size=H, num_experts=E, experts_per_token=K, expert_width=F,
       shared_width=F, norm_topk=norm_topk, held_first=first, held_count=count,
-      dtype=dtype)
+      dtype=dtype, **(SIGMOID if router == 'sigmoid' else {}))
 
 
-def whole_layer_weights(seed=0):
+def whole_layer_weights(seed=0, router='softmax'):
   """The uncut layer's leaves, drawn so that every part counts."""
   rng = np.random.default_rng(seed)
   draw = lambda *shape: jnp.asarray(
       rng.normal(0, shape[-2] ** -0.5, shape), jnp.float32)
-  return {
+  weights = {
       'router': {'kernel': draw(H, E) * 3.0},
       'experts_gate': draw(E, H, F), 'experts_up': draw(E, H, F),
       'experts_down': draw(E, F, H),
@@ -41,6 +52,20 @@ def whole_layer_weights(seed=0):
                         'output_layer': {'kernel': draw(F, H)}},
       'shared_expert_gate': {'kernel': draw(H, 1)},
   }
+  if router == 'sigmoid':
+    del weights['shared_expert_gate']  # the shared expert has no gate
+    # A bias of the size of the gaps between neighbouring scores.
+    weights['router_selection_bias'] = jnp.asarray(
+        rng.uniform(-0.2, 0.2, E), jnp.float32)
+  return weights
+
+
+def reference_routed(weights, n, router='softmax', **kwargs):
+  """(moe(n), counts) of the router's plain reference."""
+  if router == 'sigmoid':
+    return sigmoid_ref.routed_experts(weights, n, top_k=K, factor=FACTOR,
+                                      **kwargs)
+  return ref.routed_experts(weights, n, top_k=K, **kwargs)
 
 
 def share_of(weights, first, count):
@@ -66,23 +91,24 @@ def apply(first, count, weights, x, **kwargs):
 def shared_part(weights, x):
   flat = x.reshape(-1, H)
   s = weights['shared_expert']
-  out = jax.nn.sigmoid(
-      flat @ weights['shared_expert_gate']['kernel']) * ref.swiglu(
-          flat, s['gate_layer']['kernel'], s['up_layer']['kernel'],
-          s['output_layer']['kernel'])
+  out = ref.swiglu(flat, s['gate_layer']['kernel'], s['up_layer']['kernel'],
+                   s['output_layer']['kernel'])
+  if 'shared_expert_gate' in weights:
+    out = jax.nn.sigmoid(flat @ weights['shared_expert_gate']['kernel']) * out
   return out.reshape(x.shape)
 
 
+@ROUTERS
 @pytest.mark.parametrize('cuts', [((0, 8), (8, 8)), ((0, 4), (4, 4), (8, 8)),
                                   ((0, 1), (1, 15))],
                          ids=['halves', 'three_shares', 'one_and_the_rest'])
-def test_shares_and_the_shared_expert_once_are_the_uncut_layer(cuts):
-  weights, x = whole_layer_weights(), tokens()
-  want, want_counts = ref.routed_experts(weights, x.reshape(-1, H), top_k=K)
+def test_shares_and_the_shared_expert_once_are_the_uncut_layer(cuts, router):
+  weights, x = whole_layer_weights(router=router), tokens()
+  want, want_counts = reference_routed(weights, x.reshape(-1, H), router)
   shared = shared_part(weights, x)
   total, counts = 0.0, []
   for first, count in cuts:
-    out, took = apply(first, count, weights, x)
+    out, took = apply(first, count, weights, x, router=router)
     total = total + (out - shared)  # the routed part of this share
     counts.append(took)
   total = total + shared  # what every chip computes alike, once
@@ -92,13 +118,14 @@ def test_shares_and_the_shared_expert_once_are_the_uncut_layer(cuts):
   assert want_counts.sum() == x.shape[0] * x.shape[1] * K
 
 
+@ROUTERS
 @pytest.mark.parametrize('first,count', [(0, 16), (0, 8), (8, 8), (5, 3)])
-def test_a_share_is_the_references_routed_part_for_that_share(first, count):
-  weights, x = whole_layer_weights(seed=3), tokens(seed=4)
-  got, took = apply(first, count, weights, x)
-  want, want_counts = ref.routed_experts(
-      share_of(weights, first, count), x.reshape(-1, H), top_k=K,
-      first=first)
+def test_a_share_is_the_references_routed_part_for_that_share(first, count,
+                                                              router):
+  weights, x = whole_layer_weights(seed=3, router=router), tokens(seed=4)
+  got, took = apply(first, count, weights, x, router=router)
+  want, want_counts = reference_routed(
+      share_of(weights, first, count), x.reshape(-1, H), router, first=first)
   np.testing.assert_allclose(np.asarray(got).reshape(-1, H),
                              np.asarray(want), atol=2e-5)
   assert np.array_equal(took, want_counts)
@@ -175,6 +202,59 @@ def test_router_keeps_the_k_largest_of_a_float32_softmax(renormalise):
     kept = kept / kept.sum(-1, keepdims=True)
     np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, atol=1e-6)
   np.testing.assert_allclose(np.asarray(weights), kept, atol=1e-6)
+
+
+@pytest.mark.parametrize('renormalise', [True, False])
+def test_sigmoid_router_chooses_by_the_bias_and_weighs_without_it(
+    renormalise):
+  """Scores are a sigmoid each; the k largest of s + b are kept; the
+  weights are s of the chosen over their sum, times the factor: a non-zero
+  b changes who is chosen and never a weight."""
+  rng = np.random.default_rng(17)
+  logits = jnp.asarray(rng.normal(size=(200, E)), jnp.bfloat16)
+  bias = jnp.asarray(rng.uniform(-0.2, 0.2, E), jnp.float32)
+  route = lambda b: tuple(np.asarray(a) for a in moe.route_top_k(
+      logits, K, renormalise, scoring='sigmoid', bias=b, scale=FACTOR))
+  weights, experts = route(bias)
+  assert weights.dtype == np.float32 and experts.dtype == np.int32
+  scores = np.asarray(jax.nn.sigmoid(logits.astype(jnp.float32)))
+  order = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :K]
+  assert np.array_equal(np.sort(experts), np.sort(order))
+  kept = np.take_along_axis(scores, experts, axis=-1)
+  if renormalise:
+    kept = kept / kept.sum(-1, keepdims=True)
+    np.testing.assert_allclose(weights.sum(-1), FACTOR, rtol=1e-6)
+  np.testing.assert_allclose(weights, kept * FACTOR, rtol=1e-6)
+  # Without the bias other experts are chosen for a share of the tokens;
+  # where the same expert is chosen its unnormalised weight is the same.
+  plain_weights, plain_experts = route(jnp.zeros(E))
+  moved = (np.sort(plain_experts) != np.sort(experts)).any(axis=-1)
+  assert 0.1 < moved.mean() < 1.0
+  if not renormalise:
+    same = plain_experts[:, :, None] == experts[:, None, :]
+    i, j, l = np.nonzero(same)
+    np.testing.assert_array_equal(plain_weights[i, j], weights[i, l])
+  # No bias at all is the bias of zeros.
+  none = moe.route_top_k(logits, K, renormalise, scoring='sigmoid',
+                         scale=FACTOR)
+  np.testing.assert_array_equal(np.asarray(none[1]), plain_experts)
+  np.testing.assert_array_equal(np.asarray(none[0]), plain_weights)
+  with pytest.raises(ValueError, match="unknown router scoring 'tanh'"):
+    moe.route_top_k(logits, K, True, scoring='tanh')
+
+
+@ROUTERS
+def test_counts_sum_to_positions_times_k_and_weights_to_the_factor(router):
+  weights, x = whole_layer_weights(seed=18, router=router), tokens(seed=19)
+  _out, counts = apply(0, E, weights, x, router=router)
+  assert counts.sum() == x.shape[0] * x.shape[1] * K
+  logits = x.reshape(-1, H) @ weights['router']['kernel']
+  kwargs = dict(scoring='sigmoid', bias=weights['router_selection_bias'],
+                scale=FACTOR) if router == 'sigmoid' else {}
+  top_p, _ = moe.route_top_k(logits, K, True, **kwargs)
+  np.testing.assert_allclose(
+      np.asarray(top_p).sum(-1), FACTOR if router == 'sigmoid' else 1.0,
+      rtol=1e-6)
 
 
 def test_top_k_weights_left_unnormalised_are_a_different_layer():

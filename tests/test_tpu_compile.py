@@ -7,7 +7,9 @@ described (not attached) `v5e:2x2` device at production shapes
 inference, the attention sublayer kernel the bfloat16 forward takes by
 itself among them, and 256 for the loss; two layers of the power-retention block
 kind at hidden 5120, batch 256; one period of the gated-delta and
-sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512). A compile that passes here is not a
+sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512; one
+dense and one expert layer of the latent-attention kind at hidden 2048 with
+128 experts, batch 512). A compile that passes here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
 
@@ -227,6 +229,54 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   # The experts' sorted rows are one turn of 25,600 tokens in bfloat16
   # (the temporaries above would not hold the pack's 512,000 at once).
   assert 'bf16[256000,2048]' in text
+
+
+def test_latent_attention_moe_forward_b512_at_published_widths(one_chip):
+  """The fourth block kind as it is served on one chip, by shape alone: the
+  leading dense layer and one expert layer (of the seven a chip holds) at
+  the published widths, all 128 experts, bfloat16 leaves, a pack of 512
+  windows. No kernel of the repository's own: the attention is plain
+  products, the grouped products the compiler's."""
+  p = config_lib.get_config('transformer_learn_values_mla_moe+custom')
+  with p.unlocked():
+    p.num_hidden_layers = 2
+  config_lib.finalize_params(p, is_training=False)
+  assert config_lib.ffn_pattern(p) == 'DE'
+  model = model_lib.get_model(p)
+  tree = jax.eval_shape(
+      lambda key: model.init(
+          key, jnp.zeros((1, p.total_rows, p.max_length, 1), jnp.float32)),
+      jax.random.PRNGKey(0))['params']
+  variables = {'params': jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                     sharding=one_chip), tree)}
+  rows = jax.ShapeDtypeStruct(
+      (512, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+
+  def forward(variables, rows):
+    with pallas_util.single_device_inference():
+      return model.apply(variables, rows, mutable=['moe_counts'])
+
+  compiled = jax.jit(forward).lower(variables, rows).compile()
+  text = compiled.as_text()
+  # The expert layer's three grouped products with the metadata call that
+  # sizes their groups: no masked dense product a group.
+  assert text.count('ragged-dot') >= 3
+  assert _n_kernels(compiled) >= 3
+  # The one rotary key is scored as it is: neither a key of 192 a head nor
+  # the rotary key repeated to 32 heads is laid out.
+  assert 'bf16[512,100,32,192]' in text  # the query
+  assert not re.search(r'= bf16\[512,100,32,64\]\S* broadcast', text)
+  assert not re.search(r'= \(?bf16\[512,100,32,192\]\S* concatenate', text)
+  memory = compiled.memory_analysis()
+  # 64,098,816 + 640,029,312 block parameters and what lies outside.
+  assert 2 * 704_128_128 < memory.argument_size_in_bytes < 1.45e9
+  # A pack's temporaries do not grow with depth (2.80 GiB at 8 layers, my
+  # compile of PR 34): with 8.46 GiB of weights they leave the chip room.
+  assert memory.temp_size_in_bytes < 3.2 * 2**30
+  # The experts' sorted rows are one turn of 25,600 tokens, six
+  # assignments each, in bfloat16.
+  assert 'bf16[153600,2048]' in text
 
 
 @pytest.mark.parametrize('length', [130, 512])
