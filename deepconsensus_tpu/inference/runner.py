@@ -1082,7 +1082,8 @@ class ModelRunner:
                        pack=handle.seq, block_kind=self._block_kind,
                        n_positions=n_positions,
                        weight_bytes=self._weight_bytes,
-                       **self._kernel_paths(inputs[0].shape[2],
+                       **self._kernel_paths(inputs[0].shape[0],
+                                            inputs[0].shape[2],
                                             handle.ragged),
                        **self._launch_fields):
       try:
@@ -1095,20 +1096,25 @@ class ModelRunner:
       except Exception as e:
         handle.error = faults.classify_device_error(e)
 
-  def _kernel_paths(self, length: int, ragged: bool) -> Dict[str, str]:
-    """What the compiled forward of this width takes of the kernels the
-    model chooses by itself: `attention_path` for its attention sublayers
-    and, where it has Gated DeltaNet mixers, `delta_rule_path`; the
-    model's own rules, asked as the forward's trace asks them."""
+  def _kernel_paths(self, batch: int, length: int,
+                    ragged: bool) -> Dict[str, str]:
+    """What the compiled forward of this pack takes of the kernels the
+    model chooses by itself: `attention_path` for its attention sublayers,
+    where it has Gated DeltaNet mixers `delta_rule_path` and where it has
+    sparse experts `grouped_product_path`; the model's own rules, asked as
+    the forward's trace asks them."""
     if 'transformer' not in self.params.model_name:
       return {'attention_path': model_lib.ATTENTION_XLA}
     with pallas_util.single_device_inference(self._single_device):
-      paths = {'attention_path': model_lib.attention_path(
-          self.params, length=length, ragged=ragged)}
-      delta_rule = model_lib.delta_rule_path(self.params, length=length)
-    if delta_rule is not None:
-      paths['delta_rule_path'] = delta_rule
-    return paths
+      paths = {
+          'attention_path': model_lib.attention_path(
+              self.params, length=length, ragged=ragged),
+          'delta_rule_path': model_lib.delta_rule_path(
+              self.params, length=length),
+          'grouped_product_path': model_lib.grouped_product_path(
+              self.params, batch=batch, length=length),
+      }
+    return {name: path for name, path in paths.items() if path is not None}
 
   def raw_outputs(self, dispatched: _DispatchHandle):
     """Device arrays (pred_ids, max_prob, n) for a dispatch handle —

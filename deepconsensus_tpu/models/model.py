@@ -773,6 +773,22 @@ def delta_rule_path(p, *, length: int) -> Optional[str]:
       num_value_heads=p.linear_num_value_heads, length=length)
 
 
+def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
+  """How a forward of this pack runs the grouped products of its sparse
+  experts (`forward_launch`'s `grouped_product_path`): `group_kernel`, the
+  Pallas kernel whose grid follows the groups, or `ragged_dot`, the
+  compiler's own; None for a block kind without sparse experts. The rule is
+  ops/moe.py::grouped_product_path, asked with the rows of one turn as
+  `held_experts` asks it where the forward is traced; no option asks for
+  the kernel."""
+  if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
+    return None
+  tokens, k = batch * length, p.num_experts_per_tok
+  return moe.grouped_product_path(
+      tokens // moe.turns_of(tokens, k) * k, p.experts_held_count,
+      p.hidden_size, p.moe_intermediate_size, p.get('dtype', 'float32'))
+
+
 def _attn_softmax_dtype(p):
   return jnp.dtype(p.get('attn_softmax_dtype', None) or 'float32')
 

@@ -265,6 +265,9 @@ def summarize(events: List[Dict[str, Any]],
                                  if a.get('attention_path')}),
       'delta_rule_paths': sorted({str(a['delta_rule_path']) for a in launches
                                   if a.get('delta_rule_path')}),
+      'grouped_product_paths': sorted(
+          {str(a['grouped_product_path']) for a in launches
+           if a.get('grouped_product_path')}),
       'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
                                 if a.get('layer_pattern')}),
       'ffn_patterns': sorted({str(a['ffn_pattern']) for a in launches
@@ -338,12 +341,16 @@ def format_summary(summary: Dict[str, Any]) -> str:
       delta_rule = ', '.join(forward.get('delta_rule_paths', ()))
       ffn = ', '.join(forward.get('ffn_patterns', ()))
       scoring = ', '.join(forward.get('router_scorings', ()))
+      grouped = ', '.join(forward.get('grouped_product_paths', ()))
+      experts = '; '.join(
+          f'{what}: {said}' for what, said in (
+              ('router', scoring), ('grouped products', grouped)) if said)
       lines.append(
           f'  layers: {", ".join(forward["layer_patterns"])}'
           + (f' (delta rule: {delta_rule})' if delta_rule else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'
               for lo, hi, published in forward.get('experts_held', ()))
-          + (f' (router: {scoring})' if scoring else '')
+          + (f' ({experts})' if experts else '')
           + (f'; feed-forward: {ffn}' if ffn else ''))
   overlap = summary['overlap']
   lines.append(

@@ -19,11 +19,21 @@ those that fall on held experts are computed here and the others are
 left to the process that holds them (shares add up: tests/
 test_moe_share.py). There is no capacity and no dropped token: the (token,
 expert) assignments are sorted by expert, the held ones first, and the
-three products run as grouped products over `held` ragged groups of rows
-(`jax.lax.ragged_dot`, which the TPU compiler lowers to one grouped
-matrix-multiply kernel that visits only the rows its groups cover, not
-to a masked dense product a group). Rows behind the last held group are
-never computed and never read.
+three products run as grouped products over `held` ragged groups of rows.
+Rows behind the last held group are never computed and never read.
+
+Which grouped product, `grouped_product_path` says from the shapes. On one
+TPU, in a trace declared inference for one device, bfloat16 rows whose
+count a row tile divides take the repository's Pallas kernel
+(ops/grouped_product.py): its grid follows the groups, a group's matrices
+stay in VMEM while its row tiles pass, and gate and up are one call that
+reads a row tile once and writes silu(gate) * up * weight. Everywhere else
+(the CPU, a mesh, export, float32) `jax.lax.ragged_dot` runs, which the TPU
+compiler lowers to a grouped matrix-multiply kernel of its own that visits
+only the rows its groups cover; on a v5e that lowering ran the published
+shapes at 37-38% of the chip's peak (74.6 TFLOP/s where the same program's
+plain products reach 159-187; PERF.md, PR 34), which is why it was
+replaced there.
 
 Tokens are taken MAX_ROWS assignments at a time (jax.lax.map): the sorted
 copy of the tokens and the experts' output are [tokens x k, hidden] each,
@@ -36,7 +46,15 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepconsensus_tpu.ops import grouped_product
+from deepconsensus_tpu.ops import pallas_util
+
 MAX_ROWS = 1 << 18
+
+# Which form of the grouped products a turn runs (`forward_launch`'s
+# `grouped_product_path`, docs/observability.md).
+GROUPED_GROUP_KERNEL = 'group_kernel'
+GROUPED_RAGGED_DOT = 'ragged_dot'
 
 SCORING_SOFTMAX = 'softmax'
 SCORING_SIGMOID = 'sigmoid'
@@ -75,6 +93,29 @@ def route_top_k(logits: jnp.ndarray, k: int, renormalise: bool,
   return weights, experts.astype(jnp.int32)
 
 
+def grouped_product_path(rows: int, groups: int, k: int, n: int,
+                         dtype) -> str:
+  """The one rule by which a turn's grouped products, `rows` sorted rows
+  over `groups` matrices [k, n] (and [n, k] back), take the Pallas kernel
+  in place of `jax.lax.ragged_dot`; no option asks for it. bfloat16 rows,
+  shapes the kernel has a tile for, and a TPU in a trace its caller
+  declared inference for one device (pallas_util.may_choose_kernels:
+  ModelRunner without a mesh)."""
+  kernel = (
+      jnp.dtype(dtype) == jnp.bfloat16
+      and grouped_product.tile_rows(rows, groups, k, n) is not None
+      and pallas_util.may_choose_kernels())
+  return GROUPED_GROUP_KERNEL if kernel else GROUPED_RAGGED_DOT
+
+
+def turns_of(n: int, k: int) -> int:
+  """In how many turns `held_experts` takes n tokens of k assignments."""
+  turns = 1
+  while (n // turns) * k > MAX_ROWS and n % (turns * 2) == 0:
+    turns *= 2
+  return turns
+
+
 def _held_experts(x, weights, experts, w_gate, w_up, w_down, first: int):
   """One turn of `held_experts`: every row of x at once."""
   n, k = experts.shape
@@ -99,11 +140,16 @@ def _held_experts(x, weights, experts, w_gate, w_up, w_down, first: int):
     # accumulator: a float32 copy of [n * k, hidden] is never written. The
     # routing weight multiplies the row before the last product, which is
     # linear, so the combine only adds.
-    grouped = lambda a, w: jax.lax.ragged_dot(
-        a, w.astype(a.dtype), counts, preferred_element_type=a.dtype)
-    hidden = jax.nn.silu(grouped(rows, w_gate).astype(jnp.float32))
-    hidden = hidden * grouped(rows, w_up).astype(jnp.float32)
-    out = grouped((hidden * row_weight[:, None]).astype(x.dtype), w_down)
+    if grouped_product_path(n * k, held, x.shape[1], w_gate.shape[2],
+                            x.dtype) == GROUPED_GROUP_KERNEL:
+      hidden = grouped_product.gated_up(rows, w_gate, w_up, row_weight, bounds)
+      out = grouped_product.grouped_product(hidden, w_down, bounds)
+    else:
+      grouped = lambda a, w: jax.lax.ragged_dot(
+          a, w.astype(a.dtype), counts, preferred_element_type=a.dtype)
+      hidden = jax.nn.silu(grouped(rows, w_gate).astype(jnp.float32))
+      hidden = hidden * grouped(rows, w_up).astype(jnp.float32)
+      out = grouped((hidden * row_weight[:, None]).astype(x.dtype), w_down)
   with jax.named_scope('combine'):
     # Where each assignment's row went: the inverse of the sort. The rows
     # come back one assignment of every token after another ([k, n, H]: a
@@ -135,9 +181,7 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, experts: jnp.ndarray,
   float32 accumulator and leave it in x's type; the gate, the routing
   weight and the combine's sum are float32."""
   n, k = experts.shape
-  turns = 1
-  while (n // turns) * k > MAX_ROWS and n % (turns * 2) == 0:
-    turns *= 2
+  turns = turns_of(n, k)
   if turns == 1:
     return _held_experts(x, weights, experts, w_gate, w_up, w_down, first)
   split = lambda a: a.reshape((turns, n // turns) + a.shape[1:])

@@ -77,6 +77,13 @@ def execution_report() -> Dict[str, Any]:
 # for more than 16. 48 MiB stays far under the 128 MiB a v5e core has.
 BATCH_TILE_VMEM_LIMIT_BYTES = 48 << 20
 
+# Scoped VMEM for the grouped products (ops/grouped_product.py): a group's
+# matrices stay while its row tiles pass, two of them for gate and up, each
+# buffered twice. At hidden 2048 x width 768 that is 12 MiB of matrices, 4
+# of row tiles of 512 and 3 of outputs and routing weights: the call
+# compiles within 20 MiB and not within the default 16.
+GROUPED_PRODUCT_VMEM_LIMIT_BYTES = 32 << 20
+
 
 def batch_tile_compiler_params(
     vmem_limit_bytes: int = BATCH_TILE_VMEM_LIMIT_BYTES):

@@ -521,6 +521,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     assert 'router_scoring' not in e['args']
     # Nor a delta rule: these kinds have no Gated DeltaNet mixer.
     assert 'delta_rule_path' not in e['args']
+    # Nor grouped products: no sparse experts.
+    assert 'grouped_product_path' not in e['args']
   stats = engine.stats()
   assert stats['block_kind'] == kind
   assert stats['model_weight_bytes'] == 62
@@ -534,6 +536,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
+                     'grouped_product_paths': [],
                      'layer_patterns': [config_lib.layer_pattern(p)],
                      'ffn_patterns': [config_lib.ffn_pattern(p)],
                      'router_scorings': [], 'experts_held': [],

@@ -196,6 +196,7 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   (drain,) = [e['args'] for e in events if e['name'] == 'finalize_drain']
   assert launch['block_kind'] == KIND and launch['attention_path'] == 'xla'
   assert 'delta_rule_path' not in launch
+  assert launch['grouped_product_path'] == 'ragged_dot'
   assert launch['layer_pattern'] == 'LLL' and launch['ffn_pattern'] == 'DEE'
   assert launch['experts_held'] == [8, 16]
   assert launch['experts_published'] == 16
@@ -249,13 +250,15 @@ def test_dctpu_trace_lists_both_patterns_and_the_router(tmp_path, capsys):
   assert forward['block_kinds'] == [KIND]
   assert forward['attention_paths'] == ['xla']
   assert forward['delta_rule_paths'] == []
+  assert forward['grouped_product_paths'] == ['ragged_dot']
   assert forward['layer_patterns'] == ['LLL']
   assert forward['ffn_patterns'] == ['DEE']
   assert forward['router_scorings'] == ['sigmoid_bias']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
-  assert ('layers: LLL; experts 8-15 of 16 held (router: sigmoid_bias); '
-          'feed-forward: DEE' in capsys.readouterr().out)
+  assert ('layers: LLL; experts 8-15 of 16 held (router: sigmoid_bias; '
+          'grouped products: ragged_dot); feed-forward: DEE'
+          in capsys.readouterr().out)
 
 
 # ------------------------------------------------------ the per-layer patterns
@@ -365,6 +368,14 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
   with pallas_util.single_device_inference():
     assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
     assert model_lib.delta_rule_path(p, length=100) is None
+    # The grouped products decline the toy widths, and take the kernel at
+    # whole lane tiles (the published 2048 and 768 are).
+    assert model_lib.grouped_product_path(p, batch=8, length=100) == (
+        'ragged_dot')
+    wide = tiny_params(100, dtype='bfloat16', transformer_input_size=128,
+                       moe_intermediate_size=256)
+    assert model_lib.grouped_product_path(wide, batch=8, length=100) == (
+        'group_kernel')
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

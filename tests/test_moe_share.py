@@ -119,6 +119,48 @@ def test_shares_and_the_shared_expert_once_are_the_uncut_layer(cuts, router):
 
 
 @ROUTERS
+@pytest.mark.parametrize('cuts', [((0, 8), (8, 8)), ((0, 1), (1, 15))],
+                         ids=['halves', 'one_and_the_rest'])
+def test_shares_add_up_through_the_group_kernel(cuts, router, monkeypatch):
+  """As on one TPU: at widths of a lane tile in bfloat16 the rule
+  (ops/moe.py::grouped_product_path) takes the Pallas kernel, interpreted
+  here. The shares still add up to the uncut layer, the counts are those
+  `ragged_dot`'s path gives, and no assignment is lost or doubled."""
+  import sys
+
+  from deepconsensus_tpu.ops import pallas_util
+
+  for name in ('H', 'F'):
+    monkeypatch.setattr(sys.modules[__name__], name, 128)
+  weights = whole_layer_weights(seed=20, router=router)
+  x = tokens(batch=4, length=32, seed=21).astype(jnp.bfloat16)
+  want, _ = reference_routed(weights, x.reshape(-1, H).astype(jnp.float32),
+                             router)
+  shared = shared_part(weights, x.astype(jnp.float32))
+  plain = [apply(first, count, weights, x, router=router, dtype=jnp.bfloat16)
+           for first, count in cuts]
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+  monkeypatch.setattr(pallas_util, 'resolve_interpret', lambda _: True)
+  with pallas_util.single_device_inference():
+    assert moe.grouped_product_path(4 * 32 * K, 8, H, F, x.dtype) == (
+        moe.GROUPED_GROUP_KERNEL)
+    kernel = [apply(first, count, weights, x, router=router,
+                    dtype=jnp.bfloat16) for first, count in cuts]
+  total = shared
+  for (out, took), (plain_out, plain_took) in zip(kernel, plain):
+    assert np.array_equal(took, plain_took)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(plain_out, np.float32),
+                               rtol=2 ** -6, atol=2 ** -5)
+    total = total + (np.asarray(out, np.float32) - shared)
+  assert sum(took.sum() for _, took in kernel) == 4 * 32 * K
+  # bfloat16 keeps 8 bits of every operand and of each share's output, and
+  # may send a token's near-tie to another expert.
+  gap = np.abs(np.asarray(total).reshape(-1, H) - np.asarray(want))
+  assert gap.mean() < 0.02 and (gap > 0.12).mean() < 1e-3
+
+
+@ROUTERS
 @pytest.mark.parametrize('first,count', [(0, 16), (0, 8), (8, 8), (5, 3)])
 def test_a_share_is_the_references_routed_part_for_that_share(first, count,
                                                               router):

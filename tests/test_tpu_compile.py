@@ -9,7 +9,8 @@ itself among them, and 256 for the loss; two layers of the power-retention block
 kind at hidden 5120, batch 256; one period of the gated-delta and
 sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512; one
 dense and one expert layer of the latent-attention kind at hidden 2048 with
-128 experts, batch 512). A compile that passes here is not a
+128 experts, batch 512; the grouped-product kernel both take, alone at one
+turn of each). A compile that passes here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
 
@@ -179,7 +180,7 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   0-255 of 512 in each, bfloat16 leaves, a pack of 512 windows, by shape
   alone (no array of the 6.3 GiB is made). As ModelRunner traces it
   without a mesh: the delta rule takes its window kernel, the grouped
-  products the compiler's own."""
+  products the kernel whose grid follows the groups."""
   p = config_lib.get_config('transformer_learn_values_gdn_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 4
@@ -203,9 +204,9 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
 
   compiled = jax.jit(forward).lower(variables, rows).compile()
   text = compiled.as_text()
-  # One window kernel a DeltaNet layer, and in every layer the three
-  # grouped products with the metadata call that sizes their groups: no
-  # masked dense product a group.
+  # One window kernel a DeltaNet layer, and in every layer two calls of the
+  # grouped products' kernel (gate and up as one, down): none of the
+  # compiler's own grouped products, no masked dense product a group.
   assert text.count('gated_delta_window') >= 3
   # The kernel takes the flat stream of each direction as the convolution
   # leaves it and writes the gated norm's output in the stream's type: no
@@ -215,8 +216,17 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   assert 'bf16[2,512,100,' not in text
   assert 'f32[512,128,4096]' not in text
   assert 'f32[512,100,4096]' not in text
-  assert text.count('ragged-dot') >= 4 * 3
-  assert _n_kernels(compiled) >= 3 + 4 * 3
+  assert 'ragged-dot' not in text
+  # (A layer's two turns are one loop, which the compiler may unroll.)
+  assert text.count(' custom-call(') >= 3 + 4 * 2
+  assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (4, 8)
+  assert len(re.findall(r'%grouped_product\S* = ', text)) in (4, 8)
+  assert _n_kernels(compiled) in (3 + 4 * 2, 3 + 8 * 2)
+  # Gate and up never leave their kernel: no [rows, width] pair in float32
+  # and no second bfloat16 one beside the gated product.
+  assert 'f32[256000,512]' not in text
+  # A turn's 25,600 tokens stay in VMEM for the dispatch's gather to read.
+  assert 'bf16[25600,2048]{1,0:T(8,128)(2,1)S(1)}' in text
   memory = compiled.memory_analysis()
   # 3,366,446,144 block parameters and what lies outside, 2 bytes each.
   assert 2 * 3_366_446_144 < memory.argument_size_in_bytes < 6.8e9
@@ -231,12 +241,14 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   assert 'bf16[256000,2048]' in text
 
 
-def test_latent_attention_moe_forward_b512_at_published_widths(one_chip):
+def test_latent_attention_moe_forward_b512_at_published_widths(
+    one_chip, compiled_kernels, monkeypatch):
   """The fourth block kind as it is served on one chip, by shape alone: the
   leading dense layer and one expert layer (of the seven a chip holds) at
   the published widths, all 128 experts, bfloat16 leaves, a pack of 512
-  windows. No kernel of the repository's own: the attention is plain
-  products, the grouped products the compiler's."""
+  windows. As ModelRunner traces it without a mesh: the attention is plain
+  products, the grouped products the kernel whose grid follows the
+  groups."""
   p = config_lib.get_config('transformer_learn_values_mla_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 2
@@ -252,6 +264,7 @@ def test_latent_attention_moe_forward_b512_at_published_widths(one_chip):
                                      sharding=one_chip), tree)}
   rows = jax.ShapeDtypeStruct(
       (512, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
 
   def forward(variables, rows):
     with pallas_util.single_device_inference():
@@ -259,10 +272,20 @@ def test_latent_attention_moe_forward_b512_at_published_widths(one_chip):
 
   compiled = jax.jit(forward).lower(variables, rows).compile()
   text = compiled.as_text()
-  # The expert layer's three grouped products with the metadata call that
-  # sizes their groups: no masked dense product a group.
-  assert text.count('ragged-dot') >= 3
-  assert _n_kernels(compiled) >= 3
+  # The expert layer's grouped products as two calls of the kernel (gate
+  # and up as one, down): none of the compiler's own, no masked dense
+  # product a group.
+  assert 'ragged-dot' not in text
+  # (The layer's two turns are one loop, which the compiler may unroll.)
+  assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (1, 2)
+  assert len(re.findall(r'%grouped_product\S* = ', text)) in (1, 2)
+  assert _n_kernels(compiled) in (2, 4)
+  assert 'f32[153600,768]' not in text
+  # A turn's 25,600 tokens (100 MiB) stay in VMEM for the dispatch's gather
+  # to read, as they did beside the compiler's own grouped products: with
+  # the sort's weights handed to the kernel's call as they were, XLA's
+  # memory assignment left them in HBM and the gather ran 7x as long.
+  assert 'bf16[25600,2048]{1,0:T(8,128)(2,1)S(1)}' in text
   # The one rotary key is scored as it is: neither a key of 192 a head nor
   # the rotary key repeated to 32 heads is laid out.
   assert 'bf16[512,100,32,192]' in text  # the query
@@ -277,6 +300,36 @@ def test_latent_attention_moe_forward_b512_at_published_widths(one_chip):
   # The experts' sorted rows are one turn of 25,600 tokens, six
   # assignments each, in bfloat16.
   assert 'bf16[153600,2048]' in text
+
+
+@pytest.mark.parametrize('rows,groups,width', [
+    (153_600, 128, 768), (256_000, 256, 512)],
+                         ids=['kanana_polish', 'qwen3next_polish'])
+def test_grouped_product_kernel_at_one_turn_of_both_cells(
+    one_chip, compiled_kernels, rows, groups, width):
+  """The grouped products' kernel alone at one turn of the two cells that
+  run it (25,600 tokens of 6 and of 10 assignments, hidden 2048): gate and
+  up as one call, then the down product, each with its group's matrices
+  resident and within pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES."""
+  from deepconsensus_tpu.ops import grouped_product
+
+  hidden = 2048
+  assert grouped_product.tile_rows(rows, groups, hidden, width) == 512
+  sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+      shape, dtype, sharding=one_chip)
+  bounds = sds((groups + 1,), jnp.int32)
+  up = jax.jit(grouped_product.gated_up).lower(
+      sds((rows, hidden)), sds((groups, hidden, width)),
+      sds((groups, hidden, width)), sds((rows,), jnp.float32),
+      bounds).compile()
+  assert 'grouped_gated_up' in up.as_text() and _n_kernels(up) == 1
+  down = jax.jit(grouped_product.grouped_product).lower(
+      sds((rows, width)), sds((groups, width, hidden)), bounds).compile()
+  assert 'grouped_product' in down.as_text() and _n_kernels(down) == 1
+  # Nothing of the rows' size beside the operands and the result, but the
+  # routing weights as a column, which the chip pads to a lane tile a row.
+  assert down.memory_analysis().temp_size_in_bytes < 1 << 20
+  assert up.memory_analysis().temp_size_in_bytes < rows * 128 * 4 + (1 << 20)
 
 
 @pytest.mark.parametrize('length', [130, 512])
