@@ -16,6 +16,15 @@ TPU-native re-design of the reference's quick_inference driver
 """
 from __future__ import annotations
 
+import sys
+import time
+
+# The `import_runner` span (recorded at the foot of this module): what it
+# costs a process to import the runner and all it pulls in, jax included
+# unless the caller had it already.
+_T_IMPORT = time.time()
+_JAX_PRELOADED = 'jax' in sys.modules
+
 import collections
 import csv
 import dataclasses
@@ -25,7 +34,6 @@ import logging
 import atexit
 import os
 import threading
-import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -40,6 +48,7 @@ warnings.filterwarnings(
     'ignore', message='Some donated buffers were not usable')
 
 from deepconsensus_tpu import obs as obs_lib
+from deepconsensus_tpu.obs import compiles as compiles_lib
 from deepconsensus_tpu.calibration import lib as calibration_lib
 from deepconsensus_tpu.inference import engine as engine_lib
 from deepconsensus_tpu.inference import faults
@@ -501,6 +510,26 @@ class ModelRunner:
 
   def __init__(self, params, variables, options: InferenceOptions,
                mesh=None):
+    self._init_obs()
+    with obs_lib.stage(self.obs, obs_lib.trace.STAGE_RUNNER_INIT) as st:
+      self._build(params, variables, options, mesh)
+      st.set(weight_bytes=self._weight_bytes, block_kind=self._block_kind,
+             mesh_dp=self.mesh_dp)
+
+  def _init_obs(self) -> None:
+    """One metrics registry per runner process; the engine, service and
+    batch driver all observe into this same registry so /metricz and
+    the run sidecar read one coherent view (obs/metrics.py). The
+    process's compile events count into the registry bound last
+    (obs/compiles.py), and it is told what importing this module cost."""
+    self.obs = obs_lib.MetricsRegistry()
+    compiles_lib.install(self.obs)
+    self.obs.observe(
+        obs_lib.stage_histogram_name(obs_lib.trace.STAGE_IMPORT_RUNNER),
+        IMPORT_S)
+
+  def _build(self, params, variables, options: InferenceOptions,
+             mesh) -> None:
     self.params = params
     sparse_experts = _holds_sparse_experts(params)
     if sparse_experts:
@@ -512,38 +541,17 @@ class ModelRunner:
     if variables:
       from deepconsensus_tpu.models import quantize as quantize_lib
 
-      variables, self._n_quantized_matmuls = (
-          quantize_lib.prepare_inference_variables(variables, params))
+      with obs_lib.stage(self.obs, obs_lib.trace.STAGE_WEIGHTS_PREPARE):
+        variables, self._n_quantized_matmuls = (
+            quantize_lib.prepare_inference_variables(variables, params))
     self.variables = variables
     self.options = options
     self.mesh = mesh
     if mesh is not None:
-      from deepconsensus_tpu.parallel import mesh as mesh_lib
-
       _check_dp_divisible(options, mesh)
-      # Place the weights on the mesh once; otherwise every forward
-      # re-broadcasts host arrays to all devices. param_shardings
-      # shards attention heads / FFN filters on the model axis under
-      # tp>1 and degenerates to replication at tp=1 (same rules as
-      # training); the non-params collections always replicate.
-      if variables:
-        self.variables = {
-            key: jax.device_put(
-                value,
-                mesh_lib.param_shardings(mesh, value)
-                if key == 'params' else mesh_lib.replicated(mesh),
-            )
-            for key, value in variables.items()
-        }
-    elif variables:
-      # Single-device residency: pin the weights (and the quant
-      # collections) on the device once, same as the mesh branch —
-      # otherwise every forward re-transfers the host arrays, leaving
-      # a host gap between consecutive packs' device_compute spans.
-      # With the input buffers donated, the steady-state pack loop
-      # then touches the host only for the uint8 pack in and the
-      # uint8 (ids, quals) planes out.
-      self.variables = jax.device_put(variables)
+    if variables:
+      with obs_lib.stage(self.obs, obs_lib.trace.STAGE_WEIGHTS_PLACE):
+        self.variables = self._place_weights(variables, mesh)
     model = model_lib.get_model(params)
     self._bq_row = _bq_row_index(params)
     bq_row = self._bq_row
@@ -599,6 +607,38 @@ class ModelRunner:
     self._ragged_forward = self._make_ragged_forward(mesh)
     self._init_dispatch_state(mesh)
 
+  @staticmethod
+  def _place_weights(variables, mesh):
+    """The weights (and the quant collections) on the device(s), once,
+    and there when this returns: placing is asynchronous, and without
+    the wait `weights_place` would read the enqueue and the first
+    forward pay the copy."""
+    if mesh is not None:
+      from deepconsensus_tpu.parallel import mesh as mesh_lib
+
+      # Otherwise every forward re-broadcasts host arrays to all
+      # devices. param_shardings shards attention heads / FFN filters
+      # on the model axis under tp>1 and degenerates to replication at
+      # tp=1 (same rules as training); the non-params collections
+      # always replicate.
+      placed = {
+          key: jax.device_put(
+              value,
+              mesh_lib.param_shardings(mesh, value)
+              if key == 'params' else mesh_lib.replicated(mesh),
+          )
+          for key, value in variables.items()
+      }
+    else:
+      # Single-device residency: same as the mesh branch — otherwise
+      # every forward re-transfers the host arrays, leaving a host gap
+      # between consecutive packs' device_compute spans. With the input
+      # buffers donated, the steady-state pack loop then touches the
+      # host only for the uint8 pack in and the uint8 (ids, quals)
+      # planes out.
+      placed = jax.device_put(variables)
+    return jax.block_until_ready(placed)
+
   def _configure_epilogue(self) -> None:
     """Resolves the tri-state device_epilogue option against the
     quality knobs: builds the exact threshold table
@@ -634,10 +674,6 @@ class ModelRunner:
       self._input_sharding = mesh_lib.batch_sharding(mesh)
     else:
       self._input_sharding = None
-    # One metrics registry per runner process; the engine, service and
-    # batch driver all observe into this same registry so /metricz and
-    # the run sidecar read one coherent view (obs/metrics.py).
-    self.obs = obs_lib.MetricsRegistry()
     # What the forward holds and computes, for the forward_launch span
     # and the registry: the encoder block kind (the model family where
     # there is no encoder), and the resident parameter bytes by leaf
@@ -717,11 +753,12 @@ class ModelRunner:
     # Measured at the first finalize drain (actual device-array bytes
     # pulled host-side per pack), for /metricz and the bench A/B.
     self._d2h_bytes_per_pack = 0
-    # Bucketed-dispatch accounting: every distinct (batch, L) input
-    # shape traces (and compiles) the jitted forward once, so the set
-    # size is the compile count the per-bucket compile-once contract
-    # asserts on; the per-bucket dict counts dispatches (including
-    # bisection retries, unlike the engine's per-packer n_packs).
+    # Bucketed-dispatch accounting: the distinct (batch, L) input shapes
+    # this runner has dispatched, which is how many executables the
+    # per-bucket compile-once contract allows the jitted forward (what
+    # XLA really compiled is `n_xla_compiles`, obs/compiles.py); the
+    # per-bucket dict counts dispatches (including bisection retries,
+    # unlike the engine's per-packer n_packs).
     self._forward_shapes: set = set()
     self._n_dispatched_by_bucket: Dict[int, int] = {}
     # Ragged dispatch contract: absent on exported-artifact runners
@@ -791,8 +828,16 @@ class ModelRunner:
     params = config_lib.read_params_from_json(checkpoint_path)
     config_lib.finalize_params(params, is_training=False)
     _apply_quant_levers(params, options)
-    return cls(params, {'params': load_params(checkpoint_path)}, options,
-               mesh=mesh)
+    t0 = time.time()
+    loaded = load_params(checkpoint_path)
+    t1 = time.time()
+    runner = cls(params, {'params': loaded}, options, mesh=mesh)
+    # Stamped once there is a registry to hold its histogram.
+    obs_lib.record_stage(
+        runner.obs, obs_lib.trace.STAGE_CHECKPOINT_LOAD, t0, t1,
+        bytes=sum(int(leaf.nbytes)
+                  for leaf in jax.tree_util.tree_leaves(loaded)))
+    return runner
 
   @classmethod
   def from_exported(cls, export_dir: str,
@@ -809,13 +854,20 @@ class ModelRunner:
     """
     from deepconsensus_tpu.models import export as export_lib
 
+    t0 = time.time()
     serving, meta = export_lib.load_exported(export_dir)
+    t1 = time.time()
     params = config_lib.read_params_from_json(export_dir)
     config_lib.finalize_params(params, is_training=False)
     _check_exported_levers(meta, options, export_dir)
     _check_exported_epilogue(meta, options, export_dir)
     baked_epilogue = bool(meta.get('device_epilogue'))
     runner = cls.__new__(cls)
+    runner._init_obs()
+    obs_lib.record_stage(
+        runner.obs, obs_lib.trace.STAGE_CHECKPOINT_LOAD, t0, t1,
+        bytes=os.path.getsize(
+            os.path.join(export_dir, export_lib.ARTIFACT_NAME)))
     runner.params = params
     runner.variables = None
     # The output plane is part of the compiled program: when baked, the
@@ -954,7 +1006,7 @@ class ModelRunner:
               for plane in (main_u8, sn))
         st.set(bytes_in=bytes_in, bytes_out=main_u8.nbytes + sn.nbytes)
       # Per-bucket compile-once accounting: jit keeps one executable per
-      # distinct (batch, L); the set is the compile count.
+      # distinct (batch, L).
       return self._place_pack((main_u8, sn), n=n, n_rows=n, bucket=width,
                               shape_key=(batch, width))
 
@@ -1150,6 +1202,10 @@ class ModelRunner:
         'n_epilogue_packs': self._n_epilogue_packs,
         'd2h_bytes_per_pack': self._d2h_bytes_per_pack,
         'n_forward_shapes': len(self._forward_shapes),
+        # What XLA compiled, or read from the persistent cache, in this
+        # process since this runner was built (obs/compiles.py).
+        'n_xla_compiles': self.obs.counter('xla_compiles_total').value,
+        'n_xla_cache_hits': self.obs.counter('xla_cache_hits_total').value,
         'block_kind': self._block_kind,
         'model_weight_bytes': self._weight_bytes,
         'n_forward_positions': self._n_forward_positions.value,
@@ -1206,14 +1262,7 @@ class ModelRunner:
     devices = np.asarray(self.mesh.devices).reshape(-1)[:new_dp * tp]
     mesh = mesh_lib.make_mesh(dp=new_dp, tp=tp, devices=list(devices))
     if self.variables:
-      self.variables = {
-          key: jax.device_put(
-              value,
-              mesh_lib.param_shardings(mesh, value)
-              if key == 'params' else mesh_lib.replicated(mesh),
-          )
-          for key, value in self.variables.items()
-      }
+      self.variables = self._place_weights(self.variables, mesh)
     self.mesh = mesh
     self._forward = self._make_forward(mesh)
     if self._make_ragged_forward is not None:
@@ -1606,6 +1655,14 @@ def run_inference(
   committed groups of an interrupted run.
   """
   options = options or InferenceOptions()
+  # Run-scoped tracing: honor DCTPU_TRACE unless the CLI already
+  # configured a writer, and stamp every span (and dead letter) from
+  # this run's threads with one minted trace id. Before the runner is
+  # built, so that its start-up spans carry the id too.
+  if not obs_lib.trace.enabled():
+    obs_lib.trace.configure_from_env(tier='run')
+  run_trace_id = obs_lib.trace.mint_trace_id()
+  obs_lib.trace.set_trace_id(run_trace_id)
   if runner is None:
     if checkpoint is None:
       # dclint: allow=typed-faults (API misuse by the caller, not a
@@ -1623,14 +1680,6 @@ def run_inference(
   options.window_buckets = config_lib.normalize_window_buckets(
       options.window_buckets or getattr(params, 'window_buckets', None),
       params.max_length)
-
-  # Run-scoped tracing: honor DCTPU_TRACE unless the CLI already
-  # configured a writer, and stamp every span (and dead letter) from
-  # this run's threads with one minted trace id.
-  if not obs_lib.trace.enabled():
-    obs_lib.trace.configure_from_env(tier='run')
-  run_trace_id = obs_lib.trace.mint_trace_id()
-  obs_lib.trace.set_trace_id(run_trace_id)
 
   fail_fast = options.on_zmw_error == faults.OnZmwError.FAIL
   dead_letter: Optional[faults.DeadLetterWriter] = None
@@ -2221,6 +2270,21 @@ def run_inference(
       thread = threading.Thread(target=producer, daemon=True)
       thread.start()
       batches_ingested = 0
+      startup_logged = False
+
+      def log_startup() -> None:
+        """One line, once the first forward has been launched: start-up,
+        that forward's compile included, is behind us (read from the
+        registry, so with tracing off too)."""
+        nonlocal startup_logged
+        if startup_logged or not runner.obs.histogram(
+            obs_lib.stage_histogram_name(
+                obs_lib.trace.STAGE_LAUNCH)).snapshot()['count']:
+          return
+        startup_logged = True
+        log.info('%s', compiles_lib.format_startup(
+            compiles_lib.startup_split(runner.obs)))
+
       try:
         while True:
           kind, payload = feat_queue.get()
@@ -2235,6 +2299,7 @@ def run_inference(
             release_shm(payload)
           pop_ready()
           batches_ingested += 1
+          log_startup()
           if (crash_after and emit_thread is None
               and batches_ingested >= crash_after):
             # Without an emit stage the main thread is the whole
@@ -2249,6 +2314,7 @@ def run_inference(
             )
         if engine is not None:
           engine.flush()  # end of input: cut the tail pack, drain all
+        log_startup()  # an input of one pack launches only here
         pop_ready()
         if states:
           # dclint: allow=typed-faults (internal invariant violation —
@@ -2348,3 +2414,19 @@ def run_inference(
   if not outcome.success and options.end_after_stage == 'full':
     log.warning('No reads passed filters; outcome=%s', outcome)
   return counters
+
+
+IMPORT_S = time.time() - _T_IMPORT
+
+
+def record_import_span() -> None:
+  """The `import_runner` span, as stamped at this module's scope (a test
+  that emptied the start-up record puts it back with this)."""
+  obs_lib.record_stage(None, obs_lib.trace.STAGE_IMPORT_RUNNER, _T_IMPORT,
+                       _T_IMPORT + IMPORT_S, jax_preloaded=_JAX_PRELOADED)
+
+
+record_import_span()
+# From here on JAX's trace, lower and compile events are spans and
+# counts (a program may jit before it builds a runner).
+compiles_lib.install()

@@ -24,6 +24,11 @@ fleet processes appending to one file) and derives:
 * what the forward held and computed — from the `forward_launch`
   spans' args: block kind, layer pattern, the share of the experts held,
   positions launched, resident weight bytes;
+* start-up and compiles — the start-up stages in order; JAX's trace,
+  lower and compile seconds (`jit_trace`, `jit_lower`, `xla_compile`:
+  nested events of a thread counted once) by the stage they arrived
+  under; the functions that took the most trace time; and every compile
+  after the first `submit`, with the pack of the launch that caused it;
 * a span-derived transfer-overlap fraction that must agree with the
   counter-derived ``transfer_overlap_fraction``: a pack's forward
   launch (the device_compute span start) happening strictly BEFORE its
@@ -163,15 +168,43 @@ def _is_wait(event: Dict[str, Any]) -> bool:
           or event.get('name') in trace_lib.WAITS)
 
 
+def _thread(event: Dict[str, Any]) -> Tuple[int, int]:
+  return int(event.get('pid', 0)), int(event.get('tid', 0))
+
+
+def _outermost(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+  """The events no other of `events` on the same thread encloses. JAX's
+  compile events nest (a function traced inside another's trace) and
+  all name the same open stage: their seconds are those of the
+  outermost."""
+  out: List[Dict[str, Any]] = []
+  end_of: Dict[Tuple[int, int], float] = {}
+  for e in sorted(events, key=lambda e: (float(e['ts']),
+                                         -float(e.get('dur', 0.0)))):
+    end = float(e['ts']) + float(e.get('dur', 0.0))
+    if end <= end_of.get(_thread(e), float('-inf')):
+      continue
+    end_of[_thread(e)] = end
+    out.append(e)
+  return out
+
+
+def _is_compile(event: Dict[str, Any]) -> bool:
+  return event.get('name') in trace_lib.COMPILE_SPANS
+
+
 def self_times(events: List[Dict[str, Any]]) -> Dict[str, Dict[str, Any]]:
   """stage name -> {'total_s', 'self_s', 'count', 'under'}: over the
   work stages (cat 'stage', waits left out), a stage's self time is its
   duration minus the durations of the stages whose `args.parent` is its
   `args.span` in the same process (children of one parent run one after
-  the other on its thread, so they do not overlap). 'under' lists the
+  the other on its thread, so they do not overlap; of JAX's compile
+  events, which nest, only the outermost count). 'under' lists the
   names of the stages it ran under ('' at top level)."""
   stages = [e for e in _complete_spans(events)
             if e.get('cat') == trace_lib.CAT_STAGE and not _is_wait(e)]
+  stages = ([e for e in stages if not _is_compile(e)]
+            + _outermost([e for e in stages if _is_compile(e)]))
   name_of: Dict[Tuple[int, Any], str] = {}
   child_us: Dict[Tuple[int, Any], float] = {}
   for e in stages:
@@ -193,12 +226,89 @@ def self_times(events: List[Dict[str, Any]]) -> Dict[str, Dict[str, Any]]:
     row['total_s'] += dur / 1e6
     row['self_s'] += max(0.0, own) / 1e6
     row['count'] += 1
-    row['under'].add(name_of.get((pid, args.get('parent')), ''))
+    row['under'].add(name_of.get((pid, args.get('parent')),
+                                 str(args.get('under', ''))))
   return {
       name: {'total_s': round(row['total_s'], 6),
              'self_s': round(row['self_s'], 6),
              'count': row['count'], 'under': sorted(row['under'])}
       for name, row in sorted(out.items())}
+
+
+def startup(events: List[Dict[str, Any]], top: int = 5) -> Dict[str, Any]:
+  """The start-up stages in order, and what JAX traced, lowered and
+  compiled: seconds of each kind by the stage the events arrived under
+  (`args.under`; nested events of a thread counted once), the `top`
+  functions by trace seconds, and the compiles that began after the
+  first `submit` of their process ("which step recompiled")."""
+  spans = _complete_spans(events)
+  stages = sorted(
+      (e for e in spans if e.get('name') in trace_lib.STARTUP_SPANS
+       and not _is_compile(e)), key=lambda e: float(e['ts']))
+  # The stage above one: by name where it was stamped with tracing off,
+  # through its parent's id otherwise.
+  name_of = {(int(e.get('pid', 0)), e['args']['span']): str(e['name'])
+             for e in spans if 'span' in (e.get('args') or {})}
+
+  def under(e: Dict[str, Any]):
+    args = e.get('args') or {}
+    return args.get('under') or name_of.get(
+        (int(e.get('pid', 0)), args.get('parent')))
+
+  def seconds(group: List[Dict[str, Any]]) -> float:
+    return round(
+        sum(float(e.get('dur', 0.0)) for e in _outermost(group)) / 1e6, 6)
+
+  def grouped(group: List[Dict[str, Any]], key) -> Dict[str, float]:
+    by_key: Dict[str, List[Dict[str, Any]]] = {}
+    for e in group:
+      by_key.setdefault(key(e.get('args') or {}), []).append(e)
+    return {k: seconds(v) for k, v in sorted(by_key.items())}
+
+  by_kind = {kind: [e for e in spans if e.get('name') == kind]
+             for kind in trace_lib.COMPILE_SPANS}
+  kinds = {
+      kind: {'total_s': seconds(group), 'count': len(group),
+             'under': grouped(group, lambda a: str(a.get('under', '')))}
+      for kind, group in by_kind.items()}
+  compiles = by_kind[trace_lib.STAGE_XLA_COMPILE]
+  kinds[trace_lib.STAGE_XLA_COMPILE]['cache_hits'] = sum(
+      1 for e in compiles if (e.get('args') or {}).get('cache_hit'))
+  by_fun = grouped(by_kind[trace_lib.STAGE_JIT_TRACE],
+                   lambda a: str(a.get('fun', '')))
+  first_submit: Dict[int, float] = {}
+  for e in spans:
+    if e.get('name') == trace_lib.STAGE_SUBMIT:
+      pid = int(e.get('pid', 0))
+      first_submit[pid] = min(first_submit.get(pid, float('inf')),
+                              float(e['ts']))
+  late = []
+  for e in sorted(compiles, key=lambda e: float(e['ts'])):
+    if float(e['ts']) < first_submit.get(int(e.get('pid', 0)),
+                                         float('inf')):
+      continue
+    args = e.get('args') or {}
+    late.append({
+        'fun': args.get('fun'), 'under': args.get('under'),
+        'pack': args.get('pack'),
+        'dur_s': round(float(e.get('dur', 0.0)) / 1e6, 6),
+        'cache_hit': bool(args.get('cache_hit'))})
+  return {
+      'stages': [
+          {'stage': str(e['name']),
+           'dur_s': round(float(e.get('dur', 0.0)) / 1e6, 6),
+           'under': under(e)}
+          for e in stages],
+      'compile_kinds': kinds,
+      'top_traced': [
+          {'fun': fun, 'trace_s': s} for fun, s in sorted(
+              by_fun.items(), key=lambda item: -item[1])[:top]],
+      'compiles_after_first_submit': late,
+      'early_events_dropped': sum(
+          int((e.get('args') or {}).get('count', 0)) for e in events
+          if e.get('ph') == 'M'
+          and e.get('name') == trace_lib.EARLY_DROPPED_EVENT),
+  }
 
 
 def summarize(events: List[Dict[str, Any]],
@@ -297,9 +407,43 @@ def summarize(events: List[Dict[str, Any]],
       'critical_path': critical_path,
       'stragglers': stragglers,
       'forward': forward,
+      'startup': startup(events),
       'overlap': span_overlap(events),
       'n_traces': len(trace_groups(events)),
   }
+
+
+def _format_startup(startup: Dict[str, Any]) -> List[str]:
+  kinds = {kind: row for kind, row in (
+      startup.get('compile_kinds') or {}).items() if row['count']}
+  if not startup.get('stages') and not kinds:
+    return []
+  lines = ['start-up and compiles:']
+  for row in startup.get('stages', ()):
+    under = f'  under: {row["under"]}' if row.get('under') else ''
+    lines.append(f'  {row["stage"]:<16} {row["dur_s"]:>10.4f}s{under}')
+  for kind, row in kinds.items():
+    under = ', '.join(f'{name or "-"} {s:.4f}s'
+                      for name, s in row['under'].items())
+    hits = (f'  cache hits {row["cache_hits"]}/{row["count"]}'
+            if 'cache_hits' in row else '')
+    lines.append(f'  {kind:<16} {row["total_s"]:>10.4f}s  n={row["count"]}'
+                 f'{hits}  under: {under}')
+  if startup.get('top_traced'):
+    lines.append('  most trace time: ' + ', '.join(
+        f'{row["fun"]} {row["trace_s"]:.4f}s'
+        for row in startup['top_traced']))
+  late = startup.get('compiles_after_first_submit') or ()
+  lines.append(f'  compiles after the first submit: {len(late)}')
+  for row in late:
+    lines.append(
+        f'    {row["fun"]} {row["dur_s"]:.4f}s under {row["under"] or "-"} '
+        f'pack={row["pack"]}'
+        + (' (cache hit)' if row['cache_hit'] else ''))
+  if startup.get('early_events_dropped'):
+    lines.append(f'  start-up events dropped before tracing was '
+                 f'configured: {startup["early_events_dropped"]}')
+  return lines
 
 
 def format_summary(summary: Dict[str, Any]) -> str:
@@ -352,6 +496,7 @@ def format_summary(summary: Dict[str, Any]) -> str:
               for lo, hi, published in forward.get('experts_held', ()))
           + (f' ({experts})' if experts else '')
           + (f'; feed-forward: {ffn}' if ffn else ''))
+  lines.extend(_format_startup(summary.get('startup') or {}))
   overlap = summary['overlap']
   lines.append(
       f'transfer overlap (span-derived): '

@@ -34,7 +34,22 @@ leaves through ``os._exit`` (a multiprocessing worker) calls
 Overhead when off. Tracing is enabled by ``DCTPU_TRACE=<path>`` (or
 ``configure(path)``); when unset, ``enabled()`` is a module-global
 ``is None`` check, ``span()`` yields a no-op context and a stage costs
-its two clock reads and its histogram observation: no event is built.
+its two clock reads, its histogram observation and an append and a pop
+on the thread's stack of open stages (kept with tracing off too, so a
+compile can say which stage it arrived under): no id is taken and no
+event is built. The one exception is the start-up record below.
+
+Start-up record. A program builds its runner, and compiles, before it
+knows where to trace to. While no writer is configured, the events whose
+name is in ``STARTUP_SPANS`` (the start-up stages and JAX's trace, lower
+and compile events, obs/compiles.py: a handful a process, seconds each)
+are built and kept in a list of at most ``EARLY_EVENTS``; the oldest
+stay, and what does not fit is counted. ``configure(path)`` writes them
+into the new file first, each as it was stamped, with the count of the
+dropped ones if there were any. They carry ``args.under`` (the name of
+the stage they arrived under) but no ids, which are taken only with
+tracing on. ``clear_early()`` empties the list, and so does a fork in
+the child.
 
 Profiler bridge. While tracing is on and ``jax`` is already imported, a
 stage also enters ``jax.profiler.TraceAnnotation(name)``, so a profiler
@@ -84,6 +99,24 @@ STAGE_STITCH = 'stitch'
 STAGE_PACK_WAIT = 'pack_wait'
 STAGE_DEVICE_COMPUTE = 'device_compute'
 WAITS = (STAGE_PACK_WAIT, STAGE_DEVICE_COMPUTE)
+# Start-up (docs/observability.md#start-up-and-compiles): the stages of
+# a runner's construction, and JAX's own trace, lower and compile events
+# (obs/compiles.py) under whichever stage caused them.
+STAGE_IMPORT_RUNNER = 'import_runner'
+STAGE_CHECKPOINT_LOAD = 'checkpoint_load'
+STAGE_RUNNER_INIT = 'runner_init'
+STAGE_WEIGHTS_PREPARE = 'weights_prepare'
+STAGE_WEIGHTS_PLACE = 'weights_place'
+STAGE_JIT_TRACE = 'jit_trace'
+STAGE_JIT_LOWER = 'jit_lower'
+STAGE_XLA_COMPILE = 'xla_compile'
+COMPILE_SPANS = (STAGE_JIT_TRACE, STAGE_JIT_LOWER, STAGE_XLA_COMPILE)
+# Kept while no writer is configured, and written first by configure().
+STARTUP_SPANS = frozenset((
+    STAGE_IMPORT_RUNNER, STAGE_CHECKPOINT_LOAD, STAGE_RUNNER_INIT,
+    STAGE_WEIGHTS_PREPARE, STAGE_WEIGHTS_PLACE) + COMPILE_SPANS)
+EARLY_EVENTS = 1024
+EARLY_DROPPED_EVENT = 'early_events_dropped'
 
 # Events buffered before a flush: a resident server's memory bound.
 FLUSH_EVENTS = 4096
@@ -106,6 +139,17 @@ def _write_lines(fd: int, events: List[Dict[str, Any]]) -> None:
       for e in events).encode()
   while data:  # a short write continues where it stopped
     data = data[os.write(fd, data):]
+
+
+def _complete(name: str, cat: str, ts_s: float, dur_s: float,
+              args: Optional[Dict[str, Any]], pid: int) -> Dict[str, Any]:
+  """One 'X' (complete) event of the calling thread."""
+  return {
+      'name': name, 'cat': cat, 'ph': 'X',
+      'ts': ts_s * 1e6, 'dur': max(0.0, dur_s) * 1e6,
+      'pid': pid, 'tid': threading.get_ident() & 0xffffffff,
+      'args': args or {},
+  }
 
 
 class TraceWriter:
@@ -145,12 +189,7 @@ class TraceWriter:
   def complete_event(self, name: str, cat: str, ts_s: float, dur_s: float,
                      args: Optional[Dict[str, Any]] = None) -> None:
     """One 'X' (complete) event; ts/dur in seconds of time.time()."""
-    self._emit_raw({
-        'name': name, 'cat': cat, 'ph': 'X',
-        'ts': ts_s * 1e6, 'dur': max(0.0, dur_s) * 1e6,
-        'pid': self._pid, 'tid': threading.get_ident() & 0xffffffff,
-        'args': args or {},
-    })
+    self._emit_raw(_complete(name, cat, ts_s, dur_s, args, self._pid))
 
   def flush(self) -> None:
     """Writes out what is buffered."""
@@ -184,17 +223,51 @@ _writer: Optional[TraceWriter] = None
 _local = threading.local()
 # Stage ids, unique in the process (next() on a count is atomic).
 _span_ids = itertools.count(1)
+# The start-up record: STARTUP_SPANS events stamped while no writer is
+# configured, at most EARLY_EVENTS of them, and how many did not fit.
+_early_lock = threading.Lock()
+_early: List[Dict[str, Any]] = []  # guarded by: _early_lock
+early_events_dropped = 0  # guarded by: _early_lock
+
+
+def _keep_early(event: Dict[str, Any]) -> None:
+  global early_events_dropped
+  with _early_lock:
+    if len(_early) < EARLY_EVENTS:
+      _early.append(event)
+    else:
+      early_events_dropped += 1
+
+
+def clear_early() -> None:
+  """Empties the start-up record (a test that builds a runner and
+  configures late starts from here, whatever other tests compiled)."""
+  global early_events_dropped
+  with _early_lock:
+    del _early[:]
+    early_events_dropped = 0
 
 
 def configure(path: Optional[str], tier: str = '') -> Optional[TraceWriter]:
   """Enables tracing to `path` (None/'' disables, and writes out what
-  the last writer still held). Returns the writer."""
-  global _writer
+  the last writer still held). A new writer starts with the start-up
+  record. Returns the writer."""
+  global _writer, early_events_dropped
   if _writer is not None:
     _writer.close()
     _writer = None
   if path:
-    _writer = TraceWriter(path, tier=tier)
+    _writer = writer = TraceWriter(path, tier=tier)
+    with _early_lock:
+      early, dropped = list(_early), early_events_dropped
+      del _early[:]
+      early_events_dropped = 0
+    for event in early:
+      writer._emit_raw(event)
+    if dropped:
+      writer._emit_raw({
+          'name': EARLY_DROPPED_EVENT, 'ph': 'M', 'pid': writer._pid,
+          'tid': 0, 'args': {'count': dropped}})
   return _writer
 
 
@@ -221,9 +294,13 @@ def flush() -> None:
 
 
 def _after_fork_in_child() -> None:
+  global _early_lock
   w = _writer
   if w is not None:
     w.reset_after_fork()
+  # The parent's start-up record is the parent's to write.
+  _early_lock = threading.Lock()
+  clear_early()
 
 
 atexit.register(flush)
@@ -247,16 +324,40 @@ def get_trace_id() -> Optional[str]:
 
 def complete_event(name: str, cat: str, t0: float, t1: float,
                    args: Optional[Dict[str, Any]] = None) -> None:
-  """After-the-fact span from two time.time() stamps. No-op when
-  tracing is off, so instrumentation sites call it unconditionally."""
+  """After-the-fact span from two time.time() stamps. With tracing off
+  a no-op, so instrumentation sites call it unconditionally, except for
+  the STARTUP_SPANS, which are kept for the writer to come."""
   w = _writer
-  if w is None:
+  if w is None and name not in STARTUP_SPANS:
     return
   args = dict(args or {})
   trace_id = get_trace_id()
   if trace_id and 'trace_id' not in args:
     args['trace_id'] = trace_id
-  w.complete_event(name, cat, t0, t1 - t0, args)
+  if w is None:
+    _keep_early(_complete(name, cat, t0, t1 - t0, args, os.getpid()))
+  else:
+    w.complete_event(name, cat, t0, t1 - t0, args)
+
+
+def caused_event(name: str, t0: float, t1: float,
+                 args: Dict[str, Any]) -> None:
+  """A stage stamped after the fact that belongs to whatever stage is
+  open on this thread (a compile belongs to the call that caused it):
+  `args.under` is that stage's name, so a reader needs no join,
+  `args.pack` its pack where it has one and, with tracing on,
+  `args.parent` its id and `args.span` an id of this event's own."""
+  stack = getattr(_local, 'stack', None)
+  if stack:
+    cause = stack[-1]
+    args['under'] = cause.name
+    if 'pack' in cause.args:
+      args['pack'] = cause.args['pack']
+    if cause._span:
+      args['parent'] = cause._span
+  if _writer is not None:
+    args['span'] = next(_span_ids)
+  complete_event(name, CAT_STAGE, t0, t1, args)
 
 
 @contextlib.contextmanager
@@ -287,11 +388,12 @@ class Stage:
   """One lexically scoped stage: `with obs.stage(registry, name, **args)`.
 
   Feeds the same interval to the `stage_<name>_s` histogram and to the
-  span. With tracing on it takes a process-unique id, records the
-  enclosing stage of this thread as its parent, and pushes itself on
-  the thread's stack for its own children. `set()` adds counts that are
-  only known inside the block (bytes of a result). Once per submit or
-  per pack, never per window: it is a few microseconds, not free.
+  span, and stands on the thread's stack of open stages while it is open
+  (with tracing off too: caused_event reads the top). With tracing on it
+  takes a process-unique id and records the enclosing stage of this
+  thread as its parent. `set()` adds counts that are only known inside
+  the block (bytes of a result). Once per submit or per pack, never per
+  window: it is a few microseconds, not free.
   """
 
   __slots__ = ('_registry', 'name', 'args', 't0', '_span', '_parent',
@@ -310,16 +412,16 @@ class Stage:
     self.args.update(args)
 
   def __enter__(self) -> 'Stage':
+    stack = getattr(_local, 'stack', None)
+    if stack is None:
+      stack = _local.stack = []
     if _writer is not None:
-      stack = getattr(_local, 'stack', None)
-      if stack is None:
-        stack = _local.stack = []
       self._span = next(_span_ids)
-      self._parent = stack[-1] if stack else 0
-      stack.append(self._span)
+      self._parent = stack[-1]._span if stack else 0
       self._annotation = _profiler_annotation(self.name)
       if self._annotation is not None:
         self._annotation.__enter__()
+    stack.append(self)
     self.t0 = time.time()
     return self
 
@@ -327,16 +429,24 @@ class Stage:
     t1 = time.time()
     if self._registry is not None:
       self._registry.observe(f'stage_{self.name}_s', t1 - self.t0)
+    # (A thread that opened no stage has no stack: a generator may be
+    # closed by another thread than the one that ran it.)
+    stack = getattr(_local, 'stack', ())
+    if stack and stack[-1] is self:
+      stack.pop()
+    elif self in stack:
+      # A generator abandoned mid-stage left its stages above this one.
+      del stack[stack.index(self):]
     if not self._span:
+      # Opened with tracing off: no event, but for the start-up stages,
+      # which name the stage above them in place of the id it lacks.
+      if self.name in STARTUP_SPANS:
+        if stack:
+          self.args['under'] = stack[-1].name
+        complete_event(self.name, CAT_STAGE, self.t0, t1, self.args)
       return
     if self._annotation is not None:
       self._annotation.__exit__(exc_type, exc, tb)
-    stack = _local.stack
-    if stack and stack[-1] == self._span:
-      stack.pop()
-    elif self._span in stack:
-      # A generator abandoned mid-stage left its stages above this one.
-      del stack[stack.index(self._span):]
     args = self.args
     args['span'] = self._span
     if self._parent:

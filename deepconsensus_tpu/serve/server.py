@@ -335,6 +335,7 @@ def serve_main(runner, options, serve_options: ServeOptions,
   bound port. stop_event (threading.Event) is the in-process stand-in
   for SIGTERM when serve_main runs off the main thread.
   """
+  from deepconsensus_tpu.obs import compiles as compiles_lib
   from deepconsensus_tpu.ops import pallas_util
   from deepconsensus_tpu.serve.service import ConsensusService
 
@@ -353,17 +354,21 @@ def serve_main(runner, options, serve_options: ServeOptions,
   stop.install()
   preempt = _PreemptionWatch()
   preempt.install()
+  startup = compiles_lib.startup_split(service.metrics)
   info = {
       'event': 'ready',
       'host': host,
       'port': bound_port,
       'warmup_s': round(warm_s, 3),
+      # Where start-up went (obs/compiles.py): import, checkpoint,
+      # weights, and the warm-up's trace, lower and compile seconds.
+      'startup': startup,
       # The device the warmed forward really runs on, and how its
       # Pallas calls resolved (ops/pallas_util.py).
       'device': pallas_util.execution_report(),
   }
-  log.info('dctpu serve ready on %s:%d (warmup %.3fs)',
-           host, bound_port, warm_s)
+  log.info('dctpu serve ready on %s:%d (warmup %.3fs); %s',
+           host, bound_port, warm_s, compiles_lib.format_startup(startup))
   if ready_fn is not None:
     ready_fn(info)
   try:

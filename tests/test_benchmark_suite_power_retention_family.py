@@ -32,8 +32,12 @@ def test_cell_configuration_traffic_and_metrics_are_appended_entries(real):  # n
   assert [m['name'] for m in mine] == metrics
   for metric in mine:
     assert metric['moves'] == 'windows_per_s' and metric['layer'] == 'forward'
-  # The 14 metrics the benchmark had carry no list: all apply to the cell.
-  assert len(loaded.per_layer) == 18
-  assert [m['name'] for m in loaded.per_layer[-4:]] == metrics
+  # The metrics that carry no list (14 when the cell came, six more since
+  # PR 36) all apply to the cell.
+  shared = [m for m in bench['per_layer'] if 'workloads' not in m]
+  assert len(shared) >= 14
+  assert len(loaded.per_layer) == len(shared) + len(metrics)
+  assert [m['name'] for m in loaded.per_layer
+          if 'workloads' in m] == metrics
   assert set(loaded.limits) == {'id_gap_mean_vs_bf16',
                                 'qual_diff_mean_vs_bf16'}

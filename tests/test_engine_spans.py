@@ -74,14 +74,21 @@ def _raw(params, n, seed=0, width=None):
 
 
 def _traced(tmp_path, fn):
-  """Runs fn() with tracing on; returns the complete events."""
+  """Runs fn() with tracing on; returns the complete events. The runner
+  was built, and other tests compiled, with tracing off: the start-up
+  record they left is not this run's."""
   path = str(tmp_path / 'spans.jsonl')
+  trace_lib.clear_early()
   trace_lib.configure(path, tier='test')
   try:
     fn()
   finally:
     trace_lib.configure(None)
-  return [e for e in summarize_lib.load_trace(path) if e.get('ph') == 'X']
+  # Without what the stub's eager jnp calls compiled at first use under
+  # `forward_launch` (obs/compiles.py): whether they did depends on what
+  # ran before in the process.
+  return [e for e in summarize_lib.load_trace(path) if e.get('ph') == 'X'
+          and e['name'] not in trace_lib.COMPILE_SPANS]
 
 
 def _by_name(events):
@@ -412,6 +419,7 @@ def test_dctpu_trace_prints_self_time_and_no_gap_accounting(
   engine, _ = _engine(params)
   windows = list(_raw(params, 2 * BATCH + 3))
   path = str(tmp_path / 'spans.jsonl')
+  trace_lib.clear_early()  # the runner's start-up is not under a submit
   trace_lib.configure(path, tier='run')
   try:
     engine.submit(windows, list(range(len(windows))))

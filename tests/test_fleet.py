@@ -1061,6 +1061,12 @@ def test_preemption_notice_drains_replica_and_exits_clean(
   while 'port' not in ready and time.monotonic() < deadline:
     time.sleep(0.01)
   assert 'port' in ready
+  # The ready line says where start-up went, beside warmup_s.
+  assert ready['warmup_s'] >= 0
+  assert set(ready['startup']) == {
+      'import_s', 'checkpoint_s', 'weights_s', 'jit_trace_s',
+      'jit_lower_s', 'xla_compile_s', 'n_xla_compiles', 'n_xla_cache_hits'}
+  assert ready['startup']['import_s'] > 0
   # The replica serves normally until the notice lands.
   client = ServeClient(port=ready['port'], timeout=10)
   assert client.polish(**_mol(params, 'p/1/ccs'))['status'] == 'ok'

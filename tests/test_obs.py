@@ -15,6 +15,10 @@ Covers the four obs subsystems in isolation plus their contracts:
     `args.span` / `args.parent`, and the buffered writer (nothing
     before the threshold, everything at close, whole lines, an empty
     buffer after a fork);
+  * start-up and compiles — the start-up record that waits for
+    `configure()` (bounded, first in the file, empty after a fork) and
+    JAX's trace, lower and compile events as spans under the stage that
+    caused them, with their counters and histograms (obs/compiles.py);
   * summarize — per-stage totals, self time through `args.parent`,
     waits apart, critical-path ordering by self time, straggler
     extraction, span-derived overlap (launch-before-finalize ordering),
@@ -39,9 +43,11 @@ from deepconsensus_tpu.obs import trace as trace_lib
 
 @pytest.fixture(autouse=True)
 def _reset_trace():
-  """Each test starts and ends with tracing off and no trace id."""
+  """Each test starts and ends with tracing off and no trace id, and
+  starts with an empty start-up record (other tests compile too)."""
   trace_lib.configure(None)
   trace_lib.set_trace_id(None)
+  trace_lib.clear_early()
   yield
   trace_lib.configure(None)
   trace_lib.set_trace_id(None)
@@ -629,7 +635,7 @@ class TestSummarize:
     assert set(s) == {
         'n_events', 'n_spans', 'wall_s', 'tiers', 'stage_totals_s',
         'stage_counts', 'self_time', 'waits', 'critical_path', 'stragglers',
-        'forward', 'overlap', 'n_traces'}
+        'forward', 'startup', 'overlap', 'n_traces'}
     # No forward_launch span in this trace: the block says so and the
     # text leaves its line out.
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
@@ -773,3 +779,278 @@ class TestTraceCli:
     bad.write_text('{nope\n')
     assert cli.main(['trace', str(bad)]) == 2
     assert 'dctpu:' in capsys.readouterr().err
+
+
+# ---------------------------------------------------------------------------
+# Start-up record and compile events
+# ---------------------------------------------------------------------------
+
+
+class TestStartupRecord:
+
+  def _events(self, path):
+    return [e for e in summarize_lib.load_trace(path) if e['ph'] == 'X']
+
+  def test_startup_spans_wait_for_configure_and_come_first(self, tmp_path):
+    reg = metrics_lib.MetricsRegistry()
+    with obs_lib.stage(reg, trace_lib.STAGE_RUNNER_INIT) as st:
+      with obs_lib.stage(reg, trace_lib.STAGE_WEIGHTS_PLACE):
+        pass
+      st.set(weight_bytes=12)
+    obs_lib.record_stage(reg, trace_lib.STAGE_CHECKPOINT_LOAD, 1.0, 2.5,
+                         bytes=7)
+    # In-window names raised before configure() are not kept.
+    with obs_lib.stage(reg, 'submit'):
+      with obs_lib.stage(reg, 'dispatch'):
+        pass
+    obs_lib.record_stage(reg, trace_lib.STAGE_FEATURIZE, 3.0, 4.0)
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    with obs_lib.stage(reg, 'flush'):
+      pass
+    trace_lib.configure(None)
+    events = self._events(path)
+    assert [e['name'] for e in events] == [
+        'weights_place', 'runner_init', 'checkpoint_load', 'flush']
+    place, init, load, flush = events
+    # Stamped with tracing off: the stage above by name, no ids.
+    assert place['args'] == {'under': 'runner_init'}
+    assert init['args'] == {'weight_bytes': 12}
+    assert load['args'] == {'bytes': 7}
+    assert load['ts'] == pytest.approx(1.0 * 1e6)
+    assert load['dur'] == pytest.approx(1.5 * 1e6)
+    assert init['ts'] <= place['ts']
+    assert place['ts'] + place['dur'] <= init['ts'] + init['dur']
+    assert 'span' in flush['args']
+    assert reg.histogram('stage_runner_init_s').snapshot()['count'] == 1
+    # Written once: a second file starts empty.
+    second = str(tmp_path / 'second.jsonl')
+    trace_lib.configure(second)
+    trace_lib.configure(None)
+    assert self._events(second) == []
+
+  def test_the_list_stops_at_its_bound_and_counts_the_rest(
+      self, tmp_path, monkeypatch):
+    monkeypatch.setattr(trace_lib, 'EARLY_EVENTS', 4)
+    for i in range(7):
+      obs_lib.record_stage(None, trace_lib.STAGE_JIT_TRACE, float(i),
+                           i + 0.5, fun=f'f{i}')
+    assert trace_lib.early_events_dropped == 3
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path)
+    trace_lib.configure(None)
+    events = summarize_lib.load_trace(path)
+    # The oldest stay.
+    assert [e['args']['fun'] for e in events if e['ph'] == 'X'] == [
+        'f0', 'f1', 'f2', 'f3']
+    dropped = [e for e in events
+               if e['name'] == trace_lib.EARLY_DROPPED_EVENT]
+    assert [e['args']['count'] for e in dropped] == [3]
+    assert summarize_lib.startup(events)['early_events_dropped'] == 3
+    assert trace_lib.early_events_dropped == 0
+
+  def test_clear_early_empties_the_record(self, tmp_path):
+    obs_lib.record_stage(None, trace_lib.STAGE_IMPORT_RUNNER, 0.0, 1.0)
+    trace_lib.clear_early()
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path)
+    trace_lib.configure(None)
+    assert self._events(path) == []
+
+  @pytest.mark.skipif(not hasattr(os, 'fork'), reason='needs fork')
+  def test_forked_child_starts_with_no_startup_record(self, tmp_path):
+    obs_lib.record_stage(None, trace_lib.STAGE_IMPORT_RUNNER, 0.0, 1.0)
+    path = str(tmp_path / 'child.jsonl')
+    pid = os.fork()
+    if pid == 0:
+      try:
+        trace_lib.configure(path)
+        trace_lib.complete_event('child', 'stage', 1.0, 2.0)
+        trace_lib.configure(None)
+      finally:
+        os._exit(0)
+    _, status = os.waitpid(pid, 0)
+    assert status == 0
+    assert [e['name'] for e in self._events(path)] == ['child']
+    # The parent still holds its own.
+    mine = str(tmp_path / 'parent.jsonl')
+    trace_lib.configure(mine)
+    trace_lib.configure(None)
+    assert [e['name'] for e in self._events(mine)] == ['import_runner']
+
+  def test_stack_of_open_stages_is_kept_with_tracing_off(self):
+    assert not trace_lib.enabled()
+    with obs_lib.stage(None, 'dispatch', pack=3):
+      with obs_lib.stage(None, 'forward_launch', pack=3) as launch:
+        assert trace_lib._local.stack[-1] is launch
+        trace_lib.caused_event(trace_lib.STAGE_XLA_COMPILE, 1.0, 2.0,
+                               {'fun': 'jit(f)'})
+    assert trace_lib._local.stack == []
+    (event,) = trace_lib._early
+    assert event['args'] == {'fun': 'jit(f)', 'under': 'forward_launch',
+                             'pack': 3}
+
+
+def test_obs_imports_no_jax():
+  """The router has no jax: the listeners of obs/compiles.py are
+  registered where jax already is (inference/runner.py)."""
+  import subprocess
+  import sys
+
+  done = subprocess.run(
+      [sys.executable, '-c',
+       'import sys; import deepconsensus_tpu.obs; '
+       'import deepconsensus_tpu.obs.trace; '
+       'sys.exit(int("jax" in sys.modules))'],
+      cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+      timeout=60)
+  assert done.returncode == 0
+
+
+class TestCompileEvents:
+
+  COMPILE = ('jit_trace', 'jit_lower', 'xla_compile')
+
+  @pytest.fixture
+  def compiles_lib(self):
+    """The module; the registry a test binds is let go of afterwards."""
+    from deepconsensus_tpu.obs import compiles
+
+    yield compiles
+    compiles.install(metrics_lib.MetricsRegistry())
+
+  @staticmethod
+  def _jitted():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+      return jnp.sin(x) * 2.0
+
+    def forward(x):
+      for _ in range(3):
+        x = inner(x) + jnp.tanh(x)
+      return x
+
+    return jax.jit(forward), jnp.ones((8, 16))
+
+  def test_a_jit_under_a_stage_yields_its_three_spans(
+      self, tmp_path, monkeypatch, compiles_lib):
+    monkeypatch.setattr(compiles_lib, 'MIN_SPAN_S', 0.0)
+    forward, x = self._jitted()
+    x.block_until_ready()  # the array's own programs compile out here,
+    trace_lib.clear_early()  # and are not this test's
+    reg = metrics_lib.MetricsRegistry()
+    compiles_lib.install(reg)
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    with obs_lib.stage(reg, trace_lib.STAGE_LAUNCH, pack=5):
+      forward(x)
+    with obs_lib.stage(reg, trace_lib.STAGE_LAUNCH, pack=6):
+      forward(x)  # the same shape: nothing fires
+    trace_lib.configure(None)
+    events = [e for e in summarize_lib.load_trace(path) if e['ph'] == 'X']
+    first, second = [e for e in events if e['name'] == 'forward_launch']
+    caused = [e for e in events if e['name'] in self.COMPILE]
+    assert {e['name'] for e in caused} == set(self.COMPILE)
+    for e in caused:
+      assert e['cat'] == 'stage'
+      assert e['args']['under'] == 'forward_launch'
+      assert e['args']['parent'] == first['args']['span']
+      assert e['args']['pack'] == 5
+      assert e['args']['span'] not in (first['args']['span'],
+                                       second['args']['span'])
+      assert first['ts'] <= e['ts']
+      assert e['ts'] + e['dur'] <= first['ts'] + first['dur'] + 1.0
+    by_fun = {(e['name'], e['args']['fun']) for e in caused}
+    assert ('jit_trace', 'forward') in by_fun
+    assert ('jit_trace', 'inner') in by_fun
+    assert ('jit_lower', 'jit(forward)') in by_fun
+    (compiled,) = [e for e in caused if e['name'] == 'xla_compile']
+    assert compiled['args']['fun'] == 'jit(forward)'
+    assert compiled['args']['cache_hit'] in (True, False)
+    # Counters and histograms hold the same intervals.
+    after = reg.counter_values()
+    n = {name: sum(1 for e in caused if e['name'] == name)
+         for name in self.COMPILE}
+    assert after['xla_compiles_total'] == n['xla_compile'] == 1
+    assert after['jit_traces_total'] == n['jit_trace'] >= 2
+    snap = reg.snapshot()
+    for name in self.COMPILE:
+      hist = snap['histograms'][f'stage_{name}_s']
+      assert hist['count'] == n[name]
+      assert hist['sum'] == pytest.approx(
+          sum(e['dur'] for e in caused if e['name'] == name) / 1e6,
+          abs=1e-5)
+    # The seconds gauges count a nested trace once: the outermost's own.
+    outer = max((e for e in caused if e['name'] == 'jit_trace'),
+                key=lambda e: e['dur'])
+    assert outer['args']['fun'] == 'forward'
+    assert snap['gauges']['jit_trace_seconds'] == pytest.approx(
+        outer['dur'] / 1e6, abs=1e-5)
+    assert snap['gauges']['jit_trace_seconds'] < (
+        snap['histograms']['stage_jit_trace_s']['sum'])
+    split = compiles_lib.startup_split(reg)
+    assert split['n_xla_compiles'] == 1
+    assert split['xla_compile_s'] == pytest.approx(
+        compiled['dur'] / 1e6, abs=1e-3)
+    assert compiles_lib.format_startup(split).startswith('start-up: import')
+    # dctpu trace: seconds by the stage above, nested traces once.
+    startup = summarize_lib.summarize(
+        summarize_lib.load_trace(path))['startup']
+    kinds = startup['compile_kinds']
+    assert kinds['jit_trace']['count'] == n['jit_trace']
+    assert kinds['jit_trace']['total_s'] == pytest.approx(
+        outer['dur'] / 1e6, abs=1e-5)
+    assert list(kinds['xla_compile']['under']) == ['forward_launch']
+    assert startup['top_traced'][0]['fun'] == 'forward'
+    assert startup['compiles_after_first_submit'] == []
+
+  def test_short_events_are_counted_and_not_written(
+      self, tmp_path, monkeypatch, compiles_lib):
+    monkeypatch.setattr(compiles_lib, 'MIN_SPAN_S', 1e9)
+    forward, x = self._jitted()
+    reg = metrics_lib.MetricsRegistry()
+    compiles_lib.install(reg)
+    path = str(tmp_path / 'trace.jsonl')
+    trace_lib.configure(path, tier='run')
+    with obs_lib.stage(reg, trace_lib.STAGE_LAUNCH, pack=1):
+      forward(x)
+    trace_lib.configure(None)
+    names = [e['name'] for e in summarize_lib.load_trace(path)
+             if e['ph'] == 'X']
+    assert names == ['forward_launch']
+    assert reg.counter_values()['jit_traces_total'] >= 2
+    assert reg.counter_values()['xla_compiles_total'] >= 1
+    assert reg.histogram('stage_jit_lower_s').snapshot()['count'] >= 1
+
+  def test_a_compile_after_the_first_submit_names_its_pack(self):
+    events = [
+        _span('submit', 10.0, 5.0, span=1),
+        _span('xla_compile', 2.0, 1.0, fun='jit(forward)',
+              under='forward_launch', pack=1, cache_hit=True),
+        _span('forward_launch', 12.0, 2.5, span=2, parent=1, pack=9),
+        _span('jit_trace', 12.0, 0.5, fun='forward', under='forward_launch',
+              pack=9, parent=2, span=3),
+        _span('jit_trace', 12.1, 0.2, fun='inner', under='forward_launch',
+              pack=9, parent=2, span=4),
+        _span('xla_compile', 12.5, 2.0, fun='jit(forward)',
+              under='forward_launch', pack=9, parent=2, span=5,
+              cache_hit=False),
+    ]
+    summary = summarize_lib.summarize(events)
+    assert summary['startup']['compiles_after_first_submit'] == [
+        {'fun': 'jit(forward)', 'under': 'forward_launch', 'pack': 9,
+         'dur_s': 2.0, 'cache_hit': False}]
+    assert summary['startup']['compile_kinds']['xla_compile'][
+        'cache_hits'] == 1
+    # The launch's self time: its 2.5 s less the trace (nested one once)
+    # and the compile.
+    assert summary['self_time']['forward_launch']['self_s'] == (
+        pytest.approx(0.0))
+    assert summary['self_time']['jit_trace']['total_s'] == pytest.approx(0.5)
+    text = summarize_lib.format_summary(summary)
+    assert 'start-up and compiles:' in text
+    assert 'compiles after the first submit: 1' in text
+    assert 'jit(forward) 2.0000s under forward_launch pack=9' in text
