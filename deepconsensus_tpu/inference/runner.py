@@ -1153,8 +1153,8 @@ class ModelRunner:
     """What the compiled forward of this pack takes of the kernels the
     model chooses by itself: `attention_path` for its attention sublayers,
     where it has Gated DeltaNet mixers `delta_rule_path` and where it has
-    sparse experts `grouped_product_path`; the model's own rules, asked as
-    the forward's trace asks them."""
+    sparse experts `grouped_product_path` and `combine_path`; the model's
+    own rules, asked as the forward's trace asks them."""
     if 'transformer' not in self.params.model_name:
       return {'attention_path': model_lib.ATTENTION_XLA}
     with pallas_util.single_device_inference(self._single_device):
@@ -1164,6 +1164,8 @@ class ModelRunner:
           'delta_rule_path': model_lib.delta_rule_path(
               self.params, length=length),
           'grouped_product_path': model_lib.grouped_product_path(
+              self.params, batch=batch, length=length),
+          'combine_path': model_lib.combine_path(
               self.params, batch=batch, length=length),
       }
     return {name: path for name, path in paths.items() if path is not None}

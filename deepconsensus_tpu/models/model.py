@@ -789,6 +789,22 @@ def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
       p.hidden_size, p.moe_intermediate_size, p.get('dtype', 'float32'))
 
 
+def combine_path(p, *, batch: int, length: int) -> Optional[str]:
+  """How a forward of this pack adds up what its held experts computed
+  (`forward_launch`'s `combine_path`): `token_tile_kernel`, one Pallas call
+  a turn that reads the held assignments' rows by its own copies, or
+  `gather`, XLA's gather and sum; None for a block kind without sparse
+  experts. The rule is ops/moe.py::combine_path, asked with the tokens of
+  one turn as `held_experts` asks it where the forward is traced; no option
+  asks for the kernel."""
+  if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
+    return None
+  tokens, k = batch * length, p.num_experts_per_tok
+  return moe.combine_path(
+      tokens // moe.turns_of(tokens, k), k, p.experts_held_count,
+      p.hidden_size, p.get('dtype', 'float32'))
+
+
 def _attn_softmax_dtype(p):
   return jnp.dtype(p.get('attn_softmax_dtype', None) or 'float32')
 

@@ -378,6 +378,8 @@ def summarize(events: List[Dict[str, Any]],
       'grouped_product_paths': sorted(
           {str(a['grouped_product_path']) for a in launches
            if a.get('grouped_product_path')}),
+      'combine_paths': sorted({str(a['combine_path']) for a in launches
+                               if a.get('combine_path')}),
       'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
                                 if a.get('layer_pattern')}),
       'ffn_patterns': sorted({str(a['ffn_pattern']) for a in launches
@@ -486,9 +488,11 @@ def format_summary(summary: Dict[str, Any]) -> str:
       ffn = ', '.join(forward.get('ffn_patterns', ()))
       scoring = ', '.join(forward.get('router_scorings', ()))
       grouped = ', '.join(forward.get('grouped_product_paths', ()))
+      combine = ', '.join(forward.get('combine_paths', ()))
       experts = '; '.join(
           f'{what}: {said}' for what, said in (
-              ('router', scoring), ('grouped products', grouped)) if said)
+              ('router', scoring), ('grouped products', grouped),
+              ('combine', combine)) if said)
       lines.append(
           f'  layers: {", ".join(forward["layer_patterns"])}'
           + (f' (delta rule: {delta_rule})' if delta_rule else '') + ''.join(

@@ -35,6 +35,16 @@ shapes at 37-38% of the chip's peak (74.6 TFLOP/s where the same program's
 plain products reach 159-187; PERF.md, PR 34), which is why it was
 replaced there.
 
+The combine adds, for every token, the rows of the down product's output
+that its held assignments point at. `combine_path` says how, by the same
+conditions: on one TPU one Pallas call a turn (ops/moe_combine.py) reads,
+for a tile of 128 tokens, each held expert's run of rows by its own copies
+of whole 8-row blocks into VMEM and brings them to their tokens by a 0/1
+product with a float32 accumulator; no [k, tokens, hidden] copy is
+written and an assignment held elsewhere is not read. Everywhere else
+XLA's gather of one row an assignment and a float32 sum over k, which on a
+v5e ran at 34-46 ns a row of 4 kB out of HBM (PERF.md, PR 35 and PR 37).
+
 Tokens are taken MAX_ROWS assignments at a time (jax.lax.map): the sorted
 copy of the tokens and the experts' output are [tokens x k, hidden] each,
 4 kB a row at hidden 2048.
@@ -47,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from deepconsensus_tpu.ops import grouped_product
+from deepconsensus_tpu.ops import moe_combine
 from deepconsensus_tpu.ops import pallas_util
 
 MAX_ROWS = 1 << 18
@@ -55,6 +66,11 @@ MAX_ROWS = 1 << 18
 # `grouped_product_path`, docs/observability.md).
 GROUPED_GROUP_KERNEL = 'group_kernel'
 GROUPED_RAGGED_DOT = 'ragged_dot'
+
+# Which form of the combine a turn runs (`forward_launch`'s `combine_path`,
+# docs/observability.md).
+COMBINE_TOKEN_TILE_KERNEL = 'token_tile_kernel'
+COMBINE_GATHER = 'gather'
 
 SCORING_SOFTMAX = 'softmax'
 SCORING_SIGMOID = 'sigmoid'
@@ -108,6 +124,20 @@ def grouped_product_path(rows: int, groups: int, k: int, n: int,
   return GROUPED_GROUP_KERNEL if kernel else GROUPED_RAGGED_DOT
 
 
+def combine_path(n: int, k: int, groups: int, hidden: int, dtype) -> str:
+  """The one rule by which a turn's combine, n tokens of k assignments over
+  `groups` held experts, takes the Pallas kernel a tile of tokens
+  (ops/moe_combine.py) in place of XLA's gather and sum; no option asks for
+  it. bfloat16 rows of whole lane tiles, n a multiple of the tile, buffers
+  that fit the kernel's VMEM, and a TPU in a trace its caller declared
+  inference for one device (pallas_util.may_choose_kernels)."""
+  kernel = (
+      jnp.dtype(dtype) == jnp.bfloat16
+      and moe_combine.fits(n, k, groups, hidden)
+      and pallas_util.may_choose_kernels())
+  return COMBINE_TOKEN_TILE_KERNEL if kernel else COMBINE_GATHER
+
+
 def turns_of(n: int, k: int) -> int:
   """In how many turns `held_experts` takes n tokens of k assignments."""
   turns = 1
@@ -151,12 +181,20 @@ def _held_experts(x, weights, experts, w_gate, w_up, w_down, first: int):
       hidden = hidden * grouped(rows, w_up).astype(jnp.float32)
       out = grouped((hidden * row_weight[:, None]).astype(x.dtype), w_down)
   with jax.named_scope('combine'):
-    # Where each assignment's row went: the inverse of the sort. The rows
-    # come back one assignment of every token after another ([k, n, H]: a
-    # token's k rows are then k planes to add, and no [n, k, H] array is
-    # laid out anew).
+    # Where each assignment's row went: the inverse of the sort.
     _, place = jax.lax.sort((order, jnp.arange(n * k, dtype=jnp.int32)),
                             num_keys=1)
+    if combine_path(n, k, held, x.shape[1], x.dtype) == (
+        COMBINE_TOKEN_TILE_KERNEL):
+      # A tile of tokens a grid step: the held rows come by the kernel's
+      # own copies and are added in VMEM; held elsewhere is place -1.
+      y = moe_combine.combine(
+          out, group.reshape(n, k), jnp.where(mine, place.reshape(n, k), -1),
+          bounds)
+      return y, counts
+    # The rows come back one assignment of every token after another
+    # ([k, n, H]: a token's k rows are then k planes to add, and no
+    # [n, k, H] array is laid out anew).
     mine_t = mine.T  # [k, n]
     # An assignment held elsewhere reads row 0 (any row: it is masked
     # below), so that half the reads do not wander over rows nobody wrote.

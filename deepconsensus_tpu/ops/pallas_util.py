@@ -84,6 +84,14 @@ BATCH_TILE_VMEM_LIMIT_BYTES = 48 << 20
 # compiles within 20 MiB and not within the default 16.
 GROUPED_PRODUCT_VMEM_LIMIT_BYTES = 32 << 20
 
+# Scoped VMEM for the experts' combine (ops/moe_combine.py): two buffers of
+# a tile's runs in bfloat16 rows of 4 KiB at hidden 2048, 128 * k rows and
+# up to 14 rows of other tokens a run: 2,560 rows at k = 6 over 128 groups
+# (2 x 10 MiB), 5,120 at k = 10 over 256 (2 x 20 MiB); the accumulator, a
+# product, the places along the lanes and the blocks' double buffers are 4
+# MiB more (moe_combine.vmem_bytes: 23.5 / 43.7 MiB).
+COMBINE_VMEM_LIMIT_BYTES = 56 << 20
+
 
 def batch_tile_compiler_params(
     vmem_limit_bytes: int = BATCH_TILE_VMEM_LIMIT_BYTES):

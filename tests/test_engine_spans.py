@@ -531,6 +531,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     assert 'delta_rule_path' not in e['args']
     # Nor grouped products: no sparse experts.
     assert 'grouped_product_path' not in e['args']
+    assert 'combine_path' not in e['args']
   stats = engine.stats()
   assert stats['block_kind'] == kind
   assert stats['model_weight_bytes'] == 62
@@ -545,6 +546,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
                      'grouped_product_paths': [],
+                     'combine_paths': [],
                      'layer_patterns': [config_lib.layer_pattern(p)],
                      'ffn_patterns': [config_lib.ffn_pattern(p)],
                      'router_scorings': [], 'experts_held': [],

@@ -80,6 +80,7 @@ def test_engine_submit_to_delivery_serves_the_reference_bases(length,
     # The CPU takes no kernel on its own; nor do heads of 8 anywhere.
     assert args['delta_rule_path'] == 'plain'
     assert args['grouped_product_path'] == 'ragged_dot'
+    assert args['combine_path'] == 'gather'
     assert args['layer_pattern'] == 'GGGS'
     assert args['ffn_pattern'] == 'EEEE'
     assert args['router_scoring'] == 'softmax'
@@ -119,11 +120,12 @@ def test_dctpu_trace_shows_the_pattern_and_the_held_share(tmp_path, capsys):
   assert forward['attention_paths'] == ['xla']
   assert forward['delta_rule_paths'] == ['plain']
   assert forward['grouped_product_paths'] == ['ragged_dot']
+  assert forward['combine_paths'] == ['gather']
   assert forward['layer_patterns'] == ['GGGS']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
   assert ('layers: GGGS (delta rule: plain); experts 8-15 of 16 held '
-          '(router: softmax; grouped products: ragged_dot); '
+          '(router: softmax; grouped products: ragged_dot; combine: gather); '
           'feed-forward: EEEE' in capsys.readouterr().out)
 
 
@@ -193,6 +195,34 @@ def test_grouped_product_path_is_the_kernel_on_one_tpu_at_lane_tile_widths(
     got = model_lib.grouped_product_path(p, batch=batch, length=100)
   assert got == {'tpu': 'group_kernel', 'other_kind': None}.get(
       where, 'ragged_dot')
+
+
+@pytest.mark.parametrize('where', ['cpu', 'tpu', 'tpu_mesh', 'tpu_float32',
+                                   'tpu_toy_widths', 'tpu_odd_pack',
+                                   'other_kind'])
+def test_combine_path_is_the_kernel_on_one_tpu_at_lane_tile_widths(
+    where, monkeypatch):
+  """`forward_launch`'s `combine_path`: the model's rule asked as the
+  runner asks it, with the tokens of one turn of the pack. bfloat16 rows of
+  whole lane tiles (the published 2048 is) on one TPU device at inference,
+  a turn's tokens whole tiles of 128, take the kernel a tile of tokens; the
+  CPU, a mesh, float32, the toy widths and a pack whose tokens no tile
+  divides take XLA's gather; a kind without sparse experts says nothing."""
+  from deepconsensus_tpu.ops import pallas_util
+
+  sizes = {} if where == 'tpu_toy_widths' else dict(
+      transformer_input_size=128, moe_intermediate_size=256)
+  p = tiny_params(100, dtype='float32' if where == 'tpu_float32'
+                  else 'bfloat16', **sizes)
+  if where == 'other_kind':
+    p = config_lib.get_config('transformer_learn_values+custom')
+    config_lib.finalize_params(p, is_training=False)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: where != 'cpu')
+  batch = 5 if where == 'tpu_odd_pack' else 32  # 5 x 100 = 500 tokens
+  with pallas_util.single_device_inference(where != 'tpu_mesh'):
+    got = model_lib.combine_path(p, batch=batch, length=100)
+  assert got == {'tpu': 'token_tile_kernel', 'other_kind': None}.get(
+      where, 'gather')
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

@@ -197,6 +197,7 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   assert launch['block_kind'] == KIND and launch['attention_path'] == 'xla'
   assert 'delta_rule_path' not in launch
   assert launch['grouped_product_path'] == 'ragged_dot'
+  assert launch['combine_path'] == 'gather'
   assert launch['layer_pattern'] == 'LLL' and launch['ffn_pattern'] == 'DEE'
   assert launch['experts_held'] == [8, 16]
   assert launch['experts_published'] == 16
@@ -251,13 +252,14 @@ def test_dctpu_trace_lists_both_patterns_and_the_router(tmp_path, capsys):
   assert forward['attention_paths'] == ['xla']
   assert forward['delta_rule_paths'] == []
   assert forward['grouped_product_paths'] == ['ragged_dot']
+  assert forward['combine_paths'] == ['gather']
   assert forward['layer_patterns'] == ['LLL']
   assert forward['ffn_patterns'] == ['DEE']
   assert forward['router_scorings'] == ['sigmoid_bias']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
   assert ('layers: LLL; experts 8-15 of 16 held (router: sigmoid_bias; '
-          'grouped products: ragged_dot); feed-forward: DEE'
+          'grouped products: ragged_dot; combine: gather); feed-forward: DEE'
           in capsys.readouterr().out)
 
 
@@ -376,6 +378,12 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
                        moe_intermediate_size=256)
     assert model_lib.grouped_product_path(wide, batch=8, length=100) == (
         'group_kernel')
+    # The combine likewise: rows of whole lane tiles, and a turn's tokens
+    # (32 x 100) whole tiles of 128.
+    assert model_lib.combine_path(p, batch=32, length=100) == 'gather'
+    assert model_lib.combine_path(wide, batch=32, length=100) == (
+        'token_tile_kernel')
+    assert model_lib.combine_path(wide, batch=5, length=100) == 'gather'
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

@@ -122,12 +122,15 @@ def test_shares_and_the_shared_expert_once_are_the_uncut_layer(cuts, router):
 @pytest.mark.parametrize('cuts', [((0, 8), (8, 8)), ((0, 1), (1, 15))],
                          ids=['halves', 'one_and_the_rest'])
 def test_shares_add_up_through_the_group_kernel(cuts, router, monkeypatch):
-  """As on one TPU: at widths of a lane tile in bfloat16 the rule
-  (ops/moe.py::grouped_product_path) takes the Pallas kernel, interpreted
-  here. The shares still add up to the uncut layer, the counts are those
-  `ragged_dot`'s path gives, and no assignment is lost or doubled."""
+  """As on one TPU: at widths of a lane tile in bfloat16 the rules
+  (ops/moe.py::grouped_product_path, ::combine_path) take the Pallas
+  kernels, interpreted here: the grouped products' and the combine's, a
+  tile of 128 tokens a step. The shares still add up to the uncut layer,
+  the counts are those `ragged_dot`'s path gives, and no assignment is lost
+  or doubled."""
   import sys
 
+  from deepconsensus_tpu.ops import moe_combine
   from deepconsensus_tpu.ops import pallas_util
 
   for name in ('H', 'F'):
@@ -144,8 +147,17 @@ def test_shares_add_up_through_the_group_kernel(cuts, router, monkeypatch):
   with pallas_util.single_device_inference():
     assert moe.grouped_product_path(4 * 32 * K, 8, H, F, x.dtype) == (
         moe.GROUPED_GROUP_KERNEL)
+    combined = []
+    real = moe_combine.combine
+    monkeypatch.setattr(
+        moe_combine, 'combine',
+        lambda *a, **k: combined.append(1) or real(*a, **k))
+    for _, count in cuts:
+      assert moe.combine_path(4 * 32, K, count, H, x.dtype) == (
+          moe.COMBINE_TOKEN_TILE_KERNEL)
     kernel = [apply(first, count, weights, x, router=router,
                     dtype=jnp.bfloat16) for first, count in cuts]
+    assert len(combined) == len(cuts)
   total = shared
   for (out, took), (plain_out, plain_took) in zip(kernel, plain):
     assert np.array_equal(took, plain_took)

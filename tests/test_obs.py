@@ -641,6 +641,7 @@ class TestSummarize:
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
                             'attention_paths': [], 'delta_rule_paths': [],
                             'grouped_product_paths': [],
+                            'combine_paths': [],
                             'layer_patterns': [], 'ffn_patterns': [],
                             'router_scorings': [], 'experts_held': [],
                             'n_positions': 0, 'weight_bytes': 0}

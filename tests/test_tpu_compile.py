@@ -9,8 +9,9 @@ itself among them, and 256 for the loss; two layers of the power-retention block
 kind at hidden 5120, batch 256; one period of the gated-delta and
 sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512; one
 dense and one expert layer of the latent-attention kind at hidden 2048 with
-128 experts, batch 512; the grouped-product kernel both take, alone at one
-turn of each). A compile that passes here is not a
+128 experts, batch 512; the grouped-product kernel and the combine's
+kernel both take, each alone at one turn of each). A compile that passes
+here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
 
@@ -180,7 +181,8 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   0-255 of 512 in each, bfloat16 leaves, a pack of 512 windows, by shape
   alone (no array of the 6.3 GiB is made). As ModelRunner traces it
   without a mesh: the delta rule takes its window kernel, the grouped
-  products the kernel whose grid follows the groups."""
+  products the kernel whose grid follows the groups, the combine the
+  kernel a tile of tokens."""
   p = config_lib.get_config('transformer_learn_values_gdn_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 4
@@ -205,8 +207,9 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   compiled = jax.jit(forward).lower(variables, rows).compile()
   text = compiled.as_text()
   # One window kernel a DeltaNet layer, and in every layer two calls of the
-  # grouped products' kernel (gate and up as one, down): none of the
-  # compiler's own grouped products, no masked dense product a group.
+  # grouped products' kernel (gate and up as one, down) and one of the
+  # combine's: none of the compiler's own grouped products, no masked dense
+  # product a group.
   assert text.count('gated_delta_window') >= 3
   # The kernel takes the flat stream of each direction as the convolution
   # leaves it and writes the gated norm's output in the stream's type: no
@@ -218,10 +221,16 @@ def test_gated_delta_hybrid_forward_b512_at_published_widths(
   assert 'f32[512,100,4096]' not in text
   assert 'ragged-dot' not in text
   # (A layer's two turns are one loop, which the compiler may unroll.)
-  assert text.count(' custom-call(') >= 3 + 4 * 2
+  assert text.count(' custom-call(') >= 3 + 4 * 3
   assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (4, 8)
   assert len(re.findall(r'%grouped_product\S* = ', text)) in (4, 8)
-  assert _n_kernels(compiled) in (3 + 4 * 2, 3 + 8 * 2)
+  assert len(re.findall(r'%moe_combine\S* = ', text)) in (4, 8)
+  assert _n_kernels(compiled) in (3 + 4 * 3, 3 + 8 * 3)
+  # The combine's kernel copies the held rows itself: no gather of the
+  # down product's output is left, and no [k, tokens, hidden] array of the
+  # rows that came back.
+  assert 'combine/jit(_take)/gather' not in text
+  assert 'bf16[10,25600,2048]' not in text
   # Gate and up never leave their kernel: no [rows, width] pair in float32
   # and no second bfloat16 one beside the gated product.
   assert 'f32[256000,512]' not in text
@@ -248,7 +257,7 @@ def test_latent_attention_moe_forward_b512_at_published_widths(
   the published widths, all 128 experts, bfloat16 leaves, a pack of 512
   windows. As ModelRunner traces it without a mesh: the attention is plain
   products, the grouped products the kernel whose grid follows the
-  groups."""
+  groups, the combine the kernel a tile of tokens."""
   p = config_lib.get_config('transformer_learn_values_mla_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 2
@@ -279,7 +288,12 @@ def test_latent_attention_moe_forward_b512_at_published_widths(
   # (The layer's two turns are one loop, which the compiler may unroll.)
   assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (1, 2)
   assert len(re.findall(r'%grouped_product\S* = ', text)) in (1, 2)
-  assert _n_kernels(compiled) in (2, 4)
+  # And the combine as one call of its own: no gather of the down
+  # product's output, no [k, tokens, hidden] array.
+  assert len(re.findall(r'%moe_combine\S* = ', text)) in (1, 2)
+  assert _n_kernels(compiled) in (3, 6)
+  assert 'combine/jit(_take)/gather' not in text
+  assert 'bf16[6,25600,2048]' not in text
   assert 'f32[153600,768]' not in text
   # A turn's 25,600 tokens (100 MiB) stay in VMEM for the dispatch's gather
   # to read, as they did beside the compiler's own grouped products: with
@@ -330,6 +344,33 @@ def test_grouped_product_kernel_at_one_turn_of_both_cells(
   # routing weights as a column, which the chip pads to a lane tile a row.
   assert down.memory_analysis().temp_size_in_bytes < 1 << 20
   assert up.memory_analysis().temp_size_in_bytes < rows * 128 * 4 + (1 << 20)
+
+
+@pytest.mark.parametrize('k,groups', [(6, 128), (10, 256)],
+                         ids=['kanana_polish', 'qwen3next_polish'])
+def test_combine_kernel_at_one_turn_of_both_cells(one_chip, compiled_kernels,
+                                                  k, groups):
+  """The combine's kernel alone at one turn of the two cells that run it
+  (25,600 tokens of 6 assignments over 128 held experts and of 10 over
+  256, hidden 2048): 8-row copies out of a [rows, 2048] array in HBM, the
+  0/1 product, two buffers of a tile's runs within
+  pallas_util.COMBINE_VMEM_LIMIT_BYTES."""
+  from deepconsensus_tpu.ops import moe_combine
+
+  tokens, hidden = 25_600, 2048
+  assert moe_combine.fits(tokens, k, groups, hidden)
+  sds = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
+      shape, dtype, sharding=one_chip)
+  compiled = jax.jit(moe_combine.combine).lower(
+      sds((tokens * k, hidden), jnp.bfloat16), sds((tokens, k)),
+      sds((tokens, k)), sds((groups + 1,))).compile()
+  assert 'moe_combine' in compiled.as_text() and _n_kernels(compiled) == 1
+  # Beside the operands and y: the one copy of `out` that zeroing the rows
+  # behind the last held group costs where `out` is an argument (in the
+  # forward it is a temporary, updated where it lies), the tiles' lists of
+  # blocks and of buffer rows; nothing of [k, tokens, hidden].
+  assert compiled.memory_analysis().temp_size_in_bytes < (
+      tokens * k * hidden * 2 + (16 << 20))
 
 
 @pytest.mark.parametrize('length', [130, 512])
