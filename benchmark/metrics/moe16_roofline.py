@@ -1,0 +1,13 @@
+"""The routed experts' share of their roofline where a chip holds an eighth
+of each layer's experts: `moe128_roofline` under the name of the cell whose
+held experts are 16 of 128 (that entry lists one cell, and no entry may be
+edited). What router, dispatch, grouped products and combine need (the
+family's `moe_work` on the window's COUNTED held assignments, the held
+experts' bytes once a turn) / device seconds in scope `moe`. An uneven
+router cannot read over 100%: the work is what was routed. Only on a chip,
+and only from a program that counts its assignments."""
+from benchmark.metrics import moe128_roofline
+
+
+def read(r):
+  return moe128_roofline.read(r)

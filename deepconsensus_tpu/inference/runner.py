@@ -693,15 +693,22 @@ class ModelRunner:
         for leaf in jax.tree_util.tree_leaves(self.variables))
     self.obs.set_gauge('model_weight_bytes', self._weight_bytes)
     self._n_forward_positions = self.obs.counter('n_forward_positions')
-    # What `forward_launch` says of the stack: one letter a layer for its
-    # attention and one for its feed-forward (config.layer_pattern,
-    # config.ffn_pattern) and, for sparse experts, the share held and how
-    # the router scores.
+    # What `forward_launch` says of the stack: how a layer composes its
+    # sublayers (config.block_form), one letter a layer for its attention
+    # and one for its feed-forward (config.layer_pattern,
+    # config.ffn_pattern), the window of the layers that attend within one
+    # and, for sparse experts, the share held, how the router scores and
+    # how many shared experts are averaged.
     self._launch_fields = {}
     if 'transformer' in self.params.model_name:
       self._launch_fields.update(
+          block_form=config_lib.block_form(self.params),
           layer_pattern=config_lib.layer_pattern(self.params),
           ffn_pattern=config_lib.ffn_pattern(self.params))
+      if config_lib.LAYER_WINDOW_SOFTMAX in self._launch_fields[
+          'layer_pattern']:
+        self._launch_fields.update(
+            attention_window=int(self.params.sliding_window))
     self._sparse_experts = _holds_sparse_experts(self.params)
     if self._sparse_experts:
       first = int(self.params.experts_held_first)
@@ -710,6 +717,9 @@ class ModelRunner:
           experts_published=int(self.params.num_experts),
           router_scoring=str(self.params.router_scoring) + (
               '_bias' if self.params.router_selection_bias else ''))
+      if self.params.get('num_shared_experts', None):
+        self._launch_fields.update(
+            shared_experts=int(self.params.num_shared_experts))
       # Assignments the router made (positions x k x expert layers), those
       # that fell on held experts, and the most any one held expert took
       # in a pack of one layer.

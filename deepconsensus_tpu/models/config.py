@@ -135,16 +135,45 @@ def bucket_for(width: int, buckets):
 #                           with a balancing bias (ops/moe.py) plus an
 #                           ungated shared expert; pre-RMSNorm residuals,
 #                           final RMSNorm.
+#   parallel_window_moe     a block that is no chain of sublayers: ONE
+#                           bias-free LayerNorm, attention and feed-forward
+#                           both on its output, one addition (x + attn(u) +
+#                           ffn(u), u = LN(x)). Every layer's attention is
+#                           softmax attention over grouped heads without
+#                           q/k norm or gate; layer n has no positions at
+#                           all where (n + 1) % layer_switch == 0 and
+#                           otherwise rotates the whole head and attends
+#                           within `sliding_window` positions, both ways;
+#                           every layer's feed-forward is sparse experts
+#                           scored by a sigmoid without a bias (ops/moe.py)
+#                           plus `num_shared_experts` shared experts that
+#                           are averaged; final bias-free LayerNorm.
 BLOCK_BANDED_SOFTMAX = 'banded_softmax_relu'
 BLOCK_POWER_RETENTION = 'power_retention_swiglu'
 BLOCK_GATED_DELTA_MOE = 'gated_delta_hybrid_moe'
 BLOCK_LATENT_MOE = 'latent_attention_moe'
+BLOCK_PARALLEL_WINDOW_MOE = 'parallel_window_moe'
 BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION,
-               BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE)
+               BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
+               BLOCK_PARALLEL_WINDOW_MOE)
 # The kinds some of whose layers' feed-forward is sparse experts: what
 # they cannot run yet (--tp, int8, train, distill, export) is refused by
 # name.
-SPARSE_EXPERT_KINDS = (BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE)
+SPARSE_EXPERT_KINDS = (BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
+                       BLOCK_PARALLEL_WINDOW_MOE)
+
+# How a layer composes its two sublayers (`forward_launch`'s `block_form`):
+# one after the other, each behind a norm of its own (x + f(norm_1(x)), then
+# h + g(norm_2(h))), or both on ONE norm's output with one addition.
+FORM_SEQUENTIAL = 'sequential'
+FORM_PARALLEL = 'parallel'
+
+
+def block_form(params) -> str:
+  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
+  return FORM_PARALLEL if kind == BLOCK_PARALLEL_WINDOW_MOE else (
+      FORM_SEQUENTIAL)
+
 
 # A layer's attention, one letter a layer in `layer_pattern` (the
 # `forward_launch` span, docs/observability.md).
@@ -153,6 +182,8 @@ LAYER_POWER_RETENTION = 'R'
 LAYER_GATED_DELTA = 'G'
 LAYER_GATED_SOFTMAX = 'S'
 LAYER_LATENT = 'L'
+LAYER_WINDOW_SOFTMAX = 'W'
+LAYER_FULL_SOFTMAX = 'F'
 
 # A layer's feed-forward, one letter a layer in `ffn_pattern`: one dense
 # feed-forward of the kind's form, or sparse experts.
@@ -171,6 +202,11 @@ def layer_pattern(params) -> str:
     return ''.join(
         LAYER_GATED_SOFTMAX if (n + 1) % interval == 0 else LAYER_GATED_DELTA
         for n in layers)
+  if kind == BLOCK_PARALLEL_WINDOW_MOE:
+    switch = params.layer_switch
+    return ''.join(
+        LAYER_FULL_SOFTMAX if (n + 1) % switch == 0 else LAYER_WINDOW_SOFTMAX
+        for n in layers)
   letter = {BLOCK_BANDED_SOFTMAX: LAYER_BANDED_SOFTMAX,
             BLOCK_POWER_RETENTION: LAYER_POWER_RETENTION,
             BLOCK_LATENT_MOE: LAYER_LATENT}[kind]
@@ -179,12 +215,12 @@ def layer_pattern(params) -> str:
 
 def ffn_pattern(params) -> str:
   """The feed-forward of every layer of the stack, in order: sparse
-  experts in every layer of the gated-delta kind; in the latent-attention
-  kind dense in the first `first_k_dense_replace` layers and sparse
-  experts behind them; dense everywhere else."""
+  experts in every layer of the gated-delta and the parallel kinds; in the
+  latent-attention kind dense in the first `first_k_dense_replace` layers
+  and sparse experts behind them; dense everywhere else."""
   kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
   layers = range(params.num_hidden_layers)
-  if kind == BLOCK_GATED_DELTA_MOE:
+  if kind in (BLOCK_GATED_DELTA_MOE, BLOCK_PARALLEL_WINDOW_MOE):
     return FFN_EXPERTS * len(layers)
   if kind == BLOCK_LATENT_MOE:
     leading = params.first_k_dense_replace
@@ -431,6 +467,68 @@ def _set_transformer_learned_embeddings_mla_moe_hparams(params):
   params.experts_held_count = 128
   # Rotary positions take the sinusoidal encoding's place, and the
   # pre-RMSNorm residual the ReZero one's.
+  params.add_pos_encoding = False
+  params.rezero = False
+  params.attn_win_size = 0
+  # The published model has no dropout.
+  params.layer_postprocess_dropout = 0.0
+  params.attention_dropout = 0.0
+  params.relu_dropout = 0.0
+  params.dtype = 'bfloat16'
+  params.inference_dtype = 'bfloat16'
+  params.use_fused_hotpath = False
+
+
+def _set_transformer_learned_embeddings_parallel_moe_hparams(params):
+  """A fifth encoder block kind at the widths of a public 218B
+  sparse-expert language model with 25B active parameters: hidden 4096, 32
+  layers, each ONE bias-free LayerNorm (eps 1e-5) whose output both the
+  attention and the feed-forward read, added to the stream together. The
+  attention has 128 query / 8 key-value heads of 128 without q/k norm or
+  gate; three layers in four rotate the whole head (base 50,000) and attend
+  within 4,096 positions, the fourth has no positions and no mask. The
+  feed-forward is 128 routed experts of width 4096, 8 a token, scored by a
+  sigmoid without a bias or a factor and renormalised, plus the mean of 4
+  shared experts of width 4096 (run as one SwiGLU of 16384 times 1/4).
+  Behind this system's pile-up embedding and 5-way head, served in
+  bfloat16.
+
+  Which experts this process holds is a size of the configuration, as for
+  the other sparse-expert kinds. One v5e chip holds one period of the
+  pattern with an eighth of each layer's experts (--set num_hidden_layers=4
+  --set experts_held_count=16) and a pack of 256 windows
+  (docs/inference.md)."""
+  _set_transformer_learned_embeddings_hparams(params)
+  params.model_name = 'transformer_learn_values_parallel_moe'
+  params.block_kind = BLOCK_PARALLEL_WINDOW_MOE
+  params.transformer_input_size = 4096
+  params.num_hidden_layers = 32
+  params.layer_norm_eps = 1.0e-5
+  # Window and full attention, one full layer a `layer_switch` layers.
+  params.layer_switch = 4
+  params.sliding_window = 4096
+  params.num_heads = 128
+  params.num_kv_heads = 8
+  params.head_dim = 128
+  params.rope_theta = 5.0e4
+  # Sparse experts in every layer; filter_size is one expert's width.
+  params.first_k_dense_replace = 0
+  params.num_experts = 128
+  params.num_experts_per_tok = 8
+  params.moe_intermediate_size = 4096
+  params.filter_size = 4096
+  params.num_shared_experts = 4
+  params.shared_expert_combination = 'average'
+  params.shared_expert_intermediate_size = 4 * 4096
+  params.norm_topk_prob = True
+  params.router_scoring = 'sigmoid'
+  params.router_selection_bias = False
+  params.routed_scaling_factor = 1.0
+  params.shared_expert_gated = False
+  params.experts_held_first = 0
+  params.experts_held_count = 128
+  # Rotary positions (or none) take the sinusoidal encoding's place, and
+  # the parallel block the ReZero residual's.
   params.add_pos_encoding = False
   params.rezero = False
   params.attn_win_size = 0
@@ -692,6 +790,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
     _set_transformer_learned_embeddings_gdn_moe_hparams(params)
   elif model_config_name == 'transformer_learn_values_mla_moe':
     _set_transformer_learned_embeddings_mla_moe_hparams(params)
+  elif model_config_name == 'transformer_learn_values_parallel_moe':
+    _set_transformer_learned_embeddings_parallel_moe_hparams(params)
   else:
     raise ValueError(f'Unknown model_config_name: {model_config_name}')
 

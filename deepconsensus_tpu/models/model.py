@@ -304,6 +304,25 @@ class RMSNorm(nn.Module):
     return (y * scale).astype(self.dtype)
 
 
+class BiasFreeLayerNorm(nn.Module):
+  """(x - mean(x)) * rsqrt(var(x) + epsilon) * scale over the last axis, no
+  bias: reckoned in float32 (the variance of the centred values, two
+  passes) and returned in `dtype`."""
+
+  epsilon: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    scale = self.param('scale', nn.initializers.ones, (x.shape[-1],),
+                       jnp.float32).astype(jnp.float32)
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    y = centred * jax.lax.rsqrt(
+        jnp.mean(jnp.square(centred), axis=-1, keepdims=True) + self.epsilon)
+    return (y * scale).astype(self.dtype)
+
+
 def rotary_tables(length: int, head_dim: int, theta: float):
   """(cos, sin) [L, head_dim] float32 of the rotate-half rotary position
   embedding: frequencies theta**(-2i/head_dim) over the first half of the
@@ -462,14 +481,22 @@ class GatedDeltaNetMixer(nn.Module):
     return dense(self.hidden_size, 'out_proj')(out)
 
 
-class GatedSoftmaxAttention(nn.Module):
-  """Softmax attention over the whole window with grouped heads and an
-  output gate: the query projection yields, per head, the query and a
-  gate of the same size; q and k are RMSNorm'd over the head (zero-centred
-  weights) and rotated on the first `rotary_dim` of the head; query head
-  h reads key-value head h // (heads // kv heads); the attention's
-  output is multiplied by sigmoid(gate) before the output projection. No
-  biases."""
+class GroupedSoftmaxAttention(nn.Module):
+  """Softmax attention with grouped heads: query head h reads key-value
+  head h // (heads // kv heads). What a layer adds to that is its sizes:
+
+  `output_gate`  the query projection yields, per head, the query and a
+                 gate of the same size, and the attention's output is
+                 multiplied by sigmoid(gate) before the output projection;
+  `qk_norm`      q and k are RMSNorm'd over the head (zero-centred
+                 weights, `rms_norm_eps`);
+  `rotary_dim`   q and k are rotated on the first `rotary_dim` of the head
+                 (0: the layer has no positions at all);
+  `window`       position i attends to j only where |i - j| < window, both
+                 ways (None: the whole window). A window that covers the
+                 forward's length masks nothing and builds no mask.
+
+  No biases; the softmax is float32."""
 
   hidden_size: int
   num_heads: int
@@ -477,7 +504,10 @@ class GatedSoftmaxAttention(nn.Module):
   head_dim: int
   rotary_dim: int
   rope_theta: float
-  rms_norm_eps: float
+  rms_norm_eps: Optional[float] = None
+  output_gate: bool = True
+  qk_norm: bool = True
+  window: Optional[int] = None
   dtype: Any = jnp.float32
 
   @nn.compact
@@ -491,30 +521,41 @@ class GatedSoftmaxAttention(nn.Module):
     dense = lambda name, heads, width: nn.DenseGeneral(
         features=(heads, width), axis=-1, use_bias=False, dtype=self.dtype,
         kernel_init=nn.initializers.lecun_normal(), name=name)
-    head_norm = lambda name: RMSNorm(self.rms_norm_eps, zero_centred=True,
-                                     name=name)
-    query, gate = jnp.split(dense('query', n_q, 2 * d)(x), 2, axis=-1)
-    query = head_norm('query_norm')(query)
-    key = head_norm('key_norm')(dense('key', n_kv, d)(x))
+    if self.output_gate:
+      query, gate = jnp.split(dense('query', n_q, 2 * d)(x), 2, axis=-1)
+    else:
+      query = dense('query', n_q, d)(x)
+    key = dense('key', n_kv, d)(x)
+    if self.qk_norm:
+      head_norm = lambda name: RMSNorm(self.rms_norm_eps, zero_centred=True,
+                                       name=name)
+      query = head_norm('query_norm')(query)
+      key = head_norm('key_norm')(key)
     value = dense('value', n_kv, d)(x)
-    # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
-    # after norm and rotation in float32)
-    query = apply_rotary(query, self.rope_theta, self.rotary_dim).astype(
-        self.dtype)
-    # dclint: allow=dtype-downcast (as above)
-    key = apply_rotary(key, self.rope_theta, self.rotary_dim).astype(
-        self.dtype)
+    if self.rotary_dim:
+      # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+      # after norm and rotation in float32)
+      rotate = lambda t: apply_rotary(
+          t.astype(jnp.float32), self.rope_theta, self.rotary_dim).astype(
+              self.dtype)
+      query, key = rotate(query), rotate(key)
     with jax.named_scope('softmax'):
       grouped = query.reshape(batch, length, n_kv, n_q // n_kv, d)
       scores = jnp.einsum('blkgd,bmkd->bkglm', grouped, key,
                           preferred_element_type=jnp.float32)
-      weights = jax.nn.softmax(scores * jnp.float32(d ** -0.5), axis=-1)
+      scores = scores * jnp.float32(d ** -0.5)
+      if self.window is not None and length > self.window:
+        i = np.arange(length)
+        near = np.abs(i[:, None] - i[None, :]) < self.window
+        scores = jnp.where(near, scores, jnp.float32(-1e9))
+      weights = jax.nn.softmax(scores, axis=-1)
       out = jnp.einsum('bkglm,bmkd->blkgd', weights.astype(self.dtype), value,
                        preferred_element_type=jnp.float32)
-    out = out.reshape(batch, length, n_q, d) * jax.nn.sigmoid(
-        gate.astype(jnp.float32))
-    # dclint: allow=dtype-downcast (the gate is float32; the stream is the
-    # compute dtype)
+    out = out.reshape(batch, length, n_q, d)
+    if self.output_gate:
+      out = out * jax.nn.sigmoid(gate.astype(jnp.float32))
+    # dclint: allow=dtype-downcast (the values' sum and the gate are
+    # float32; the stream is the compute dtype)
     out = out.astype(self.dtype)
     return nn.DenseGeneral(
         features=self.hidden_size, axis=(-2, -1), use_bias=False,
@@ -598,7 +639,9 @@ class SparseExpertsFeedForward(nn.Module):
   by `routed_scale`; the products of the experts `held_first` ...
   `held_first + held_count - 1` alone, each a SwiGLU of `expert_width`;
   plus a SwiGLU of `shared_width`, times sigmoid(x w_s) where
-  `shared_gate`. The assignments each held expert took are sown as
+  `shared_gate` and times `shared_scale` (1 / m makes one SwiGLU of m x the
+  width the mean of m shared experts: the down product is linear). The
+  assignments each held expert took are sown as
   `assignments` in the `moe_counts` collection, for whoever asks for it."""
 
   hidden_size: int
@@ -613,6 +656,7 @@ class SparseExpertsFeedForward(nn.Module):
   selection_bias: bool = False
   routed_scale: float = 1.0
   shared_gate: bool = True
+  shared_scale: float = 1.0
   dtype: Any = jnp.float32
 
   @nn.compact
@@ -657,6 +701,8 @@ class SparseExpertsFeedForward(nn.Module):
         # dclint: allow=dtype-downcast (the gate is float32; the stream is
         # the compute dtype)
         shared = (share * shared.astype(jnp.float32)).astype(self.dtype)
+      if self.shared_scale != 1.0:
+        shared = shared * jnp.asarray(self.shared_scale, shared.dtype)
     return routed.reshape(batch, length, h) + shared
 
 
@@ -784,9 +830,10 @@ def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
   if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
     return None
   tokens, k = batch * length, p.num_experts_per_tok
+  dtype = p.get('dtype', 'float32')
   return moe.grouped_product_path(
-      tokens // moe.turns_of(tokens, k) * k, p.experts_held_count,
-      p.hidden_size, p.moe_intermediate_size, p.get('dtype', 'float32'))
+      tokens // moe.turns_of(tokens, k, p.hidden_size, dtype) * k,
+      p.experts_held_count, p.hidden_size, p.moe_intermediate_size, dtype)
 
 
 def combine_path(p, *, batch: int, length: int) -> Optional[str]:
@@ -800,9 +847,10 @@ def combine_path(p, *, batch: int, length: int) -> Optional[str]:
   if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
     return None
   tokens, k = batch * length, p.num_experts_per_tok
+  dtype = p.get('dtype', 'float32')
   return moe.combine_path(
-      tokens // moe.turns_of(tokens, k), k, p.experts_held_count,
-      p.hidden_size, p.get('dtype', 'float32'))
+      tokens // moe.turns_of(tokens, k, p.hidden_size, dtype), k,
+      p.experts_held_count, p.hidden_size, dtype)
 
 
 def _attn_softmax_dtype(p):
@@ -812,7 +860,9 @@ def _attn_softmax_dtype(p):
 def _sparse_experts(p, n: int, dtype):
   """Layer n's sparse experts: every size, the router's scoring, bias and
   factor and the shared expert's gate among them, as the configuration
-  states it."""
+  states it. Shared experts that are averaged run as one of their summed
+  width, times one over their number."""
+  averaged = p.get('shared_expert_combination', None) == 'average'
   return SparseExpertsFeedForward(
       hidden_size=p.hidden_size,
       num_experts=p.num_experts,
@@ -826,23 +876,50 @@ def _sparse_experts(p, n: int, dtype):
       selection_bias=p.router_selection_bias,
       routed_scale=p.routed_scaling_factor,
       shared_gate=p.shared_expert_gated,
+      shared_scale=1.0 / p.num_shared_experts if averaged else 1.0,
       dtype=dtype,
       name=f'moe_{n}',
   )
 
 
 def _block_modules(p, n: int, dtype):
-  """(attention, feed-forward, wrap) of encoder layer `n` for the
+  """(attention, feed-forward, wrap, norm) of encoder layer `n` for the
   configuration's block kind and, where the kind's layers are not alike,
   the layer's place in the patterns (config.layer_pattern for the
   attention, config.ffn_pattern for the feed-forward): the one place
-  that knows the kinds. `wrap(sublayer, name)` gives the kind's residual
-  form. Called inside EncoderStack's compact method, so the modules are
-  its children."""
+  that knows the kinds. The last two say the form of the block
+  (config.block_form) and one of them is None: a sequential block has
+  `wrap(sublayer, name)`, the kind's residual form around each sublayer; a
+  parallel block has `norm`, the ONE norm both sublayers read. Called
+  inside EncoderStack's compact method, so the modules are its children."""
   kind = block_kind_of(p)
   gated_ffn = lambda: GatedFeedForward(
       hidden_size=p.hidden_size, filter_size=p.filter_size, dtype=dtype,
       name=f'ffn_{n}')
+  if kind == config_lib.BLOCK_PARALLEL_WINDOW_MOE:
+    if p.first_k_dense_replace:
+      raise ValueError(
+          f'first_k_dense_replace {p.first_k_dense_replace} is not served: '
+          'the parallel block has no dense feed-forward')
+    windowed = (config_lib.layer_pattern(p)[n]
+                == config_lib.LAYER_WINDOW_SOFTMAX)
+    attn = GroupedSoftmaxAttention(
+        hidden_size=p.hidden_size,
+        num_heads=p.num_heads,
+        num_kv_heads=p.num_kv_heads,
+        head_dim=p.head_dim,
+        # A window layer rotates the whole head and attends within the
+        # window; a full layer has neither positions nor mask.
+        rotary_dim=p.head_dim if windowed else 0,
+        rope_theta=p.rope_theta,
+        output_gate=False,
+        qk_norm=False,
+        window=p.sliding_window if windowed else None,
+        dtype=dtype,
+        name=f'self_attention_{n}',
+    )
+    return attn, _sparse_experts(p, n, dtype), None, BiasFreeLayerNorm(
+        p.layer_norm_eps, dtype=dtype, name=f'block_norm_{n}')
   if kind == config_lib.BLOCK_LATENT_MOE:
     if p.q_lora_rank is not None or (p.n_group, p.topk_group) != (1, 1):
       raise ValueError(
@@ -866,7 +943,7 @@ def _block_modules(p, n: int, dtype):
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
   elif kind == config_lib.BLOCK_GATED_DELTA_MOE:
     if config_lib.layer_pattern(p)[n] == config_lib.LAYER_GATED_SOFTMAX:
-      attn = GatedSoftmaxAttention(
+      attn = GroupedSoftmaxAttention(
           hidden_size=p.hidden_size,
           num_heads=p.num_heads,
           num_kv_heads=p.num_kv_heads,
@@ -931,7 +1008,7 @@ def _block_modules(p, n: int, dtype):
   wrap = lambda sublayer, name: ResidualWrapper(
       sublayer, dropout_rate=p.layer_postprocess_dropout, name=name,
       **residual)
-  return attn, ffn, wrap
+  return attn, ffn, wrap, None
 
 
 def expert_assignments(sown) -> jnp.ndarray:
@@ -948,6 +1025,8 @@ def expert_assignments(sown) -> jnp.ndarray:
 def _output_norm(p):
   """The stack's final normalization, by block kind (float32 out)."""
   kind = block_kind_of(p)
+  if kind == config_lib.BLOCK_PARALLEL_WINDOW_MOE:
+    return BiasFreeLayerNorm(p.layer_norm_eps, name='output_normalization')
   if kind != config_lib.BLOCK_BANDED_SOFTMAX:
     return RMSNorm(p.rms_norm_eps,
                    zero_centred=kind == config_lib.BLOCK_GATED_DELTA_MOE,
@@ -1009,7 +1088,16 @@ class EncoderStack(nn.Module):
     batch, length, hidden = x.shape
 
     for n in range(p.num_hidden_layers):
-      attn, ffn, wrap = _block_modules(p, n, self.dtype)
+      attn, ffn, wrap, norm = _block_modules(p, n, self.dtype)
+      if norm is not None:
+        # The parallel form: one norm, both sublayers on it, one addition;
+        # neither sublayer waits for the other.
+        u = norm(x)
+        with jax.named_scope('attention'):
+          attended = attn(u, deterministic=deterministic)
+        with jax.named_scope('ffn'):
+          x = x + attended + ffn(u, deterministic=deterministic)
+        continue
       if skip_first_attention and n == 0:
         # The fused hot path (ops/fused_window_attention.py) already
         # applied attention_wrapper_0's block including the residual;

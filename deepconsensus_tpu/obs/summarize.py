@@ -380,8 +380,15 @@ def summarize(events: List[Dict[str, Any]],
            if a.get('grouped_product_path')}),
       'combine_paths': sorted({str(a['combine_path']) for a in launches
                                if a.get('combine_path')}),
+      'block_forms': sorted({str(a['block_form']) for a in launches
+                             if a.get('block_form')}),
       'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
                                 if a.get('layer_pattern')}),
+      'attention_windows': sorted({int(a['attention_window'])
+                                   for a in launches
+                                   if a.get('attention_window')}),
+      'shared_experts': sorted({int(a['shared_experts']) for a in launches
+                                if a.get('shared_experts')}),
       'ffn_patterns': sorted({str(a['ffn_pattern']) for a in launches
                               if a.get('ffn_pattern')}),
       'router_scorings': sorted({str(a['router_scoring']) for a in launches
@@ -489,12 +496,22 @@ def format_summary(summary: Dict[str, Any]) -> str:
       scoring = ', '.join(forward.get('router_scorings', ()))
       grouped = ', '.join(forward.get('grouped_product_paths', ()))
       combine = ', '.join(forward.get('combine_paths', ()))
+      shared = ', '.join(
+          f'{n} averaged' for n in forward.get('shared_experts', ()))
       experts = '; '.join(
           f'{what}: {said}' for what, said in (
               ('router', scoring), ('grouped products', grouped),
-              ('combine', combine)) if said)
+              ('combine', combine), ('shared experts', shared)) if said)
+      # A sequential block is what every kind but one has: only the other
+      # form is said.
+      forms = ', '.join(form for form in forward.get('block_forms', ())
+                        if form != 'sequential')
+      windows = ', '.join(
+          str(w) for w in forward.get('attention_windows', ()))
       lines.append(
           f'  layers: {", ".join(forward["layer_patterns"])}'
+          + (f' ({forms} block)' if forms else '')
+          + (f' (window: {windows})' if windows else '')
           + (f' (delta rule: {delta_rule})' if delta_rule else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'
               for lo, hi, published in forward.get('experts_held', ()))

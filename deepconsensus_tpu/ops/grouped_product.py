@@ -21,6 +21,18 @@ rounded to the rows' type before the float32 silu, as two separate
 products that leave their kernels in that type would be. bfloat16 (or
 float32) operands, float32 accumulators.
 
+A group's matrix that does not fit the call's VMEM whole (`tiles`: [4096,
+4096] is 32 MiB, gate and up two of them, each buffered twice) passes as
+blocks of `tn` columns, for the gated call the same columns of gate and up
+together. The column blocks are the grid's OUTER axis and the visits run
+under each: a block [k, tn] of a group is fetched when the visits reach
+that group and stays while the group's row tiles pass, so every byte of
+every held matrix is fetched once a call, as in the whole-matrix form; what
+is read once a column block instead is the rows. A tile that two groups
+share is still visited by both one after the other, so its output block
+stays in VMEM between them. Where the matrix fits whole the grid is the
+visits alone, as it was.
+
 Measured on a v5e (PERF.md, PR 35) against the TPU compiler's lowering of
 `ragged_dot`, which runs these shapes at 37-38% of the chip's peak.
 """
@@ -42,18 +54,41 @@ LANES = 128
 SUB_ROWS = LANES
 
 
-def tile_rows(rows: int, groups: int, k: int, n: int) -> Optional[int]:
-  """Rows a tile for these shapes, or None where the kernel does not take
-  them (rows that no tile divides, widths that are not whole lane tiles).
-  The largest of 512, 256 and 128 rows that divides `rows`: parts are
+def vmem_bytes(rows: int, tm: int, k: int, tn: int, matrices: int,
+               itemsize: int = 2) -> int:
+  """What a call keeps in VMEM: a group's `matrices` blocks [k, tn], a row
+  tile [tm, k] and an output tile [tm, tn], each buffered twice; for the
+  gated call every row's float32 weight, twice; and a part's float32
+  products and epilogue, [SUB_ROWS, tn] each."""
+  return (2 * matrices * k * tn * itemsize + 2 * tm * k * itemsize
+          + 2 * tm * tn * itemsize + (matrices > 1) * 2 * rows * 4
+          + (matrices + 1) * SUB_ROWS * tn * 4)
+
+
+def tiles(rows: int, k: int, n: int, matrices: int = 1,
+          itemsize: int = 2) -> Optional[Tuple[int, int]]:
+  """(rows, columns) of a tile for `rows` sorted rows times `matrices`
+  matrices [k, n] a group, or None where the kernel does not take the
+  shapes: rows that no tile divides, widths that are not whole lane tiles,
+  or blocks that do not fit the call's scoped VMEM with an eighth to spare.
+
+  Rows: the largest of 512, 256 and 128 that divides `rows`; parts are
   skipped inside a tile, so a larger tile costs no more at a boundary and
-  pays the grid's step less often (tiles of 1,024 measured no faster)."""
-  del groups  # A boundary costs one part, whatever the tile.
+  pays the grid's step less often (tiles of 1,024 measured no faster).
+  Columns: all n where a group's matrices fit whole, else the widest block
+  of whole lane tiles that divides n and fits: every block more is one
+  more read of the rows."""
   if k % LANES or n % LANES:
     return None
-  for tm in (512, 256, 128):
-    if rows % tm == 0:
-      return tm
+  tm = next((tm for tm in (512, 256, 128) if rows % tm == 0), None)
+  if tm is None:
+    return None
+  budget = pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES * 7 // 8
+  for blocks in range(1, n // LANES + 1):
+    tn = n // blocks
+    if n % blocks == 0 and tn % LANES == 0 and vmem_bytes(
+        rows, tm, k, tn, matrices, itemsize) <= budget:
+      return tm, tn
   return None
 
 
@@ -77,12 +112,13 @@ def visits(bounds: jnp.ndarray, rows: int,
   return group, jnp.clip(tile, 0, rows // tm - 1).astype(jnp.int32), upto[-1]
 
 
-def _kernel(bounds_ref, group_ref, tile_ref, *refs, tm: int, gated: bool):
+def _kernel(bounds_ref, group_ref, tile_ref, *refs, tm: int, gated: bool,
+            visit_axis: int):
   if gated:
     rows_ref, w_gate_ref, w_up_ref, weight_ref, out_ref = refs
   else:
     rows_ref, w_ref, out_ref = refs
-  v = pl.program_id(0)
+  v = pl.program_id(visit_axis)
   g = group_ref[v]
   start, end = bounds_ref[g], bounds_ref[g + 1]
   row0 = tile_ref[v] * tm
@@ -126,17 +162,30 @@ def _kernel(bounds_ref, group_ref, tile_ref, *refs, tm: int, gated: bool):
 def _call(rows, weights, bounds, row_weight, interpret: bool):
   m, k = rows.shape
   groups, _, n = weights[0].shape
-  tm = tile_rows(m, groups, k, n)
-  if tm is None:
+  gated = row_weight is not None
+  tile_of = tiles(m, k, n, len(weights), rows.dtype.itemsize)
+  if tile_of is None:
     raise ValueError(
         f'no tile for rows {rows.shape} over {groups} groups of {(k, n)}')
+  tm, tn = tile_of
   bounds = bounds.astype(jnp.int32)
   group, tile, count = visits(bounds, m, tm)
+  # A block's place from (column block c, visit v) and the visits' lists.
+  if tn == n:
+    # A group's matrices whole: the grid is the visits.
+    grid = (count,)
+    at = lambda place: lambda v, bounds, group, tile: place(0, v, group, tile)
+  else:
+    # Column blocks outside, the visits under each.
+    grid = (n // tn, count)
+    at = lambda place: lambda c, v, bounds, group, tile: place(
+        c, v, group, tile)
   by_tile = lambda width: pl.BlockSpec(
-      (tm, width), lambda v, bounds, group, tile: (tile[v], 0))
+      (tm, width), at(lambda c, v, group, tile: (tile[v], 0)))
   by_group = pl.BlockSpec(
-      (None, k, n), lambda v, bounds, group, tile: (group[v], 0, 0))
-  gated = row_weight is not None
+      (None, k, tn), at(lambda c, v, group, tile: (group[v], 0, c)))
+  out_spec = pl.BlockSpec(
+      (tm, tn), at(lambda c, v, group, tile: (tile[v], c)))
   operands = [rows] + [w.astype(rows.dtype) for w in weights]
   in_specs = [by_tile(k)] + [by_group] * len(weights)
   if gated:
@@ -151,15 +200,16 @@ def _call(rows, weights, bounds, row_weight, interpret: bool):
     row_weight = jnp.where(held, row_weight.astype(jnp.float32), 0.0)
     operands.append(row_weight.reshape(m // SUB_ROWS, SUB_ROWS))
     in_specs.append(pl.BlockSpec(
-        (m // SUB_ROWS, SUB_ROWS), lambda v, bounds, group, tile: (0, 0)))
+        (m // SUB_ROWS, SUB_ROWS), at(lambda c, v, group, tile: (0, 0))))
   return pl.pallas_call(
-      functools.partial(_kernel, tm=tm, gated=gated),
+      functools.partial(_kernel, tm=tm, gated=gated,
+                        visit_axis=len(grid) - 1),
       grid_spec=pltpu.PrefetchScalarGridSpec(
-          num_scalar_prefetch=3, grid=(count,), in_specs=in_specs,
-          out_specs=by_tile(n)),
+          num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
+          out_specs=out_spec),
       out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
       compiler_params=pltpu.CompilerParams(
-          dimension_semantics=('arbitrary',),
+          dimension_semantics=('arbitrary',) * len(grid),
           vmem_limit_bytes=pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES),
       interpret=interpret,
       name='grouped_gated_up' if gated else 'grouped_product',

@@ -45,9 +45,11 @@ written and an assignment held elsewhere is not read. Everywhere else
 XLA's gather of one row an assignment and a float32 sum over k, which on a
 v5e ran at 34-46 ns a row of 4 kB out of HBM (PERF.md, PR 35 and PR 37).
 
-Tokens are taken MAX_ROWS assignments at a time (jax.lax.map): the sorted
+Tokens are taken a turn at a time (jax.lax.map; `turns_of`): the sorted
 copy of the tokens and the experts' output are [tokens x k, hidden] each,
-4 kB a row at hidden 2048.
+and a turn is as many assignments as keep one such buffer within
+MAX_TURN_BYTES: 2^18 rows of 4 kB at hidden 2048 in bfloat16, 2^17 of 8 kB
+at hidden 4096.
 """
 from __future__ import annotations
 
@@ -60,7 +62,7 @@ from deepconsensus_tpu.ops import grouped_product
 from deepconsensus_tpu.ops import moe_combine
 from deepconsensus_tpu.ops import pallas_util
 
-MAX_ROWS = 1 << 18
+MAX_TURN_BYTES = 1 << 30
 
 # Which form of the grouped products a turn runs (`forward_launch`'s
 # `grouped_product_path`, docs/observability.md).
@@ -114,12 +116,15 @@ def grouped_product_path(rows: int, groups: int, k: int, n: int,
   """The one rule by which a turn's grouped products, `rows` sorted rows
   over `groups` matrices [k, n] (and [n, k] back), take the Pallas kernel
   in place of `jax.lax.ragged_dot`; no option asks for it. bfloat16 rows,
-  shapes the kernel has a tile for, and a TPU in a trace its caller
-  declared inference for one device (pallas_util.may_choose_kernels:
-  ModelRunner without a mesh)."""
+  shapes both calls have tiles for within their VMEM (gate and up
+  together, then down), and a TPU in a trace its caller declared inference
+  for one device (pallas_util.may_choose_kernels: ModelRunner without a
+  mesh)."""
+  del groups  # A boundary costs one part of a tile, however many there are.
   kernel = (
       jnp.dtype(dtype) == jnp.bfloat16
-      and grouped_product.tile_rows(rows, groups, k, n) is not None
+      and grouped_product.tiles(rows, k, n, matrices=2) is not None
+      and grouped_product.tiles(rows, n, k) is not None
       and pallas_util.may_choose_kernels())
   return GROUPED_GROUP_KERNEL if kernel else GROUPED_RAGGED_DOT
 
@@ -138,10 +143,14 @@ def combine_path(n: int, k: int, groups: int, hidden: int, dtype) -> str:
   return COMBINE_TOKEN_TILE_KERNEL if kernel else COMBINE_GATHER
 
 
-def turns_of(n: int, k: int) -> int:
-  """In how many turns `held_experts` takes n tokens of k assignments."""
+def turns_of(n: int, k: int, hidden: int, dtype) -> int:
+  """In how many turns `held_experts` takes n tokens of k assignments: the
+  fewest halvings that bring one [rows, hidden] buffer of a turn within
+  MAX_TURN_BYTES."""
+  row_bytes = hidden * jnp.dtype(dtype).itemsize
   turns = 1
-  while (n // turns) * k > MAX_ROWS and n % (turns * 2) == 0:
+  while (n // turns) * k * row_bytes > MAX_TURN_BYTES and (
+      n % (turns * 2) == 0):
     turns *= 2
   return turns
 
@@ -219,7 +228,7 @@ def held_experts(x: jnp.ndarray, weights: jnp.ndarray, experts: jnp.ndarray,
   float32 accumulator and leave it in x's type; the gate, the routing
   weight and the combine's sum are float32."""
   n, k = experts.shape
-  turns = turns_of(n, k)
+  turns = turns_of(n, k, x.shape[1], x.dtype)
   if turns == 1:
     return _held_experts(x, weights, experts, w_gate, w_up, w_down, first)
   split = lambda a: a.reshape((turns, n // turns) + a.shape[1:])

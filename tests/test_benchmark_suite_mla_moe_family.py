@@ -25,3 +25,28 @@ def test_cell_configuration_traffic_and_metrics_are_entries_of_their_own(real): 
           'moe128_roofline': 'device_trace',
           'moe128_device_share': 'device_trace',
           'moe128_load_max_over_mean': 'program_counter'})
+
+
+def test_the_cells_the_benchmark_had_are_as_they_were():  # noqa: F811
+  """The benchmark's own test of this name holds the list of cells to the
+  five there were when PR 34 appended `kanana_polish`. PR 38 appended a
+  sixth behind them, and no PR but a `benchmark` one may edit the
+  benchmark's files, so here the same facts are held of the first five
+  places."""
+  import json
+
+  with open(_family.BENCH) as f:
+    bench = json.load(f)
+  assert [w['name'] for w in bench['workloads']][:5] == [
+      'teacher_polish', 'student_polish', 'brumby_polish', 'qwen3next_polish',
+      _family.CELL]
+  assert [c['name'] for c in bench['configs']][:4] == [
+      'teacher_6x280_L100', 'student_5x280_L100', 'brumby14b_8of40_L100',
+      'qwen3next80b_4of48_e256_L100']
+  assert bench['run_seconds'] == 30
+  assert [m['name'] for m in bench['end_to_end']] == ['windows_per_s',
+                                                      'setup_s']
+  qwen = [m['name'] for m in bench['per_layer']
+          if m.get('workloads') == ['qwen3next_polish']]
+  assert qwen == ['moe_roofline', 'gdn_roofline', 'moe_device_share',
+                  'expert_load_max_over_mean']

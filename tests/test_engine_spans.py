@@ -525,7 +525,10 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
         {config_lib.BLOCK_BANDED_SOFTMAX: 'B',
          config_lib.BLOCK_POWER_RETENTION: 'R'}[kind] * p.num_hidden_layers)
     assert e['args']['ffn_pattern'] == 'D' * p.num_hidden_layers
+    assert e['args']['block_form'] == 'sequential'
+    assert 'attention_window' not in e['args']
     assert 'experts_held' not in e['args']
+    assert 'shared_experts' not in e['args']
     assert 'router_scoring' not in e['args']
     # Nor a delta rule: these kinds have no Gated DeltaNet mixer.
     assert 'delta_rule_path' not in e['args']
@@ -547,9 +550,12 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
                      'grouped_product_paths': [],
                      'combine_paths': [],
+                     'block_forms': ['sequential'],
                      'layer_patterns': [config_lib.layer_pattern(p)],
+                     'attention_windows': [],
                      'ffn_patterns': [config_lib.ffn_pattern(p)],
-                     'router_scorings': [], 'experts_held': [],
+                     'router_scorings': [], 'shared_experts': [],
+                     'experts_held': [],
                      'n_positions': 3 * BATCH * p.max_length,
                      'weight_bytes': 62}
   assert cli.main(['trace', path]) == 0

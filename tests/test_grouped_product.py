@@ -189,13 +189,17 @@ def test_layers_alike_trace_the_kernel_once(monkeypatch):
 
 
 def test_a_tile_that_divides_the_rows_or_none():
-  assert grouped_product.tile_rows(153_600, 128, 2048, 768) == 512
-  assert grouped_product.tile_rows(256_000, 256, 2048, 512) == 512
-  assert grouped_product.tile_rows(768, 8, 128, 256) == 256
-  assert grouped_product.tile_rows(640, 8, 128, 256) == 128
-  assert grouped_product.tile_rows(1000, 8, 128, 256) is None
-  assert grouped_product.tile_rows(1024, 8, 64, 256) is None
-  assert grouped_product.tile_rows(1024, 8, 128, 24) is None
+  # Widths of 512 and 768 at hidden 2048 keep today's block: a group's
+  # matrices whole, gate and up together and the down product alike.
+  assert grouped_product.tiles(153_600, 2048, 768, matrices=2) == (512, 768)
+  assert grouped_product.tiles(153_600, 768, 2048) == (512, 2048)
+  assert grouped_product.tiles(256_000, 2048, 512, matrices=2) == (512, 512)
+  assert grouped_product.tiles(256_000, 512, 2048) == (512, 2048)
+  assert grouped_product.tiles(768, 128, 256) == (256, 256)
+  assert grouped_product.tiles(640, 128, 256) == (128, 256)
+  assert grouped_product.tiles(1000, 128, 256) is None
+  assert grouped_product.tiles(1024, 64, 256) is None
+  assert grouped_product.tiles(1024, 128, 24) is None
   with pytest.raises(ValueError, match='no tile'):
     grouped_product.grouped_product(
         jnp.zeros((1000, 128)), jnp.zeros((2, 128, 128)), bounds_of([5, 5]),
@@ -294,8 +298,9 @@ def test_turns_take_the_kernel_too(monkeypatch):
   args = _routed(jnp.bfloat16, seed=2)
   with kernel_taken(monkeypatch):
     whole, counts = moe.held_experts(*args)
-    monkeypatch.setattr(moe, 'MAX_ROWS', 128 * 4)  # 128 tokens a turn
-    assert moe.turns_of(256, 4) == 2
+    # 128 tokens a turn: 512 rows of 128 in bfloat16.
+    monkeypatch.setattr(moe, 'MAX_TURN_BYTES', 128 * 4 * 128 * 2)
+    assert moe.turns_of(256, 4, 128, jnp.bfloat16) == 2
     in_turn, counts_in_turn = moe.held_experts(*args)
   np.testing.assert_allclose(np.asarray(in_turn, np.float32),
                              np.asarray(whole, np.float32),
@@ -313,3 +318,107 @@ def test_the_rule_declines_toy_widths_and_float32_on_the_cpu_and_a_tpu(
         moe.GROUPED_RAGGED_DOT)
     assert moe.grouped_product_path(240, 8, 64, 24, jnp.bfloat16) == (
         moe.GROUPED_RAGGED_DOT)
+
+
+# ------------------------------------------- matrices wider than the VMEM
+
+def test_a_matrix_of_4096_by_4096_passes_in_column_blocks_by_the_vmem_rule():
+  """One [4096, 4096] matrix is 32 MiB in bfloat16, the call's whole scoped
+  VMEM, and gate and up are two, each buffered twice: asked by widths
+  alone the rule would take the kernel and Mosaic refuse it."""
+  limit = pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES
+  rows = 102_400  # one turn of commanda_polish: 12,800 tokens x 8
+  assert grouped_product.vmem_bytes(rows, 512, 4096, 4096, 2) > 4 * limit
+  assert grouped_product.tiles(rows, 4096, 4096, matrices=2) == (512, 512)
+  assert grouped_product.tiles(rows, 4096, 4096) == (512, 1024)
+  for matrices, tn in ((2, 512), (1, 1024)):
+    assert grouped_product.vmem_bytes(rows, 512, 4096, tn, matrices) <= (
+        limit * 7 // 8)
+    # The next wider block does not fit.
+    assert grouped_product.vmem_bytes(rows, 512, 4096, 2 * tn, matrices) > (
+        limit * 7 // 8)
+
+
+def test_no_block_that_fits_declines_the_kernel(monkeypatch):
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+  with pallas_util.single_device_inference():
+    assert moe.grouped_product_path(102_400, 16, 4096, 4096, jnp.bfloat16) == (
+        moe.GROUPED_GROUP_KERNEL)
+    # A row tile of [512, 65536] alone is 64 MiB: no column block helps.
+    assert grouped_product.tiles(102_400, 65_536, 4096, matrices=2) is None
+    assert moe.grouped_product_path(
+        102_400, 16, 65_536, 4096, jnp.bfloat16) == moe.GROUPED_RAGGED_DOT
+
+
+@pytest.fixture
+def narrow_vmem(monkeypatch):
+  """A scoped VMEM so small that float32 [256, 384] matrices pass as three
+  column blocks of 128 (and gate and up of 256 columns as two), the kernel
+  traced anew under it."""
+  monkeypatch.setattr(pallas_util, 'GROUPED_PRODUCT_VMEM_LIMIT_BYTES',
+                      2_700_000)
+  grouped_product._call.clear_cache()
+  yield
+  grouped_product._call.clear_cache()
+
+
+@pytest.mark.parametrize('case', sorted(COUNTS))
+def test_column_blocks_are_the_ragged_dot(case, narrow_vmem):
+  counts = COUNTS[case]
+  k, n = 256, 384
+  assert grouped_product.tiles(ROWS, k, n, itemsize=4) == (512, 128)
+  rows, w = draw(1, ROWS, k), draw(2, len(counts), k, n)
+  got = np.asarray(grouped_product.grouped_product(
+      rows, w, bounds_of(counts), interpret=True))
+  held = sum(counts)
+  want = np.asarray(jax.lax.ragged_dot(
+      rows, w, jnp.asarray(counts, jnp.int32)))
+  np.testing.assert_allclose(got[:held], want[:held], atol=2e-5)
+  np.testing.assert_allclose(got[:held], loop_product(rows, w, counts)[:held],
+                             atol=2e-5)
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('case', ['uneven', 'rows_behind_the_last_group',
+                                  'boundaries_on_tiles_and_parts'])
+def test_gate_and_up_in_column_blocks_are_the_whole_matrix_call(
+    case, dtype, narrow_vmem, monkeypatch):
+  """The same columns of gate and up pass together: the epilogue of a
+  block is the epilogue of those columns of the whole-matrix call."""
+  counts = COUNTS[case]
+  # The narrower type takes the deeper matrices to need the blocks.
+  k, n = (256 if dtype == jnp.float32 else 512), 256
+  size = jnp.dtype(dtype).itemsize
+  assert grouped_product.tiles(ROWS, k, n, matrices=2, itemsize=size)[1] < n
+  rows = draw(3, ROWS, k, dtype=dtype)
+  w_gate = draw(4, len(counts), k, n, dtype=dtype)
+  w_up = draw(5, len(counts), k, n, dtype=dtype)
+  weight = jnp.asarray(np.random.default_rng(6).uniform(0.1, 1, ROWS),
+                       jnp.float32)
+  bounds = bounds_of(counts)
+  blocked = np.asarray(grouped_product.gated_up(
+      rows, w_gate, w_up, weight, bounds, interpret=True), np.float32)
+  monkeypatch.undo()  # the shipped VMEM: the matrices whole
+  grouped_product._call.clear_cache()
+  assert grouped_product.tiles(ROWS, k, n, matrices=2, itemsize=size)[1] == n
+  whole = np.asarray(grouped_product.gated_up(
+      rows, w_gate, w_up, weight, bounds, interpret=True), np.float32)
+  held = sum(counts)
+  # A column of the output is one column of gate and of up: the same
+  # products, whatever block they came in.
+  assert np.array_equal(blocked[:held], whole[:held])
+
+
+@pytest.mark.parametrize('cell,tokens,k,hidden,turns', [
+    ('kanana_polish', 51_200, 6, 2048, 2),
+    ('qwen3next_polish', 51_200, 10, 2048, 2),
+    ('commanda_polish', 25_600, 8, 4096, 2)])
+def test_a_turn_is_reckoned_in_bytes_of_one_buffer_of_rows(cell, tokens, k,
+                                                           hidden, turns):
+  """2^18 rows of 4 kB at hidden 2048 (the two cells keep their turns of
+  153,600 and 256,000 assignments), 2^17 rows of 8 kB at hidden 4096."""
+  del cell
+  assert moe.turns_of(tokens, k, hidden, jnp.bfloat16) == turns
+  rows = tokens // turns * k
+  assert rows * hidden * 2 <= moe.MAX_TURN_BYTES < 2 * rows * hidden * 2

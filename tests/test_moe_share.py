@@ -10,7 +10,10 @@ to positions x k. Each for both routers the one class serves: the softmax
 router with a gated shared expert (the third block kind) and the sigmoid
 router with a selection bias, a scaling factor and an ungated shared expert
 (the fourth; reference tests/mla_moe_reference.py), whose bias changes who
-is chosen and never a weight.
+is chosen and never a weight. And for the fifth kind's layer as eight chips
+share it: 128 experts, 16 a chip, a sigmoid router without bias or factor,
+four averaged shared experts, in a parallel block whose norm and attention
+every chip computes alike (reference tests/parallel_moe_reference.py).
 """
 import jax
 import jax.numpy as jnp
@@ -235,7 +238,8 @@ def test_every_token_on_experts_held_elsewhere_adds_nothing():
 def test_more_assignments_than_one_go_holds_are_taken_in_turn(monkeypatch):
   weights, x = whole_layer_weights(seed=10), tokens(batch=4, seed=11)
   whole, counts = apply(4, 8, weights, x)
-  monkeypatch.setattr(moe, 'MAX_ROWS', 20 * K)  # one window a turn
+  # One window a turn: 20 positions of K float32 rows of H.
+  monkeypatch.setattr(moe, 'MAX_TURN_BYTES', 20 * K * H * 4)
   in_turn, counts_in_turn = apply(4, 8, weights, x)
   np.testing.assert_allclose(np.asarray(in_turn), np.asarray(whole),
                              atol=1e-6)
@@ -338,3 +342,91 @@ def test_a_share_that_is_no_share_of_the_experts_is_refused(first, count):
   with pytest.raises(ValueError, match='are not a share of 16'):
     layer(first, count).init(jax.random.PRNGKey(0), tokens(),
                              deterministic=True)
+
+
+# ----------------------- the fifth kind: an eighth of the experts a chip
+
+def test_eight_shares_of_sixteen_experts_add_up_to_the_uncut_parallel_layer():
+  """The parallel block's layer as 8 chips share it (128 experts, 16 a
+  chip, 8 a token; sigmoid router without bias or factor; four shared
+  experts averaged): the routed parts of the eight shares, with what every
+  chip computes alike (the norm, the attention, the shared experts)
+  counted once, are the plain reference's uncut layer x + attn(u) + ffn(u)
+  (tests/parallel_moe_reference.py)."""
+  from tests import parallel_moe_reference as parallel_ref
+
+  experts, held, top_k, n_shared = 128, 16, 8, 4
+  heads, kv_heads, d = 8, 2, 8
+  rng = np.random.default_rng(20)
+  draw = lambda *shape, fan=None: jnp.asarray(
+      rng.normal(0, (fan or shape[-2]) ** -0.5, shape), jnp.float32)
+  weights = {
+      'router': {'kernel': draw(H, experts)},
+      'experts_gate': draw(experts, H, F), 'experts_up': draw(experts, H, F),
+      'experts_down': draw(experts, F, H),
+      'shared_expert': {'gate_layer': {'kernel': draw(H, n_shared * F)},
+                        'up_layer': {'kernel': draw(H, n_shared * F)},
+                        'output_layer': {'kernel': draw(n_shared * F, H)}},
+  }
+  attention_weights = {
+      'query': {'kernel': draw(H, heads, d, fan=H)},
+      'key': {'kernel': draw(H, kv_heads, d, fan=H)},
+      'value': {'kernel': draw(H, kv_heads, d, fan=H)},
+      'output_transform': {'kernel': draw(heads, d, H, fan=heads * d)}}
+  scale = jnp.asarray(rng.uniform(0.5, 1.5, H), jnp.float32)
+  x = tokens(seed=21)
+
+  with jax.default_matmul_precision('highest'):
+    u = model_lib.BiasFreeLayerNorm(1e-5).apply(
+        {'params': {'scale': scale}}, x)
+    attended = model_lib.GroupedSoftmaxAttention(
+        hidden_size=H, num_heads=heads, num_kv_heads=kv_heads, head_dim=d,
+        rotary_dim=d, rope_theta=5e4, output_gate=False, qk_norm=False,
+        window=6).apply({'params': attention_weights}, u, deterministic=True)
+    ffn = lambda first: model_lib.SparseExpertsFeedForward(
+        hidden_size=H, num_experts=experts, experts_per_token=top_k,
+        expert_width=F, shared_width=n_shared * F, norm_topk=True,
+        held_first=first, held_count=held, scoring='sigmoid',
+        selection_bias=False, routed_scale=1.0, shared_gate=False,
+        shared_scale=1.0 / n_shared).apply(
+            {'params': share_of(weights, first, held)}, u,
+            deterministic=True, mutable=['moe_counts'])
+    flat = u.reshape(-1, H)
+    shared = parallel_ref.shared_experts(
+        weights['shared_expert'], flat, n_shared).reshape(x.shape)
+    total, counts = x + attended + shared, []
+    for first in range(0, experts, held):
+      out, sown = ffn(first)
+      total = total + (out - shared)  # the routed part of this share
+      counts.append(np.asarray(sown['moe_counts']['assignments'][0]))
+    # The uncut layer of the reference: every expert held.
+    want_u = parallel_ref.layer_norm(x, scale, 1e-5)
+    routed, want_counts = parallel_ref.routed_experts(
+        weights, want_u.reshape(-1, H), top_k=top_k)
+    want = x + parallel_ref.attention(
+        attention_weights, want_u, rotated=True, window=6, theta=5e4) + (
+            routed + parallel_ref.shared_experts(
+                weights['shared_expert'], want_u.reshape(-1, H),
+                n_shared)).reshape(x.shape)
+  # Eight subtractions of the shared part and float32 sums in two orders.
+  np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=5e-5)
+  assert np.array_equal(np.concatenate(counts), want_counts)
+  assert want_counts.sum() == x.shape[0] * x.shape[1] * top_k
+  # The layer is not its residual alone: every part counts.
+  assert np.abs(np.asarray(want - x)).max() > 0.5
+
+
+def test_sigmoid_router_without_bias_or_factor_sums_to_one():
+  """The combination the fifth kind runs: a sigmoid each, the k largest of
+  the scores themselves, renormalised, no factor."""
+  rng = np.random.default_rng(22)
+  logits = jnp.asarray(rng.normal(size=(200, 128)), jnp.float32)
+  weights, experts = (np.asarray(a) for a in moe.route_top_k(
+      logits, 8, True, scoring='sigmoid', bias=None, scale=1.0))
+  scores = np.asarray(jax.nn.sigmoid(logits))
+  assert np.array_equal(np.sort(experts), np.sort(
+      np.argsort(-scores, axis=-1)[:, :8]))
+  np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-6)
+  kept = np.take_along_axis(scores, experts, axis=-1)
+  np.testing.assert_allclose(weights, kept / kept.sum(-1, keepdims=True),
+                             rtol=1e-6)

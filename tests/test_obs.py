@@ -641,9 +641,10 @@ class TestSummarize:
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
                             'attention_paths': [], 'delta_rule_paths': [],
                             'grouped_product_paths': [],
-                            'combine_paths': [],
-                            'layer_patterns': [], 'ffn_patterns': [],
-                            'router_scorings': [], 'experts_held': [],
+                            'combine_paths': [], 'block_forms': [],
+                            'layer_patterns': [], 'attention_windows': [],
+                            'ffn_patterns': [], 'router_scorings': [],
+                            'shared_experts': [], 'experts_held': [],
                             'n_positions': 0, 'weight_bytes': 0}
     assert 'forward:' not in text
 

@@ -9,8 +9,9 @@ itself among them, and 256 for the loss; two layers of the power-retention block
 kind at hidden 5120, batch 256; one period of the gated-delta and
 sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512; one
 dense and one expert layer of the latent-attention kind at hidden 2048 with
-128 experts, batch 512; the grouped-product kernel and the combine's
-kernel both take, each alone at one turn of each). A compile that passes
+128 experts, batch 512; one period of the parallel window-and-full kind at
+hidden 4096 with 16 of 128 experts, batch 256; the grouped-product kernel
+and the combine's kernel the three take, each alone at one turn of each). A compile that passes
 here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
 first, at no chip time.
@@ -316,19 +317,87 @@ def test_latent_attention_moe_forward_b512_at_published_widths(
   assert 'bf16[153600,2048]' in text
 
 
-@pytest.mark.parametrize('rows,groups,width', [
-    (153_600, 128, 768), (256_000, 256, 512)],
-                         ids=['kanana_polish', 'qwen3next_polish'])
-def test_grouped_product_kernel_at_one_turn_of_both_cells(
-    one_chip, compiled_kernels, rows, groups, width):
-  """The grouped products' kernel alone at one turn of the two cells that
-  run it (25,600 tokens of 6 and of 10 assignments, hidden 2048): gate and
-  up as one call, then the down product, each with its group's matrices
-  resident and within pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES."""
+def test_parallel_window_moe_forward_b256_at_published_widths(
+    one_chip, compiled_kernels, monkeypatch):
+  """The fifth block kind as it is served on one chip, by shape alone (no
+  array of the 8.57 GiB is made): one period of the pattern (three window
+  layers, one full layer) at the published widths, experts 0-15 of 128 in
+  each, bfloat16 leaves, a pack of 256 windows. As ModelRunner traces it
+  without a mesh: the attention is plain products, the grouped products
+  the kernel whose grid follows the groups with a [4096, 4096] matrix in
+  column blocks, the combine the kernel a tile of tokens."""
+  p = config_lib.get_config('transformer_learn_values_parallel_moe+custom')
+  with p.unlocked():
+    p.num_hidden_layers = 4
+    p.experts_held_count = 16
+  config_lib.finalize_params(p, is_training=False)
+  assert config_lib.layer_pattern(p) == 'WWWF'
+  model = model_lib.get_model(p)
+  tree = jax.eval_shape(
+      lambda key: model.init(
+          key, jnp.zeros((1, p.total_rows, p.max_length, 1), jnp.float32)),
+      jax.random.PRNGKey(0))['params']
+  variables = {'params': jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                     sharding=one_chip), tree)}
+  rows = jax.ShapeDtypeStruct(
+      (256, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+
+  def forward(variables, rows):
+    with pallas_util.single_device_inference():
+      return model.apply(variables, rows, mutable=['moe_counts'])
+
+  compiled = jax.jit(forward).lower(variables, rows).compile()
+  text = compiled.as_text()
+  # In every layer two calls of the grouped products' kernel (gate and up
+  # as one, down) and one of the combine's: none of the compiler's own
+  # grouped products. (A layer's two turns are one loop, which the compiler
+  # may unroll.)
+  assert 'ragged-dot' not in text
+  assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (4, 8)
+  assert len(re.findall(r'%grouped_product\S* = ', text)) in (4, 8)
+  assert len(re.findall(r'%moe_combine\S* = ', text)) in (4, 8)
+  assert _n_kernels(compiled) in (12, 24)
+  assert 'combine/jit(_take)/gather' not in text
+  # A turn is 12,800 tokens of 8 assignments: rows of 8 kB, two turns a
+  # pack, never the pack's 204,800 at once.
+  assert 'bf16[102400,4096]' in text and 'bf16[204800,4096]' not in text
+  # Grouped heads: the scores are [B, 8, 16, L, L], and no k or v repeated
+  # to the 128 query heads is laid out.
+  assert 'f32[256,8,16,100,100]' in text
+  assert not re.search(r'= bf16\[256,100,128,128\]\S* broadcast', text)
+  # At L=100 the window of 4,096 masks nothing and builds no mask.
+  assert 'pred[100,100]' not in text
+  memory = compiled.memory_analysis()
+  # 4,599,070,720 block parameters and what lies outside, 2 bytes each.
+  assert 2 * 4_599_070_720 < memory.argument_size_in_bytes < 9.25e9
+  # With 8.57 GiB of weights a pack's temporaries have to leave room on a
+  # chip of 15.75 GiB: 4.89 GiB as compiled (PR 38; a window layer's query
+  # in float32 for its rotation, 1.56 GiB, the scores, 1.22 GiB, and one
+  # turn of the experts' rows are the largest), and a twentieth.
+  assert memory.temp_size_in_bytes < 5.15 * 2**30
+
+
+@pytest.mark.parametrize('rows,groups,hidden,width,columns', [
+    (153_600, 128, 2048, 768, (768, 2048)),
+    (256_000, 256, 2048, 512, (512, 2048)),
+    (102_400, 16, 4096, 4096, (512, 1024))],
+                         ids=['kanana_polish', 'qwen3next_polish',
+                              'commanda_polish'])
+def test_grouped_product_kernel_at_one_turn_of_each_cell(
+    one_chip, compiled_kernels, rows, groups, hidden, width, columns):
+  """The grouped products' kernel alone at one turn of the three cells
+  that run it (25,600 tokens of 6 and of 10 assignments at hidden 2048,
+  12,800 of 8 at hidden 4096): gate and up as one call, then the down
+  product, within pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES: a group's
+  matrices resident whole at widths 768 and 512, in column blocks of 512
+  and 1,024 where one matrix is [4096, 4096]."""
   from deepconsensus_tpu.ops import grouped_product
 
-  hidden = 2048
-  assert grouped_product.tile_rows(rows, groups, hidden, width) == 512
+  assert grouped_product.tiles(rows, hidden, width, matrices=2) == (
+      512, columns[0])
+  assert grouped_product.tiles(rows, width, hidden) == (512, columns[1])
   sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
       shape, dtype, sharding=one_chip)
   bounds = sds((groups + 1,), jnp.int32)
@@ -342,22 +411,24 @@ def test_grouped_product_kernel_at_one_turn_of_both_cells(
   assert 'grouped_product' in down.as_text() and _n_kernels(down) == 1
   # Nothing of the rows' size beside the operands and the result, but the
   # routing weights as a column, which the chip pads to a lane tile a row.
-  assert down.memory_analysis().temp_size_in_bytes < 1 << 20
+  # (With column blocks the call holds 1.7 MB of its own beside them.)
+  assert down.memory_analysis().temp_size_in_bytes < 2 << 20
   assert up.memory_analysis().temp_size_in_bytes < rows * 128 * 4 + (1 << 20)
 
 
-@pytest.mark.parametrize('k,groups', [(6, 128), (10, 256)],
-                         ids=['kanana_polish', 'qwen3next_polish'])
-def test_combine_kernel_at_one_turn_of_both_cells(one_chip, compiled_kernels,
-                                                  k, groups):
-  """The combine's kernel alone at one turn of the two cells that run it
+@pytest.mark.parametrize('tokens,k,groups,hidden', [
+    (25_600, 6, 128, 2048), (25_600, 10, 256, 2048), (12_800, 8, 16, 4096)],
+                         ids=['kanana_polish', 'qwen3next_polish',
+                              'commanda_polish'])
+def test_combine_kernel_at_one_turn_of_each_cell(one_chip, compiled_kernels,
+                                                 tokens, k, groups, hidden):
+  """The combine's kernel alone at one turn of the three cells that run it
   (25,600 tokens of 6 assignments over 128 held experts and of 10 over
-  256, hidden 2048): 8-row copies out of a [rows, 2048] array in HBM, the
-  0/1 product, two buffers of a tile's runs within
-  pallas_util.COMBINE_VMEM_LIMIT_BYTES."""
+  256 at hidden 2048; 12,800 of 8 over 16 at hidden 4096): 8-row copies
+  out of a [rows, hidden] array in HBM, the 0/1 product, two buffers of a
+  tile's runs within pallas_util.COMBINE_VMEM_LIMIT_BYTES."""
   from deepconsensus_tpu.ops import moe_combine
 
-  tokens, hidden = 25_600, 2048
   assert moe_combine.fits(tokens, k, groups, hidden)
   sds = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
       shape, dtype, sharding=one_chip)
