@@ -15,6 +15,8 @@ attention_layer.py:34-237, ffn_layer.py:34-87), re-designed TPU-first:
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Dict, Optional
 
 import flax.linen as nn
@@ -349,8 +351,44 @@ def apply_rotary(x: jnp.ndarray, theta: float,
   return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
 
 
+@functools.lru_cache(maxsize=None)
+def _flat_rotary_tables(length: int, rotary_dim: int, theta: float,
+                        windows: int, heads: int):
+  """(cos, sin) [windows * L, heads * rotary_dim / 2] float32: a half
+  head's tables side by side a head and `windows` times down the rows.
+  One array a stack, so that its layers share one constant."""
+  cos, sin = rotary_tables(length, rotary_dim, theta)
+  tiled = lambda table: np.tile(table[:, :rotary_dim // 2], (windows, heads))
+  return tiled(cos), tiled(sin)
+
+
+def apply_rotary_flat(first: jnp.ndarray, second: jnp.ndarray, length: int,
+                      theta: float, rotary_dim: int):
+  """The first halves of N heads and their second halves, each
+  [B*L, N * rotary_dim / 2] with windows of `length` rows one after another
+  and the heads along the lanes -> both rotated in float32 as `apply_rotary`
+  rotates a head [first | second], to the bit (the same two products a
+  value and their sum). Halves apart, a value and the one it turns with lie
+  in the same lane of two arrays, so the rotation shifts nothing; the
+  tables are laid out a row of the flat stream, a few windows tiled down
+  the rows, and never as [B, L, ...]."""
+  rows, width = first.shape
+  # As many windows as make whole sublane tiles of every type: tiling that
+  # many down the rows is a broadcast in front of a view that copies
+  # nothing.
+  windows = math.lcm(length, 16) // length
+  if rows // length % windows:
+    windows = 1
+  cos, sin = (
+      jnp.tile(table, (rows // (windows * length), 1))
+      for table in _flat_rotary_tables(
+          length, rotary_dim, theta, windows, 2 * width // rotary_dim))
+  first, second = first.astype(jnp.float32), second.astype(jnp.float32)
+  return first * cos + -second * sin, second * cos + first * sin
+
+
 class _GateProjection(nn.Module):
-  """x [B, L, H] -> x W (+ b) [B, L, features] in float32: logits that
+  """x [..., H] -> x W (+ b) [..., features] in float32: logits that
   are summed along the window (the retention gate), exponentiated (the
   delta rule's decay) or ranked (the router) leave the matmul
   unrounded."""
@@ -362,7 +400,7 @@ class _GateProjection(nn.Module):
   def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
     kernel = self.param('kernel', nn.initializers.lecun_normal(),
                         (x.shape[-1], self.features), jnp.float32)
-    out = jnp.einsum('blh,hk->blk', x, kernel.astype(x.dtype),
+    out = jnp.einsum('...h,hk->...k', x, kernel.astype(x.dtype),
                      preferred_element_type=jnp.float32)
     if not self.use_bias:
       return out
@@ -572,7 +610,13 @@ class LatentAttention(nn.Module):
   output projection over the heads' values. The rotation is
   `apply_rotary`'s, over halves: a checkpoint published for interleaved
   pairs loads with its rotary columns in
-  `latent_attention.halves_from_pairs` order."""
+  `latent_attention.halves_from_pairs` order.
+
+  Called with `window_length`, x is the flat stream [B*L, H], windows of
+  that many rows one after another, and the operator is the Pallas call a
+  tile of windows (`latent_attention.window_tile_attention`): the caller
+  asked `latent_attention_path`. The leaves are the ones the modules below
+  declare, contracted flat, heads along the lanes."""
 
   hidden_size: int
   num_heads: int
@@ -585,8 +629,11 @@ class LatentAttention(nn.Module):
   dtype: Any = jnp.float32
 
   @nn.compact
-  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+  def __call__(self, x: jnp.ndarray, deterministic: bool,
+               window_length: Optional[int] = None) -> jnp.ndarray:
     del deterministic  # the published layer has no dropout
+    if window_length is not None:
+      return self._on_the_flat_stream(x, window_length)
     n, nope, rope = self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim
     dense = lambda name, features, axis=-1: nn.DenseGeneral(
         features=features, axis=axis, use_bias=False, dtype=self.dtype,
@@ -610,6 +657,42 @@ class LatentAttention(nn.Module):
           q_nope, q_rope, k_nope, k_rope, value,
           scale=(nope + rope) ** -0.5)
     return dense('output_transform', self.hidden_size, axis=(-2, -1))(out)
+
+  def _on_the_flat_stream(self, x: jnp.ndarray, length: int) -> jnp.ndarray:
+    """The same sublayer on x [B*L, H]. Every product is the modules' own
+    dot product an output element, written where the kernel reads it: the
+    query's position-free columns of all heads as one product, so that a
+    head's position-free part is a whole lane tile, and its rotary columns
+    as another, the heads' first halves in front of their second halves,
+    so that the rotation shifts nothing along the lanes
+    (`apply_rotary_flat`); `kv_b`'s output as it lies, a head's k_nope and
+    then its v. No [B, L, N, D] array of q, k or v is laid out."""
+    n, nope, rope = self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim
+    rank, h = self.kv_lora_rank, self.hidden_size
+    params = self.variables['params']
+    flat = lambda name, rows: params[name]['kernel'].astype(
+        self.dtype).reshape(rows, -1)
+    w_nope, w_halves = latent_attention.flat_query_kernels(
+        params['query']['kernel'].astype(self.dtype), nope)
+    half = rope // 2
+    q_nope, q_halves = jnp.dot(x, w_nope), jnp.dot(x, w_halves)
+    latent_and_key = jnp.dot(x, flat('kv_a', h))
+    latent = RMSNorm(self.rms_norm_eps, dtype=self.dtype, name='kv_a_norm')(
+        latent_and_key[:, :rank])
+    kv = jnp.dot(latent, flat('kv_b', rank))
+    # dclint: allow=dtype-downcast (rotated in float32; q and k meet in
+    # the compute dtype)
+    rotate = lambda first, second: tuple(
+        t.astype(self.dtype) for t in apply_rotary_flat(
+            first, second, length, self.rope_theta, rope))
+    q_first, q_second = rotate(q_halves[:, :n * half], q_halves[:, n * half:])
+    keys = latent_attention.placed_rotary_keys(*rotate(
+        latent_and_key[:, rank:rank + half], latent_and_key[:, rank + half:]))
+    with jax.named_scope('latent'):
+      out = latent_attention.window_tile_attention(
+          q_nope, q_first, q_second, kv, keys, length=length, num_heads=n,
+          scale=(nope + rope) ** -0.5)
+    return jnp.dot(out, flat('output_transform', n * self.v_head_dim))
 
 
 class GatedFeedForward(nn.Module):
@@ -667,7 +750,8 @@ class SparseExpertsFeedForward(nn.Module):
           f'experts held [{self.held_first}, '
           f'{self.held_first + self.held_count}) are not a share of '
           f'{self.num_experts}')
-    batch, length, h = x.shape
+    h = x.shape[-1]
+    tokens = x.reshape(-1, h)  # [B, L, H], or the flat stream as it is
     expert_init = nn.initializers.variance_scaling(
         1.0, 'fan_in', 'truncated_normal', batch_axis=(0,))
     w_gate, w_up = (
@@ -684,12 +768,11 @@ class SparseExpertsFeedForward(nn.Module):
             'router_selection_bias', nn.initializers.zeros,
             (self.num_experts,), jnp.float32) if self.selection_bias else None
         weights, experts = moe.route_top_k(
-            logits.reshape(batch * length, self.num_experts),
+            logits.reshape(-1, self.num_experts),
             self.experts_per_token, self.norm_topk, scoring=self.scoring,
             bias=bias, scale=self.routed_scale)
       routed, counts = moe.held_experts(
-          x.reshape(batch * length, h), weights, experts, w_gate, w_up,
-          w_down, self.held_first)
+          tokens, weights, experts, w_gate, w_up, w_down, self.held_first)
     self.sow('moe_counts', 'assignments', counts)
     with jax.named_scope('shared_expert'):
       shared = GatedFeedForward(
@@ -703,7 +786,7 @@ class SparseExpertsFeedForward(nn.Module):
         shared = (share * shared.astype(jnp.float32)).astype(self.dtype)
       if self.shared_scale != 1.0:
         shared = shared * jnp.asarray(self.shared_scale, shared.dtype)
-    return routed.reshape(batch, length, h) + shared
+    return routed.reshape(x.shape) + shared
 
 
 class ResidualWrapper(nn.Module):
@@ -817,6 +900,22 @@ def delta_rule_path(p, *, length: int) -> Optional[str]:
       value_head_dim=p.linear_value_head_dim,
       num_key_heads=p.linear_num_key_heads,
       num_value_heads=p.linear_num_value_heads, length=length)
+
+
+def latent_attention_path(p, *, length: int) -> Optional[str]:
+  """How a forward of this width runs the operator of its latent attention
+  layers (`forward_launch`'s `latent_attention_path`): `window_tile_kernel`,
+  one Pallas call a layer over tiles of windows of the flat stream, or
+  `plain`, the same arithmetic as XLA compiles it; None for a block kind
+  without such a layer. The rule is
+  ops/latent_attention.py::latent_attention_path, the one `EncoderStack`
+  asks where the forward is traced; no option asks for the kernel."""
+  if block_kind_of(p) != config_lib.BLOCK_LATENT_MOE:
+    return None
+  return latent_attention.latent_attention_path(
+      num_heads=p.num_heads, qk_nope_head_dim=p.qk_nope_head_dim,
+      qk_rope_head_dim=p.qk_rope_head_dim, v_head_dim=p.v_head_dim,
+      length=length, dtype=p.get('dtype', 'float32'))
 
 
 def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
@@ -1041,7 +1140,8 @@ class EncoderStack(nn.Module):
   (reference encoder_stack.py:96-198 for the published block).
 
   [B, L, H] in; [B, L, H] out, or the same rows flat, [B*L, H], where
-  the stack took the attention sublayer kernel (`attention_path`)."""
+  the stack took the attention sublayer kernel (`attention_path`) or the
+  latent attention's (`latent_attention_path`)."""
 
   params: ml_collections.FrozenConfigDict
   dtype: Any = jnp.float32
@@ -1086,6 +1186,13 @@ class EncoderStack(nn.Module):
         sow_intermediates=self.is_mutable_collection('intermediates'),
     ) == ATTENTION_FUSED_SUBLAYER
     batch, length, hidden = x.shape
+    if not self.is_initializing() and latent_attention_path(
+        p, length=length) == latent_attention.LATENT_WINDOW_TILE_KERNEL:
+      # That kernel's blocks are row ranges of the flat stream too, and
+      # norms, experts and feed-forwards are position-wise: flattened once,
+      # here (init runs the modules on [B, L, H], which declare the leaves).
+      x = x.reshape(batch * length, hidden)
+      attn_kwargs = dict(window_length=length)
 
     for n in range(p.num_hidden_layers):
       attn, ffn, wrap, norm = _block_modules(p, n, self.dtype)

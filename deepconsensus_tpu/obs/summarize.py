@@ -375,6 +375,9 @@ def summarize(events: List[Dict[str, Any]],
                                  if a.get('attention_path')}),
       'delta_rule_paths': sorted({str(a['delta_rule_path']) for a in launches
                                   if a.get('delta_rule_path')}),
+      'latent_attention_paths': sorted(
+          {str(a['latent_attention_path']) for a in launches
+           if a.get('latent_attention_path')}),
       'grouped_product_paths': sorted(
           {str(a['grouped_product_path']) for a in launches
            if a.get('grouped_product_path')}),
@@ -492,6 +495,7 @@ def format_summary(summary: Dict[str, Any]) -> str:
         f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
     if forward.get('layer_patterns'):
       delta_rule = ', '.join(forward.get('delta_rule_paths', ()))
+      latent = ', '.join(forward.get('latent_attention_paths', ()))
       ffn = ', '.join(forward.get('ffn_patterns', ()))
       scoring = ', '.join(forward.get('router_scorings', ()))
       grouped = ', '.join(forward.get('grouped_product_paths', ()))
@@ -512,7 +516,8 @@ def format_summary(summary: Dict[str, Any]) -> str:
           f'  layers: {", ".join(forward["layer_patterns"])}'
           + (f' ({forms} block)' if forms else '')
           + (f' (window: {windows})' if windows else '')
-          + (f' (delta rule: {delta_rule})' if delta_rule else '') + ''.join(
+          + (f' (delta rule: {delta_rule})' if delta_rule else '')
+          + (f' (latent attention: {latent})' if latent else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'
               for lo, hi, published in forward.get('experts_held', ()))
           + (f' ({experts})' if experts else '')

@@ -92,6 +92,15 @@ GROUPED_PRODUCT_VMEM_LIMIT_BYTES = 32 << 20
 # MiB more (moe_combine.vmem_bytes: 23.5 / 43.7 MiB).
 COMBINE_VMEM_LIMIT_BYTES = 56 << 20
 
+# Scoped VMEM for the latent attention's kernel (ops/latent_attention.py):
+# a step's blocks of 400 rows are 2.7 MiB at four heads of the published
+# sizes (q 0.4 + 0.2, k and v 0.8, the placed rotary keys 0.8, o 0.4),
+# twice each for the pipeline where their index changes, and what of its
+# 16 head-windows' float32 scores and weights the register file cannot
+# hold side by side. The call compiles within 12 MiB at twice the
+# windows; the limit is the batch-tiled kernels'.
+LATENT_ATTENTION_VMEM_LIMIT_BYTES = BATCH_TILE_VMEM_LIMIT_BYTES
+
 
 def batch_tile_compiler_params(
     vmem_limit_bytes: int = BATCH_TILE_VMEM_LIMIT_BYTES):

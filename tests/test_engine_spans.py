@@ -532,6 +532,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     assert 'router_scoring' not in e['args']
     # Nor a delta rule: these kinds have no Gated DeltaNet mixer.
     assert 'delta_rule_path' not in e['args']
+    # Nor a latent attention layer.
+    assert 'latent_attention_path' not in e['args']
     # Nor grouped products: no sparse experts.
     assert 'grouped_product_path' not in e['args']
     assert 'combine_path' not in e['args']
@@ -548,6 +550,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
+                     'latent_attention_paths': [],
                      'grouped_product_paths': [],
                      'combine_paths': [],
                      'block_forms': ['sequential'],

@@ -79,6 +79,7 @@ def test_engine_submit_to_delivery_serves_the_reference_bases(length,
     assert args['block_kind'] == KIND and args['attention_path'] == 'xla'
     # The CPU takes no kernel on its own; nor do heads of 8 anywhere.
     assert args['delta_rule_path'] == 'plain'
+    assert 'latent_attention_path' not in args
     assert args['grouped_product_path'] == 'ragged_dot'
     assert args['combine_path'] == 'gather'
     assert args['layer_pattern'] == 'GGGS'

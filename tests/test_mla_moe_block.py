@@ -25,7 +25,9 @@ from deepconsensus_tpu.models import config as config_lib
 from deepconsensus_tpu.models import model as model_lib
 from deepconsensus_tpu.obs import summarize as summarize_lib
 from deepconsensus_tpu.obs import trace as trace_lib
+from deepconsensus_tpu.ops import latent_attention
 from tests import mla_moe_reference as ref
+from tests.test_grouped_product import kernel_taken as as_on_one_tpu
 from tests.test_power_retention import pileup_rows
 
 PRESET = 'transformer_learn_values_mla_moe+custom'
@@ -196,6 +198,8 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   (drain,) = [e['args'] for e in events if e['name'] == 'finalize_drain']
   assert launch['block_kind'] == KIND and launch['attention_path'] == 'xla'
   assert 'delta_rule_path' not in launch
+  # The toy heads are no lane tiles and the CPU is no TPU.
+  assert launch['latent_attention_path'] == 'plain'
   assert launch['grouped_product_path'] == 'ragged_dot'
   assert launch['combine_path'] == 'gather'
   assert launch['layer_pattern'] == 'LLL' and launch['ffn_pattern'] == 'DEE'
@@ -251,6 +255,8 @@ def test_dctpu_trace_lists_both_patterns_and_the_router(tmp_path, capsys):
   assert forward['block_kinds'] == [KIND]
   assert forward['attention_paths'] == ['xla']
   assert forward['delta_rule_paths'] == []
+  # The toy heads are no lane tiles and the CPU is no TPU: the plain form.
+  assert forward['latent_attention_paths'] == ['plain']
   assert forward['grouped_product_paths'] == ['ragged_dot']
   assert forward['combine_paths'] == ['gather']
   assert forward['layer_patterns'] == ['LLL']
@@ -258,7 +264,8 @@ def test_dctpu_trace_lists_both_patterns_and_the_router(tmp_path, capsys):
   assert forward['router_scorings'] == ['sigmoid_bias']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
-  assert ('layers: LLL; experts 8-15 of 16 held (router: sigmoid_bias; '
+  assert ('layers: LLL (latent attention: plain); experts 8-15 of 16 held '
+          '(router: sigmoid_bias; '
           'grouped products: ragged_dot; combine: gather); feed-forward: DEE'
           in capsys.readouterr().out)
 
@@ -360,6 +367,91 @@ def test_eight_layers_at_the_published_widths_have_the_hand_counted_parameters()
   assert block == 64_098_816 + 7 * 640_029_312 == 4_544_304_000
 
 
+# --------------------------------- the flat stream and the window-tile kernel
+
+PUBLISHED_HEADS = dict(qk_nope_head_dim=128, qk_rope_head_dim=64,
+                       v_head_dim=128)
+
+
+def test_stack_on_the_flat_stream_through_the_kernel_is_the_stack_of_modules(
+    monkeypatch):
+  """Four heads of the published sizes on the toy stream, bfloat16: where
+  `latent_attention_path` says so the stack runs flat from its first layer
+  to the head, every latent attention through the Pallas call, the dense
+  and the expert feed-forwards on [B*L, H]; the same leaves, the modules'
+  own outputs up to the order of a float32 sum."""
+  p = tiny_params(100, held=(0, 16), dtype='bfloat16',
+                  inference_dtype='bfloat16', **PUBLISHED_HEADS)
+  model = model_lib.get_model(p)
+  variables = jax.tree_util.tree_map(
+      lambda a: a.astype(jnp.bfloat16), seeded_variables(model, p, seed=21))
+  rows = jnp.asarray(pileup_rows(p, 8, seed=21))
+  forward = lambda v, r: model.apply(v, r, mutable=['moe_counts'])
+  want, want_sown = jax.jit(forward)(variables, rows)
+  traced = []
+  real = latent_attention.window_tile_attention
+  monkeypatch.setattr(
+      latent_attention, 'window_tile_attention',
+      lambda *a, **k: traced.append(k['length']) or real(*a, **k))
+  init = lambda k: model.init(k, jnp.zeros((1, p.total_rows, 100, 1)))
+  with as_on_one_tpu(monkeypatch):
+    # (A function of its own: jit's cache does not see the declaration.)
+    got, got_sown = jax.jit(lambda v, r: forward(v, r))(variables, rows)
+    # And init, even so declared, runs the modules: the tree is theirs.
+    tree = jax.eval_shape(init, jax.random.PRNGKey(0))
+  assert traced == [100] * 3  # every layer, none through the plain form
+  assert got.shape == want.shape == (8, 100, 5)
+  difference = np.abs(np.asarray(got, np.float32)
+                      - np.asarray(want, np.float32))
+  # A weight a unit off moves an output a unit; a routed near-tie that
+  # falls the other way moves a position by more.
+  assert np.median(difference) < 2e-3 and (difference < 0.05).mean() > 0.98
+  counts = lambda sown: np.asarray(
+      model_lib.expert_assignments(sown['moe_counts']))
+  assert np.abs(counts(got_sown) - counts(want_sown)).sum() <= (
+      0.01 * counts(want_sown).sum())
+  shapes = lambda t: jax.tree_util.tree_map(lambda a: a.shape, t)
+  assert shapes(tree['params']) == shapes(variables['params'])
+
+
+def test_parameter_tree_of_latent_attention_is_what_it_was():
+  p = tiny_params(100, **PUBLISHED_HEADS)
+  tree = jax.eval_shape(
+      lambda k: model_lib.get_model(p).init(
+          k, jnp.zeros((1, p.total_rows, 100, 1))),
+      jax.random.PRNGKey(0))['params']['encoder']['latent_attention_1']
+  assert jax.tree_util.tree_map(lambda a: a.shape, tree) == {
+      'query': {'kernel': (64, 4, 192)}, 'kv_a': {'kernel': (64, 24 + 64)},
+      'kv_a_norm': {'scale': (24,)}, 'kv_b': {'kernel': (24, 4, 256)},
+      'output_transform': {'kernel': (4, 128, 64)}}
+
+
+def test_forward_launch_says_window_tile_kernel_as_on_one_tpu(
+    monkeypatch, tmp_path, capsys):
+  from deepconsensus_tpu import cli
+
+  p = tiny_params(100, held=(0, 16), dtype='bfloat16',
+                  inference_dtype='bfloat16', **PUBLISHED_HEADS)
+  variables = seeded_variables(model_lib.get_model(p), p, seed=22)
+  path = str(tmp_path / 'spans.jsonl')
+  with as_on_one_tpu(monkeypatch):
+    runner, _ = _runner(p, variables)
+    trace_lib.clear_early()
+    trace_lib.configure(path, tier='run')
+    try:
+      ids, _quals = runner.predict(pileup_rows(p, 8, seed=22))
+    finally:
+      trace_lib.configure(None)
+  assert np.asarray(ids).shape == (8, 100)
+  events = [e for e in summarize_lib.load_trace(path) if e.get('ph') == 'X']
+  (launch,) = [e['args'] for e in events if e['name'] == 'forward_launch']
+  assert launch['latent_attention_path'] == 'window_tile_kernel'
+  assert launch['attention_path'] == 'xla'
+  assert cli.main(['trace', path]) == 0
+  assert 'layers: LLL (latent attention: window_tile_kernel); experts' in (
+      capsys.readouterr().out)
+
+
 # ------------------------------------------------- what the kind declines
 
 def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
@@ -370,6 +462,14 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
   with pallas_util.single_device_inference():
     assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
     assert model_lib.delta_rule_path(p, length=100) is None
+    # Its own attention declines the toy heads (16 + 8 / 12 are no lane
+    # tiles) and takes the kernel at the published ones.
+    assert model_lib.latent_attention_path(p, length=100) == 'plain'
+    published = config_lib.get_config(PRESET)
+    config_lib.finalize_params(published, is_training=False)
+    assert model_lib.latent_attention_path(published, length=100) == (
+        'window_tile_kernel')
+    assert model_lib.latent_attention_path(published, length=130) == 'plain'
     # The grouped products decline the toy widths, and take the kernel at
     # whole lane tiles (the published 2048 and 768 are).
     assert model_lib.grouped_product_path(p, batch=8, length=100) == (

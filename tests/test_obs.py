@@ -640,6 +640,7 @@ class TestSummarize:
     # text leaves its line out.
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
                             'attention_paths': [], 'delta_rule_paths': [],
+                            'latent_attention_paths': [],
                             'grouped_product_paths': [],
                             'combine_paths': [], 'block_forms': [],
                             'layer_patterns': [], 'attention_windows': [],

@@ -270,6 +270,7 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   assert launch['block_kind'] == KIND and launch['attention_path'] == 'xla'
   assert launch['block_form'] == 'parallel'
   assert 'delta_rule_path' not in launch
+  assert 'latent_attention_path' not in launch
   assert launch['grouped_product_path'] == 'ragged_dot'
   assert launch['combine_path'] == 'gather'
   assert launch['layer_pattern'] == 'WWWF' and launch['ffn_pattern'] == 'EEEE'
@@ -461,6 +462,7 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
   with pallas_util.single_device_inference():
     assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
     assert model_lib.delta_rule_path(p, length=100) is None
+    assert model_lib.latent_attention_path(p, length=100) is None
     # The grouped products and the combine decline the toy widths, and take
     # their kernels at the published ones: a pack of 256 is two turns of
     # 12,800 tokens, a [4096, 4096] matrix passes in column blocks.

@@ -9,7 +9,7 @@ itself among them, and 256 for the loss; two layers of the power-retention block
 kind at hidden 5120, batch 256; one period of the gated-delta and
 sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512; one
 dense and one expert layer of the latent-attention kind at hidden 2048 with
-128 experts, batch 512; one period of the parallel window-and-full kind at
+128 experts, batch 512, and its attention's kernel alone at that pack; one period of the parallel window-and-full kind at
 hidden 4096 with 16 of 128 experts, batch 256; the grouped-product kernel
 and the combine's kernel the three take, each alone at one turn of each). A compile that passes
 here is not a
@@ -256,9 +256,11 @@ def test_latent_attention_moe_forward_b512_at_published_widths(
   """The fourth block kind as it is served on one chip, by shape alone: the
   leading dense layer and one expert layer (of the seven a chip holds) at
   the published widths, all 128 experts, bfloat16 leaves, a pack of 512
-  windows. As ModelRunner traces it without a mesh: the attention is plain
-  products, the grouped products the kernel whose grid follows the
-  groups, the combine the kernel a tile of tokens."""
+  windows. As ModelRunner traces it without a mesh: the stream flat, the
+  latent attention's operator the kernel a tile of windows with its
+  operands where the flat products write them, the grouped products the
+  kernel whose grid follows the groups, the combine the kernel a tile of
+  tokens."""
   p = config_lib.get_config('transformer_learn_values_mla_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 2
@@ -292,7 +294,29 @@ def test_latent_attention_moe_forward_b512_at_published_widths(
   # And the combine as one call of its own: no gather of the down
   # product's output, no [k, tokens, hidden] array.
   assert len(re.findall(r'%moe_combine\S* = ', text)) in (1, 2)
-  assert _n_kernels(compiled) in (3, 6)
+  # And each layer's latent attention as one call: no score tensor, no
+  # [B, L, N, D] or [B, N, L, D] array of q, k or v.
+  calls = re.findall(
+      r'%latent_window_tile\S* = \S+ custom-call\(([^)]*)\)', text)
+  assert len(calls) == 2
+  assert _n_kernels(compiled) in (5, 8)
+  assert 'f32[512,32,100,100]' not in text
+  assert 'bf16[512,32,100,100]' not in text
+  assert not re.search(r'bf16\[512,(100,32|32,100),(64|128|192|256)\]', text)
+  # The call reads what the products' fusions (and the rotation's, and the
+  # placed keys') wrote and the output product reads what it wrote: XLA
+  # puts no copy in front of an operand or behind the result. The only
+  # copies of the stream's size are its own on the way in and out,
+  # [512,100,2048] <-> [51200,2048].
+  made_by = dict(re.findall(r'\n\s*(%\S+) = \S+ (\S+?)\(', text))
+  for operands in calls:
+    for operand in operands.split(', '):
+      assert made_by[operand.split(' ')[-1]] in (
+          'fusion', 'get-tuple-element'), operand
+  assert not re.search(
+      r'= bf16\[(51200|512,100),(4096|8192|1024)\]\S* copy\(', text)
+  assert len(re.findall(r'= bf16\[51200,2048\]\S* copy\(', text)) == 0
+  assert len(re.findall(r'= bf16\[512,100,2048\]\S* copy\(', text)) <= 2
   assert 'combine/jit(_take)/gather' not in text
   assert 'bf16[6,25600,2048]' not in text
   assert 'f32[153600,768]' not in text
@@ -301,11 +325,12 @@ def test_latent_attention_moe_forward_b512_at_published_widths(
   # the sort's weights handed to the kernel's call as they were, XLA's
   # memory assignment left them in HBM and the gather ran 7x as long.
   assert 'bf16[25600,2048]{1,0:T(8,128)(2,1)S(1)}' in text
-  # The one rotary key is scored as it is: neither a key of 192 a head nor
-  # the rotary key repeated to 32 heads is laid out.
-  assert 'bf16[512,100,32,192]' in text  # the query
-  assert not re.search(r'= bf16\[512,100,32,64\]\S* broadcast', text)
-  assert not re.search(r'= \(?bf16\[512,100,32,192\]\S* concatenate', text)
+  # The one rotary key is scored out of four placed copies a layer
+  # ([51200, 1024]: a lane tile a half a head of a group): neither a key
+  # of 192 a head nor the rotary key repeated to 32 heads is laid out.
+  assert 'bf16[51200,1024]' in text
+  assert 'bf16[51200,6144]' in text  # the dense layer's gate and up
+  assert not re.search(r'bf16\[51200,(12288|2048,192)\]', text)
   memory = compiled.memory_analysis()
   # 64,098,816 + 640,029,312 block parameters and what lies outside.
   assert 2 * 704_128_128 < memory.argument_size_in_bytes < 1.45e9
@@ -414,6 +439,29 @@ def test_grouped_product_kernel_at_one_turn_of_each_cell(
   # (With column blocks the call holds 1.7 MB of its own beside them.)
   assert down.memory_analysis().temp_size_in_bytes < 2 << 20
   assert up.memory_analysis().temp_size_in_bytes < rows * 128 * 4 + (1 << 20)
+
+
+def test_latent_window_tile_kernel_at_a_pack_of_the_cell(one_chip,
+                                                        compiled_kernels):
+  """The latent attention's kernel alone at kanana_polish's pack: 512
+  windows of 100 positions, 32 heads of 128 + 64 / 128, the flat operands
+  as the products write them; Mosaic takes the row slices of a window
+  (100 rows are no whole tiles of bfloat16) and the step's 16 head-windows
+  within the call's VMEM limit."""
+  from deepconsensus_tpu.ops import latent_attention
+
+  rows, heads = 512 * 100, 32
+  flat = lambda width: jax.ShapeDtypeStruct(
+      (rows, width), jnp.bfloat16, sharding=one_chip)
+  call = lambda *operands: latent_attention.window_tile_attention(
+      *operands, length=100, num_heads=heads, scale=192 ** -0.5)
+  compiled = jax.jit(call).lower(
+      flat(heads * 128), flat(heads * 32), flat(heads * 32),
+      flat(heads * 256), flat(4 * 256)).compile()
+  assert _n_kernels(compiled) == 1
+  memory = compiled.memory_analysis()
+  assert memory.output_size_in_bytes == rows * heads * 128 * 2
+  assert memory.temp_size_in_bytes == 0  # nothing beside the operands
 
 
 @pytest.mark.parametrize('tokens,k,groups,hidden', [
