@@ -696,9 +696,11 @@ class ModelRunner:
     # What `forward_launch` says of the stack: how a layer composes its
     # sublayers (config.block_form), one letter a layer for its attention
     # and one for its feed-forward (config.layer_pattern,
-    # config.ffn_pattern), the window of the layers that attend within one
-    # and, for sparse experts, the share held, how the router scores and
-    # how many shared experts are averaged.
+    # config.ffn_pattern), the window of the layers that attend within one,
+    # each layer type's rotation where the configuration gives one a type
+    # (`rope`, {letter: 'default' | 'yarn×<factor>'}) and, for sparse
+    # experts, the share held, how the router scores and how many shared
+    # experts are averaged (absent where there are none).
     self._launch_fields = {}
     if 'transformer' in self.params.model_name:
       self._launch_fields.update(
@@ -709,6 +711,12 @@ class ModelRunner:
           'layer_pattern']:
         self._launch_fields.update(
             attention_window=int(self.params.sliding_window))
+      if self.params.get('rope_parameters', None):
+        self._launch_fields.update(rope={
+            letter: model_lib.Rope.of(config_lib.rope_parameters(
+                self.params, letter)).describe()
+            for letter in dict.fromkeys(
+                self._launch_fields['layer_pattern'])})
     self._sparse_experts = _holds_sparse_experts(self.params)
     if self._sparse_experts:
       first = int(self.params.experts_held_first)

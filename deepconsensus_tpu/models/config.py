@@ -148,19 +148,33 @@ def bucket_for(width: int, buckets):
 #                           scored by a sigmoid without a bias (ops/moe.py)
 #                           plus `num_shared_experts` shared experts that
 #                           are averaged; final bias-free LayerNorm.
+#   window_moe              a sequential pre-RMSNorm stack (plain weights)
+#                           whose layers' attention is LISTED, not derived:
+#                           `layer_types` names each layer's
+#                           (sliding_attention: grouped softmax attention
+#                           within `sliding_window` positions, both ways;
+#                           full_attention: over the whole window), and
+#                           `rope_parameters` gives each layer type its
+#                           rotation (the default law, or YaRN with its
+#                           magnitude); no q/k norm, no gate. Every layer's
+#                           feed-forward is sparse experts alone
+#                           (`mlp_layer_types` all sparse): a softmax router
+#                           without a bias, the top-k renormalised, no
+#                           shared expert; final RMSNorm.
 BLOCK_BANDED_SOFTMAX = 'banded_softmax_relu'
 BLOCK_POWER_RETENTION = 'power_retention_swiglu'
 BLOCK_GATED_DELTA_MOE = 'gated_delta_hybrid_moe'
 BLOCK_LATENT_MOE = 'latent_attention_moe'
 BLOCK_PARALLEL_WINDOW_MOE = 'parallel_window_moe'
+BLOCK_WINDOW_MOE = 'window_moe'
 BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION,
                BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
-               BLOCK_PARALLEL_WINDOW_MOE)
+               BLOCK_PARALLEL_WINDOW_MOE, BLOCK_WINDOW_MOE)
 # The kinds some of whose layers' feed-forward is sparse experts: what
 # they cannot run yet (--tp, int8, train, distill, export) is refused by
 # name.
 SPARSE_EXPERT_KINDS = (BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
-                       BLOCK_PARALLEL_WINDOW_MOE)
+                       BLOCK_PARALLEL_WINDOW_MOE, BLOCK_WINDOW_MOE)
 
 # How a layer composes its two sublayers (`forward_launch`'s `block_form`):
 # one after the other, each behind a norm of its own (x + f(norm_1(x)), then
@@ -190,6 +204,35 @@ LAYER_FULL_SOFTMAX = 'F'
 FFN_DENSE = 'D'
 FFN_EXPERTS = 'E'
 
+# The published names of a listed pattern's entries (`layer_types`,
+# `mlp_layer_types`), and the letter each is.
+LAYER_TYPES = {'sliding_attention': LAYER_WINDOW_SOFTMAX,
+               'full_attention': LAYER_FULL_SOFTMAX}
+MLP_LAYER_TYPES = {'sparse': FFN_EXPERTS}
+
+
+def _listed(params, key: str, letters: dict) -> str:
+  """The configuration's list `key`, one published name a layer, as
+  letters: refused by name where it does not name every layer once, or
+  names a type this kind does not run."""
+  names = list(params[key])
+  if len(names) != params.num_hidden_layers:
+    raise ValueError(
+        f'{key} lists {len(names)} layers and num_hidden_layers is '
+        f'{params.num_hidden_layers}: a stage lists its own layers')
+  unknown = sorted(set(names) - set(letters))
+  if unknown:
+    raise ValueError(f'{key} {unknown} are not served; the kind runs '
+                     f'{sorted(letters)}')
+  return ''.join(letters[name] for name in names)
+
+
+def rope_parameters(params, letter: str) -> dict:
+  """The published `rope_parameters` entry of the layer type whose letter
+  `letter` is (window_moe: one entry a layer type)."""
+  name = {v: k for k, v in LAYER_TYPES.items()}[letter]
+  return dict(params.rope_parameters[name])
+
 
 def layer_pattern(params) -> str:
   """The attention of every layer of the stack, in order. With
@@ -207,6 +250,8 @@ def layer_pattern(params) -> str:
     return ''.join(
         LAYER_FULL_SOFTMAX if (n + 1) % switch == 0 else LAYER_WINDOW_SOFTMAX
         for n in layers)
+  if kind == BLOCK_WINDOW_MOE:
+    return _listed(params, 'layer_types', LAYER_TYPES)
   letter = {BLOCK_BANDED_SOFTMAX: LAYER_BANDED_SOFTMAX,
             BLOCK_POWER_RETENTION: LAYER_POWER_RETENTION,
             BLOCK_LATENT_MOE: LAYER_LATENT}[kind]
@@ -216,12 +261,15 @@ def layer_pattern(params) -> str:
 def ffn_pattern(params) -> str:
   """The feed-forward of every layer of the stack, in order: sparse
   experts in every layer of the gated-delta and the parallel kinds; in the
-  latent-attention kind dense in the first `first_k_dense_replace` layers
-  and sparse experts behind them; dense everywhere else."""
+  window kind as `mlp_layer_types` lists them (sparse alone is served); in
+  the latent-attention kind dense in the first `first_k_dense_replace`
+  layers and sparse experts behind them; dense everywhere else."""
   kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
   layers = range(params.num_hidden_layers)
   if kind in (BLOCK_GATED_DELTA_MOE, BLOCK_PARALLEL_WINDOW_MOE):
     return FFN_EXPERTS * len(layers)
+  if kind == BLOCK_WINDOW_MOE:
+    return _listed(params, 'mlp_layer_types', MLP_LAYER_TYPES)
   if kind == BLOCK_LATENT_MOE:
     leading = params.first_k_dense_replace
     return ''.join(FFN_DENSE if n < leading else FFN_EXPERTS for n in layers)
@@ -541,6 +589,74 @@ def _set_transformer_learned_embeddings_parallel_moe_hparams(params):
   params.use_fused_hotpath = False
 
 
+# A public 12B sparse-expert model's layer types, period 4 over 28 layers.
+_WINDOW_MOE_LAYER_TYPES = (['sliding_attention'] * 3 + ['full_attention']) * 7
+
+
+def _set_transformer_learned_embeddings_window_moe_hparams(params):
+  """A sixth encoder block kind at the widths of a public 12B sparse-expert
+  model with 2.5B active parameters: hidden 2304, 28 layers, each a
+  sequential pre-RMSNorm block (eps 1e-6, plain weights). The attention has
+  32 query / 4 key-value heads of 128 without q/k norm, gate or bias, and
+  rotates the whole head; `layer_types` lists three sliding_attention
+  layers (window 1,024, default rotation at base 500,000) to one
+  full_attention layer (YaRN rotation: factor 16 over an original 8,192
+  positions, beta 32 / 1, magnitude 1.2772588722239782). The feed-forward
+  of every layer is 64 routed experts of width 896, 8 a token, scored by a
+  softmax and renormalised, with no shared expert. Behind this system's
+  pile-up embedding and 5-way head, served in bfloat16.
+
+  Which experts this process holds is a size of the configuration, as for
+  the other sparse-expert kinds. One v5e chip holds two periods of the
+  pattern with every expert (num_hidden_layers 8 and the first 8 entries
+  of both lists) and a pack of 512 windows (docs/inference.md)."""
+  _set_transformer_learned_embeddings_hparams(params)
+  params.model_name = 'transformer_learn_values_window_moe'
+  params.block_kind = BLOCK_WINDOW_MOE
+  params.transformer_input_size = 2304
+  params.num_hidden_layers = 28
+  params.rms_norm_eps = 1.0e-6
+  # Each layer's attention as listed, and each layer type's rotation.
+  params.layer_types = list(_WINDOW_MOE_LAYER_TYPES)
+  params.mlp_layer_types = ['sparse'] * 28
+  params.sliding_window = 1024
+  params.num_heads = 32
+  params.num_kv_heads = 4
+  params.head_dim = 128
+  params.rope_parameters = {
+      'sliding_attention': {'rope_type': 'default', 'rope_theta': 500000},
+      'full_attention': {
+          'rope_type': 'yarn', 'rope_theta': 500000, 'factor': 16,
+          'original_max_position_embeddings': 8192, 'beta_fast': 32,
+          'beta_slow': 1, 'attention_factor': 1.2772588722239782}}
+  # Routed experts alone in every layer; filter_size is one expert's width.
+  params.num_experts = 64
+  params.num_experts_per_tok = 8
+  params.moe_intermediate_size = 896
+  params.filter_size = 896
+  params.num_shared_experts = 0
+  params.shared_expert_intermediate_size = 0
+  params.norm_topk_prob = True
+  params.router_scoring = 'softmax'
+  params.router_selection_bias = False
+  params.routed_scaling_factor = 1.0
+  params.shared_expert_gated = False
+  params.experts_held_first = 0
+  params.experts_held_count = 64
+  # Rotary positions take the sinusoidal encoding's place, and pre-RMSNorm
+  # residuals the ReZero residual's.
+  params.add_pos_encoding = False
+  params.rezero = False
+  params.attn_win_size = 0
+  # The published model has no dropout.
+  params.layer_postprocess_dropout = 0.0
+  params.attention_dropout = 0.0
+  params.relu_dropout = 0.0
+  params.dtype = 'bfloat16'
+  params.inference_dtype = 'bfloat16'
+  params.use_fused_hotpath = False
+
+
 def _set_base_fc_hparams(params):
   params.model_name = 'fc'
   params.fc_size = [256, 512, 256, 128]
@@ -792,6 +908,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
     _set_transformer_learned_embeddings_mla_moe_hparams(params)
   elif model_config_name == 'transformer_learn_values_parallel_moe':
     _set_transformer_learned_embeddings_parallel_moe_hparams(params)
+  elif model_config_name == 'transformer_learn_values_window_moe':
+    _set_transformer_learned_embeddings_window_moe_hparams(params)
   else:
     raise ValueError(f'Unknown model_config_name: {model_config_name}')
 

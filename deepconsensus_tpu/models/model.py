@@ -15,6 +15,7 @@ attention_layer.py:34-237, ffn_layer.py:34-87), re-designed TPU-first:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any, Dict, Optional
@@ -325,45 +326,108 @@ class BiasFreeLayerNorm(nn.Module):
     return (y * scale).astype(self.dtype)
 
 
-def rotary_tables(length: int, head_dim: int, theta: float):
+@dataclasses.dataclass(frozen=True)
+class Rope:
+  """A layer type's rotary position embedding, as a published
+  `rope_parameters` entry states it: `default`, frequencies
+  theta**(-2i/D) at magnitude 1, or `yarn`, those frequencies interpolated
+  by `factor` where a dimension turns fewer than `beta_slow` times over
+  `original_max_position` positions, kept where it turns more than
+  `beta_fast` times, a linear ramp between, and cos and sin multiplied by
+  `attention_factor` (so q . k carries its square)."""
+
+  theta: float
+  kind: str = 'default'
+  factor: float = 1.0
+  original_max_position: int = 0
+  beta_fast: float = 32.0
+  beta_slow: float = 1.0
+  attention_factor: float = 1.0
+
+  @classmethod
+  def of(cls, parameters) -> 'Rope':
+    """From a published `rope_parameters` entry; a rope_type this module
+    does not compute is refused by name."""
+    parameters = dict(parameters)
+    kind = parameters.get('rope_type', 'default')
+    theta = float(parameters['rope_theta'])
+    if kind == 'default':
+      return cls(theta)
+    if kind != 'yarn':
+      raise ValueError(f'rope_type {kind!r} is not served; Rope computes '
+                       "'default' and 'yarn'")
+    return cls(theta, 'yarn', float(parameters['factor']),
+               int(parameters['original_max_position_embeddings']),
+               float(parameters.get('beta_fast', 32)),
+               float(parameters.get('beta_slow', 1)),
+               float(parameters['attention_factor']))
+
+  def describe(self) -> str:
+    """What `forward_launch` says of it: `default`, or `yarn×<factor>`."""
+    return 'default' if self.kind == 'default' else f'yarn×{self.factor:g}'
+
+
+def rope_frequencies(rope, head_dim: int):
+  """(inverse frequencies [head_dim / 2] float64, magnitude) of a Rope, or
+  of the default law at a bare base theta."""
+  if not isinstance(rope, Rope):
+    rope = Rope(float(rope))
+  inv = rope.theta ** (-np.arange(0, head_dim, 2, dtype=np.float64)
+                       / head_dim)
+  if rope.kind == 'default':
+    return inv, 1.0
+  # The dimension that turns `rotations` times over the original positions.
+  dim_of = lambda rotations: head_dim * math.log(
+      rope.original_max_position / (rotations * 2 * math.pi)) / (
+          2 * math.log(rope.theta))
+  low = max(math.floor(dim_of(rope.beta_fast)), 0)
+  high = min(math.ceil(dim_of(rope.beta_slow)), head_dim - 1)
+  ramp = np.clip((np.arange(head_dim // 2) - low) / max(high - low, 1e-3),
+                 0.0, 1.0)
+  return inv * ((1.0 - ramp) + ramp / rope.factor), rope.attention_factor
+
+
+def rotary_tables(length: int, head_dim: int, rope):
   """(cos, sin) [L, head_dim] float32 of the rotate-half rotary position
-  embedding: frequencies theta**(-2i/head_dim) over the first half of the
-  head, repeated over the second."""
-  inv = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+  embedding: `rope_frequencies` over the first half of the head, repeated
+  over the second, times the rope's magnitude. `rope` is a Rope or the
+  default law's base theta."""
+  inv, magnitude = rope_frequencies(rope, head_dim)
   angles = np.arange(length, dtype=np.float64)[:, None] * inv[None, :]
   angles = np.concatenate([angles, angles], axis=1)
-  return (np.cos(angles).astype(np.float32),
-          np.sin(angles).astype(np.float32))
+  return ((np.cos(angles) * magnitude).astype(np.float32),
+          (np.sin(angles) * magnitude).astype(np.float32))
 
 
-def apply_rotary(x: jnp.ndarray, theta: float,
+def apply_rotary(x: jnp.ndarray, rope,
                  rotary_dim: Optional[int] = None) -> jnp.ndarray:
-  """x [B, L, N, D] float32, positions 0..L-1 -> x rotated; with
-  `rotary_dim`, the first `rotary_dim` of the D alone (at frequencies
-  theta**(-2i/rotary_dim)), the rest as they are."""
+  """x [B, L, N, D] float32, positions 0..L-1 -> x rotated by `rope` (a
+  Rope, or the default law's base theta); with `rotary_dim`, the first
+  `rotary_dim` of the D alone (at frequencies over `rotary_dim`), the rest
+  as they are."""
   if rotary_dim is not None and rotary_dim != x.shape[3]:
     return jnp.concatenate(
-        [apply_rotary(x[..., :rotary_dim], theta), x[..., rotary_dim:]],
+        [apply_rotary(x[..., :rotary_dim], rope), x[..., rotary_dim:]],
         axis=-1)
-  cos, sin = rotary_tables(x.shape[1], x.shape[3], theta)
+  cos, sin = rotary_tables(x.shape[1], x.shape[3], rope)
   half = x.shape[3] // 2
   rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
   return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
 
 
 @functools.lru_cache(maxsize=None)
-def _flat_rotary_tables(length: int, rotary_dim: int, theta: float,
+def _flat_rotary_tables(length: int, rotary_dim: int, rope,
                         windows: int, heads: int):
   """(cos, sin) [windows * L, heads * rotary_dim / 2] float32: a half
   head's tables side by side a head and `windows` times down the rows.
   One array a stack, so that its layers share one constant."""
-  cos, sin = rotary_tables(length, rotary_dim, theta)
+  cos, sin = rotary_tables(length, rotary_dim, rope)
   tiled = lambda table: np.tile(table[:, :rotary_dim // 2], (windows, heads))
   return tiled(cos), tiled(sin)
 
 
 def apply_rotary_flat(first: jnp.ndarray, second: jnp.ndarray, length: int,
-                      theta: float, rotary_dim: int):
+                      rope, rotary_dim: int):
   """The first halves of N heads and their second halves, each
   [B*L, N * rotary_dim / 2] with windows of `length` rows one after another
   and the heads along the lanes -> both rotated in float32 as `apply_rotary`
@@ -382,7 +446,7 @@ def apply_rotary_flat(first: jnp.ndarray, second: jnp.ndarray, length: int,
   cos, sin = (
       jnp.tile(table, (rows // (windows * length), 1))
       for table in _flat_rotary_tables(
-          length, rotary_dim, theta, windows, 2 * width // rotary_dim))
+          length, rotary_dim, rope, windows, 2 * width // rotary_dim))
   first, second = first.astype(jnp.float32), second.astype(jnp.float32)
   return first * cos + -second * sin, second * cos + first * sin
 
@@ -434,11 +498,12 @@ class PowerRetentionAttention(nn.Module):
     query = head_norm('query_norm')(dense('query', self.num_heads)(x))
     key = head_norm('key_norm')(dense('key', self.num_kv_heads)(x))
     value = dense('value', self.num_kv_heads)(x)
-    # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
-    # after norm and rotation in float32)
-    query = apply_rotary(query, self.rope_theta).astype(self.dtype)
-    # dclint: allow=dtype-downcast (as above)
-    key = apply_rotary(key, self.rope_theta).astype(self.dtype)
+    with jax.named_scope('rotary'):
+      # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
+      # after norm and rotation in float32)
+      query = apply_rotary(query, self.rope_theta).astype(self.dtype)
+      # dclint: allow=dtype-downcast (as above)
+      key = apply_rotary(key, self.rope_theta).astype(self.dtype)
     log_gate = jax.nn.log_sigmoid(
         _GateProjection(self.num_kv_heads, name='gate')(x))
     with jax.named_scope('retention'):
@@ -528,8 +593,10 @@ class GroupedSoftmaxAttention(nn.Module):
                  multiplied by sigmoid(gate) before the output projection;
   `qk_norm`      q and k are RMSNorm'd over the head (zero-centred
                  weights, `rms_norm_eps`);
-  `rotary_dim`   q and k are rotated on the first `rotary_dim` of the head
-                 (0: the layer has no positions at all);
+  `rotary_dim`   q and k are rotated by `rope` (a Rope, or the default
+                 law's base theta) on the first `rotary_dim` of the head
+                 (0: the layer has no positions at all), inside scope
+                 `rotary`;
   `window`       position i attends to j only where |i - j| < window, both
                  ways (None: the whole window). A window that covers the
                  forward's length masks nothing and builds no mask.
@@ -541,7 +608,7 @@ class GroupedSoftmaxAttention(nn.Module):
   num_kv_heads: int
   head_dim: int
   rotary_dim: int
-  rope_theta: float
+  rope: Any
   rms_norm_eps: Optional[float] = None
   output_gate: bool = True
   qk_norm: bool = True
@@ -574,9 +641,10 @@ class GroupedSoftmaxAttention(nn.Module):
       # dclint: allow=dtype-downcast (q and k meet in the compute dtype,
       # after norm and rotation in float32)
       rotate = lambda t: apply_rotary(
-          t.astype(jnp.float32), self.rope_theta, self.rotary_dim).astype(
+          t.astype(jnp.float32), self.rope, self.rotary_dim).astype(
               self.dtype)
-      query, key = rotate(query), rotate(key)
+      with jax.named_scope('rotary'):
+        query, key = rotate(query), rotate(key)
     with jax.named_scope('softmax'):
       grouped = query.reshape(batch, length, n_kv, n_q // n_kv, d)
       scores = jnp.einsum('blkgd,bmkd->bkglm', grouped, key,
@@ -650,8 +718,9 @@ class LatentAttention(nn.Module):
     # the compute dtype)
     rotate = lambda t: apply_rotary(t.astype(jnp.float32),
                                     self.rope_theta).astype(self.dtype)
-    q_rope = rotate(q_rope)
-    k_rope = rotate(k_rope[:, :, None, :])[:, :, 0, :]
+    with jax.named_scope('rotary'):
+      q_rope = rotate(q_rope)
+      k_rope = rotate(k_rope[:, :, None, :])[:, :, 0, :]
     with jax.named_scope('latent'):
       out = latent_attention.latent_attention(
           q_nope, q_rope, k_nope, k_rope, value,
@@ -685,9 +754,12 @@ class LatentAttention(nn.Module):
     rotate = lambda first, second: tuple(
         t.astype(self.dtype) for t in apply_rotary_flat(
             first, second, length, self.rope_theta, rope))
-    q_first, q_second = rotate(q_halves[:, :n * half], q_halves[:, n * half:])
-    keys = latent_attention.placed_rotary_keys(*rotate(
-        latent_and_key[:, rank:rank + half], latent_and_key[:, rank + half:]))
+    with jax.named_scope('rotary'):
+      q_first, q_second = rotate(q_halves[:, :n * half],
+                                 q_halves[:, n * half:])
+      key_halves = rotate(latent_and_key[:, rank:rank + half],
+                          latent_and_key[:, rank + half:])
+    keys = latent_attention.placed_rotary_keys(*key_halves)
     with jax.named_scope('latent'):
       out = latent_attention.window_tile_attention(
           q_nope, q_first, q_second, kv, keys, length=length, num_heads=n,
@@ -723,7 +795,8 @@ class SparseExpertsFeedForward(nn.Module):
   `held_first + held_count - 1` alone, each a SwiGLU of `expert_width`;
   plus a SwiGLU of `shared_width`, times sigmoid(x w_s) where
   `shared_gate` and times `shared_scale` (1 / m makes one SwiGLU of m x the
-  width the mean of m shared experts: the down product is linear). The
+  width the mean of m shared experts: the down product is linear); a
+  `shared_width` of 0 is no shared expert: no leaf, nothing added. The
   assignments each held expert took are sown as
   `assignments` in the `moe_counts` collection, for whoever asks for it."""
 
@@ -774,6 +847,8 @@ class SparseExpertsFeedForward(nn.Module):
       routed, counts = moe.held_experts(
           tokens, weights, experts, w_gate, w_up, w_down, self.held_first)
     self.sow('moe_counts', 'assignments', counts)
+    if not self.shared_width:
+      return routed.reshape(x.shape)
     with jax.named_scope('shared_expert'):
       shared = GatedFeedForward(
           hidden_size=h, filter_size=self.shared_width, dtype=self.dtype,
@@ -960,7 +1035,7 @@ def _sparse_experts(p, n: int, dtype):
   """Layer n's sparse experts: every size, the router's scoring, bias and
   factor and the shared expert's gate among them, as the configuration
   states it. Shared experts that are averaged run as one of their summed
-  width, times one over their number."""
+  width, times one over their number; a shared width of 0 is none."""
   averaged = p.get('shared_expert_combination', None) == 'average'
   return SparseExpertsFeedForward(
       hidden_size=p.hidden_size,
@@ -1010,7 +1085,7 @@ def _block_modules(p, n: int, dtype):
         # A window layer rotates the whole head and attends within the
         # window; a full layer has neither positions nor mask.
         rotary_dim=p.head_dim if windowed else 0,
-        rope_theta=p.rope_theta,
+        rope=p.rope_theta,
         output_gate=False,
         qk_norm=False,
         window=p.sliding_window if windowed else None,
@@ -1048,7 +1123,7 @@ def _block_modules(p, n: int, dtype):
           num_kv_heads=p.num_kv_heads,
           head_dim=p.head_dim,
           rotary_dim=int(p.head_dim * p.partial_rotary_factor),
-          rope_theta=p.rope_theta,
+          rope=p.rope_theta,
           rms_norm_eps=p.rms_norm_eps,
           dtype=dtype,
           name=f'gated_attention_{n}',
@@ -1068,6 +1143,28 @@ def _block_modules(p, n: int, dtype):
     ffn = _sparse_experts(p, n, dtype)
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps,
                     rms_norm_zero_centred=True)
+  elif kind == config_lib.BLOCK_WINDOW_MOE:
+    # Listed, not derived: the layer's type names its attention and its
+    # rotation (both refused by name where the lists name what is not
+    # served).
+    letter = config_lib.layer_pattern(p)[n]
+    config_lib.ffn_pattern(p)
+    attn = GroupedSoftmaxAttention(
+        hidden_size=p.hidden_size,
+        num_heads=p.num_heads,
+        num_kv_heads=p.num_kv_heads,
+        head_dim=p.head_dim,
+        rotary_dim=p.head_dim,
+        rope=Rope.of(config_lib.rope_parameters(p, letter)),
+        output_gate=False,
+        qk_norm=False,
+        window=(p.sliding_window
+                if letter == config_lib.LAYER_WINDOW_SOFTMAX else None),
+        dtype=dtype,
+        name=f'self_attention_{n}',
+    )
+    ffn = _sparse_experts(p, n, dtype)
+    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
   elif kind == config_lib.BLOCK_POWER_RETENTION:
     if p.retention_degree != power_retention.DEGREE:
       raise ValueError(

@@ -392,6 +392,11 @@ def summarize(events: List[Dict[str, Any]],
                                    if a.get('attention_window')}),
       'shared_experts': sorted({int(a['shared_experts']) for a in launches
                                 if a.get('shared_experts')}),
+      # Each layer type's rotation, one string a stack: 'W default, F
+      # yarn×16'.
+      'ropes': sorted({', '.join(f'{letter} {rope}' for letter, rope in
+                                 dict(a['rope']).items())
+                       for a in launches if a.get('rope')}),
       'ffn_patterns': sorted({str(a['ffn_pattern']) for a in launches
                               if a.get('ffn_pattern')}),
       'router_scorings': sorted({str(a['router_scoring']) for a in launches
@@ -512,10 +517,12 @@ def format_summary(summary: Dict[str, Any]) -> str:
                         if form != 'sequential')
       windows = ', '.join(
           str(w) for w in forward.get('attention_windows', ()))
+      ropes = '; '.join(forward.get('ropes', ()))
       lines.append(
           f'  layers: {", ".join(forward["layer_patterns"])}'
           + (f' ({forms} block)' if forms else '')
           + (f' (window: {windows})' if windows else '')
+          + (f' (rope: {ropes})' if ropes else '')
           + (f' (delta rule: {delta_rule})' if delta_rule else '')
           + (f' (latent attention: {latent})' if latent else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'

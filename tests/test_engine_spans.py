@@ -529,6 +529,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     assert 'attention_window' not in e['args']
     assert 'experts_held' not in e['args']
     assert 'shared_experts' not in e['args']
+    assert 'rope' not in e['args']
     assert 'router_scoring' not in e['args']
     # Nor a delta rule: these kinds have no Gated DeltaNet mixer.
     assert 'delta_rule_path' not in e['args']
@@ -557,7 +558,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
                      'layer_patterns': [config_lib.layer_pattern(p)],
                      'attention_windows': [],
                      'ffn_patterns': [config_lib.ffn_pattern(p)],
-                     'router_scorings': [], 'shared_experts': [],
+                     'router_scorings': [], 'shared_experts': [], 'ropes': [],
                      'experts_held': [],
                      'n_positions': 3 * BATCH * p.max_length,
                      'weight_bytes': 62}

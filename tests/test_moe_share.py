@@ -381,7 +381,7 @@ def test_eight_shares_of_sixteen_experts_add_up_to_the_uncut_parallel_layer():
         {'params': {'scale': scale}}, x)
     attended = model_lib.GroupedSoftmaxAttention(
         hidden_size=H, num_heads=heads, num_kv_heads=kv_heads, head_dim=d,
-        rotary_dim=d, rope_theta=5e4, output_gate=False, qk_norm=False,
+        rotary_dim=d, rope=5e4, output_gate=False, qk_norm=False,
         window=6).apply({'params': attention_weights}, u, deterministic=True)
     ffn = lambda first: model_lib.SparseExpertsFeedForward(
         hidden_size=H, num_experts=experts, experts_per_token=top_k,

@@ -645,7 +645,8 @@ class TestSummarize:
                             'combine_paths': [], 'block_forms': [],
                             'layer_patterns': [], 'attention_windows': [],
                             'ffn_patterns': [], 'router_scorings': [],
-                            'shared_experts': [], 'experts_held': [],
+                            'shared_experts': [], 'ropes': [],
+                            'experts_held': [],
                             'n_positions': 0, 'weight_bytes': 0}
     assert 'forward:' not in text
 

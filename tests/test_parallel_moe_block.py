@@ -211,7 +211,7 @@ def test_the_window_masks_only_where_it_is_shorter_than_the_forward():
   mask, |i - j| < window both ways."""
   attention = lambda window: model_lib.GroupedSoftmaxAttention(
       hidden_size=16, num_heads=4, num_kv_heads=2, head_dim=4, rotary_dim=4,
-      rope_theta=5e4, output_gate=False, qk_norm=False, window=window)
+      rope=5e4, output_gate=False, qk_norm=False, window=window)
   x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 10, 16)),
                   jnp.float32)
   variables = attention(None).init(jax.random.PRNGKey(0), x,

@@ -404,20 +404,77 @@ def test_parallel_window_moe_forward_b256_at_published_widths(
   assert memory.temp_size_in_bytes < 5.15 * 2**30
 
 
-@pytest.mark.parametrize('rows,groups,hidden,width,columns', [
-    (153_600, 128, 2048, 768, (768, 2048)),
-    (256_000, 256, 2048, 512, (512, 2048)),
-    (102_400, 16, 4096, 4096, (512, 1024))],
+def test_window_moe_forward_b512_at_published_widths(
+    one_chip, compiled_kernels, monkeypatch):
+  """The sixth block kind as it is served on one chip, by shape alone (no
+  array of the 6.22 GiB is made): two periods of the listed pattern
+  (`WWWFWWWF`) at the published widths, all 64 experts of each layer,
+  bfloat16 leaves, a pack of 512 windows. As ModelRunner traces it without
+  a mesh: the attention is plain products with the rotation in scope
+  `rotary`, the grouped products and the combine their kernels, and no
+  shared expert anywhere."""
+  p = config_lib.get_config('transformer_learn_values_window_moe+custom')
+  with p.unlocked():
+    p.num_hidden_layers = 8
+    p.layer_types = list(p.layer_types)[:8]
+    p.mlp_layer_types = list(p.mlp_layer_types)[:8]
+  config_lib.finalize_params(p, is_training=False)
+  assert config_lib.layer_pattern(p) == 'WWWFWWWF'
+  model = model_lib.get_model(p)
+  tree = jax.eval_shape(
+      lambda key: model.init(
+          key, jnp.zeros((1, p.total_rows, p.max_length, 1), jnp.float32)),
+      jax.random.PRNGKey(0))['params']
+  variables = {'params': jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                     sharding=one_chip), tree)}
+  rows = jax.ShapeDtypeStruct(
+      (512, p.total_rows, p.max_length, 1), jnp.float32, sharding=one_chip)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+
+  def forward(variables, rows):
+    with pallas_util.single_device_inference():
+      return model.apply(variables, rows, mutable=['moe_counts'])
+
+  compiled = jax.jit(forward).lower(variables, rows).compile()
+  text = compiled.as_text()
+  # In every layer two calls of the grouped products' kernel and one of
+  # the combine's (a layer's two turns are one loop, which the compiler may
+  # unroll); none of the compiler's own grouped products.
+  assert 'ragged-dot' not in text
+  assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (8, 16)
+  assert len(re.findall(r'%moe_combine\S* = ', text)) in (8, 16)
+  assert 'shared_expert' not in text
+  # A turn is 25,600 tokens of 8 assignments, two turns a pack.
+  assert 'bf16[204800,2304]' in text and 'bf16[409600,2304]' not in text
+  # Grouped heads: the scores are [B, 4, 8, L, L]; at L=100 the window of
+  # 1,024 masks nothing and builds no mask.
+  assert 'f32[512,4,8,100,100]' in text
+  assert 'pred[100,100]' not in text
+  memory = compiled.memory_analysis()
+  # 3,341,979,648 block parameters and what lies outside, 2 bytes each.
+  assert 2 * 3_341_979_648 < memory.argument_size_in_bytes < 6.72e9
+  # 6.24 GiB of arguments and 2.24 GiB of temporaries as compiled (PR 40),
+  # 8.48 of the chip's 15.75 GiB: the temporaries held to a twentieth over.
+  assert memory.temp_size_in_bytes < 2.35 * 2**30
+
+
+@pytest.mark.parametrize('rows,groups,hidden,width,columns,held_mib', [
+    (153_600, 128, 2048, 768, (768, 2048), 2),
+    (256_000, 256, 2048, 512, (512, 2048), 2),
+    (102_400, 16, 4096, 4096, (512, 1024), 2),
+    (204_800, 64, 2304, 896, (896, 2304), 7)],
                          ids=['kanana_polish', 'qwen3next_polish',
-                              'commanda_polish'])
+                              'commanda_polish', 'mellum_polish'])
 def test_grouped_product_kernel_at_one_turn_of_each_cell(
-    one_chip, compiled_kernels, rows, groups, hidden, width, columns):
-  """The grouped products' kernel alone at one turn of the three cells
+    one_chip, compiled_kernels, rows, groups, hidden, width, columns,
+    held_mib):
+  """The grouped products' kernel alone at one turn of the four cells
   that run it (25,600 tokens of 6 and of 10 assignments at hidden 2048,
-  12,800 of 8 at hidden 4096): gate and up as one call, then the down
+  12,800 of 8 at hidden 4096, 25,600 of 8 at hidden 2304): gate and up as one call, then the down
   product, within pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES: a group's
-  matrices resident whole at widths 768 and 512, in column blocks of 512
-  and 1,024 where one matrix is [4096, 4096]."""
+  matrices resident whole at widths 768, 512 and 896 (seven lane tiles),
+  in column blocks of 512 and 1,024 where one matrix is [4096, 4096]."""
   from deepconsensus_tpu.ops import grouped_product
 
   assert grouped_product.tiles(rows, hidden, width, matrices=2) == (
@@ -436,8 +493,10 @@ def test_grouped_product_kernel_at_one_turn_of_each_cell(
   assert 'grouped_product' in down.as_text() and _n_kernels(down) == 1
   # Nothing of the rows' size beside the operands and the result, but the
   # routing weights as a column, which the chip pads to a lane tile a row.
-  # (With column blocks the call holds 1.7 MB of its own beside them.)
-  assert down.memory_analysis().temp_size_in_bytes < 2 << 20
+  # (With column blocks the call holds 1.7 MB of its own beside them; at
+  # 204,800 rows over 64 groups 6.5 MiB, whatever the width, and 0.2 MiB
+  # at half the rows: the compiler's, nothing of [rows, width], PR 40.)
+  assert down.memory_analysis().temp_size_in_bytes < held_mib << 20
   assert up.memory_analysis().temp_size_in_bytes < rows * 128 * 4 + (1 << 20)
 
 
@@ -465,14 +524,16 @@ def test_latent_window_tile_kernel_at_a_pack_of_the_cell(one_chip,
 
 
 @pytest.mark.parametrize('tokens,k,groups,hidden', [
-    (25_600, 6, 128, 2048), (25_600, 10, 256, 2048), (12_800, 8, 16, 4096)],
+    (25_600, 6, 128, 2048), (25_600, 10, 256, 2048), (12_800, 8, 16, 4096),
+    (25_600, 8, 64, 2304)],
                          ids=['kanana_polish', 'qwen3next_polish',
-                              'commanda_polish'])
+                              'commanda_polish', 'mellum_polish'])
 def test_combine_kernel_at_one_turn_of_each_cell(one_chip, compiled_kernels,
                                                  tokens, k, groups, hidden):
-  """The combine's kernel alone at one turn of the three cells that run it
+  """The combine's kernel alone at one turn of the four cells that run it
   (25,600 tokens of 6 assignments over 128 held experts and of 10 over
-  256 at hidden 2048; 12,800 of 8 over 16 at hidden 4096): 8-row copies
+  256 at hidden 2048; 12,800 of 8 over 16 at hidden 4096; 25,600 of 8 over
+  64 at hidden 2304, 18 lane tiles): 8-row copies
   out of a [rows, hidden] array in HBM, the 0/1 product, two buffers of a
   tile's runs within pallas_util.COMBINE_VMEM_LIMIT_BYTES."""
   from deepconsensus_tpu.ops import moe_combine
