@@ -1171,7 +1171,8 @@ class ModelRunner:
     """What the compiled forward of this pack takes of the kernels the
     model chooses by itself: `attention_path` for its attention sublayers,
     where it has Gated DeltaNet mixers `delta_rule_path`, where it has
-    latent attention layers `latent_attention_path` and where it has
+    latent attention layers `latent_attention_path`, where it has
+    grouped-head softmax layers `grouped_attention_path` and where it has
     sparse experts `grouped_product_path` and `combine_path`; the model's
     own rules, asked as the forward's trace asks them."""
     if 'transformer' not in self.params.model_name:
@@ -1183,6 +1184,8 @@ class ModelRunner:
           'delta_rule_path': model_lib.delta_rule_path(
               self.params, length=length),
           'latent_attention_path': model_lib.latent_attention_path(
+              self.params, length=length),
+          'grouped_attention_path': model_lib.grouped_attention_path(
               self.params, length=length),
           'grouped_product_path': model_lib.grouped_product_path(
               self.params, batch=batch, length=length),

@@ -29,6 +29,7 @@ import numpy as np
 from deepconsensus_tpu import constants
 from deepconsensus_tpu.models import config as config_lib
 from deepconsensus_tpu.ops import gated_delta
+from deepconsensus_tpu.ops import grouped_attention
 from deepconsensus_tpu.ops import latent_attention
 from deepconsensus_tpu.ops import moe
 from deepconsensus_tpu.ops import pallas_util
@@ -601,7 +602,14 @@ class GroupedSoftmaxAttention(nn.Module):
                  ways (None: the whole window). A window that covers the
                  forward's length masks nothing and builds no mask.
 
-  No biases; the softmax is float32."""
+  No biases; the softmax is float32.
+
+  Called with `window_length`, x is the flat stream [B*L, H] (or [B, L, H],
+  flattened here and given back so), windows of that many rows one after
+  another, and the operator with the rotation of q and k is the Pallas
+  call a tile of windows (`grouped_attention.window_tile_attention`): the
+  caller asked `grouped_attention_path`. The leaves are the ones the
+  modules below declare, contracted flat, heads along the lanes."""
 
   hidden_size: int
   num_heads: int
@@ -616,12 +624,16 @@ class GroupedSoftmaxAttention(nn.Module):
   dtype: Any = jnp.float32
 
   @nn.compact
-  def __call__(self, x: jnp.ndarray, deterministic: bool) -> jnp.ndarray:
+  def __call__(self, x: jnp.ndarray, deterministic: bool,
+               window_length: Optional[int] = None) -> jnp.ndarray:
     del deterministic
     n_q, n_kv, d = self.num_heads, self.num_kv_heads, self.head_dim
     if n_q % n_kv:
       raise ValueError(f'{n_q} query heads do not group over {n_kv} '
                        'key-value heads')
+    if window_length is not None:
+      return self._on_the_flat_stream(
+          x.reshape(-1, x.shape[-1]), window_length).reshape(x.shape)
     batch, length, _ = x.shape
     dense = lambda name, heads, width: nn.DenseGeneral(
         features=(heads, width), axis=-1, use_bias=False, dtype=self.dtype,
@@ -667,6 +679,43 @@ class GroupedSoftmaxAttention(nn.Module):
         features=self.hidden_size, axis=(-2, -1), use_bias=False,
         dtype=self.dtype, kernel_init=nn.initializers.lecun_normal(),
         name='output_transform')(out)
+
+  def _on_the_flat_stream(self, x: jnp.ndarray, length: int) -> jnp.ndarray:
+    """The same sublayer on x [B*L, H]. q, k and v are each one product of
+    x against its leaf laid flat, [H, heads x D], so a head is a lane tile
+    of what the kernel reads; the rotation is the kernel's prologue. A gate
+    and q/k norms, where the layer has them, run round the call in XLA:
+    the normed q and k are rounded to the compute dtype before the
+    rotation, and the gate multiplies o as the call rounded it. No
+    [B, L, N, D] array of q, k or v is laid out."""
+    n_q, n_kv, d = self.num_heads, self.num_kv_heads, self.head_dim
+    params = self.variables['params']
+    leaf = lambda name: params[name]['kernel'].astype(self.dtype)
+    flat = lambda w: w.reshape(w.shape[0], -1)
+    query_leaf = leaf('query')
+    query = jnp.dot(x, flat(query_leaf[..., :d]))
+    key, value = jnp.dot(x, flat(leaf('key'))), jnp.dot(x, flat(leaf('value')))
+    if self.qk_norm:
+      # dclint: allow=dtype-downcast (q and k meet in the compute dtype)
+      head_norm = lambda name, t, heads: RMSNorm(
+          self.rms_norm_eps, zero_centred=True, name=name)(
+              t.reshape(-1, heads, d)).reshape(t.shape).astype(self.dtype)
+      query = head_norm('query_norm', query, n_q)
+      key = head_norm('key_norm', key, n_kv)
+    tables = (None, None)
+    if self.rotary_dim:
+      tables = grouped_attention.signed_tables(
+          *rotary_tables(length, d, self.rope))
+    with jax.named_scope('softmax'):
+      out = grouped_attention.window_tile_attention(
+          query, key, value, *tables, length=length, num_heads=n_q,
+          num_kv_heads=n_kv, scale=d ** -0.5)
+    if self.output_gate:
+      gate = jnp.dot(x, flat(query_leaf[..., d:]))
+      # dclint: allow=dtype-downcast (as in the plain form)
+      out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
+          self.dtype)
+    return jnp.dot(out, leaf('output_transform').reshape(n_q * d, -1))
 
 
 class LatentAttention(nn.Module):
@@ -977,6 +1026,28 @@ def delta_rule_path(p, *, length: int) -> Optional[str]:
       num_value_heads=p.linear_num_value_heads, length=length)
 
 
+def grouped_attention_path(p, *, length: int) -> Optional[str]:
+  """How a forward of this width runs the operator of its grouped-head
+  softmax attention layers, the rotation of q and k with it
+  (`forward_launch`'s `grouped_attention_path`): `window_tile_kernel`, one
+  Pallas call a layer over tiles of windows of the flat stream, where the
+  rule takes it for every such layer of the stack, or `plain`, the module's
+  arithmetic as XLA compiles it; None for a stack without such a layer. The
+  rule is ops/grouped_attention.py::grouped_attention_path, asked of each
+  layer's sizes as `EncoderStack` asks it where the forward is traced; no
+  option asks for the kernel."""
+  layers = [sizes for sizes in (_grouped_attention_sizes(p, n)
+                                for n in range(p.num_hidden_layers)) if sizes]
+  if not layers:
+    return None
+  paths = {grouped_attention.grouped_attention_path(
+      num_heads=sizes['num_heads'], num_kv_heads=sizes['num_kv_heads'],
+      head_dim=sizes['head_dim'], rotary_dim=sizes['rotary_dim'],
+      window=sizes['window'], length=length, dtype=p.get('dtype', 'float32'))
+           for sizes in layers}
+  return paths.pop() if len(paths) == 1 else grouped_attention.GROUPED_PLAIN
+
+
 def latent_attention_path(p, *, length: int) -> Optional[str]:
   """How a forward of this width runs the operator of its latent attention
   layers (`forward_launch`'s `latent_attention_path`): `window_tile_kernel`,
@@ -1056,6 +1127,39 @@ def _sparse_experts(p, n: int, dtype):
   )
 
 
+def _grouped_attention_sizes(p, n: int) -> Optional[Dict[str, Any]]:
+  """Layer n's GroupedSoftmaxAttention sizes, where its attention is one
+  (None elsewhere): what `_block_modules` builds it with and what
+  `grouped_attention_path` asks the rule of."""
+  kind = block_kind_of(p)
+  if kind not in (config_lib.BLOCK_PARALLEL_WINDOW_MOE,
+                  config_lib.BLOCK_WINDOW_MOE,
+                  config_lib.BLOCK_GATED_DELTA_MOE):
+    return None
+  letter = config_lib.layer_pattern(p)[n]
+  heads = dict(hidden_size=p.hidden_size, num_heads=p.num_heads,
+               num_kv_heads=p.num_kv_heads, head_dim=p.head_dim)
+  windowed = letter == config_lib.LAYER_WINDOW_SOFTMAX
+  window = p.sliding_window if windowed else None
+  if kind == config_lib.BLOCK_PARALLEL_WINDOW_MOE:
+    # A window layer rotates the whole head and attends within the
+    # window; a full layer has neither positions nor mask.
+    return dict(heads, rotary_dim=p.head_dim if windowed else 0,
+                rope=p.rope_theta, output_gate=False, qk_norm=False,
+                window=window)
+  if kind == config_lib.BLOCK_WINDOW_MOE:
+    # Listed, not derived: the layer's type names its attention and its
+    # rotation (both refused by name where the lists name what is not
+    # served).
+    return dict(heads, rotary_dim=p.head_dim,
+                rope=Rope.of(config_lib.rope_parameters(p, letter)),
+                output_gate=False, qk_norm=False, window=window)
+  if letter != config_lib.LAYER_GATED_SOFTMAX:
+    return None
+  return dict(heads, rotary_dim=int(p.head_dim * p.partial_rotary_factor),
+              rope=p.rope_theta, rms_norm_eps=p.rms_norm_eps, window=None)
+
+
 def _block_modules(p, n: int, dtype):
   """(attention, feed-forward, wrap, norm) of encoder layer `n` for the
   configuration's block kind and, where the kind's layers are not alike,
@@ -1075,23 +1179,9 @@ def _block_modules(p, n: int, dtype):
       raise ValueError(
           f'first_k_dense_replace {p.first_k_dense_replace} is not served: '
           'the parallel block has no dense feed-forward')
-    windowed = (config_lib.layer_pattern(p)[n]
-                == config_lib.LAYER_WINDOW_SOFTMAX)
     attn = GroupedSoftmaxAttention(
-        hidden_size=p.hidden_size,
-        num_heads=p.num_heads,
-        num_kv_heads=p.num_kv_heads,
-        head_dim=p.head_dim,
-        # A window layer rotates the whole head and attends within the
-        # window; a full layer has neither positions nor mask.
-        rotary_dim=p.head_dim if windowed else 0,
-        rope=p.rope_theta,
-        output_gate=False,
-        qk_norm=False,
-        window=p.sliding_window if windowed else None,
-        dtype=dtype,
-        name=f'self_attention_{n}',
-    )
+        **_grouped_attention_sizes(p, n), dtype=dtype,
+        name=f'self_attention_{n}')
     return attn, _sparse_experts(p, n, dtype), None, BiasFreeLayerNorm(
         p.layer_norm_eps, dtype=dtype, name=f'block_norm_{n}')
   if kind == config_lib.BLOCK_LATENT_MOE:
@@ -1116,18 +1206,10 @@ def _block_modules(p, n: int, dtype):
     ffn = _sparse_experts(p, n, dtype) if experts else gated_ffn()
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
   elif kind == config_lib.BLOCK_GATED_DELTA_MOE:
-    if config_lib.layer_pattern(p)[n] == config_lib.LAYER_GATED_SOFTMAX:
-      attn = GroupedSoftmaxAttention(
-          hidden_size=p.hidden_size,
-          num_heads=p.num_heads,
-          num_kv_heads=p.num_kv_heads,
-          head_dim=p.head_dim,
-          rotary_dim=int(p.head_dim * p.partial_rotary_factor),
-          rope=p.rope_theta,
-          rms_norm_eps=p.rms_norm_eps,
-          dtype=dtype,
-          name=f'gated_attention_{n}',
-      )
+    sizes = _grouped_attention_sizes(p, n)
+    if sizes:
+      attn = GroupedSoftmaxAttention(**sizes, dtype=dtype,
+                                     name=f'gated_attention_{n}')
     else:
       attn = GatedDeltaNetMixer(
           hidden_size=p.hidden_size,
@@ -1144,25 +1226,10 @@ def _block_modules(p, n: int, dtype):
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps,
                     rms_norm_zero_centred=True)
   elif kind == config_lib.BLOCK_WINDOW_MOE:
-    # Listed, not derived: the layer's type names its attention and its
-    # rotation (both refused by name where the lists name what is not
-    # served).
-    letter = config_lib.layer_pattern(p)[n]
-    config_lib.ffn_pattern(p)
     attn = GroupedSoftmaxAttention(
-        hidden_size=p.hidden_size,
-        num_heads=p.num_heads,
-        num_kv_heads=p.num_kv_heads,
-        head_dim=p.head_dim,
-        rotary_dim=p.head_dim,
-        rope=Rope.of(config_lib.rope_parameters(p, letter)),
-        output_gate=False,
-        qk_norm=False,
-        window=(p.sliding_window
-                if letter == config_lib.LAYER_WINDOW_SOFTMAX else None),
-        dtype=dtype,
-        name=f'self_attention_{n}',
-    )
+        **_grouped_attention_sizes(p, n), dtype=dtype,
+        name=f'self_attention_{n}')
+    config_lib.ffn_pattern(p)
     ffn = _sparse_experts(p, n, dtype)
     residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
   elif kind == config_lib.BLOCK_POWER_RETENTION:
@@ -1237,8 +1304,9 @@ class EncoderStack(nn.Module):
   (reference encoder_stack.py:96-198 for the published block).
 
   [B, L, H] in; [B, L, H] out, or the same rows flat, [B*L, H], where
-  the stack took the attention sublayer kernel (`attention_path`) or the
-  latent attention's (`latent_attention_path`)."""
+  the stack took the attention sublayer kernel (`attention_path`), the
+  latent attention's (`latent_attention_path`) or the grouped-head
+  attention's (`grouped_attention_path`)."""
 
   params: ml_collections.FrozenConfigDict
   dtype: Any = jnp.float32
@@ -1283,22 +1351,33 @@ class EncoderStack(nn.Module):
         sow_intermediates=self.is_mutable_collection('intermediates'),
     ) == ATTENTION_FUSED_SUBLAYER
     batch, length, hidden = x.shape
-    if not self.is_initializing() and latent_attention_path(
-        p, length=length) == latent_attention.LATENT_WINDOW_TILE_KERNEL:
-      # That kernel's blocks are row ranges of the flat stream too, and
+    grouped_kwargs = {}
+    if not self.is_initializing():
+      # Those kernels' blocks are row ranges of the flat stream too, and
       # norms, experts and feed-forwards are position-wise: flattened once,
-      # here (init runs the modules on [B, L, H], which declare the leaves).
-      x = x.reshape(batch * length, hidden)
-      attn_kwargs = dict(window_length=length)
+      # here (init runs the modules on [B, L, H], which declare the
+      # leaves). The Gated DeltaNet mixer's convolution runs along the
+      # window, so beside it a grouped-head layer flattens its own input.
+      if latent_attention_path(
+          p, length=length) == latent_attention.LATENT_WINDOW_TILE_KERNEL:
+        x = x.reshape(batch * length, hidden)
+        attn_kwargs = dict(window_length=length)
+      if grouped_attention_path(p, length=length) == (
+          grouped_attention.GROUPED_WINDOW_TILE_KERNEL):
+        grouped_kwargs = dict(window_length=length)
+        if block_kind_of(p) != config_lib.BLOCK_GATED_DELTA_MOE:
+          x = x.reshape(batch * length, hidden)
 
     for n in range(p.num_hidden_layers):
       attn, ffn, wrap, norm = _block_modules(p, n, self.dtype)
+      kwargs = (grouped_kwargs if isinstance(attn, GroupedSoftmaxAttention)
+                else attn_kwargs)
       if norm is not None:
         # The parallel form: one norm, both sublayers on it, one addition;
         # neither sublayer waits for the other.
         u = norm(x)
         with jax.named_scope('attention'):
-          attended = attn(u, deterministic=deterministic)
+          attended = attn(u, deterministic=deterministic, **kwargs)
         with jax.named_scope('ffn'):
           x = x + attended + ffn(u, deterministic=deterministic)
         continue
@@ -1319,8 +1398,7 @@ class EncoderStack(nn.Module):
             x = self._fused_attention_sublayer(
                 n, x.reshape(batch * length, hidden), length)
           else:
-            x = run_block(wrap(attn, f'attention_wrapper_{n}'), x,
-                          **attn_kwargs)
+            x = run_block(wrap(attn, f'attention_wrapper_{n}'), x, **kwargs)
       with jax.named_scope('ffn'):
         x = run_block(wrap(ffn, f'ffn_wrapper_{n}'), x)
     return _output_norm(p)(x)

@@ -378,6 +378,9 @@ def summarize(events: List[Dict[str, Any]],
       'latent_attention_paths': sorted(
           {str(a['latent_attention_path']) for a in launches
            if a.get('latent_attention_path')}),
+      'grouped_attention_paths': sorted(
+          {str(a['grouped_attention_path']) for a in launches
+           if a.get('grouped_attention_path')}),
       'grouped_product_paths': sorted(
           {str(a['grouped_product_path']) for a in launches
            if a.get('grouped_product_path')}),
@@ -501,7 +504,8 @@ def format_summary(summary: Dict[str, Any]) -> str:
     if forward.get('layer_patterns'):
       delta_rule = ', '.join(forward.get('delta_rule_paths', ()))
       latent = ', '.join(forward.get('latent_attention_paths', ()))
-      ffn = ', '.join(forward.get('ffn_patterns', ()))
+      grouped_heads = ', '.join(forward.get('grouped_attention_paths', ()))
+      ffn =', '.join(forward.get('ffn_patterns', ()))
       scoring = ', '.join(forward.get('router_scorings', ()))
       grouped = ', '.join(forward.get('grouped_product_paths', ()))
       combine = ', '.join(forward.get('combine_paths', ()))
@@ -524,7 +528,9 @@ def format_summary(summary: Dict[str, Any]) -> str:
           + (f' (window: {windows})' if windows else '')
           + (f' (rope: {ropes})' if ropes else '')
           + (f' (delta rule: {delta_rule})' if delta_rule else '')
-          + (f' (latent attention: {latent})' if latent else '') + ''.join(
+          + (f' (latent attention: {latent})' if latent else '')
+          + (f' (grouped-head attention: {grouped_heads})'
+             if grouped_heads else '') + ''.join(
               f'; experts {lo}-{hi - 1} of {published} held'
               for lo, hi, published in forward.get('experts_held', ()))
           + (f' ({experts})' if experts else '')

@@ -101,6 +101,15 @@ COMBINE_VMEM_LIMIT_BYTES = 56 << 20
 # windows; the limit is the batch-tiled kernels'.
 LATENT_ATTENTION_VMEM_LIMIT_BYTES = BATCH_TILE_VMEM_LIMIT_BYTES
 
+# Scoped VMEM for the grouped-head attention's kernel
+# (ops/grouped_attention.py): a step's blocks of 400 rows at a group of 16
+# heads of 128 are q and o 1.6 MiB each, twice each for the pipeline, k, v
+# and the two tables 0.6, the rotated q and k 1.7, and what of its 64
+# head-windows' float32 scores (3.4 MiB) and weights the register file
+# cannot hold side by side: about 14 MiB, too near the default 16 to
+# leave it; the limit is the batch-tiled kernels'.
+GROUPED_ATTENTION_VMEM_LIMIT_BYTES = BATCH_TILE_VMEM_LIMIT_BYTES
+
 
 def batch_tile_compiler_params(
     vmem_limit_bytes: int = BATCH_TILE_VMEM_LIMIT_BYTES):

@@ -535,6 +535,8 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     assert 'delta_rule_path' not in e['args']
     # Nor a latent attention layer.
     assert 'latent_attention_path' not in e['args']
+    # Nor a grouped-head softmax layer.
+    assert 'grouped_attention_path' not in e['args']
     # Nor grouped products: no sparse experts.
     assert 'grouped_product_path' not in e['args']
     assert 'combine_path' not in e['args']
@@ -552,6 +554,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
                      'latent_attention_paths': [],
+                     'grouped_attention_paths': [],
                      'grouped_product_paths': [],
                      'combine_paths': [],
                      'block_forms': ['sequential'],

@@ -80,6 +80,9 @@ def test_engine_submit_to_delivery_serves_the_reference_bases(length,
     # The CPU takes no kernel on its own; nor do heads of 8 anywhere.
     assert args['delta_rule_path'] == 'plain'
     assert 'latent_attention_path' not in args
+    # Its gated softmax layer (heads of 256, a quarter rotated) never takes
+    # the grouped-head kernel.
+    assert args['grouped_attention_path'] == 'plain'
     assert args['grouped_product_path'] == 'ragged_dot'
     assert args['combine_path'] == 'gather'
     assert args['layer_pattern'] == 'GGGS'
@@ -125,7 +128,8 @@ def test_dctpu_trace_shows_the_pattern_and_the_held_share(tmp_path, capsys):
   assert forward['layer_patterns'] == ['GGGS']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
-  assert ('layers: GGGS (delta rule: plain); experts 8-15 of 16 held '
+  assert ('layers: GGGS (delta rule: plain) (grouped-head attention: '
+          'plain); experts 8-15 of 16 held '
           '(router: softmax; grouped products: ragged_dot; combine: gather); '
           'feed-forward: EEEE' in capsys.readouterr().out)
 

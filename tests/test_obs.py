@@ -641,6 +641,7 @@ class TestSummarize:
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
                             'attention_paths': [], 'delta_rule_paths': [],
                             'latent_attention_paths': [],
+                            'grouped_attention_paths': [],
                             'grouped_product_paths': [],
                             'combine_paths': [], 'block_forms': [],
                             'layer_patterns': [], 'attention_windows': [],

@@ -271,6 +271,8 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   assert launch['block_form'] == 'parallel'
   assert 'delta_rule_path' not in launch
   assert 'latent_attention_path' not in launch
+  # Heads of 8 on the CPU: the plain form.
+  assert launch['grouped_attention_path'] == 'plain'
   assert launch['grouped_product_path'] == 'ragged_dot'
   assert launch['combine_path'] == 'gather'
   assert launch['layer_pattern'] == 'WWWF' and launch['ffn_pattern'] == 'EEEE'
@@ -341,7 +343,8 @@ def test_dctpu_trace_lists_the_form_the_patterns_and_the_router(tmp_path,
   assert forward['shared_experts'] == [N_SHARED]
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
-  assert ('layers: WWWF (parallel block) (window: 8); experts 8-15 of 16 '
+  assert ('layers: WWWF (parallel block) (window: 8) (grouped-head '
+          'attention: plain); experts 8-15 of 16 '
           'held (router: sigmoid; grouped products: ragged_dot; combine: '
           'gather; shared experts: 4 averaged); feed-forward: EEEE'
           in capsys.readouterr().out)

@@ -10,7 +10,9 @@ kind at hidden 5120, batch 256; one period of the gated-delta and
 sparse-experts kind at hidden 2048 with 256 of 512 experts, batch 512; one
 dense and one expert layer of the latent-attention kind at hidden 2048 with
 128 experts, batch 512, and its attention's kernel alone at that pack; one period of the parallel window-and-full kind at
-hidden 4096 with 16 of 128 experts, batch 256; the grouped-product kernel
+hidden 4096 with 16 of 128 experts, batch 256, and of the window-and-full
+kind at hidden 2304, batch 512, with the grouped-head attention's kernel
+alone at both packs; the grouped-product kernel
 and the combine's kernel the three take, each alone at one turn of each). A compile that passes
 here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
@@ -348,9 +350,11 @@ def test_parallel_window_moe_forward_b256_at_published_widths(
   array of the 8.57 GiB is made): one period of the pattern (three window
   layers, one full layer) at the published widths, experts 0-15 of 128 in
   each, bfloat16 leaves, a pack of 256 windows. As ModelRunner traces it
-  without a mesh: the attention is plain products, the grouped products
-  the kernel whose grid follows the groups with a [4096, 4096] matrix in
-  column blocks, the combine the kernel a tile of tokens."""
+  without a mesh: the stream flat, the attention flat products round the
+  grouped-head kernel a tile of windows (the window layers' rotation in
+  it), the grouped products the kernel whose grid follows the groups with
+  a [4096, 4096] matrix in column blocks, the combine the kernel a tile of
+  tokens."""
   p = config_lib.get_config('transformer_learn_values_parallel_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 4
@@ -383,25 +387,30 @@ def test_parallel_window_moe_forward_b256_at_published_widths(
   assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (4, 8)
   assert len(re.findall(r'%grouped_product\S* = ', text)) in (4, 8)
   assert len(re.findall(r'%moe_combine\S* = ', text)) in (4, 8)
-  assert _n_kernels(compiled) in (12, 24)
+  # And each layer's grouped-head attention as one call, the three window
+  # layers' with their tables.
+  calls = re.findall(
+      r'%grouped_window_tile\S* = \S+ custom-call\(([^)]*)\)', text)
+  assert sorted(len(c.split(', ')) for c in calls) == [3, 5, 5, 5]
+  assert _n_kernels(compiled) in (16, 28)
   assert 'combine/jit(_take)/gather' not in text
   # A turn is 12,800 tokens of 8 assignments: rows of 8 kB, two turns a
   # pack, never the pack's 204,800 at once.
   assert 'bf16[102400,4096]' in text and 'bf16[204800,4096]' not in text
-  # Grouped heads: the scores are [B, 8, 16, L, L], and no k or v repeated
-  # to the 128 query heads is laid out.
-  assert 'f32[256,8,16,100,100]' in text
-  assert not re.search(r'= bf16\[256,100,128,128\]\S* broadcast', text)
+  # No score tensor, no query in float32 for its rotation, no [B, L, N, D]
+  # array of q, k or v, and no k or v repeated to the 128 query heads.
+  assert not re.search(r'f32\[256,8,16,100,100\]|\[256,100,(128|8),128\]',
+                       text)
   # At L=100 the window of 4,096 masks nothing and builds no mask.
   assert 'pred[100,100]' not in text
   memory = compiled.memory_analysis()
   # 4,599,070,720 block parameters and what lies outside, 2 bytes each.
   assert 2 * 4_599_070_720 < memory.argument_size_in_bytes < 9.25e9
   # With 8.57 GiB of weights a pack's temporaries have to leave room on a
-  # chip of 15.75 GiB: 4.89 GiB as compiled (PR 38; a window layer's query
-  # in float32 for its rotation, 1.56 GiB, the scores, 1.22 GiB, and one
-  # turn of the experts' rows are the largest), and a twentieth.
-  assert memory.temp_size_in_bytes < 5.15 * 2**30
+  # chip of 15.75 GiB: 2.58 GiB as compiled, where the plain attention's
+  # were 4.89 (a window layer's query in float32 for its rotation, 1.56
+  # GiB, and the scores, 1.22 GiB, were the largest); a twentieth over.
+  assert memory.temp_size_in_bytes < 2.71 * 2**30
 
 
 def test_window_moe_forward_b512_at_published_widths(
@@ -410,9 +419,10 @@ def test_window_moe_forward_b512_at_published_widths(
   array of the 6.22 GiB is made): two periods of the listed pattern
   (`WWWFWWWF`) at the published widths, all 64 experts of each layer,
   bfloat16 leaves, a pack of 512 windows. As ModelRunner traces it without
-  a mesh: the attention is plain products with the rotation in scope
-  `rotary`, the grouped products and the combine their kernels, and no
-  shared expert anywhere."""
+  a mesh: the stream flat, the attention flat products round the
+  grouped-head kernel a tile of windows with the rotation in it (no scope
+  `rotary` left), the grouped products and the combine their kernels, and
+  no shared expert anywhere."""
   p = config_lib.get_config('transformer_learn_values_window_moe+custom')
   with p.unlocked():
     p.num_hidden_layers = 8
@@ -447,16 +457,22 @@ def test_window_moe_forward_b512_at_published_widths(
   assert 'shared_expert' not in text
   # A turn is 25,600 tokens of 8 assignments, two turns a pack.
   assert 'bf16[204800,2304]' in text and 'bf16[409600,2304]' not in text
-  # Grouped heads: the scores are [B, 4, 8, L, L]; at L=100 the window of
-  # 1,024 masks nothing and builds no mask.
-  assert 'f32[512,4,8,100,100]' in text
+  # Each layer's grouped-head attention as one call with its layer type's
+  # two tables: no score tensor, no float32 q or k, no [B, L, N, D] array;
+  # at L=100 the window of 1,024 masks nothing and builds no mask.
+  calls = re.findall(
+      r'%grouped_window_tile\S* = \S+ custom-call\(([^)]*)\)', text)
+  assert [len(c.split(', ')) for c in calls] == [5] * 8
+  assert not re.search(r'f32\[512,4,8,100,100\]|\[512,100,(32|4),128\]',
+                       text)
+  assert '/rotary/' not in text
   assert 'pred[100,100]' not in text
   memory = compiled.memory_analysis()
   # 3,341,979,648 block parameters and what lies outside, 2 bytes each.
   assert 2 * 3_341_979_648 < memory.argument_size_in_bytes < 6.72e9
-  # 6.24 GiB of arguments and 2.24 GiB of temporaries as compiled (PR 40),
-  # 8.48 of the chip's 15.75 GiB: the temporaries held to a twentieth over.
-  assert memory.temp_size_in_bytes < 2.35 * 2**30
+  # 6.24 GiB of arguments and 2.13 GiB of temporaries as compiled, where
+  # the plain attention's were 2.24: the temporaries no larger than those.
+  assert memory.temp_size_in_bytes < 2.24 * 2**30
 
 
 @pytest.mark.parametrize('rows,groups,hidden,width,columns,held_mib', [
@@ -521,6 +537,36 @@ def test_latent_window_tile_kernel_at_a_pack_of_the_cell(one_chip,
   memory = compiled.memory_analysis()
   assert memory.output_size_in_bytes == rows * heads * 128 * 2
   assert memory.temp_size_in_bytes == 0  # nothing beside the operands
+
+
+@pytest.mark.parametrize('batch,heads,kv_heads,rotated', [
+    (512, 32, 4, True), (256, 128, 8, True), (256, 128, 8, False)],
+                         ids=['mellum_polish', 'commanda_polish_W',
+                              'commanda_polish_F'])
+def test_grouped_window_tile_kernel_at_a_pack_of_each_cell(
+    one_chip, compiled_kernels, batch, heads, kv_heads, rotated):
+  """The grouped-head attention's kernel alone at the packs of the two cells
+  that run it: windows of 100 positions, 32 / 4 and 128 / 8 heads of 128
+  (groups of 8 and 16), q, k and v flat as the products write them, the
+  rotation in VMEM (`pltpu.roll` of a half lane tile) or none; the step's
+  32 and 64 head-windows within the call's VMEM limit."""
+  from deepconsensus_tpu.ops import grouped_attention
+
+  rows = batch * 100
+  flat = lambda width: jax.ShapeDtypeStruct(
+      (rows, width), jnp.bfloat16, sharding=one_chip)
+  tables = grouped_attention.signed_tables(
+      *model_lib.rotary_tables(100, 128, 5e5)) if rotated else (None, None)
+  call = lambda q, k, v: grouped_attention.window_tile_attention(
+      q, k, v, *tables, length=100, num_heads=heads,
+      num_kv_heads=kv_heads, scale=128 ** -0.5)
+  compiled = jax.jit(call).lower(
+      flat(heads * 128), flat(kv_heads * 128), flat(kv_heads * 128)).compile()
+  assert _n_kernels(compiled) == 1
+  memory = compiled.memory_analysis()
+  assert memory.output_size_in_bytes == rows * heads * 128 * 2
+  # Nothing beside the operands but the two tables of a step's 400 rows.
+  assert memory.temp_size_in_bytes <= 2 * 400 * 128 * 4
 
 
 @pytest.mark.parametrize('tokens,k,groups,hidden', [

@@ -378,7 +378,8 @@ def test_model_runner_serves_the_reference_and_says_each_layer_types_rope(
   assert forward['ropes'] == ['W default, F yarn×16']
   assert forward['shared_experts'] == []
   assert cli.main(['trace', path]) == 0
-  assert ('layers: WWWF (window: 8) (rope: W default, F yarn×16); experts '
+  assert ('layers: WWWF (window: 8) (rope: W default, F yarn×16) '
+          '(grouped-head attention: plain); experts '
           '8-15 of 16 held (router: softmax; grouped products: ragged_dot; '
           'combine: gather); feed-forward: EEEE' in capsys.readouterr().out)
 
