@@ -1167,14 +1167,15 @@ class ModelRunner:
         handle.error = faults.classify_device_error(e)
 
   def _kernel_paths(self, batch: int, length: int,
-                    ragged: bool) -> Dict[str, str]:
+                    ragged: bool) -> Dict[str, Any]:
     """What the compiled forward of this pack takes of the kernels the
     model chooses by itself: `attention_path` for its attention sublayers,
     where it has Gated DeltaNet mixers `delta_rule_path`, where it has
     latent attention layers `latent_attention_path`, where it has
     grouped-head softmax layers `grouped_attention_path` and where it has
-    sparse experts `grouped_product_path` and `combine_path`; the model's
-    own rules, asked as the forward's trace asks them."""
+    sparse experts `grouped_product_path`, `combine_path` and the turns a
+    layer takes them in, `moe_turns`; the model's own rules, asked as the
+    forward's trace asks them."""
     if 'transformer' not in self.params.model_name:
       return {'attention_path': model_lib.ATTENTION_XLA}
     with pallas_util.single_device_inference(self._single_device):
@@ -1190,6 +1191,8 @@ class ModelRunner:
           'grouped_product_path': model_lib.grouped_product_path(
               self.params, batch=batch, length=length),
           'combine_path': model_lib.combine_path(
+              self.params, batch=batch, length=length),
+          'moe_turns': model_lib.moe_turns(
               self.params, batch=batch, length=length),
       }
     return {name: path for name, path in paths.items() if path is not None}

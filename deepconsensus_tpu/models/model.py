@@ -1064,6 +1064,17 @@ def latent_attention_path(p, *, length: int) -> Optional[str]:
       length=length, dtype=p.get('dtype', 'float32'))
 
 
+def moe_turns(p, *, batch: int, length: int) -> Optional[int]:
+  """In how many turns a forward of this pack runs each layer's sparse
+  experts (`forward_launch`'s `moe_turns`); None for a block kind without
+  them. The rule is ops/moe.py::turns_of, asked as `held_experts` asks it
+  where the forward is traced."""
+  if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
+    return None
+  return moe.turns_of(batch * length, p.num_experts_per_tok, p.hidden_size,
+                      p.get('dtype', 'float32'))
+
+
 def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
   """How a forward of this pack runs the grouped products of its sparse
   experts (`forward_launch`'s `grouped_product_path`): `group_kernel`, the
@@ -1072,13 +1083,12 @@ def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
   ops/moe.py::grouped_product_path, asked with the rows of one turn as
   `held_experts` asks it where the forward is traced; no option asks for
   the kernel."""
-  if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
+  turns = moe_turns(p, batch=batch, length=length)
+  if turns is None:
     return None
-  tokens, k = batch * length, p.num_experts_per_tok
-  dtype = p.get('dtype', 'float32')
   return moe.grouped_product_path(
-      tokens // moe.turns_of(tokens, k, p.hidden_size, dtype) * k,
-      p.experts_held_count, p.hidden_size, p.moe_intermediate_size, dtype)
+      batch * length // turns * p.num_experts_per_tok, p.experts_held_count,
+      p.hidden_size, p.moe_intermediate_size, p.get('dtype', 'float32'))
 
 
 def combine_path(p, *, batch: int, length: int) -> Optional[str]:
@@ -1089,13 +1099,12 @@ def combine_path(p, *, batch: int, length: int) -> Optional[str]:
   experts. The rule is ops/moe.py::combine_path, asked with the tokens of
   one turn as `held_experts` asks it where the forward is traced; no option
   asks for the kernel."""
-  if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
+  turns = moe_turns(p, batch=batch, length=length)
+  if turns is None:
     return None
-  tokens, k = batch * length, p.num_experts_per_tok
-  dtype = p.get('dtype', 'float32')
   return moe.combine_path(
-      tokens // moe.turns_of(tokens, k, p.hidden_size, dtype), k,
-      p.experts_held_count, p.hidden_size, dtype)
+      batch * length // turns, p.num_experts_per_tok, p.experts_held_count,
+      p.hidden_size, p.get('dtype', 'float32'))
 
 
 def _attn_softmax_dtype(p):

@@ -386,6 +386,8 @@ def summarize(events: List[Dict[str, Any]],
            if a.get('grouped_product_path')}),
       'combine_paths': sorted({str(a['combine_path']) for a in launches
                                if a.get('combine_path')}),
+      'moe_turns': sorted({int(a['moe_turns']) for a in launches
+                           if a.get('moe_turns')}),
       'block_forms': sorted({str(a['block_form']) for a in launches
                              if a.get('block_form')}),
       'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
@@ -509,12 +511,14 @@ def format_summary(summary: Dict[str, Any]) -> str:
       scoring = ', '.join(forward.get('router_scorings', ()))
       grouped = ', '.join(forward.get('grouped_product_paths', ()))
       combine = ', '.join(forward.get('combine_paths', ()))
+      turns = ', '.join(str(n) for n in forward.get('moe_turns', ()))
       shared = ', '.join(
           f'{n} averaged' for n in forward.get('shared_experts', ()))
       experts = '; '.join(
           f'{what}: {said}' for what, said in (
               ('router', scoring), ('grouped products', grouped),
-              ('combine', combine), ('shared experts', shared)) if said)
+              ('combine', combine), ('turns a pack', turns),
+              ('shared experts', shared)) if said)
       # A sequential block is what every kind but one has: only the other
       # form is said.
       forms = ', '.join(form for form in forward.get('block_forms', ())

@@ -49,7 +49,10 @@ Tokens are taken a turn at a time (jax.lax.map; `turns_of`): the sorted
 copy of the tokens and the experts' output are [tokens x k, hidden] each,
 and a turn is as many assignments as keep one such buffer within
 MAX_TURN_BYTES: 2^18 rows of 4 kB at hidden 2048 in bfloat16, 2^17 of 8 kB
-at hidden 4096.
+at hidden 4096. A turn's tokens, the [tokens, hidden] block the dispatch's
+gather reads, must also fit MAX_TURN_TOKEN_BYTES, so that XLA keeps them in
+VMEM: 25,600 tokens of 4 kB or 12,800 of 8 kB, and 12,800 of 4.5 kB at
+hidden 2304, where the first bound alone would leave 25,600 in HBM.
 """
 from __future__ import annotations
 
@@ -63,6 +66,12 @@ from deepconsensus_tpu.ops import moe_combine
 from deepconsensus_tpu.ops import pallas_util
 
 MAX_TURN_BYTES = 1 << 30
+# The largest turn of tokens that compiles for a v5e with the tokens in VMEM
+# (`S(1)`) for the dispatch's gather to read: 100 MiB in three cells'
+# forwards; 112.5 MiB (25,600 tokens at hidden 2304) stayed in HBM, where the
+# gather of a row costs about 4x what it does out of VMEM (PERF.md, section
+# 5).
+MAX_TURN_TOKEN_BYTES = 100 << 20
 
 # Which form of the grouped products a turn runs (`forward_launch`'s
 # `grouped_product_path`, docs/observability.md).
@@ -146,11 +155,13 @@ def combine_path(n: int, k: int, groups: int, hidden: int, dtype) -> str:
 def turns_of(n: int, k: int, hidden: int, dtype) -> int:
   """In how many turns `held_experts` takes n tokens of k assignments: the
   fewest halvings that bring one [rows, hidden] buffer of a turn within
-  MAX_TURN_BYTES."""
+  MAX_TURN_BYTES and the turn's [tokens, hidden], the dispatch's source,
+  within MAX_TURN_TOKEN_BYTES."""
   row_bytes = hidden * jnp.dtype(dtype).itemsize
+  over = lambda tokens: (tokens * k * row_bytes > MAX_TURN_BYTES
+                         or tokens * row_bytes > MAX_TURN_TOKEN_BYTES)
   turns = 1
-  while (n // turns) * k * row_bytes > MAX_TURN_BYTES and (
-      n % (turns * 2) == 0):
+  while over(n // turns) and n % (turns * 2) == 0:
     turns *= 2
   return turns
 

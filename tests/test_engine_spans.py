@@ -540,6 +540,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
     # Nor grouped products: no sparse experts.
     assert 'grouped_product_path' not in e['args']
     assert 'combine_path' not in e['args']
+    assert 'moe_turns' not in e['args']
   stats = engine.stats()
   assert stats['block_kind'] == kind
   assert stats['model_weight_bytes'] == 62
@@ -557,6 +558,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
                      'grouped_attention_paths': [],
                      'grouped_product_paths': [],
                      'combine_paths': [],
+                     'moe_turns': [],
                      'block_forms': ['sequential'],
                      'layer_patterns': [config_lib.layer_pattern(p)],
                      'attention_windows': [],

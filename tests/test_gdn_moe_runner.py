@@ -85,6 +85,8 @@ def test_engine_submit_to_delivery_serves_the_reference_bases(length,
     assert args['grouped_attention_path'] == 'plain'
     assert args['grouped_product_path'] == 'ragged_dot'
     assert args['combine_path'] == 'gather'
+    # A pack of toy tokens is one turn.
+    assert args['moe_turns'] == 1
     assert args['layer_pattern'] == 'GGGS'
     assert args['ffn_pattern'] == 'EEEE'
     assert args['router_scoring'] == 'softmax'
@@ -125,13 +127,14 @@ def test_dctpu_trace_shows_the_pattern_and_the_held_share(tmp_path, capsys):
   assert forward['delta_rule_paths'] == ['plain']
   assert forward['grouped_product_paths'] == ['ragged_dot']
   assert forward['combine_paths'] == ['gather']
+  assert forward['moe_turns'] == [1]
   assert forward['layer_patterns'] == ['GGGS']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
   assert ('layers: GGGS (delta rule: plain) (grouped-head attention: '
           'plain); experts 8-15 of 16 held '
-          '(router: softmax; grouped products: ragged_dot; combine: gather); '
-          'feed-forward: EEEE' in capsys.readouterr().out)
+          '(router: softmax; grouped products: ragged_dot; combine: gather; '
+          'turns a pack: 1); feed-forward: EEEE' in capsys.readouterr().out)
 
 
 def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
@@ -228,6 +231,30 @@ def test_combine_path_is_the_kernel_on_one_tpu_at_lane_tile_widths(
     got = model_lib.combine_path(p, batch=batch, length=100)
   assert got == {'tpu': 'token_tile_kernel', 'other_kind': None}.get(
       where, 'gather')
+
+
+@pytest.mark.parametrize('kind,want', [
+    ('gated_delta_moe', 2), ('latent_attention_moe', 2),
+    ('parallel_window_moe', 2), ('window_moe', 4), ('banded', None)])
+def test_moe_turns_are_the_turns_held_experts_takes_at_each_cell(kind, want):
+  """`forward_launch`'s `moe_turns`: ops/moe.py::turns_of asked as the
+  runner asks it, at the published widths and packs of the four expert
+  cells (512, 512, 256, 512 windows of 100): two turns where a turn's
+  tokens are 100 MiB, four at hidden 2304, where two would leave 112.5 MiB
+  of tokens a turn; a kind without sparse experts says nothing."""
+  presets = {
+      'gated_delta_moe': ('transformer_learn_values_gdn_moe+custom', 512),
+      'latent_attention_moe': ('transformer_learn_values_mla_moe+custom', 512),
+      'parallel_window_moe': (
+          'transformer_learn_values_parallel_moe+custom', 256),
+      'window_moe': ('transformer_learn_values_window_moe+custom', 512),
+      'banded': ('transformer_learn_values+custom', 512)}
+  name, batch = presets[kind]
+  p = config_lib.get_config(name)
+  with p.unlocked():
+    p.dtype = 'bfloat16'
+  config_lib.finalize_params(p, is_training=False)
+  assert model_lib.moe_turns(p, batch=batch, length=100) == want
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

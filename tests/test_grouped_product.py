@@ -21,6 +21,7 @@ import pytest
 
 from deepconsensus_tpu.ops import grouped_product
 from deepconsensus_tpu.ops import moe
+from deepconsensus_tpu.ops import moe_combine
 from deepconsensus_tpu.ops import pallas_util
 
 # (hidden, expert width): kanana_polish's 2048 x 768 and qwen3next_polish's
@@ -308,6 +309,36 @@ def test_turns_take_the_kernel_too(monkeypatch):
   assert np.array_equal(np.asarray(counts), np.asarray(counts_in_turn))
 
 
+def test_turns_the_tokens_bound_halves_are_the_turns_it_halves(monkeypatch):
+  """Two turns by the rows' bound against four by the tokens' bound, both
+  on the kernels (grouped products and combine): a token's assignments,
+  rows, products and sum are the same whichever turn holds it, so the
+  output and the counts are the same to the bit."""
+  args = _routed(jnp.bfloat16, seed=3, n=512)
+  taken = []
+  combine = moe_combine.combine
+  monkeypatch.setattr(moe_combine, 'combine',
+                      lambda *a, **k: taken.append(1) or combine(*a, **k))
+  with kernel_taken(monkeypatch):
+    # 256 tokens a turn: 1,024 rows of 128 in bfloat16.
+    monkeypatch.setattr(moe, 'MAX_TURN_BYTES', 256 * 4 * 128 * 2)
+    assert moe.turns_of(512, 4, 128, jnp.bfloat16) == 2
+    two, two_counts = moe.held_experts(*args)
+    # 128 tokens a turn by the tokens' bound alone.
+    monkeypatch.setattr(moe, 'MAX_TURN_TOKEN_BYTES', 128 * 128 * 2)
+    assert moe.turns_of(512, 4, 128, jnp.bfloat16) == 4
+    assert moe.grouped_product_path(128 * 4, 4, 128, 128, jnp.bfloat16) == (
+        moe.GROUPED_GROUP_KERNEL)
+    assert moe.combine_path(128, 4, 4, 128, jnp.bfloat16) == (
+        moe.COMBINE_TOKEN_TILE_KERNEL)
+    four, four_counts = moe.held_experts(*args)
+  # Each form traced the combine's kernel once, in its loop's body.
+  assert taken == [1, 1]
+  assert np.array_equal(np.asarray(two_counts), np.asarray(four_counts))
+  assert np.array_equal(np.asarray(two, np.float32),
+                        np.asarray(four, np.float32))
+
+
 def test_the_rule_declines_toy_widths_and_float32_on_the_cpu_and_a_tpu(
     monkeypatch):
   """What tier-1's other files rely on: at their widths (hidden 32-64,
@@ -410,15 +441,42 @@ def test_gate_and_up_in_column_blocks_are_the_whole_matrix_call(
   assert np.array_equal(blocked[:held], whole[:held])
 
 
-@pytest.mark.parametrize('cell,tokens,k,hidden,turns', [
-    ('kanana_polish', 51_200, 6, 2048, 2),
-    ('qwen3next_polish', 51_200, 10, 2048, 2),
-    ('commanda_polish', 25_600, 8, 4096, 2)])
+def _within(tokens, k, hidden):
+  """(rows bound, tokens bound): does a turn of `tokens` satisfy each."""
+  return (tokens * k * hidden * 2 <= moe.MAX_TURN_BYTES,
+          tokens * hidden * 2 <= moe.MAX_TURN_TOKEN_BYTES)
+
+
+@pytest.mark.parametrize('cell,tokens,k,hidden,turns,binds', [
+    ('kanana_polish', 51_200, 6, 2048, 2, 'rows'),
+    ('qwen3next_polish', 51_200, 10, 2048, 2, 'rows'),
+    ('commanda_polish', 25_600, 8, 4096, 2, 'rows'),
+    ('mellum_polish', 51_200, 8, 2304, 4, 'tokens'),
+    ('two_assignments_a_token', 51_200, 2, 2048, 2, 'tokens_alone')])
 def test_a_turn_is_reckoned_in_bytes_of_one_buffer_of_rows(cell, tokens, k,
-                                                           hidden, turns):
-  """2^18 rows of 4 kB at hidden 2048 (the two cells keep their turns of
-  153,600 and 256,000 assignments), 2^17 rows of 8 kB at hidden 4096."""
+                                                           hidden, turns,
+                                                           binds):
+  """The fewest halvings that satisfy both bounds: one [rows, hidden]
+  buffer within MAX_TURN_BYTES, the turn's [tokens, hidden] within
+  MAX_TURN_TOKEN_BYTES. 2^18 rows of 4 kB at hidden 2048 (the two cells
+  keep their turns of 153,600 and 256,000 assignments), 2^17 rows of 8 kB
+  at hidden 4096, a turn's tokens at exactly 100 MiB in all three;
+  mellum_polish's 25,600 tokens of 4.5 kB would be 112.5 MiB, so its turn
+  is 12,800 (56.25 MiB, 102,400 rows)."""
   del cell
   assert moe.turns_of(tokens, k, hidden, jnp.bfloat16) == turns
-  rows = tokens // turns * k
-  assert rows * hidden * 2 <= moe.MAX_TURN_BYTES < 2 * rows * hidden * 2
+  per_turn = tokens // turns
+  assert _within(per_turn, k, hidden) == (True, True)
+  # One halving fewer breaks the bound that decided.
+  rows_fit, tokens_fit = _within(2 * per_turn, k, hidden)
+  assert (rows_fit, tokens_fit) == {
+      'rows': (False, False), 'tokens': (True, False),
+      'tokens_alone': (True, False)}[binds]
+  if binds == 'rows':
+    assert per_turn * hidden * 2 == moe.MAX_TURN_TOKEN_BYTES
+  if binds == 'tokens':
+    assert (per_turn, per_turn * k) == (12_800, 102_400)
+    assert per_turn * hidden * 2 == 56.25 * 2 ** 20
+  if binds == 'tokens_alone':
+    # The rows alone would take the pack in one turn.
+    assert _within(tokens, k, hidden)[0]

@@ -202,6 +202,7 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   assert launch['latent_attention_path'] == 'plain'
   assert launch['grouped_product_path'] == 'ragged_dot'
   assert launch['combine_path'] == 'gather'
+  assert launch['moe_turns'] == 1
   assert launch['layer_pattern'] == 'LLL' and launch['ffn_pattern'] == 'DEE'
   assert launch['experts_held'] == [8, 16]
   assert launch['experts_published'] == 16
@@ -259,14 +260,15 @@ def test_dctpu_trace_lists_both_patterns_and_the_router(tmp_path, capsys):
   assert forward['latent_attention_paths'] == ['plain']
   assert forward['grouped_product_paths'] == ['ragged_dot']
   assert forward['combine_paths'] == ['gather']
+  assert forward['moe_turns'] == [1]
   assert forward['layer_patterns'] == ['LLL']
   assert forward['ffn_patterns'] == ['DEE']
   assert forward['router_scorings'] == ['sigmoid_bias']
   assert forward['experts_held'] == [[8, 16, 16]]
   assert cli.main(['trace', path]) == 0
   assert ('layers: LLL (latent attention: plain); experts 8-15 of 16 held '
-          '(router: sigmoid_bias; '
-          'grouped products: ragged_dot; combine: gather); feed-forward: DEE'
+          '(router: sigmoid_bias; grouped products: ragged_dot; combine: '
+          'gather; turns a pack: 1); feed-forward: DEE'
           in capsys.readouterr().out)
 
 

@@ -643,13 +643,40 @@ class TestSummarize:
                             'latent_attention_paths': [],
                             'grouped_attention_paths': [],
                             'grouped_product_paths': [],
-                            'combine_paths': [], 'block_forms': [],
+                            'combine_paths': [], 'moe_turns': [],
+                            'block_forms': [],
                             'layer_patterns': [], 'attention_windows': [],
                             'ffn_patterns': [], 'router_scorings': [],
                             'shared_experts': [], 'ropes': [],
                             'experts_held': [],
                             'n_positions': 0, 'weight_bytes': 0}
     assert 'forward:' not in text
+
+  def test_layers_line_says_the_experts_turns_a_pack(self):
+    """`moe_turns` of the launches of a sparse-expert stack: listed once
+    each, and on the `layers:` line beside the grouped products and the
+    combine; a launch without experts says nothing of turns."""
+    launch = dict(block_kind='window_moe', attention_path='xla',
+                  layer_pattern='WWWF', router_scoring='softmax',
+                  grouped_product_path='group_kernel',
+                  combine_path='token_tile_kernel', experts_held=[0, 64],
+                  experts_published=64, n_positions=51_200)
+    s = summarize_lib.summarize([
+        _span('forward_launch', 0.0, 0.1, pack=0, moe_turns=4, **launch),
+        _span('forward_launch', 1.0, 0.1, pack=1, moe_turns=4, **launch),
+        _span('forward_launch', 2.0, 0.1, pack=2, moe_turns=2, **launch),
+        _span('forward_launch', 3.0, 0.1, pack=3, block_kind='banded',
+              attention_path='xla', layer_pattern='BB', n_positions=100)])
+    assert s['forward']['moe_turns'] == [2, 4]
+    text = summarize_lib.format_summary(s)
+    assert ('experts 0-63 of 64 held (router: softmax; grouped products: '
+            'group_kernel; combine: token_tile_kernel; turns a pack: 2, 4)'
+            in text)
+    s = summarize_lib.summarize([
+        _span('forward_launch', 3.0, 0.1, pack=3, block_kind='banded',
+              attention_path='xla', layer_pattern='BB', n_positions=100)])
+    assert s['forward']['moe_turns'] == []
+    assert 'turns' not in summarize_lib.format_summary(s)
 
   def test_stragglers_slowest_decile(self):
     events = [

@@ -275,6 +275,7 @@ def test_model_runner_serves_the_reference_bases_in_float32(length, tmp_path):
   assert launch['grouped_attention_path'] == 'plain'
   assert launch['grouped_product_path'] == 'ragged_dot'
   assert launch['combine_path'] == 'gather'
+  assert launch['moe_turns'] == 1
   assert launch['layer_pattern'] == 'WWWF' and launch['ffn_pattern'] == 'EEEE'
   assert launch['attention_window'] == 8
   assert launch['experts_held'] == [8, 16]
@@ -336,6 +337,7 @@ def test_dctpu_trace_lists_the_form_the_patterns_and_the_router(tmp_path,
   assert forward['delta_rule_paths'] == []
   assert forward['grouped_product_paths'] == ['ragged_dot']
   assert forward['combine_paths'] == ['gather']
+  assert forward['moe_turns'] == [1]
   assert forward['layer_patterns'] == ['WWWF']
   assert forward['ffn_patterns'] == ['EEEE']
   assert forward['attention_windows'] == [8]
@@ -346,8 +348,8 @@ def test_dctpu_trace_lists_the_form_the_patterns_and_the_router(tmp_path,
   assert ('layers: WWWF (parallel block) (window: 8) (grouped-head '
           'attention: plain); experts 8-15 of 16 '
           'held (router: sigmoid; grouped products: ragged_dot; combine: '
-          'gather; shared experts: 4 averaged); feed-forward: EEEE'
-          in capsys.readouterr().out)
+          'gather; turns a pack: 1; shared experts: 4 averaged); '
+          'feed-forward: EEEE' in capsys.readouterr().out)
 
 
 # ------------------------------------------------- the patterns and the form

@@ -397,6 +397,9 @@ def test_parallel_window_moe_forward_b256_at_published_widths(
   # A turn is 12,800 tokens of 8 assignments: rows of 8 kB, two turns a
   # pack, never the pack's 204,800 at once.
   assert 'bf16[102400,4096]' in text and 'bf16[204800,4096]' not in text
+  # A turn's 12,800 tokens (100 MiB) stay in VMEM for the dispatch's gather
+  # to read.
+  assert 'bf16[12800,4096]{1,0:T(8,128)(2,1)S(1)}' in text
   # No score tensor, no query in float32 for its rotation, no [B, L, N, D]
   # array of q, k or v, and no k or v repeated to the 128 query heads.
   assert not re.search(r'f32\[256,8,16,100,100\]|\[256,100,(128|8),128\]',
@@ -449,14 +452,20 @@ def test_window_moe_forward_b512_at_published_widths(
   compiled = jax.jit(forward).lower(variables, rows).compile()
   text = compiled.as_text()
   # In every layer two calls of the grouped products' kernel and one of
-  # the combine's (a layer's two turns are one loop, which the compiler may
+  # the combine's (a layer's four turns are one loop, which the compiler may
   # unroll); none of the compiler's own grouped products.
   assert 'ragged-dot' not in text
-  assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (8, 16)
-  assert len(re.findall(r'%moe_combine\S* = ', text)) in (8, 16)
+  assert len(re.findall(r'%grouped_gated_up\S* = ', text)) in (8, 32)
+  assert len(re.findall(r'%grouped_product\S* = ', text)) in (8, 32)
+  assert len(re.findall(r'%moe_combine\S* = ', text)) in (8, 32)
   assert 'shared_expert' not in text
-  # A turn is 25,600 tokens of 8 assignments, two turns a pack.
-  assert 'bf16[204800,2304]' in text and 'bf16[409600,2304]' not in text
+  # A turn is 12,800 tokens of 8 assignments, four turns a pack: two turns'
+  # 25,600 tokens (112.5 MiB) would stay in HBM, where the dispatch's gather
+  # reads a row about four times as slowly; 12,800 (56.25 MiB) stay in VMEM.
+  assert 'bf16[102400,2304]' in text
+  assert 'bf16[204800,2304]' not in text and 'bf16[409600,2304]' not in text
+  assert 'bf16[12800,2304]{1,0:T(8,128)(2,1)S(1)}' in text
+  assert 'bf16[25600,2304]' not in text
   # Each layer's grouped-head attention as one call with its layer type's
   # two tables: no score tensor, no float32 q or k, no [B, L, N, D] array;
   # at L=100 the window of 1,024 masks nothing and builds no mask.
@@ -470,16 +479,17 @@ def test_window_moe_forward_b512_at_published_widths(
   memory = compiled.memory_analysis()
   # 3,341,979,648 block parameters and what lies outside, 2 bytes each.
   assert 2 * 3_341_979_648 < memory.argument_size_in_bytes < 6.72e9
-  # 6.24 GiB of arguments and 2.13 GiB of temporaries as compiled, where
-  # the plain attention's were 2.24: the temporaries no larger than those.
-  assert memory.temp_size_in_bytes < 2.24 * 2**30
+  # 6.24 GiB of arguments and 1.51 GiB of temporaries as compiled, where
+  # two turns a pack took 2.13 (and the plain attention 2.24); a twentieth
+  # over.
+  assert memory.temp_size_in_bytes < 1.59 * 2**30
 
 
 @pytest.mark.parametrize('rows,groups,hidden,width,columns,held_mib', [
     (153_600, 128, 2048, 768, (768, 2048), 2),
     (256_000, 256, 2048, 512, (512, 2048), 2),
     (102_400, 16, 4096, 4096, (512, 1024), 2),
-    (204_800, 64, 2304, 896, (896, 2304), 7)],
+    (102_400, 64, 2304, 896, (896, 2304), 2)],
                          ids=['kanana_polish', 'qwen3next_polish',
                               'commanda_polish', 'mellum_polish'])
 def test_grouped_product_kernel_at_one_turn_of_each_cell(
@@ -487,7 +497,7 @@ def test_grouped_product_kernel_at_one_turn_of_each_cell(
     held_mib):
   """The grouped products' kernel alone at one turn of the four cells
   that run it (25,600 tokens of 6 and of 10 assignments at hidden 2048,
-  12,800 of 8 at hidden 4096, 25,600 of 8 at hidden 2304): gate and up as one call, then the down
+  12,800 of 8 at hidden 4096, 12,800 of 8 at hidden 2304): gate and up as one call, then the down
   product, within pallas_util.GROUPED_PRODUCT_VMEM_LIMIT_BYTES: a group's
   matrices resident whole at widths 768, 512 and 896 (seven lane tiles),
   in column blocks of 512 and 1,024 where one matrix is [4096, 4096]."""
@@ -511,7 +521,8 @@ def test_grouped_product_kernel_at_one_turn_of_each_cell(
   # routing weights as a column, which the chip pads to a lane tile a row.
   # (With column blocks the call holds 1.7 MB of its own beside them; at
   # 204,800 rows over 64 groups 6.5 MiB, whatever the width, and 0.2 MiB
-  # at half the rows: the compiler's, nothing of [rows, width], PR 40.)
+  # at the 102,400 rows of a turn of mellum_polish: the compiler's, nothing
+  # of [rows, width].)
   assert down.memory_analysis().temp_size_in_bytes < held_mib << 20
   assert up.memory_analysis().temp_size_in_bytes < rows * 128 * 4 + (1 << 20)
 
@@ -571,14 +582,14 @@ def test_grouped_window_tile_kernel_at_a_pack_of_each_cell(
 
 @pytest.mark.parametrize('tokens,k,groups,hidden', [
     (25_600, 6, 128, 2048), (25_600, 10, 256, 2048), (12_800, 8, 16, 4096),
-    (25_600, 8, 64, 2304)],
+    (12_800, 8, 64, 2304)],
                          ids=['kanana_polish', 'qwen3next_polish',
                               'commanda_polish', 'mellum_polish'])
 def test_combine_kernel_at_one_turn_of_each_cell(one_chip, compiled_kernels,
                                                  tokens, k, groups, hidden):
   """The combine's kernel alone at one turn of the four cells that run it
   (25,600 tokens of 6 assignments over 128 held experts and of 10 over
-  256 at hidden 2048; 12,800 of 8 over 16 at hidden 4096; 25,600 of 8 over
+  256 at hidden 2048; 12,800 of 8 over 16 at hidden 4096; 12,800 of 8 over
   64 at hidden 2304, 18 lane tiles): 8-row copies
   out of a [rows, hidden] array in HBM, the 0/1 product, two buffers of a
   tile's runs within pallas_util.COMBINE_VMEM_LIMIT_BYTES."""

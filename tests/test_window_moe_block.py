@@ -372,16 +372,19 @@ def test_model_runner_serves_the_reference_and_says_each_layer_types_rope(
   assert launch['attention_window'] == 8
   assert launch['experts_held'] == [8, 16]
   assert launch['router_scoring'] == 'softmax'
+  assert launch['moe_turns'] == 1
   assert 'shared_experts' not in launch
   assert cli.main(['trace', path, '--json']) == 0
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward['ropes'] == ['W default, F yarn×16']
   assert forward['shared_experts'] == []
+  assert forward['moe_turns'] == [1]
   assert cli.main(['trace', path]) == 0
   assert ('layers: WWWF (window: 8) (rope: W default, F yarn×16) '
           '(grouped-head attention: plain); experts '
           '8-15 of 16 held (router: softmax; grouped products: ragged_dot; '
-          'combine: gather); feed-forward: EEEE' in capsys.readouterr().out)
+          'combine: gather; turns a pack: 1); feed-forward: EEEE'
+          in capsys.readouterr().out)
 
 
 def test_the_rotation_runs_in_scope_rotary():
