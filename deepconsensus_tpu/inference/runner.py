@@ -110,11 +110,11 @@ class InferenceOptions:
   # over one shared BAM (the reference's shard-the-BAM pattern without
   # the external splitting step).
   shard: Optional[Tuple[int, int]] = None
-  # >0: featurization worker pool. Measured caveat: shipping featurized
-  # windows between processes is IPC-bound (~6 MB/ZMW), so on fast
-  # hosts the serial path (~20k windows/s, matching one chip's forward
-  # throughput) wins; scale across chips by sharding input BAMs into
-  # separate runs like the reference's 500-shard pattern.
+  # >0: featurization worker pool. Caveat: shipping featurized windows
+  # between processes is IPC-bound (~6 MB/ZMW), so on fast hosts the
+  # serial path may win (its rate against the pool is not measured);
+  # scale across chips by sharding input BAMs into separate runs like
+  # the reference's 500-shard pattern.
   cpus: int = 0
   # Max batches in flight on the device before the oldest is drained.
   # A deeper pipeline overlaps the transfer latency of batches
@@ -360,17 +360,10 @@ def _check_exported_epilogue(meta, options: 'InferenceOptions',
     )
 
 
-def _holds_sparse_experts(params) -> bool:
-  """Whether the forward of this configuration routes tokens to experts
-  and returns their per-pack counts beside the predictions."""
-  return ('transformer' in params.model_name and model_lib.block_kind_of(
-      params) in config_lib.SPARSE_EXPERT_KINDS)
-
-
 def _check_sparse_experts_served(params, mesh) -> None:
   """What a block kind with sparse experts cannot run yet, refused by
   name before anything is placed (ROADMAP R-a)."""
-  kind = model_lib.block_kind_of(params)
+  kind = config_lib.block_kind_of(params)
   if params.get('quantize_matmuls', None) not in (None, 'none'):
     # dclint: allow=typed-faults (model-config validation at startup,
     # surfaced as operator error by the CLI, not a data-plane fault)
@@ -531,7 +524,9 @@ class ModelRunner:
   def _build(self, params, variables, options: InferenceOptions,
              mesh) -> None:
     self.params = params
-    sparse_experts = _holds_sparse_experts(params)
+    # Whether the forward routes tokens to experts and returns their
+    # per-pack counts beside the predictions.
+    sparse_experts = config_lib.holds_experts(params)
     if sparse_experts:
       _check_sparse_experts_served(params, mesh)
     # Quantize/cast once on the host BEFORE any device placement, so
@@ -566,7 +561,7 @@ class ModelRunner:
         counts = ()
         # Without a mesh the program is inference for one device, and
         # the model may take kernels on its own
-        # (model_lib.attention_path).
+        # (model_lib.kernel_paths).
         with pallas_util.single_device_inference(single_device):
           if sparse_experts:
             # Beside the predictions, the assignments every held expert
@@ -680,7 +675,7 @@ class ModelRunner:
     # dtype (0 for an exported artifact, whose weights are baked in). A
     # second copy or an upcast of the weights shows here.
     self._block_kind = (
-        model_lib.block_kind_of(self.params)
+        config_lib.block_kind_of(self.params)
         if 'transformer' in self.params.model_name
         else str(self.params.model_name))
     # Whether the jitted forward was traced as inference for one device
@@ -693,41 +688,10 @@ class ModelRunner:
         for leaf in jax.tree_util.tree_leaves(self.variables))
     self.obs.set_gauge('model_weight_bytes', self._weight_bytes)
     self._n_forward_positions = self.obs.counter('n_forward_positions')
-    # What `forward_launch` says of the stack: how a layer composes its
-    # sublayers (config.block_form), one letter a layer for its attention
-    # and one for its feed-forward (config.layer_pattern,
-    # config.ffn_pattern), the window of the layers that attend within one,
-    # each layer type's rotation where the configuration gives one a type
-    # (`rope`, {letter: 'default' | 'yarn×<factor>'}) and, for sparse
-    # experts, the share held, how the router scores and how many shared
-    # experts are averaged (absent where there are none).
-    self._launch_fields = {}
-    if 'transformer' in self.params.model_name:
-      self._launch_fields.update(
-          block_form=config_lib.block_form(self.params),
-          layer_pattern=config_lib.layer_pattern(self.params),
-          ffn_pattern=config_lib.ffn_pattern(self.params))
-      if config_lib.LAYER_WINDOW_SOFTMAX in self._launch_fields[
-          'layer_pattern']:
-        self._launch_fields.update(
-            attention_window=int(self.params.sliding_window))
-      if self.params.get('rope_parameters', None):
-        self._launch_fields.update(rope={
-            letter: model_lib.Rope.of(config_lib.rope_parameters(
-                self.params, letter)).describe()
-            for letter in dict.fromkeys(
-                self._launch_fields['layer_pattern'])})
-    self._sparse_experts = _holds_sparse_experts(self.params)
+    # What `forward_launch` says of the stack, the same for every pack.
+    self._launch_fields = model_lib.describe_stack(self.params)
+    self._sparse_experts = config_lib.holds_experts(self.params)
     if self._sparse_experts:
-      first = int(self.params.experts_held_first)
-      self._launch_fields.update(
-          experts_held=[first, first + int(self.params.experts_held_count)],
-          experts_published=int(self.params.num_experts),
-          router_scoring=str(self.params.router_scoring) + (
-              '_bias' if self.params.router_selection_bias else ''))
-      if self.params.get('num_shared_experts', None):
-        self._launch_fields.update(
-            shared_experts=int(self.params.num_shared_experts))
       # Assignments the router made (positions x k x expert layers), those
       # that fell on held experts, and the most any one held expert took
       # in a pack of one layer.
@@ -1146,16 +1110,19 @@ class ModelRunner:
     # Positions the forward computes: the compiled pack's rows x width.
     n_positions = inputs[0].shape[0] * inputs[0].shape[2]
     self._n_forward_positions.inc(n_positions)
+    # What the compiled forward takes of the kernels the model chooses by
+    # itself, asked under the declaration it was traced under.
+    with pallas_util.single_device_inference(self._single_device):
+      paths = model_lib.kernel_paths(
+          self.params, batch=inputs[0].shape[0], length=inputs[0].shape[2],
+          ragged=handle.ragged)
     # Host time to enqueue the forward: where a full runtime queue
     # would block.
     with obs_lib.stage(self.obs, obs_lib.trace.STAGE_LAUNCH,
                        pack=handle.seq, block_kind=self._block_kind,
                        n_positions=n_positions,
                        weight_bytes=self._weight_bytes,
-                       **self._kernel_paths(inputs[0].shape[0],
-                                            inputs[0].shape[2],
-                                            handle.ragged),
-                       **self._launch_fields):
+                       **paths, **self._launch_fields):
       try:
         faults.injected_device_fault(handle.seq)
         handle.hang_s = faults.injected_device_hang(handle.seq)
@@ -1165,37 +1132,6 @@ class ModelRunner:
       # pack-failure routing can attribute it to the right tickets)
       except Exception as e:
         handle.error = faults.classify_device_error(e)
-
-  def _kernel_paths(self, batch: int, length: int,
-                    ragged: bool) -> Dict[str, Any]:
-    """What the compiled forward of this pack takes of the kernels the
-    model chooses by itself: `attention_path` for its attention sublayers,
-    where it has Gated DeltaNet mixers `delta_rule_path`, where it has
-    latent attention layers `latent_attention_path`, where it has
-    grouped-head softmax layers `grouped_attention_path` and where it has
-    sparse experts `grouped_product_path`, `combine_path` and the turns a
-    layer takes them in, `moe_turns`; the model's own rules, asked as the
-    forward's trace asks them."""
-    if 'transformer' not in self.params.model_name:
-      return {'attention_path': model_lib.ATTENTION_XLA}
-    with pallas_util.single_device_inference(self._single_device):
-      paths = {
-          'attention_path': model_lib.attention_path(
-              self.params, length=length, ragged=ragged),
-          'delta_rule_path': model_lib.delta_rule_path(
-              self.params, length=length),
-          'latent_attention_path': model_lib.latent_attention_path(
-              self.params, length=length),
-          'grouped_attention_path': model_lib.grouped_attention_path(
-              self.params, length=length),
-          'grouped_product_path': model_lib.grouped_product_path(
-              self.params, batch=batch, length=length),
-          'combine_path': model_lib.combine_path(
-              self.params, batch=batch, length=length),
-          'moe_turns': model_lib.moe_turns(
-              self.params, batch=batch, length=length),
-      }
-    return {name: path for name, path in paths.items() if path is not None}
 
   def raw_outputs(self, dispatched: _DispatchHandle):
     """Device arrays (pred_ids, max_prob, n) for a dispatch handle —

@@ -11,9 +11,10 @@ inference time, exactly like the reference (model_utils.py:434-476).
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
-from typing import Optional
+from typing import Any, Callable, Mapping, Optional
 
 import ml_collections
 
@@ -99,8 +100,9 @@ def bucket_for(width: int, buckets):
   return None
 
 
-# Encoder block kinds, named by mechanism. EncoderStack builds the block
-# its configuration names (models/model.py::_block_modules):
+# Encoder block kinds, named by mechanism. A kind is one row of BLOCKS
+# below, the one place the kind is read: models/model.py builds layer n
+# from its two letters and the row.
 #   banded_softmax_relu     banded softmax self-attention over full heads
 #                           + ReLU feed-forward, ReZero or pre-LayerNorm
 #                           residuals, final LayerNorm (the published
@@ -128,8 +130,7 @@ def bucket_for(width: int, buckets):
 #                           (RMSNorm'd), a rotary key of its own is shared
 #                           by all heads, query/key heads are wider than
 #                           value heads (ops/latent_attention.py); the
-#                           feed-forward is chosen per layer
-#                           (`ffn_pattern`): SwiGLU in the first
+#                           feed-forward is SwiGLU in the first
 #                           `first_k_dense_replace` layers, behind them
 #                           sparse experts scored by a sigmoid and chosen
 #                           with a balancing bias (ops/moe.py) plus an
@@ -170,11 +171,6 @@ BLOCK_WINDOW_MOE = 'window_moe'
 BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION,
                BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
                BLOCK_PARALLEL_WINDOW_MOE, BLOCK_WINDOW_MOE)
-# The kinds some of whose layers' feed-forward is sparse experts: what
-# they cannot run yet (--tp, int8, train, distill, export) is refused by
-# name.
-SPARSE_EXPERT_KINDS = (BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
-                       BLOCK_PARALLEL_WINDOW_MOE, BLOCK_WINDOW_MOE)
 
 # How a layer composes its two sublayers (`forward_launch`'s `block_form`):
 # one after the other, each behind a norm of its own (x + f(norm_1(x)), then
@@ -182,12 +178,26 @@ SPARSE_EXPERT_KINDS = (BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
 FORM_SEQUENTIAL = 'sequential'
 FORM_PARALLEL = 'parallel'
 
+# A block's norm, around each sublayer (or the parallel block's one) and at
+# the end of the stack: ReZero residuals (pre-LayerNorm 1e-6 where `rezero`
+# is off) and a final LayerNorm 1e-6; RMSNorm (`rms_norm_eps`), its weights
+# plain or zero-centred (multiplying as 1 + w); a bias-free LayerNorm
+# (`layer_norm_eps`).
+NORM_REZERO = 'rezero'
+NORM_RMS = 'rms'
+NORM_RMS_ZERO_CENTRED = 'rms_zero_centred'
+NORM_BIAS_FREE_LAYER = 'bias_free_layer'
 
-def block_form(params) -> str:
-  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
-  return FORM_PARALLEL if kind == BLOCK_PARALLEL_WINDOW_MOE else (
-      FORM_SEQUENTIAL)
+# A dense feed-forward: relu with biases and dropout (FeedForward), or
+# SwiGLU without biases (GatedFeedForward).
+DENSE_RELU = 'relu'
+DENSE_SWIGLU = 'swiglu'
 
+# How an attention letter rotates q and k: by the default law at base
+# `rope_theta`, or by its layer type's entry of `rope_parameters`. A letter
+# a row does not name has no rotary positions.
+ROPE_THETA = 'rope_theta'
+ROPE_LISTED = 'rope_parameters'
 
 # A layer's attention, one letter a layer in `layer_pattern` (the
 # `forward_launch` span, docs/observability.md).
@@ -211,20 +221,130 @@ LAYER_TYPES = {'sliding_attention': LAYER_WINDOW_SOFTMAX,
 MLP_LAYER_TYPES = {'sparse': FFN_EXPERTS}
 
 
-def _listed(params, key: str, letters: dict) -> str:
+def _repeat(letter: str) -> Callable[[Any], str]:
+  """Every layer `letter`."""
+  return lambda params: letter * params.num_hidden_layers
+
+
+def _every(key: str, letter: str, others: str) -> Callable[[Any], str]:
+  """`letter` where (n + 1) % params[key] == 0, `others` elsewhere."""
+  def pattern(params) -> str:
+    every = params[key]
+    return ''.join(letter if (n + 1) % every == 0 else others
+                   for n in range(params.num_hidden_layers))
+  return pattern
+
+
+def _leading(key: str, letter: str, others: str) -> Callable[[Any], str]:
+  """`letter` in the first params[key] layers, `others` behind them."""
+  def pattern(params) -> str:
+    leading = params[key]
+    return ''.join(letter if n < leading else others
+                   for n in range(params.num_hidden_layers))
+  return pattern
+
+
+def _listed(key: str, letters: dict) -> Callable[[Any], str]:
   """The configuration's list `key`, one published name a layer, as
   letters: refused by name where it does not name every layer once, or
   names a type this kind does not run."""
-  names = list(params[key])
-  if len(names) != params.num_hidden_layers:
-    raise ValueError(
-        f'{key} lists {len(names)} layers and num_hidden_layers is '
-        f'{params.num_hidden_layers}: a stage lists its own layers')
-  unknown = sorted(set(names) - set(letters))
-  if unknown:
-    raise ValueError(f'{key} {unknown} are not served; the kind runs '
-                     f'{sorted(letters)}')
-  return ''.join(letters[name] for name in names)
+  def pattern(params) -> str:
+    names = list(params[key])
+    if len(names) != params.num_hidden_layers:
+      raise ValueError(
+          f'{key} lists {len(names)} layers and num_hidden_layers is '
+          f'{params.num_hidden_layers}: a stage lists its own layers')
+    unknown = sorted(set(names) - set(letters))
+    if unknown:
+      raise ValueError(f'{key} {unknown} are not served; the kind runs '
+                       f'{sorted(letters)}')
+    return ''.join(letters[name] for name in names)
+  return pattern
+
+
+@dataclasses.dataclass(frozen=True)
+class Block:
+  """A block kind's parts. `layers` and `ffns` derive `layer_pattern` and
+  `ffn_pattern` from what the configuration states; `rotation` says how
+  each attention letter rotates (ROPE_THETA or ROPE_LISTED; a letter not
+  named has no positions); `dense` is the module of a `D` layer (None:
+  the kind has none)."""
+
+  form: str
+  norm: str
+  dense: Optional[str]
+  layers: Callable[[Any], str]
+  ffns: Callable[[Any], str]
+  rotation: Mapping[str, str]
+
+
+# The six kinds, value for value.
+BLOCKS = {
+    BLOCK_BANDED_SOFTMAX: Block(
+        FORM_SEQUENTIAL, NORM_REZERO, DENSE_RELU,
+        _repeat(LAYER_BANDED_SOFTMAX), _repeat(FFN_DENSE), {}),
+    BLOCK_POWER_RETENTION: Block(
+        FORM_SEQUENTIAL, NORM_RMS, DENSE_SWIGLU,
+        _repeat(LAYER_POWER_RETENTION), _repeat(FFN_DENSE),
+        {LAYER_POWER_RETENTION: ROPE_THETA}),
+    BLOCK_GATED_DELTA_MOE: Block(
+        FORM_SEQUENTIAL, NORM_RMS_ZERO_CENTRED, None,
+        _every('full_attention_interval', LAYER_GATED_SOFTMAX,
+               LAYER_GATED_DELTA),
+        _repeat(FFN_EXPERTS), {LAYER_GATED_SOFTMAX: ROPE_THETA}),
+    BLOCK_LATENT_MOE: Block(
+        FORM_SEQUENTIAL, NORM_RMS, DENSE_SWIGLU, _repeat(LAYER_LATENT),
+        _leading('first_k_dense_replace', FFN_DENSE, FFN_EXPERTS),
+        {LAYER_LATENT: ROPE_THETA}),
+    BLOCK_PARALLEL_WINDOW_MOE: Block(
+        FORM_PARALLEL, NORM_BIAS_FREE_LAYER, None,
+        _every('layer_switch', LAYER_FULL_SOFTMAX, LAYER_WINDOW_SOFTMAX),
+        _repeat(FFN_EXPERTS), {LAYER_WINDOW_SOFTMAX: ROPE_THETA}),
+    BLOCK_WINDOW_MOE: Block(
+        FORM_SEQUENTIAL, NORM_RMS, None,
+        _listed('layer_types', LAYER_TYPES),
+        _listed('mlp_layer_types', MLP_LAYER_TYPES),
+        {LAYER_WINDOW_SOFTMAX: ROPE_LISTED, LAYER_FULL_SOFTMAX: ROPE_LISTED}),
+}
+
+
+def block_kind_of(params) -> str:
+  """The encoder block kind a configuration names (params.json files
+  from before the key existed mean the one block there was)."""
+  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
+  if kind not in BLOCKS:
+    raise ValueError(f'unknown block_kind {kind!r}; have {BLOCK_KINDS}')
+  return kind
+
+
+def block(params) -> Block:
+  """The row of the kind the configuration names."""
+  return BLOCKS[block_kind_of(params)]
+
+
+def block_form(params) -> str:
+  return block(params).form
+
+
+def layer_pattern(params) -> str:
+  """The attention of every layer of the stack, in order, as the kind's
+  row derives it from what the configuration states."""
+  return block(params).layers(params)
+
+
+def ffn_pattern(params) -> str:
+  """The feed-forward of every layer of the stack, in order, as the kind's
+  row derives it from what the configuration states."""
+  return block(params).ffns(params)
+
+
+def holds_experts(params) -> bool:
+  """Whether some layer of the model's encoder stack is sparse experts:
+  what `forward_launch` says of experts, the counts the forward returns
+  and what is refused by name (--tp, int8, train, distill, export) follow
+  from it."""
+  return ('transformer' in str(params.model_name)
+          and FFN_EXPERTS in ffn_pattern(params))
 
 
 def rope_parameters(params, letter: str) -> dict:
@@ -232,48 +352,6 @@ def rope_parameters(params, letter: str) -> dict:
   `letter` is (window_moe: one entry a layer type)."""
   name = {v: k for k, v in LAYER_TYPES.items()}[letter]
   return dict(params.rope_parameters[name])
-
-
-def layer_pattern(params) -> str:
-  """The attention of every layer of the stack, in order. With
-  `ffn_pattern` below, the one place a per-layer pattern is derived from
-  what the configuration states."""
-  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
-  layers = range(params.num_hidden_layers)
-  if kind == BLOCK_GATED_DELTA_MOE:
-    interval = params.full_attention_interval
-    return ''.join(
-        LAYER_GATED_SOFTMAX if (n + 1) % interval == 0 else LAYER_GATED_DELTA
-        for n in layers)
-  if kind == BLOCK_PARALLEL_WINDOW_MOE:
-    switch = params.layer_switch
-    return ''.join(
-        LAYER_FULL_SOFTMAX if (n + 1) % switch == 0 else LAYER_WINDOW_SOFTMAX
-        for n in layers)
-  if kind == BLOCK_WINDOW_MOE:
-    return _listed(params, 'layer_types', LAYER_TYPES)
-  letter = {BLOCK_BANDED_SOFTMAX: LAYER_BANDED_SOFTMAX,
-            BLOCK_POWER_RETENTION: LAYER_POWER_RETENTION,
-            BLOCK_LATENT_MOE: LAYER_LATENT}[kind]
-  return letter * len(layers)
-
-
-def ffn_pattern(params) -> str:
-  """The feed-forward of every layer of the stack, in order: sparse
-  experts in every layer of the gated-delta and the parallel kinds; in the
-  window kind as `mlp_layer_types` lists them (sparse alone is served); in
-  the latent-attention kind dense in the first `first_k_dense_replace`
-  layers and sparse experts behind them; dense everywhere else."""
-  kind = params.get('block_kind', None) or BLOCK_BANDED_SOFTMAX
-  layers = range(params.num_hidden_layers)
-  if kind in (BLOCK_GATED_DELTA_MOE, BLOCK_PARALLEL_WINDOW_MOE):
-    return FFN_EXPERTS * len(layers)
-  if kind == BLOCK_WINDOW_MOE:
-    return _listed(params, 'mlp_layer_types', MLP_LAYER_TYPES)
-  if kind == BLOCK_LATENT_MOE:
-    leading = params.first_k_dense_replace
-    return ''.join(FFN_DENSE if n < leading else FFN_EXPERTS for n in layers)
-  return FFN_DENSE * len(layers)
 
 
 # Transformer size presets (reference: transformer_basic_params.py).
@@ -366,6 +444,22 @@ def _set_transformer_learned_embeddings_distill_hparams(params):
   params.logit_loss_identifier = 'mean_squared_error'
 
 
+def _set_published_block_hparams(params):
+  """What every published-block preset below shares: the block's own
+  positions (rotary, a convolution, or none) take the sinusoidal
+  encoding's place and its own residual the ReZero one's, the published
+  models have no dropout, and they are served in bfloat16."""
+  params.add_pos_encoding = False
+  params.rezero = False
+  params.attn_win_size = 0
+  params.layer_postprocess_dropout = 0.0
+  params.attention_dropout = 0.0
+  params.relu_dropout = 0.0
+  params.dtype = 'bfloat16'
+  params.inference_dtype = 'bfloat16'
+  params.use_fused_hotpath = False
+
+
 def _set_transformer_learned_embeddings_retention_hparams(params):
   """A second encoder block kind at the widths of a public 14B
   linear-attention language model (every attention layer a gated power
@@ -386,18 +480,7 @@ def _set_transformer_learned_embeddings_retention_hparams(params):
   params.rope_theta = 1.0e6
   params.rms_norm_eps = 1.0e-6
   params.retention_degree = 2
-  # Rotary positions take the sinusoidal encoding's place, and the
-  # pre-RMSNorm residual the ReZero one's.
-  params.add_pos_encoding = False
-  params.rezero = False
-  params.attn_win_size = 0
-  # The published model has no dropout.
-  params.layer_postprocess_dropout = 0.0
-  params.attention_dropout = 0.0
-  params.relu_dropout = 0.0
-  params.dtype = 'bfloat16'
-  params.inference_dtype = 'bfloat16'
-  params.use_fused_hotpath = False
+  _set_published_block_hparams(params)
 
 
 def _set_transformer_learned_embeddings_gdn_moe_hparams(params):
@@ -452,18 +535,7 @@ def _set_transformer_learned_embeddings_gdn_moe_hparams(params):
   params.shared_expert_gated = True
   params.experts_held_first = 0
   params.experts_held_count = 512
-  # Rotary positions and the short convolution take the sinusoidal
-  # encoding's place, and the pre-RMSNorm residual the ReZero one's.
-  params.add_pos_encoding = False
-  params.rezero = False
-  params.attn_win_size = 0
-  # The published model has no dropout.
-  params.layer_postprocess_dropout = 0.0
-  params.attention_dropout = 0.0
-  params.relu_dropout = 0.0
-  params.dtype = 'bfloat16'
-  params.inference_dtype = 'bfloat16'
-  params.use_fused_hotpath = False
+  _set_published_block_hparams(params)
 
 
 def _set_transformer_learned_embeddings_mla_moe_hparams(params):
@@ -513,18 +585,7 @@ def _set_transformer_learned_embeddings_mla_moe_hparams(params):
   params.topk_group = 1
   params.experts_held_first = 0
   params.experts_held_count = 128
-  # Rotary positions take the sinusoidal encoding's place, and the
-  # pre-RMSNorm residual the ReZero one's.
-  params.add_pos_encoding = False
-  params.rezero = False
-  params.attn_win_size = 0
-  # The published model has no dropout.
-  params.layer_postprocess_dropout = 0.0
-  params.attention_dropout = 0.0
-  params.relu_dropout = 0.0
-  params.dtype = 'bfloat16'
-  params.inference_dtype = 'bfloat16'
-  params.use_fused_hotpath = False
+  _set_published_block_hparams(params)
 
 
 def _set_transformer_learned_embeddings_parallel_moe_hparams(params):
@@ -575,18 +636,7 @@ def _set_transformer_learned_embeddings_parallel_moe_hparams(params):
   params.shared_expert_gated = False
   params.experts_held_first = 0
   params.experts_held_count = 128
-  # Rotary positions (or none) take the sinusoidal encoding's place, and
-  # the parallel block the ReZero residual's.
-  params.add_pos_encoding = False
-  params.rezero = False
-  params.attn_win_size = 0
-  # The published model has no dropout.
-  params.layer_postprocess_dropout = 0.0
-  params.attention_dropout = 0.0
-  params.relu_dropout = 0.0
-  params.dtype = 'bfloat16'
-  params.inference_dtype = 'bfloat16'
-  params.use_fused_hotpath = False
+  _set_published_block_hparams(params)
 
 
 # A public 12B sparse-expert model's layer types, period 4 over 28 layers.
@@ -643,18 +693,7 @@ def _set_transformer_learned_embeddings_window_moe_hparams(params):
   params.shared_expert_gated = False
   params.experts_held_first = 0
   params.experts_held_count = 64
-  # Rotary positions take the sinusoidal encoding's place, and pre-RMSNorm
-  # residuals the ReZero residual's.
-  params.add_pos_encoding = False
-  params.rezero = False
-  params.attn_win_size = 0
-  # The published model has no dropout.
-  params.layer_postprocess_dropout = 0.0
-  params.attention_dropout = 0.0
-  params.relu_dropout = 0.0
-  params.dtype = 'bfloat16'
-  params.inference_dtype = 'bfloat16'
-  params.use_fused_hotpath = False
+  _set_published_block_hparams(params)
 
 
 def _set_base_fc_hparams(params):
@@ -783,9 +822,9 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
   # n_examples_train to size the per-epoch step budget
   # (--set streaming=true --set n_examples_train=N).
   params.streaming = False
-  # Streaming-loader decode processes (0 = in-process decode). Each
-  # worker sustains ~10k ex/s (gzip + minimal proto parse, measured
-  # per-core); size to the mesh's consumption rate on multi-core hosts.
+  # Streaming-loader decode processes (0 = in-process decode). What one
+  # worker sustains (gzip + minimal proto parse) is not measured; size to
+  # the mesh's consumption rate on multi-core hosts.
   params.loader_workers = 0
   params.loss_function = 'alignment_loss'
 
@@ -841,9 +880,9 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
   # Route AlignmentLoss through the whole-DP Pallas wavefront kernels
   # (forward scorer + custom-VJP backward) instead of the lax.scan DP.
   # Only applies when band_width is None (the training default).
-  # None = auto: Pallas on a real TPU backend (measured 1.24x the scan
-  # DP on v5e at batch 256), lax.scan elsewhere (the interpreted kernel
-  # would dominate CPU runs).
+  # None = auto: Pallas on a real TPU backend (its speed against the scan
+  # DP is not measured), lax.scan elsewhere (the interpreted kernel would
+  # dominate CPU runs).
   params.use_pallas_wavefront = None
   # Rematerialize encoder blocks in the backward pass (jax.checkpoint):
   # trades FLOPs for HBM headroom at large batch/long windows.

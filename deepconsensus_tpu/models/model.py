@@ -221,8 +221,8 @@ class BandedSelfAttention(nn.Module):
       if long_window:
         # Long windows: the whole-L kernel's [G, L, L] VMEM block no
         # longer fits; the block-banded flash kernel scales as L*band
-        # instead (measured 1.1-3.2x the XLA path at L=256..4096 on
-        # v5e) and trains through its own custom VJP.
+        # instead (its speed against the XLA path is not measured) and
+        # trains through its own custom VJP.
         out = fba.flash_band_attention_vjp(
             query, key, value, self.attn_win_size or None
         )
@@ -608,8 +608,9 @@ class GroupedSoftmaxAttention(nn.Module):
   flattened here and given back so), windows of that many rows one after
   another, and the operator with the rotation of q and k is the Pallas
   call a tile of windows (`grouped_attention.window_tile_attention`): the
-  caller asked `grouped_attention_path`. The leaves are the ones the
-  modules below declare, contracted flat, heads along the lanes."""
+  caller asked the rule (`kernel_paths`' `grouped_attention_path`). The
+  leaves are the ones the modules below declare, contracted flat, heads
+  along the lanes."""
 
   hidden_size: int
   num_heads: int
@@ -732,8 +733,9 @@ class LatentAttention(nn.Module):
   Called with `window_length`, x is the flat stream [B*L, H], windows of
   that many rows one after another, and the operator is the Pallas call a
   tile of windows (`latent_attention.window_tile_attention`): the caller
-  asked `latent_attention_path`. The leaves are the ones the modules below
-  declare, contracted flat, heads along the lanes."""
+  asked the rule (`kernel_paths`' `latent_attention_path`). The leaves are
+  the ones the modules below declare, contracted flat, heads along the
+  lanes."""
 
   hidden_size: int
   num_heads: int
@@ -943,25 +945,13 @@ class ResidualWrapper(nn.Module):
     return x + y
 
 
-def block_kind_of(p) -> str:
-  """The encoder block kind a configuration names (params.json files
-  from before the key existed mean the one block there was)."""
-  kind = p.get('block_kind', None) or config_lib.BLOCK_BANDED_SOFTMAX
-  if kind not in config_lib.BLOCK_KINDS:
-    raise ValueError(
-        f'unknown block_kind {kind!r}; have {config_lib.BLOCK_KINDS}')
-  return kind
-
-
 def refuse_inference_only_kind(p, command: str) -> None:
-  """`dctpu train`, `distill` and `export` of a block kind that runs at
-  inference alone, refused by name before anything is built."""
-  if 'transformer' not in str(p.model_name):
-    return
-  kind = block_kind_of(p)
-  if kind in config_lib.SPARSE_EXPERT_KINDS:
+  """`dctpu train`, `distill` and `export` of a stack that holds sparse
+  experts, refused by name before anything is built."""
+  if config_lib.holds_experts(p):
     raise ValueError(
-        f'block kind {kind!r} is not served by `dctpu {command}`: routed '
+        f'block kind {config_lib.block_kind_of(p)!r} is not served by '
+        f'`dctpu {command}`: routed '
         'experts have no training step here (no balancing loss, no '
         'gradient through the grouped products) and no exported form; '
         'the kind runs through `dctpu run` and `dctpu serve`')
@@ -972,16 +962,27 @@ def refuse_inference_only_kind(p, command: str) -> None:
 ATTENTION_FUSED_SUBLAYER = 'fused_sublayer'
 ATTENTION_XLA = 'xla'
 
+# The letters whose attention is GroupedSoftmaxAttention.
+GROUPED_LETTERS = (config_lib.LAYER_GATED_SOFTMAX,
+                   config_lib.LAYER_WINDOW_SOFTMAX,
+                   config_lib.LAYER_FULL_SOFTMAX)
 
-def attention_path(p, *, length: int, deterministic: bool = True,
-                   initializing: bool = False, ragged: bool = False,
-                   sow_intermediates: bool = False) -> str:
+
+def _all_banded(p) -> bool:
+  """Whether every layer's attention is banded softmax (`B`): the stack the
+  fused kernels compute."""
+  return set(config_lib.layer_pattern(p)) == {config_lib.LAYER_BANDED_SOFTMAX}
+
+
+def _attention_path(p, *, length: int, deterministic: bool = True,
+                    initializing: bool = False, ragged: bool = False,
+                    sow_intermediates: bool = False) -> str:
   """The one rule by which a forward takes the fused attention sublayer
   kernel (ops/fused_encoder_block.py::fused_attention_sublayer) in place
   of ResidualWrapper(BandedSelfAttention); no option asks for it. Every
   term is read where the forward is traced:
 
-  * the banded-softmax block with its ReZero residual, and no attention
+  * every layer banded softmax with its ReZero residual, and no attention
     kernel asked for by option (`use_pallas_attention`);
   * inference: deterministic, not initialising (init runs the modules,
     so the parameter tree is theirs either way), no `intermediates`
@@ -995,7 +996,7 @@ def attention_path(p, *, length: int, deterministic: bool = True,
     nobody declares it, and the modules run as they always have.
   """
   fused = (
-      block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
+      _all_banded(p)
       and p.rezero
       and not p.get('use_pallas_attention', False)
       and deterministic
@@ -1009,102 +1010,118 @@ def attention_path(p, *, length: int, deterministic: bool = True,
   return ATTENTION_FUSED_SUBLAYER if fused else ATTENTION_XLA
 
 
-def delta_rule_path(p, *, length: int) -> Optional[str]:
-  """How a forward of this width runs the delta rule of its Gated DeltaNet
-  mixers (`forward_launch`'s `delta_rule_path`): `window_kernel`, one
-  Pallas call a window with the mixer's two norms inside it, or `plain`,
-  the same arithmetic by heads as XLA compiles it; None for a block kind
-  without such a layer. The rule is ops/gated_delta.py::delta_rule_path,
-  the one `gated_delta_window` asks where the forward is traced; no option
-  asks for the kernel."""
-  if block_kind_of(p) != config_lib.BLOCK_GATED_DELTA_MOE:
-    return None
-  return gated_delta.delta_rule_path(
-      key_head_dim=p.linear_key_head_dim,
-      value_head_dim=p.linear_value_head_dim,
-      num_key_heads=p.linear_num_key_heads,
-      num_value_heads=p.linear_num_value_heads, length=length)
-
-
-def grouped_attention_path(p, *, length: int) -> Optional[str]:
-  """How a forward of this width runs the operator of its grouped-head
-  softmax attention layers, the rotation of q and k with it
-  (`forward_launch`'s `grouped_attention_path`): `window_tile_kernel`, one
-  Pallas call a layer over tiles of windows of the flat stream, where the
-  rule takes it for every such layer of the stack, or `plain`, the module's
-  arithmetic as XLA compiles it; None for a stack without such a layer. The
-  rule is ops/grouped_attention.py::grouped_attention_path, asked of each
-  layer's sizes as `EncoderStack` asks it where the forward is traced; no
-  option asks for the kernel."""
-  layers = [sizes for sizes in (_grouped_attention_sizes(p, n)
-                                for n in range(p.num_hidden_layers)) if sizes]
-  if not layers:
-    return None
-  paths = {grouped_attention.grouped_attention_path(
-      num_heads=sizes['num_heads'], num_kv_heads=sizes['num_kv_heads'],
-      head_dim=sizes['head_dim'], rotary_dim=sizes['rotary_dim'],
-      window=sizes['window'], length=length, dtype=p.get('dtype', 'float32'))
-           for sizes in layers}
-  return paths.pop() if len(paths) == 1 else grouped_attention.GROUPED_PLAIN
-
-
-def latent_attention_path(p, *, length: int) -> Optional[str]:
-  """How a forward of this width runs the operator of its latent attention
-  layers (`forward_launch`'s `latent_attention_path`): `window_tile_kernel`,
-  one Pallas call a layer over tiles of windows of the flat stream, or
-  `plain`, the same arithmetic as XLA compiles it; None for a block kind
-  without such a layer. The rule is
-  ops/latent_attention.py::latent_attention_path, the one `EncoderStack`
-  asks where the forward is traced; no option asks for the kernel."""
-  if block_kind_of(p) != config_lib.BLOCK_LATENT_MOE:
-    return None
+def _latent_attention_path(p, length: int) -> str:
+  """ops/latent_attention.py::latent_attention_path asked of the stack's
+  latent attention layers (`L`)."""
   return latent_attention.latent_attention_path(
       num_heads=p.num_heads, qk_nope_head_dim=p.qk_nope_head_dim,
       qk_rope_head_dim=p.qk_rope_head_dim, v_head_dim=p.v_head_dim,
       length=length, dtype=p.get('dtype', 'float32'))
 
 
-def moe_turns(p, *, batch: int, length: int) -> Optional[int]:
-  """In how many turns a forward of this pack runs each layer's sparse
-  experts (`forward_launch`'s `moe_turns`); None for a block kind without
-  them. The rule is ops/moe.py::turns_of, asked as `held_experts` asks it
-  where the forward is traced."""
-  if block_kind_of(p) not in config_lib.SPARSE_EXPERT_KINDS:
+def _grouped_attention_path(p, length: int) -> Optional[str]:
+  """ops/grouped_attention.py::grouped_attention_path asked of each
+  grouped-head softmax letter of the stack: `window_tile_kernel` where the
+  rule takes it for every such layer, `plain` otherwise; None for a stack
+  without such a layer."""
+  letters = set(config_lib.layer_pattern(p)) & set(GROUPED_LETTERS)
+  if not letters:
     return None
-  return moe.turns_of(batch * length, p.num_experts_per_tok, p.hidden_size,
-                      p.get('dtype', 'float32'))
+  paths = set()
+  for letter in letters:
+    sizes = _grouped_attention_sizes(p, letter)
+    paths.add(grouped_attention.grouped_attention_path(
+        num_heads=sizes['num_heads'], num_kv_heads=sizes['num_kv_heads'],
+        head_dim=sizes['head_dim'], rotary_dim=sizes['rotary_dim'],
+        window=sizes['window'], length=length,
+        dtype=p.get('dtype', 'float32')))
+  return paths.pop() if len(paths) == 1 else grouped_attention.GROUPED_PLAIN
 
 
-def grouped_product_path(p, *, batch: int, length: int) -> Optional[str]:
-  """How a forward of this pack runs the grouped products of its sparse
-  experts (`forward_launch`'s `grouped_product_path`): `group_kernel`, the
-  Pallas kernel whose grid follows the groups, or `ragged_dot`, the
-  compiler's own; None for a block kind without sparse experts. The rule is
-  ops/moe.py::grouped_product_path, asked with the rows of one turn as
-  `held_experts` asks it where the forward is traced; no option asks for
-  the kernel."""
-  turns = moe_turns(p, batch=batch, length=length)
-  if turns is None:
-    return None
-  return moe.grouped_product_path(
-      batch * length // turns * p.num_experts_per_tok, p.experts_held_count,
-      p.hidden_size, p.moe_intermediate_size, p.get('dtype', 'float32'))
+def kernel_paths(p, *, batch: int, length: int,
+                 ragged: bool = False) -> Dict[str, Any]:
+  """What the compiled forward of a pack of `batch` windows of `length`
+  takes of the kernels the model chooses by itself (`forward_launch`'s
+  fields, docs/observability.md): `attention_path` always; where the stack
+  has Gated DeltaNet mixers (`G`) `delta_rule_path`, where it has latent
+  attention (`L`) `latent_attention_path`, where it has grouped-head
+  softmax layers (`S`, `W`, `F`) `grouped_attention_path`; where some
+  layer's feed-forward is sparse experts (`E`) `grouped_product_path`,
+  `combine_path` and the turns a layer takes them in, `moe_turns`. Each is
+  its `ops/` rule asked as the forward's trace asks it, so the caller asks
+  under the declaration the forward was traced under
+  (pallas_util.single_device_inference); no option asks for a kernel."""
+  if 'transformer' not in str(p.model_name):
+    return {'attention_path': ATTENTION_XLA}
+  layers = config_lib.layer_pattern(p)
+  dtype = p.get('dtype', 'float32')
+  paths = {'attention_path': _attention_path(p, length=length, ragged=ragged)}
+  if config_lib.LAYER_GATED_DELTA in layers:
+    paths['delta_rule_path'] = gated_delta.delta_rule_path(
+        key_head_dim=p.linear_key_head_dim,
+        value_head_dim=p.linear_value_head_dim,
+        num_key_heads=p.linear_num_key_heads,
+        num_value_heads=p.linear_num_value_heads, length=length)
+  if config_lib.LAYER_LATENT in layers:
+    paths['latent_attention_path'] = _latent_attention_path(p, length)
+  grouped = _grouped_attention_path(p, length)
+  if grouped is not None:
+    paths['grouped_attention_path'] = grouped
+  if config_lib.holds_experts(p):
+    # The rows and tokens of one turn, as `held_experts` asks.
+    tokens, k = batch * length, p.num_experts_per_tok
+    turns = moe.turns_of(tokens, k, p.hidden_size, dtype)
+    paths['grouped_product_path'] = moe.grouped_product_path(
+        tokens // turns * k, p.experts_held_count, p.hidden_size,
+        p.moe_intermediate_size, dtype)
+    paths['combine_path'] = moe.combine_path(
+        tokens // turns, k, p.experts_held_count, p.hidden_size, dtype)
+    paths['moe_turns'] = turns
+  return paths
 
 
-def combine_path(p, *, batch: int, length: int) -> Optional[str]:
-  """How a forward of this pack adds up what its held experts computed
-  (`forward_launch`'s `combine_path`): `token_tile_kernel`, one Pallas call
-  a turn that reads the held assignments' rows by its own copies, or
-  `gather`, XLA's gather and sum; None for a block kind without sparse
-  experts. The rule is ops/moe.py::combine_path, asked with the tokens of
-  one turn as `held_experts` asks it where the forward is traced; no option
-  asks for the kernel."""
-  turns = moe_turns(p, batch=batch, length=length)
-  if turns is None:
-    return None
-  return moe.combine_path(
-      batch * length // turns, p.num_experts_per_tok, p.experts_held_count,
-      p.hidden_size, p.get('dtype', 'float32'))
+def _rotation(p, letter: str):
+  """How layers of letter `letter` rotate q and k, as the kind's row says:
+  a Rope from their layer type's `rope_parameters` entry, the default
+  law's base `rope_theta`, or None (no positions)."""
+  how = config_lib.block(p).rotation.get(letter)
+  if how == config_lib.ROPE_LISTED:
+    return Rope.of(config_lib.rope_parameters(p, letter))
+  return None if how is None else p.rope_theta
+
+
+def describe_stack(p) -> Dict[str, Any]:
+  """What `forward_launch` says of the stack, the same for every pack: how
+  a layer composes its sublayers (`block_form`), one letter a layer for its
+  attention and one for its feed-forward (`layer_pattern`, `ffn_pattern`),
+  the window of the layers that attend within one, each layer type's
+  rotation where the configuration gives one a type (`rope`,
+  {letter: 'default' | 'yarn×<factor>'}) and, for sparse experts, the share
+  held, how the router scores and how many shared experts are averaged
+  (absent where there are none). Empty for a model without an encoder
+  stack."""
+  if 'transformer' not in str(p.model_name):
+    return {}
+  layers = config_lib.layer_pattern(p)
+  fields = dict(block_form=config_lib.block_form(p), layer_pattern=layers,
+                ffn_pattern=config_lib.ffn_pattern(p))
+  if config_lib.LAYER_WINDOW_SOFTMAX in layers:
+    fields.update(attention_window=int(p.sliding_window))
+  ropes = {letter: rope.describe() for letter, rope in (
+      (letter, _rotation(p, letter)) for letter in dict.fromkeys(layers))
+           if isinstance(rope, Rope)}
+  if ropes:
+    fields.update(rope=ropes)
+  if config_lib.holds_experts(p):
+    first = int(p.experts_held_first)
+    fields.update(
+        experts_held=[first, first + int(p.experts_held_count)],
+        experts_published=int(p.num_experts),
+        router_scoring=str(p.router_scoring) + (
+            '_bias' if p.router_selection_bias else ''))
+    if p.get('num_shared_experts', None):
+      fields.update(shared_experts=int(p.num_shared_experts))
+  return fields
 
 
 def _attn_softmax_dtype(p):
@@ -1112,8 +1129,8 @@ def _attn_softmax_dtype(p):
 
 
 def _sparse_experts(p, n: int, dtype):
-  """Layer n's sparse experts: every size, the router's scoring, bias and
-  factor and the shared expert's gate among them, as the configuration
+  """Layer n's sparse experts (`E`): every size, the router's scoring, bias
+  and factor and the shared expert's gate among them, as the configuration
   states it. Shared experts that are averaged run as one of their summed
   width, times one over their number; a shared width of 0 is none."""
   averaged = p.get('shared_expert_combination', None) == 'average'
@@ -1136,147 +1153,164 @@ def _sparse_experts(p, n: int, dtype):
   )
 
 
-def _grouped_attention_sizes(p, n: int) -> Optional[Dict[str, Any]]:
-  """Layer n's GroupedSoftmaxAttention sizes, where its attention is one
-  (None elsewhere): what `_block_modules` builds it with and what
-  `grouped_attention_path` asks the rule of."""
-  kind = block_kind_of(p)
-  if kind not in (config_lib.BLOCK_PARALLEL_WINDOW_MOE,
-                  config_lib.BLOCK_WINDOW_MOE,
-                  config_lib.BLOCK_GATED_DELTA_MOE):
-    return None
-  letter = config_lib.layer_pattern(p)[n]
-  heads = dict(hidden_size=p.hidden_size, num_heads=p.num_heads,
-               num_kv_heads=p.num_kv_heads, head_dim=p.head_dim)
-  windowed = letter == config_lib.LAYER_WINDOW_SOFTMAX
-  window = p.sliding_window if windowed else None
-  if kind == config_lib.BLOCK_PARALLEL_WINDOW_MOE:
-    # A window layer rotates the whole head and attends within the
-    # window; a full layer has neither positions nor mask.
-    return dict(heads, rotary_dim=p.head_dim if windowed else 0,
-                rope=p.rope_theta, output_gate=False, qk_norm=False,
-                window=window)
-  if kind == config_lib.BLOCK_WINDOW_MOE:
-    # Listed, not derived: the layer's type names its attention and its
-    # rotation (both refused by name where the lists name what is not
-    # served).
-    return dict(heads, rotary_dim=p.head_dim,
-                rope=Rope.of(config_lib.rope_parameters(p, letter)),
-                output_gate=False, qk_norm=False, window=window)
-  if letter != config_lib.LAYER_GATED_SOFTMAX:
-    return None
-  return dict(heads, rotary_dim=int(p.head_dim * p.partial_rotary_factor),
-              rope=p.rope_theta, rms_norm_eps=p.rms_norm_eps, window=None)
-
-
-def _block_modules(p, n: int, dtype):
-  """(attention, feed-forward, wrap, norm) of encoder layer `n` for the
-  configuration's block kind and, where the kind's layers are not alike,
-  the layer's place in the patterns (config.layer_pattern for the
-  attention, config.ffn_pattern for the feed-forward): the one place
-  that knows the kinds. The last two say the form of the block
-  (config.block_form) and one of them is None: a sequential block has
-  `wrap(sublayer, name)`, the kind's residual form around each sublayer; a
-  parallel block has `norm`, the ONE norm both sublayers read. Called
-  inside EncoderStack's compact method, so the modules are its children."""
-  kind = block_kind_of(p)
-  gated_ffn = lambda: GatedFeedForward(
-      hidden_size=p.hidden_size, filter_size=p.filter_size, dtype=dtype,
-      name=f'ffn_{n}')
-  if kind == config_lib.BLOCK_PARALLEL_WINDOW_MOE:
-    if p.first_k_dense_replace:
-      raise ValueError(
-          f'first_k_dense_replace {p.first_k_dense_replace} is not served: '
-          'the parallel block has no dense feed-forward')
-    attn = GroupedSoftmaxAttention(
-        **_grouped_attention_sizes(p, n), dtype=dtype,
-        name=f'self_attention_{n}')
-    return attn, _sparse_experts(p, n, dtype), None, BiasFreeLayerNorm(
-        p.layer_norm_eps, dtype=dtype, name=f'block_norm_{n}')
-  if kind == config_lib.BLOCK_LATENT_MOE:
-    if p.q_lora_rank is not None or (p.n_group, p.topk_group) != (1, 1):
-      raise ValueError(
-          f'q_lora_rank {p.q_lora_rank}, n_group {p.n_group} and topk_group '
-          f'{p.topk_group} are not served: LatentAttention has no query '
-          'latent and ops/moe.py::route_top_k chooses over one group')
-    attn = LatentAttention(
-        hidden_size=p.hidden_size,
-        num_heads=p.num_heads,
-        qk_nope_head_dim=p.qk_nope_head_dim,
-        qk_rope_head_dim=p.qk_rope_head_dim,
-        v_head_dim=p.v_head_dim,
-        kv_lora_rank=p.kv_lora_rank,
-        rope_theta=p.rope_theta,
-        rms_norm_eps=p.rms_norm_eps,
-        dtype=dtype,
-        name=f'latent_attention_{n}',
-    )
-    experts = config_lib.ffn_pattern(p)[n] == config_lib.FFN_EXPERTS
-    ffn = _sparse_experts(p, n, dtype) if experts else gated_ffn()
-    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
-  elif kind == config_lib.BLOCK_GATED_DELTA_MOE:
-    sizes = _grouped_attention_sizes(p, n)
-    if sizes:
-      attn = GroupedSoftmaxAttention(**sizes, dtype=dtype,
-                                     name=f'gated_attention_{n}')
-    else:
-      attn = GatedDeltaNetMixer(
-          hidden_size=p.hidden_size,
-          num_key_heads=p.linear_num_key_heads,
-          num_value_heads=p.linear_num_value_heads,
-          key_head_dim=p.linear_key_head_dim,
-          value_head_dim=p.linear_value_head_dim,
-          conv_kernel=p.linear_conv_kernel_dim,
-          rms_norm_eps=p.rms_norm_eps,
-          dtype=dtype,
-          name=f'gdn_{n}',
-      )
-    ffn = _sparse_experts(p, n, dtype)
-    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps,
-                    rms_norm_zero_centred=True)
-  elif kind == config_lib.BLOCK_WINDOW_MOE:
-    attn = GroupedSoftmaxAttention(
-        **_grouped_attention_sizes(p, n), dtype=dtype,
-        name=f'self_attention_{n}')
-    config_lib.ffn_pattern(p)
-    ffn = _sparse_experts(p, n, dtype)
-    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
-  elif kind == config_lib.BLOCK_POWER_RETENTION:
-    if p.retention_degree != power_retention.DEGREE:
-      raise ValueError(
-          f'retention_degree {p.retention_degree} is not served; '
-          f'ops/power_retention.py computes degree {power_retention.DEGREE}')
-    attn = PowerRetentionAttention(
-        hidden_size=p.hidden_size,
-        num_heads=p.num_heads,
-        num_kv_heads=p.num_kv_heads,
-        head_dim=p.head_dim,
-        rope_theta=p.rope_theta,
-        rms_norm_eps=p.rms_norm_eps,
-        dtype=dtype,
-        name=f'self_attention_{n}',
-    )
-    ffn = gated_ffn()
-    residual = dict(rezero=False, rms_norm_eps=p.rms_norm_eps)
-  else:
-    attn = BandedSelfAttention(
-        hidden_size=p.hidden_size,
-        num_heads=p.num_heads,
-        dropout_rate=p.attention_dropout,
-        attn_win_size=p.attn_win_size,
-        dtype=dtype,
-        use_pallas=p.get('use_pallas_attention', False),
-        softmax_dtype=_attn_softmax_dtype(p),
-        name=f'self_attention_{n}',
-    )
-    ffn = FeedForward(
+def _dense_ffn(p, n: int, dense: str, dtype):
+  """Layer n's dense feed-forward (`D`) of the kind's form `dense`."""
+  if dense == config_lib.DENSE_RELU:
+    return FeedForward(
         hidden_size=p.hidden_size,
         filter_size=p.filter_size,
         dropout_rate=p.relu_dropout,
         dtype=dtype,
         name=f'ffn_{n}',
     )
+  return GatedFeedForward(hidden_size=p.hidden_size,
+                          filter_size=p.filter_size, dtype=dtype,
+                          name=f'ffn_{n}')
+
+
+def _grouped_attention_sizes(p, letter: str) -> Dict[str, Any]:
+  """The GroupedSoftmaxAttention sizes of a layer of letter `letter` (`S`,
+  `W` or `F`): what `_grouped_attention` builds it with and what the rule
+  is asked of. `S` gates its output and norms q and k; `W` attends within
+  `sliding_window` positions; a letter the row rotates turns the first
+  `partial_rotary_factor` of the head (all of it where the configuration
+  says none) and one it does not has no positions."""
+  gated = letter == config_lib.LAYER_GATED_SOFTMAX
+  rope = _rotation(p, letter)
+  rotary_dim = 0 if rope is None else int(
+      p.head_dim * p.get('partial_rotary_factor', 1.0))
+  windowed = letter == config_lib.LAYER_WINDOW_SOFTMAX
+  return dict(hidden_size=p.hidden_size, num_heads=p.num_heads,
+              num_kv_heads=p.num_kv_heads, head_dim=p.head_dim,
+              rotary_dim=rotary_dim, rope=rope,
+              rms_norm_eps=p.rms_norm_eps if gated else None,
+              output_gate=gated, qk_norm=gated,
+              window=p.sliding_window if windowed else None)
+
+
+def _banded_attention(p, n: int, letter: str, dtype):
+  del letter
+  return BandedSelfAttention(
+      hidden_size=p.hidden_size,
+      num_heads=p.num_heads,
+      dropout_rate=p.attention_dropout,
+      attn_win_size=p.attn_win_size,
+      dtype=dtype,
+      use_pallas=p.get('use_pallas_attention', False),
+      softmax_dtype=_attn_softmax_dtype(p),
+      name=f'self_attention_{n}',
+  )
+
+
+def _retention_attention(p, n: int, letter: str, dtype):
+  if p.retention_degree != power_retention.DEGREE:
+    raise ValueError(
+        f'retention_degree {p.retention_degree} is not served; '
+        f'ops/power_retention.py computes degree {power_retention.DEGREE}')
+  return PowerRetentionAttention(
+      hidden_size=p.hidden_size,
+      num_heads=p.num_heads,
+      num_kv_heads=p.num_kv_heads,
+      head_dim=p.head_dim,
+      rope_theta=_rotation(p, letter),
+      rms_norm_eps=p.rms_norm_eps,
+      dtype=dtype,
+      name=f'self_attention_{n}',
+  )
+
+
+def _delta_mixer(p, n: int, letter: str, dtype):
+  del letter
+  return GatedDeltaNetMixer(
+      hidden_size=p.hidden_size,
+      num_key_heads=p.linear_num_key_heads,
+      num_value_heads=p.linear_num_value_heads,
+      key_head_dim=p.linear_key_head_dim,
+      value_head_dim=p.linear_value_head_dim,
+      conv_kernel=p.linear_conv_kernel_dim,
+      rms_norm_eps=p.rms_norm_eps,
+      dtype=dtype,
+      name=f'gdn_{n}',
+  )
+
+
+def _latent_attention(p, n: int, letter: str, dtype):
+  if p.q_lora_rank is not None or (p.n_group, p.topk_group) != (1, 1):
+    raise ValueError(
+        f'q_lora_rank {p.q_lora_rank}, n_group {p.n_group} and topk_group '
+        f'{p.topk_group} are not served: LatentAttention has no query '
+        'latent and ops/moe.py::route_top_k chooses over one group')
+  return LatentAttention(
+      hidden_size=p.hidden_size,
+      num_heads=p.num_heads,
+      qk_nope_head_dim=p.qk_nope_head_dim,
+      qk_rope_head_dim=p.qk_rope_head_dim,
+      v_head_dim=p.v_head_dim,
+      kv_lora_rank=p.kv_lora_rank,
+      rope_theta=_rotation(p, letter),
+      rms_norm_eps=p.rms_norm_eps,
+      dtype=dtype,
+      name=f'latent_attention_{n}',
+  )
+
+
+def _grouped_attention(p, n: int, letter: str, dtype):
+  name = ('gated_attention' if letter == config_lib.LAYER_GATED_SOFTMAX
+          else 'self_attention')
+  return GroupedSoftmaxAttention(**_grouped_attention_sizes(p, letter),
+                                 dtype=dtype, name=f'{name}_{n}')
+
+
+# Layer n's attention sublayer, by its letter in config.layer_pattern.
+_ATTENTION = {
+    config_lib.LAYER_BANDED_SOFTMAX: _banded_attention,
+    config_lib.LAYER_POWER_RETENTION: _retention_attention,
+    config_lib.LAYER_GATED_DELTA: _delta_mixer,
+    config_lib.LAYER_LATENT: _latent_attention,
+    config_lib.LAYER_GATED_SOFTMAX: _grouped_attention,
+    config_lib.LAYER_WINDOW_SOFTMAX: _grouped_attention,
+    config_lib.LAYER_FULL_SOFTMAX: _grouped_attention,
+}
+
+
+def _norm(p, norm: str, name: str, dtype=jnp.float32):
+  """A block's norm (config.BLOCKS) as one module: the stack's final
+  normalization (float32 out) or the parallel block's one norm."""
+  if norm == config_lib.NORM_BIAS_FREE_LAYER:
+    return BiasFreeLayerNorm(p.layer_norm_eps, dtype=dtype, name=name)
+  if norm == config_lib.NORM_REZERO:
+    return nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32, name=name)
+  return RMSNorm(p.rms_norm_eps, dtype=dtype,
+                 zero_centred=norm == config_lib.NORM_RMS_ZERO_CENTRED,
+                 name=name)
+
+
+def _layer_modules(p, n: int, attention: str, ffn: str, dtype):
+  """(attention, feed-forward, wrap, norm) of encoder layer `n`, built from
+  its two letters (`attention` of config.layer_pattern, `ffn` of
+  config.ffn_pattern) and the kind's row (config.BLOCKS) for the form and
+  the norm. The last two say the form and one of them is None: a sequential
+  block has `wrap(sublayer, name)`, the row's residual around each
+  sublayer; a parallel block has `norm`, the ONE norm both sublayers read.
+  Called inside EncoderStack's compact method, so the modules are its
+  children."""
+  row = config_lib.block(p)
+  parallel = row.form == config_lib.FORM_PARALLEL
+  if parallel and p.first_k_dense_replace:
+    raise ValueError(
+        f'first_k_dense_replace {p.first_k_dense_replace} is not served: '
+        'the parallel block has no dense feed-forward')
+  attn = _ATTENTION[attention](p, n, attention, dtype)
+  ffn = (_sparse_experts(p, n, dtype) if ffn == config_lib.FFN_EXPERTS
+         else _dense_ffn(p, n, row.dense, dtype))
+  if parallel:
+    return attn, ffn, None, _norm(p, row.norm, f'block_norm_{n}', dtype)
+  if row.norm == config_lib.NORM_REZERO:
     residual = dict(rezero=p.rezero)
+  else:
+    residual = dict(
+        rezero=False, rms_norm_eps=p.rms_norm_eps,
+        rms_norm_zero_centred=row.norm == config_lib.NORM_RMS_ZERO_CENTRED)
   wrap = lambda sublayer, name: ResidualWrapper(
       sublayer, dropout_rate=p.layer_postprocess_dropout, name=name,
       **residual)
@@ -1294,28 +1328,16 @@ def expert_assignments(sown) -> jnp.ndarray:
   return jnp.stack([layers[f'moe_{n}']['assignments'][0] for n in numbers])
 
 
-def _output_norm(p):
-  """The stack's final normalization, by block kind (float32 out)."""
-  kind = block_kind_of(p)
-  if kind == config_lib.BLOCK_PARALLEL_WINDOW_MOE:
-    return BiasFreeLayerNorm(p.layer_norm_eps, name='output_normalization')
-  if kind != config_lib.BLOCK_BANDED_SOFTMAX:
-    return RMSNorm(p.rms_norm_eps,
-                   zero_centred=kind == config_lib.BLOCK_GATED_DELTA_MOE,
-                   name='output_normalization')
-  return nn.LayerNorm(
-      epsilon=1e-6, dtype=jnp.float32, name='output_normalization')
-
-
 class EncoderStack(nn.Module):
-  """N x (self-attention + FFN) of the configuration's block kind
-  (config.BLOCK_KINDS), then the kind's final normalization
-  (reference encoder_stack.py:96-198 for the published block).
+  """N x (attention + feed-forward), layer n built from its letters in
+  config.layer_pattern and config.ffn_pattern, then the kind's final
+  normalization (config.BLOCKS; reference encoder_stack.py:96-198 for the
+  published block).
 
   [B, L, H] in; [B, L, H] out, or the same rows flat, [B*L, H], where
-  the stack took the attention sublayer kernel (`attention_path`), the
-  latent attention's (`latent_attention_path`) or the grouped-head
-  attention's (`grouped_attention_path`)."""
+  the stack took the attention sublayer kernel, the latent attention's or
+  the grouped-head attention's (`kernel_paths`' `attention_path`,
+  `latent_attention_path`, `grouped_attention_path`)."""
 
   params: ml_collections.FrozenConfigDict
   dtype: Any = jnp.float32
@@ -1327,13 +1349,15 @@ class EncoderStack(nn.Module):
                ragged_widths: Optional[jnp.ndarray] = None,
                ragged_buckets: Optional[tuple] = None) -> jnp.ndarray:
     p = self.params
+    output_norm = lambda: _norm(p, config_lib.block(p).norm,
+                                'output_normalization')
 
     if skip_blocks:
       # The fused hot path (ops/fused_encoder_block.py) already ran
       # every attention/FFN block including the ReZero residuals; only
       # the final normalization remains. Init never takes this branch,
       # so the param tree is created identically.
-      return _output_norm(p)(x)
+      return output_norm()(x)
 
     # Optional rematerialization: drop each residual block's
     # activations and recompute them in the backward pass, trading
@@ -1353,7 +1377,8 @@ class EncoderStack(nn.Module):
       attn_kwargs = dict(ragged_widths=ragged_widths,
                          ragged_buckets=ragged_buckets)
 
-    fused = attention_path(
+    layers, ffns = config_lib.layer_pattern(p), config_lib.ffn_pattern(p)
+    fused = _attention_path(
         p, length=x.shape[1], deterministic=deterministic,
         initializing=self.is_initializing(),
         ragged=ragged_widths is not None,
@@ -1367,20 +1392,20 @@ class EncoderStack(nn.Module):
       # here (init runs the modules on [B, L, H], which declare the
       # leaves). The Gated DeltaNet mixer's convolution runs along the
       # window, so beside it a grouped-head layer flattens its own input.
-      if latent_attention_path(
-          p, length=length) == latent_attention.LATENT_WINDOW_TILE_KERNEL:
+      if config_lib.LAYER_LATENT in layers and _latent_attention_path(
+          p, length) == latent_attention.LATENT_WINDOW_TILE_KERNEL:
         x = x.reshape(batch * length, hidden)
         attn_kwargs = dict(window_length=length)
-      if grouped_attention_path(p, length=length) == (
+      if _grouped_attention_path(p, length) == (
           grouped_attention.GROUPED_WINDOW_TILE_KERNEL):
         grouped_kwargs = dict(window_length=length)
-        if block_kind_of(p) != config_lib.BLOCK_GATED_DELTA_MOE:
+        if config_lib.LAYER_GATED_DELTA not in layers:
           x = x.reshape(batch * length, hidden)
 
-    for n in range(p.num_hidden_layers):
-      attn, ffn, wrap, norm = _block_modules(p, n, self.dtype)
-      kwargs = (grouped_kwargs if isinstance(attn, GroupedSoftmaxAttention)
-                else attn_kwargs)
+    for n, (attention, feed_forward) in enumerate(zip(layers, ffns)):
+      attn, ffn, wrap, norm = _layer_modules(p, n, attention, feed_forward,
+                                             self.dtype)
+      kwargs = grouped_kwargs if attention in GROUPED_LETTERS else attn_kwargs
       if norm is not None:
         # The parallel form: one norm, both sublayers on it, one addition;
         # neither sublayer waits for the other.
@@ -1410,7 +1435,7 @@ class EncoderStack(nn.Module):
             x = run_block(wrap(attn, f'attention_wrapper_{n}'), x, **kwargs)
       with jax.named_scope('ffn'):
         x = run_block(wrap(ffn, f'ffn_wrapper_{n}'), x)
-    return _output_norm(p)(x)
+    return output_norm()(x)
 
   def _fused_attention_sublayer(self, n: int, x2: jnp.ndarray,
                                 length: int) -> jnp.ndarray:
@@ -1544,7 +1569,7 @@ class DeepConsensusModel(nn.Module):
     p = self.params
     return bool(
         p.get('use_fused_hotpath', False)
-        and block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
+        and _all_banded(p)
         and not train
         and not self.is_initializing()
         and self.learn_values
@@ -1637,7 +1662,7 @@ class DeepConsensusModel(nn.Module):
     p = self.params
     return bool(
         p.get('use_fused_hotpath', False)
-        and block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
+        and _all_banded(p)
         and not self.is_initializing()
         and self.learn_values
         and p.condense_transformer_input

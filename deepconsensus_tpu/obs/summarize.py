@@ -311,6 +311,29 @@ def startup(events: List[Dict[str, Any]], top: int = 5) -> Dict[str, Any]:
   }
 
 
+# What `dctpu trace` collects of the `forward_launch` spans: a field and
+# how each of its values is read. A stack's rotations are one string, 'W
+# default, F yarn×16'.
+_LAUNCH_FIELDS = (
+    ('block_kind', str),
+    ('attention_path', str),
+    ('delta_rule_path', str),
+    ('latent_attention_path', str),
+    ('grouped_attention_path', str),
+    ('grouped_product_path', str),
+    ('combine_path', str),
+    ('moe_turns', int),
+    ('block_form', str),
+    ('layer_pattern', str),
+    ('attention_window', int),
+    ('shared_experts', int),
+    ('rope', lambda rope: ', '.join(
+        f'{letter} {how}' for letter, how in dict(rope).items())),
+    ('ffn_pattern', str),
+    ('router_scoring', str),
+)
+
+
 def summarize(events: List[Dict[str, Any]],
               straggler_decile: float = 0.9) -> Dict[str, Any]:
   """Full trace summary (the ``dctpu trace`` payload)."""
@@ -367,52 +390,18 @@ def summarize(events: List[Dict[str, Any]],
 
   launches = [e.get('args') or {} for e in spans
               if e.get('name') == trace_lib.STAGE_LAUNCH]
-  forward = {
-      'n_launches': len(launches),
-      'block_kinds': sorted({str(a['block_kind']) for a in launches
-                             if a.get('block_kind')}),
-      'attention_paths': sorted({str(a['attention_path']) for a in launches
-                                 if a.get('attention_path')}),
-      'delta_rule_paths': sorted({str(a['delta_rule_path']) for a in launches
-                                  if a.get('delta_rule_path')}),
-      'latent_attention_paths': sorted(
-          {str(a['latent_attention_path']) for a in launches
-           if a.get('latent_attention_path')}),
-      'grouped_attention_paths': sorted(
-          {str(a['grouped_attention_path']) for a in launches
-           if a.get('grouped_attention_path')}),
-      'grouped_product_paths': sorted(
-          {str(a['grouped_product_path']) for a in launches
-           if a.get('grouped_product_path')}),
-      'combine_paths': sorted({str(a['combine_path']) for a in launches
-                               if a.get('combine_path')}),
-      'moe_turns': sorted({int(a['moe_turns']) for a in launches
-                           if a.get('moe_turns')}),
-      'block_forms': sorted({str(a['block_form']) for a in launches
-                             if a.get('block_form')}),
-      'layer_patterns': sorted({str(a['layer_pattern']) for a in launches
-                                if a.get('layer_pattern')}),
-      'attention_windows': sorted({int(a['attention_window'])
-                                   for a in launches
-                                   if a.get('attention_window')}),
-      'shared_experts': sorted({int(a['shared_experts']) for a in launches
-                                if a.get('shared_experts')}),
-      # Each layer type's rotation, one string a stack: 'W default, F
-      # yarn×16'.
-      'ropes': sorted({', '.join(f'{letter} {rope}' for letter, rope in
-                                 dict(a['rope']).items())
-                       for a in launches if a.get('rope')}),
-      'ffn_patterns': sorted({str(a['ffn_pattern']) for a in launches
-                              if a.get('ffn_pattern')}),
-      'router_scorings': sorted({str(a['router_scoring']) for a in launches
-                                 if a.get('router_scoring')}),
-      'experts_held': sorted(
+  forward = {'n_launches': len(launches)}
+  # Each field's values over the launches, under its name made plural.
+  for field, cast in _LAUNCH_FIELDS:
+    forward[field if field.endswith('s') else field + 's'] = sorted(
+        {cast(a[field]) for a in launches if a.get(field)})
+  forward.update(
+      experts_held=sorted(
           {(*a['experts_held'], a.get('experts_published'))
            for a in launches if a.get('experts_held')}),
-      'n_positions': sum(int(a.get('n_positions') or 0) for a in launches),
-      'weight_bytes': max(
-          (int(a.get('weight_bytes') or 0) for a in launches), default=0),
-  }
+      n_positions=sum(int(a.get('n_positions') or 0) for a in launches),
+      weight_bytes=max(
+          (int(a.get('weight_bytes') or 0) for a in launches), default=0))
 
   return {
       'n_events': len(events),

@@ -33,9 +33,9 @@ runs one layer's `x + alpha * attention(x)` on the flat [B*L, H] stream
 in the compute dtype (bfloat16 MXU operands, float32 accumulators and
 softmax), which is what the default XLA forward takes for its attention
 sublayers at L<=128 bfloat16 inference on one TPU
-(models/model.py::attention_path; no option asks for it). The block
-kernel hands the same `_attention` float32 activations, so its products
-stay float32.
+(`attention_path` of models/model.py::kernel_paths; no option asks for
+it). The block kernel hands the same `_attention` float32 activations, so
+its products stay float32.
 
 Semantics are defined by `reference_encoder_stack` (pure jnp, shares
 the math helpers below); the kernel is validated against it per block

@@ -1,6 +1,7 @@
 """The banded attention sublayer as one kernel
 (ops/fused_encoder_block.py::fused_attention_sublayer) and the rule by
-which a forward takes it (models/model.py::attention_path).
+which a forward takes it (`attention_path` of
+models/model.py::kernel_paths).
 
 The kernel runs in interpret mode here, against the modules it replaces,
 ResidualWrapper(BandedSelfAttention), on the same parameters: float32
@@ -253,7 +254,8 @@ def test_a_cpu_never_takes_the_kernel(sublayer_calls):
   _apply_inference(p, _init(p), _rows(p))
   assert not sublayer_calls
   with pallas_util.single_device_inference():
-    assert model_lib.attention_path(p, length=p.max_length) == 'xla'
+    assert model_lib.kernel_paths(p, batch=8, length=p.max_length)[
+        'attention_path'] == 'xla'
 
 
 def test_parameter_tree_is_the_modules_own_either_way(on_a_tpu):

@@ -515,7 +515,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   assert len(launches) == 3
   for e in launches:
     assert e['args']['block_kind'] == kind
-    # The CPU takes no kernel on its own (model_lib.attention_path).
+    # The CPU takes no kernel on its own (model_lib.kernel_paths).
     assert e['args']['attention_path'] == 'xla'
     # The compiled pack's rows x width, the tail pack's padding included.
     assert e['args']['n_positions'] == BATCH * p.max_length

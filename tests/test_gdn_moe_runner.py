@@ -143,13 +143,14 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
   p = tiny_params(100, dtype='bfloat16')
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
   with pallas_util.single_device_inference():
-    assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
+    assert model_lib.kernel_paths(p, batch=8, length=100)[
+        'attention_path'] == model_lib.ATTENTION_XLA
     banded = config_lib.get_config('transformer_learn_values+custom')
     with banded.unlocked():
       banded.dtype = 'bfloat16'
     config_lib.finalize_params(banded, is_training=False)
-    assert model_lib.attention_path(banded, length=100) == (
-        model_lib.ATTENTION_FUSED_SUBLAYER)
+    assert model_lib.kernel_paths(banded, batch=8, length=100)[
+        'attention_path'] == model_lib.ATTENTION_FUSED_SUBLAYER
 
 
 @pytest.mark.parametrize('where', ['cpu', 'tpu', 'tpu_mesh', 'tpu_heads_of_8',
@@ -172,7 +173,8 @@ def test_delta_rule_path_is_the_kernel_on_one_tpu_at_heads_of_128(
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: where != 'cpu')
   length = 600 if where == 'tpu_long_window' else 100
   with pallas_util.single_device_inference(where != 'tpu_mesh'):
-    got = model_lib.delta_rule_path(p, length=length)
+    got = model_lib.kernel_paths(p, batch=8, length=length).get(
+        'delta_rule_path')
   assert got == {'tpu': 'window_kernel', 'other_kind': None}.get(
       where, 'plain')
 
@@ -200,7 +202,8 @@ def test_grouped_product_path_is_the_kernel_on_one_tpu_at_lane_tile_widths(
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: where != 'cpu')
   batch = 5 if where == 'tpu_odd_pack' else 8  # 5 x 100 x 4 = 2,000 rows
   with pallas_util.single_device_inference(where != 'tpu_mesh'):
-    got = model_lib.grouped_product_path(p, batch=batch, length=100)
+    got = model_lib.kernel_paths(p, batch=batch, length=100).get(
+        'grouped_product_path')
   assert got == {'tpu': 'group_kernel', 'other_kind': None}.get(
       where, 'ragged_dot')
 
@@ -228,7 +231,8 @@ def test_combine_path_is_the_kernel_on_one_tpu_at_lane_tile_widths(
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: where != 'cpu')
   batch = 5 if where == 'tpu_odd_pack' else 32  # 5 x 100 = 500 tokens
   with pallas_util.single_device_inference(where != 'tpu_mesh'):
-    got = model_lib.combine_path(p, batch=batch, length=100)
+    got = model_lib.kernel_paths(p, batch=batch, length=100).get(
+        'combine_path')
   assert got == {'tpu': 'token_tile_kernel', 'other_kind': None}.get(
       where, 'gather')
 
@@ -254,7 +258,8 @@ def test_moe_turns_are_the_turns_held_experts_takes_at_each_cell(kind, want):
   with p.unlocked():
     p.dtype = 'bfloat16'
   config_lib.finalize_params(p, is_training=False)
-  assert model_lib.moe_turns(p, batch=batch, length=100) == want
+  assert model_lib.kernel_paths(p, batch=batch, length=100).get(
+      'moe_turns') == want
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

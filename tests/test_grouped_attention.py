@@ -207,15 +207,18 @@ def test_stacks_without_such_a_layer_say_nothing_and_qwen3next_says_plain(
                    'transformer_learn_values_retention+custom'):
       p = config_lib.get_config(preset)
       config_lib.finalize_params(p, is_training=False)
-      assert model_lib.grouped_attention_path(p, length=100) is None
+      assert 'grouped_attention_path' not in model_lib.kernel_paths(
+          p, batch=8, length=100)
     # The gated softmax layer of the third kind: heads of 256 with a
     # quarter rotated as published, heads of 16 at toy size.
     p = config_lib.get_config('transformer_learn_values_gdn_moe+custom')
     config_lib.finalize_params(p, is_training=False)
     assert (p.head_dim, p.partial_rotary_factor) == (256, 0.25)
-    assert model_lib.grouped_attention_path(p, length=100) == 'plain'
+    assert model_lib.kernel_paths(p, batch=8, length=100)[
+        'grouped_attention_path'] == 'plain'
     toy = gdn_params(12, dtype='bfloat16', inference_dtype='bfloat16')
-    assert model_lib.grouped_attention_path(toy, length=12) == 'plain'
+    assert model_lib.kernel_paths(toy, batch=8, length=12)[
+        'grouped_attention_path'] == 'plain'
 
 
 # ------------------------------------------------- whole stacks, flat stream
@@ -265,8 +268,8 @@ def test_stack_on_the_flat_stream_through_the_call_is_the_stack_of_modules(
                           *a, **k))
   init = lambda k: model.init(k, jnp.zeros((1, p.total_rows, 100, 1)))
   with as_on_one_tpu(monkeypatch):
-    assert model_lib.grouped_attention_path(p, length=100) == (
-        ga.GROUPED_WINDOW_TILE_KERNEL)
+    assert model_lib.kernel_paths(p, batch=6, length=100)[
+        'grouped_attention_path'] == ga.GROUPED_WINDOW_TILE_KERNEL
     # (A function of its own: jit's cache does not see the declaration.)
     got, _ = jax.jit(lambda v, r: forward(v, r))(variables, rows)
     # And init, even so declared, runs the modules: the tree is theirs.

@@ -462,30 +462,28 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
   p = tiny_params(100, dtype='bfloat16')
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
   with pallas_util.single_device_inference():
-    assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
-    assert model_lib.delta_rule_path(p, length=100) is None
+    paths = lambda p, batch=8, length=100: model_lib.kernel_paths(
+        p, batch=batch, length=length)
+    assert paths(p)['attention_path'] == model_lib.ATTENTION_XLA
+    assert 'delta_rule_path' not in paths(p)
     # Its own attention declines the toy heads (16 + 8 / 12 are no lane
     # tiles) and takes the kernel at the published ones.
-    assert model_lib.latent_attention_path(p, length=100) == 'plain'
+    assert paths(p)['latent_attention_path'] == 'plain'
     published = config_lib.get_config(PRESET)
     config_lib.finalize_params(published, is_training=False)
-    assert model_lib.latent_attention_path(published, length=100) == (
-        'window_tile_kernel')
-    assert model_lib.latent_attention_path(published, length=130) == 'plain'
+    assert paths(published)['latent_attention_path'] == 'window_tile_kernel'
+    assert paths(published, length=130)['latent_attention_path'] == 'plain'
     # The grouped products decline the toy widths, and take the kernel at
     # whole lane tiles (the published 2048 and 768 are).
-    assert model_lib.grouped_product_path(p, batch=8, length=100) == (
-        'ragged_dot')
+    assert paths(p)['grouped_product_path'] == 'ragged_dot'
     wide = tiny_params(100, dtype='bfloat16', transformer_input_size=128,
                        moe_intermediate_size=256)
-    assert model_lib.grouped_product_path(wide, batch=8, length=100) == (
-        'group_kernel')
+    assert paths(wide)['grouped_product_path'] == 'group_kernel'
     # The combine likewise: rows of whole lane tiles, and a turn's tokens
     # (32 x 100) whole tiles of 128.
-    assert model_lib.combine_path(p, batch=32, length=100) == 'gather'
-    assert model_lib.combine_path(wide, batch=32, length=100) == (
-        'token_tile_kernel')
-    assert model_lib.combine_path(wide, batch=5, length=100) == 'gather'
+    assert paths(p, batch=32)['combine_path'] == 'gather'
+    assert paths(wide, batch=32)['combine_path'] == 'token_tile_kernel'
+    assert paths(wide, batch=5)['combine_path'] == 'gather'
 
 
 @pytest.mark.parametrize('flag', ['fused', 'ragged'])

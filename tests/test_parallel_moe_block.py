@@ -465,22 +465,23 @@ def test_attention_path_declines_the_kind_even_on_a_tpu(monkeypatch):
   p = tiny_params(100, dtype='bfloat16')
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
   with pallas_util.single_device_inference():
-    assert model_lib.attention_path(p, length=100) == model_lib.ATTENTION_XLA
-    assert model_lib.delta_rule_path(p, length=100) is None
-    assert model_lib.latent_attention_path(p, length=100) is None
+    paths = lambda p, batch=8: model_lib.kernel_paths(
+        p, batch=batch, length=100)
+    assert paths(p)['attention_path'] == model_lib.ATTENTION_XLA
+    assert 'delta_rule_path' not in paths(p)
+    assert 'latent_attention_path' not in paths(p)
     # The grouped products and the combine decline the toy widths, and take
     # their kernels at the published ones: a pack of 256 is two turns of
     # 12,800 tokens, a [4096, 4096] matrix passes in column blocks.
-    assert model_lib.grouped_product_path(p, batch=8, length=100) == (
-        'ragged_dot')
-    assert model_lib.combine_path(p, batch=32, length=100) == 'gather'
+    assert paths(p)['grouped_product_path'] == 'ragged_dot'
+    assert paths(p, batch=32)['combine_path'] == 'gather'
     published = config_lib.get_config(PRESET)
     with published.unlocked():
       published.num_hidden_layers, published.experts_held_count = 4, 16
     config_lib.finalize_params(published, is_training=False)
-    assert model_lib.grouped_product_path(
-        published, batch=256, length=100) == 'group_kernel'
-    assert model_lib.combine_path(published, batch=256, length=100) == (
+    assert paths(published, batch=256)['grouped_product_path'] == (
+        'group_kernel')
+    assert paths(published, batch=256)['combine_path'] == (
         'token_tile_kernel')
 
 

@@ -298,7 +298,7 @@ def test_a_params_json_from_before_the_key_means_the_old_block():
   p = config_lib.get_config('transformer_learn_values+custom')
   with p.unlocked():
     del p['block_kind']
-  assert model_lib.block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
+  assert config_lib.block_kind_of(p) == config_lib.BLOCK_BANDED_SOFTMAX
 
 
 @pytest.mark.parametrize('key,value,match', [

@@ -665,7 +665,7 @@ def test_attention_sublayer_forward_b1024(one_chip, compiled_kernels,
                                           monkeypatch):
   """The teacher's bfloat16 forward as a ModelRunner without a mesh
   traces it on a TPU: every layer's attention sublayer is one Mosaic
-  call under scope `attention` (model_lib.attention_path; no option asks
+  call under scope `attention` (model_lib.kernel_paths; no option asks
   for it), and no score tensor is left in the program. Mosaic refusing
   the kernel at the served widths fails here, before any chip time."""
   monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
