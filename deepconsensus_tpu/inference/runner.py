@@ -360,17 +360,19 @@ def _check_exported_epilogue(meta, options: 'InferenceOptions',
     )
 
 
-def _check_sparse_experts_served(params, mesh) -> None:
-  """What a block kind with sparse experts cannot run yet, refused by
-  name before anything is placed (ROADMAP R-a)."""
+def _check_inference_only_kind_served(params, mesh) -> None:
+  """What a block kind with sparse experts or Mamba mixers cannot run yet,
+  refused by name before anything is placed (ROADMAP R-a)."""
   kind = config_lib.block_kind_of(params)
+  experts = config_lib.holds_experts(params)
   if params.get('quantize_matmuls', None) not in (None, 'none'):
     # dclint: allow=typed-faults (model-config validation at startup,
     # surfaced as operator error by the CLI, not a data-plane fault)
     raise ValueError(
         f'block kind {kind!r} is not served with '
         f'quantize_matmuls={params.quantize_matmuls!r}: models/quantize.py '
-        'has no per-expert scales')
+        + ('has no per-expert scales' if experts else
+           "has no scales for the Mamba mixer's leaves"))
   if mesh is not None:
     from deepconsensus_tpu.parallel import mesh as mesh_lib
 
@@ -379,7 +381,9 @@ def _check_sparse_experts_served(params, mesh) -> None:
       # operator asked for a mesh axis the kind cannot be split over)
       raise ValueError(
           f'block kind {kind!r} is not served with --tp: '
-          'parallel/partition_rules.py has no expert axis')
+          'parallel/partition_rules.py has no '
+          + ('expert axis' if experts else
+             "rule for the Mamba mixer's leaves"))
 
 
 def _check_dp_divisible(options: 'InferenceOptions', mesh) -> int:
@@ -527,8 +531,8 @@ class ModelRunner:
     # Whether the forward routes tokens to experts and returns their
     # per-pack counts beside the predictions.
     sparse_experts = config_lib.holds_experts(params)
-    if sparse_experts:
-      _check_sparse_experts_served(params, mesh)
+    if config_lib.inference_only(params):
+      _check_inference_only_kind_served(params, mesh)
     # Quantize/cast once on the host BEFORE any device placement, so
     # the weight transfer below ships the shrunken bf16/int8 bytes
     # (and degrade_mesh()'s re-placement keeps shipping them).

@@ -162,15 +162,30 @@ def bucket_for(width: int, buckets):
 #                           (`mlp_layer_types` all sparse): a softmax router
 #                           without a bias, the top-k renormalised, no
 #                           shared expert; final RMSNorm.
+#   mamba_hybrid            a sequential pre-RMSNorm stack (plain weights)
+#                           whose layers are not alike: layer n is softmax
+#                           attention over grouped heads without positions,
+#                           q/k norm or gate where n % attn_layer_period ==
+#                           attn_layer_offset, and a Mamba-1 mixer
+#                           otherwise (a two-direction causal depthwise
+#                           convolution with a bias, then a selective scan
+#                           over a [d_inner, d_state] state with its own
+#                           RMSNorms on dt, B and C, two directions:
+#                           ops/selective_scan.py). Every layer's
+#                           feed-forward is SwiGLU (`num_experts` 1: the
+#                           published expert period chooses nothing); final
+#                           RMSNorm.
 BLOCK_BANDED_SOFTMAX = 'banded_softmax_relu'
 BLOCK_POWER_RETENTION = 'power_retention_swiglu'
 BLOCK_GATED_DELTA_MOE = 'gated_delta_hybrid_moe'
 BLOCK_LATENT_MOE = 'latent_attention_moe'
 BLOCK_PARALLEL_WINDOW_MOE = 'parallel_window_moe'
 BLOCK_WINDOW_MOE = 'window_moe'
+BLOCK_MAMBA_HYBRID = 'mamba_hybrid'
 BLOCK_KINDS = (BLOCK_BANDED_SOFTMAX, BLOCK_POWER_RETENTION,
                BLOCK_GATED_DELTA_MOE, BLOCK_LATENT_MOE,
-               BLOCK_PARALLEL_WINDOW_MOE, BLOCK_WINDOW_MOE)
+               BLOCK_PARALLEL_WINDOW_MOE, BLOCK_WINDOW_MOE,
+               BLOCK_MAMBA_HYBRID)
 
 # How a layer composes its two sublayers (`forward_launch`'s `block_form`):
 # one after the other, each behind a norm of its own (x + f(norm_1(x)), then
@@ -208,6 +223,7 @@ LAYER_GATED_SOFTMAX = 'S'
 LAYER_LATENT = 'L'
 LAYER_WINDOW_SOFTMAX = 'W'
 LAYER_FULL_SOFTMAX = 'F'
+LAYER_MAMBA = 'M'
 
 # A layer's feed-forward, one letter a layer in `ffn_pattern`: one dense
 # feed-forward of the kind's form, or sparse experts.
@@ -232,6 +248,35 @@ def _every(key: str, letter: str, others: str) -> Callable[[Any], str]:
     every = params[key]
     return ''.join(letter if (n + 1) % every == 0 else others
                    for n in range(params.num_hidden_layers))
+  return pattern
+
+
+def _offset_every(period_key: str, offset_key: str, letter: str,
+                  others: str) -> Callable[[Any], str]:
+  """`letter` where n % params[period_key] == params[offset_key], `others`
+  elsewhere; a period below 1 or an offset outside [0, period) is refused
+  by name."""
+  def pattern(params) -> str:
+    period, offset = params[period_key], params[offset_key]
+    if period < 1 or not 0 <= offset < period:
+      raise ValueError(
+          f'{offset_key} {offset} of {period_key} {period} places no layer: '
+          f'the offset lies in [0, {period_key})')
+    return ''.join(letter if n % period == offset else others
+                   for n in range(params.num_hidden_layers))
+  return pattern
+
+
+def _dense_unless_experts(key: str) -> Callable[[Any], str]:
+  """A dense feed-forward in every layer where params[key] is 1: a single
+  expert is a dense layer, and the expert period chooses nothing. More
+  experts are refused by name."""
+  def pattern(params) -> str:
+    if params[key] != 1:
+      raise ValueError(
+          f'{key} {params[key]} is not served: this kind runs a dense '
+          'feed-forward in every layer and has no expert layers')
+    return FFN_DENSE * params.num_hidden_layers
   return pattern
 
 
@@ -278,7 +323,7 @@ class Block:
   rotation: Mapping[str, str]
 
 
-# The six kinds, value for value.
+# The seven kinds, value for value.
 BLOCKS = {
     BLOCK_BANDED_SOFTMAX: Block(
         FORM_SEQUENTIAL, NORM_REZERO, DENSE_RELU,
@@ -305,6 +350,11 @@ BLOCKS = {
         _listed('layer_types', LAYER_TYPES),
         _listed('mlp_layer_types', MLP_LAYER_TYPES),
         {LAYER_WINDOW_SOFTMAX: ROPE_LISTED, LAYER_FULL_SOFTMAX: ROPE_LISTED}),
+    BLOCK_MAMBA_HYBRID: Block(
+        FORM_SEQUENTIAL, NORM_RMS, DENSE_SWIGLU,
+        _offset_every('attn_layer_period', 'attn_layer_offset',
+                      LAYER_FULL_SOFTMAX, LAYER_MAMBA),
+        _dense_unless_experts('num_experts'), {}),
 }
 
 
@@ -345,6 +395,15 @@ def holds_experts(params) -> bool:
   from it."""
   return ('transformer' in str(params.model_name)
           and FFN_EXPERTS in ffn_pattern(params))
+
+
+def inference_only(params) -> bool:
+  """Whether the model's encoder stack has a layer with no training step,
+  no exported form and no sharded or int8 form here: sparse experts, or a
+  Mamba mixer (the selective scan has no backward pass in this program)."""
+  return holds_experts(params) or (
+      'transformer' in str(params.model_name)
+      and LAYER_MAMBA in layer_pattern(params))
 
 
 def rope_parameters(params, letter: str) -> dict:
@@ -696,6 +755,47 @@ def _set_transformer_learned_embeddings_window_moe_hparams(params):
   _set_published_block_hparams(params)
 
 
+def _set_transformer_learned_embeddings_mamba_hybrid_hparams(params):
+  """A seventh encoder block kind at the widths of a public 3B dense hybrid
+  model: hidden 2560, 28 layers, each a sequential pre-RMSNorm block (eps
+  1e-6, plain weights). Layer n is attention where n % 14 == 7 (20 query
+  heads over one key-value head of 128, no positions, no q/k norm, gate or
+  bias) and a Mamba-1 mixer otherwise (inner width 2 x 2560, a causal
+  convolution of 4 taps with a bias, dt of rank 160, a state of 16, RMSNorms
+  on dt, B and C, no projection biases). Every feed-forward is SwiGLU 8192
+  (one expert: a dense layer). Behind this system's pile-up embedding and
+  5-way head, served in bfloat16.
+
+  One v5e chip holds the whole stack (5.3 GiB of weights) and a pack of 256
+  windows (docs/inference.md)."""
+  _set_transformer_learned_embeddings_hparams(params)
+  params.model_name = 'transformer_learn_values_mamba_hybrid'
+  params.block_kind = BLOCK_MAMBA_HYBRID
+  params.transformer_input_size = 2560
+  params.num_hidden_layers = 28
+  params.rms_norm_eps = 1.0e-6
+  # Attention where n % attn_layer_period == attn_layer_offset.
+  params.attn_layer_period = 14
+  params.attn_layer_offset = 7
+  params.num_heads = 20
+  params.num_kv_heads = 1
+  params.head_dim = 128
+  # The Mamba mixer.
+  params.mamba_d_state = 16
+  params.mamba_d_conv = 4
+  params.mamba_expand = 2
+  params.mamba_dt_rank = 160
+  params.mamba_conv_bias = True
+  params.mamba_proj_bias = False
+  # One expert: every feed-forward a SwiGLU of filter_size.
+  params.num_experts = 1
+  params.num_experts_per_tok = 1
+  params.expert_layer_period = 2
+  params.expert_layer_offset = 1
+  params.filter_size = 8192
+  _set_published_block_hparams(params)
+
+
 def _set_base_fc_hparams(params):
   params.model_name = 'fc'
   params.fc_size = [256, 512, 256, 128]
@@ -949,6 +1049,8 @@ def get_config(config_name: Optional[str] = None) -> ml_collections.ConfigDict:
     _set_transformer_learned_embeddings_parallel_moe_hparams(params)
   elif model_config_name == 'transformer_learn_values_window_moe':
     _set_transformer_learned_embeddings_window_moe_hparams(params)
+  elif model_config_name == 'transformer_learn_values_mamba_hybrid':
+    _set_transformer_learned_embeddings_mamba_hybrid_hparams(params)
   else:
     raise ValueError(f'Unknown model_config_name: {model_config_name}')
 

@@ -34,6 +34,7 @@ from deepconsensus_tpu.ops import latent_attention
 from deepconsensus_tpu.ops import moe
 from deepconsensus_tpu.ops import pallas_util
 from deepconsensus_tpu.ops import power_retention
+from deepconsensus_tpu.ops import selective_scan
 from deepconsensus_tpu.parallel import ring_attention as ring_lib
 from deepconsensus_tpu.preprocess.pileup import row_indices
 
@@ -516,6 +517,50 @@ class PowerRetentionAttention(nn.Module):
         name='output_transform')(out)
 
 
+def two_direction_conv(mixed: jnp.ndarray, kernel: jnp.ndarray,
+                       bias: Optional[jnp.ndarray] = None,
+                       dtype: Any = jnp.float32,
+                       length: Optional[int] = None):
+  """A causal depthwise convolution of kernel.shape[0] taps and silu over
+  each window of `mixed`, run from the window's start (position t reads
+  t - (taps - 1) ... t, tap i on t - (taps - 1) + i, as the published
+  padded convolution cut to the window) and from its end (t reads
+  t + (taps - 1) ... t with the same weights); `bias` [C], where given, is
+  added before the silu. `mixed` is [B, L, C], or the flat stream
+  [B*L, C] with windows of `length` rows one after another (a tap that
+  would reach into the next window reads zero). One pass over `mixed`,
+  float32 inside, (ahead, back) in `dtype` at the window's own
+  positions."""
+  taps = kernel.shape[0]
+  reach = taps - 1
+  if mixed.ndim == 3:
+    length = mixed.shape[1]
+    padded = jnp.pad(mixed, ((0, 0), (reach, reach), (0, 0)))
+    shifted = lambda start: padded[:, start:start + length]
+  else:
+    rows = mixed.shape[0]
+    padded = jnp.pad(mixed, ((reach, reach), (0, 0)))
+    position = (jnp.arange(rows) % length)[:, None] + np.arange(
+        -reach, reach + 1)[None, :]
+    # Row r + start - reach where it lies in r's window, zero elsewhere.
+    shifted = lambda start: jnp.where(
+        (position[:, start:start + 1] >= 0)
+        & (position[:, start:start + 1] < length),
+        padded[start:start + rows], 0)
+
+  def run(starts):
+    out = sum(shifted(start).astype(jnp.float32)
+              * kernel[i].astype(jnp.float32)
+              for i, start in enumerate(starts))
+    if bias is not None:
+      out = out + bias.astype(jnp.float32)
+    # dclint: allow=dtype-downcast (the convolution's output is the
+    # compute dtype, as a bfloat16 model's is)
+    return jax.nn.silu(out).astype(dtype)
+
+  return run(range(taps)), run(range(2 * reach, reach - 1, -1))
+
+
 class GatedDeltaNetMixer(nn.Module):
   """Gated DeltaNet mixer, two directions (ops/gated_delta.py): one
   bias-free projection to [q | k | v | z] and one, float32, to [b | a];
@@ -540,7 +585,6 @@ class GatedDeltaNetMixer(nn.Module):
     del deterministic  # the published layer has no dropout
     hk, hv = self.num_key_heads, self.num_value_heads
     key_dim, value_dim = hk * self.key_head_dim, hv * self.value_head_dim
-    length = x.shape[1]
     dense = lambda width, name: nn.Dense(
         width, use_bias=False, dtype=self.dtype,
         kernel_init=nn.initializers.lecun_normal(), name=name)
@@ -559,20 +603,8 @@ class GatedDeltaNetMixer(nn.Module):
     log_decay = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
         a + dt_bias.astype(jnp.float32))
 
-    # The run from the window's start, whose convolution reads positions
-    # t - (kernel - 1) ... t, and the run from its end, which reads
-    # t + (kernel - 1) ... t with the same weights. One pass over `mixed`,
-    # float32 inside, the compute dtype out.
-    reach = self.conv_kernel - 1
-    padded = jnp.pad(mixed, ((0, 0), (reach, reach), (0, 0)))
-    # dclint: allow=dtype-downcast (the convolution's output is the
-    # compute dtype, as a bfloat16 model's is)
-    taps = lambda starts: jax.nn.silu(sum(
-        padded[:, start:start + length].astype(jnp.float32)
-        * conv[i].astype(jnp.float32)
-        for i, start in enumerate(starts))).astype(self.dtype)
-    streams = (taps(range(self.conv_kernel)),
-               taps(range(2 * reach, reach - 1, -1)))
+    # The run from the window's start and the run from its end.
+    streams = two_direction_conv(mixed, conv, dtype=self.dtype)
     # Between the convolution and the output projection the stream stays
     # flat, heads along the lanes: the L2 norm of q and k, the rule in
     # two directions and the gated norm are one operator
@@ -583,6 +615,88 @@ class GatedDeltaNetMixer(nn.Module):
           streams, z, log_decay, beta, norm_scale, num_key_heads=hk,
           num_value_heads=hv, epsilon=self.rms_norm_eps)
     return dense(self.hidden_size, 'out_proj')(out)
+
+
+class _Leaves(nn.Module):
+  """A kernel [*shape] and, where `use_bias`, a bias [shape[-1]] declared
+  under the module's own name (a published Linear's or Conv1d's leaves),
+  returned as they are for the caller to contract."""
+
+  shape: tuple
+  use_bias: bool = True
+
+  @nn.compact
+  def __call__(self):
+    kernel = self.param('kernel', nn.initializers.lecun_normal(), self.shape,
+                        jnp.float32)
+    bias = self.param('bias', nn.initializers.zeros, self.shape[-1:],
+                      jnp.float32) if self.use_bias else None
+    return kernel, bias
+
+
+class MambaMixer(nn.Module):
+  """A Mamba-1 mixer, two directions (ops/selective_scan.py): one bias-free
+  projection to [x | z] of twice the inner width; a causal depthwise
+  convolution of `conv_kernel` taps (with a bias where `conv_bias`) and
+  silu over x, run from the window's start and from its end with the same
+  weights; for each run, x_proj to [delta | B | C] (float32 out), each
+  RMSNorm'd with a weight of its own, dt = delta W_dt (the compute dtype,
+  dt_proj's bias left to the scan); the scan of both runs with their D
+  terms added and gated by silu(z), and a bias-free output projection.
+  Leaves as published: in_proj, conv1d, x_proj, dt_layernorm, b_layernorm,
+  c_layernorm, dt_proj, A_log, D, out_proj.
+
+  From in_proj to out_proj the mixer runs on the flat stream [B*L, ...]
+  (windows one after another), which is what the scan's Pallas call reads
+  without a copy: x [B, L, H] is flattened here, or arrives flat with
+  `window_length`, and goes back as it came."""
+
+  hidden_size: int
+  inner: int
+  state_size: int
+  dt_rank: int
+  conv_kernel: int
+  conv_bias: bool
+  rms_norm_eps: float
+  dtype: Any = jnp.float32
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray, deterministic: bool,
+               window_length: Optional[int] = None) -> jnp.ndarray:
+    del deterministic  # the published layer has no dropout
+    inner, s, rank = self.inner, self.state_size, self.dt_rank
+    length = window_length or x.shape[1]
+    dense = lambda width, name: nn.Dense(
+        width, use_bias=False, dtype=self.dtype,
+        kernel_init=nn.initializers.lecun_normal(), name=name)
+    with jax.named_scope('mamba'):
+      xz = dense(2 * inner, 'in_proj')(x.reshape(-1, x.shape[-1]))
+      conv, conv_bias = _Leaves((self.conv_kernel, inner), self.conv_bias,
+                                name='conv1d')()
+      x_proj = _GateProjection(rank + 2 * s, use_bias=False, name='x_proj')
+      norm_dt, norm_b, norm_c = (
+          RMSNorm(self.rms_norm_eps, name=f'{part}_layernorm')
+          for part in ('dt', 'b', 'c'))
+      dt_kernel, dt_bias = _Leaves((rank, inner), name='dt_proj')()
+      a_log = self.param('A_log', nn.initializers.zeros, (inner, s),
+                         jnp.float32)
+      d = self.param('D', nn.initializers.ones, (inner,), jnp.float32)
+      with jax.named_scope('conv'):
+        streams = two_direction_conv(xz[:, :inner], conv, conv_bias,
+                                     self.dtype, length)
+      dts, bcs = [], []
+      for stream in streams:
+        parts = x_proj(stream)
+        # dclint: allow=dtype-downcast (dt is a product's operand: the
+        # compute dtype, normed in float32)
+        delta = norm_dt(parts[:, :rank]).astype(self.dtype)
+        dts.append(jnp.dot(delta, dt_kernel.astype(self.dtype)))
+        bcs.append(jnp.concatenate([norm_b(parts[:, rank:rank + s]),
+                                    norm_c(parts[:, rank + s:])], axis=-1))
+      with jax.named_scope('ssm_scan'):
+        out = selective_scan.selective_scan_two_directions(
+            streams, dts, bcs, xz, a_log, d, dt_bias, length=length)
+      return dense(self.hidden_size, 'out_proj')(out).reshape(x.shape)
 
 
 class GroupedSoftmaxAttention(nn.Module):
@@ -947,14 +1061,20 @@ class ResidualWrapper(nn.Module):
 
 def refuse_inference_only_kind(p, command: str) -> None:
   """`dctpu train`, `distill` and `export` of a stack that holds sparse
-  experts, refused by name before anything is built."""
+  experts or Mamba mixers (config.inference_only), refused by name before
+  anything is built."""
+  if not config_lib.inference_only(p):
+    return
   if config_lib.holds_experts(p):
-    raise ValueError(
-        f'block kind {config_lib.block_kind_of(p)!r} is not served by '
-        f'`dctpu {command}`: routed '
-        'experts have no training step here (no balancing loss, no '
-        'gradient through the grouped products) and no exported form; '
-        'the kind runs through `dctpu run` and `dctpu serve`')
+    why = ('routed experts have no training step here (no balancing loss, '
+           'no gradient through the grouped products)')
+  else:
+    why = ('the selective scan has no backward pass here '
+           '(ops/selective_scan.py)')
+  raise ValueError(
+      f'block kind {config_lib.block_kind_of(p)!r} is not served by '
+      f'`dctpu {command}`: {why} and no exported form; the kind runs '
+      'through `dctpu run` and `dctpu serve`')
 
 
 # How a layer's attention sublayer runs (`forward_launch`'s
@@ -1043,7 +1163,8 @@ def kernel_paths(p, *, batch: int, length: int,
   """What the compiled forward of a pack of `batch` windows of `length`
   takes of the kernels the model chooses by itself (`forward_launch`'s
   fields, docs/observability.md): `attention_path` always; where the stack
-  has Gated DeltaNet mixers (`G`) `delta_rule_path`, where it has latent
+  has Gated DeltaNet mixers (`G`) `delta_rule_path`, where it has Mamba
+  mixers (`M`) `ssm_scan_path`, where it has latent
   attention (`L`) `latent_attention_path`, where it has grouped-head
   softmax layers (`S`, `W`, `F`) `grouped_attention_path`; where some
   layer's feed-forward is sparse experts (`E`) `grouped_product_path`,
@@ -1062,6 +1183,10 @@ def kernel_paths(p, *, batch: int, length: int,
         value_head_dim=p.linear_value_head_dim,
         num_key_heads=p.linear_num_key_heads,
         num_value_heads=p.linear_num_value_heads, length=length)
+  if config_lib.LAYER_MAMBA in layers:
+    paths['ssm_scan_path'] = selective_scan.ssm_scan_path(
+        inner=_mamba_inner(p), state=p.mamba_d_state, batch=batch,
+        length=length)
   if config_lib.LAYER_LATENT in layers:
     paths['latent_attention_path'] = _latent_attention_path(p, length)
   grouped = _grouped_attention_path(p, length)
@@ -1094,7 +1219,8 @@ def describe_stack(p) -> Dict[str, Any]:
   """What `forward_launch` says of the stack, the same for every pack: how
   a layer composes its sublayers (`block_form`), one letter a layer for its
   attention and one for its feed-forward (`layer_pattern`, `ffn_pattern`),
-  the window of the layers that attend within one, each layer type's
+  the window of the layers that attend within one, the state of a Mamba
+  mixer (`ssm_state`, [inner width, states a channel]), each layer type's
   rotation where the configuration gives one a type (`rope`,
   {letter: 'default' | 'yarn×<factor>'}) and, for sparse experts, the share
   held, how the router scores and how many shared experts are averaged
@@ -1107,6 +1233,8 @@ def describe_stack(p) -> Dict[str, Any]:
                 ffn_pattern=config_lib.ffn_pattern(p))
   if config_lib.LAYER_WINDOW_SOFTMAX in layers:
     fields.update(attention_window=int(p.sliding_window))
+  if config_lib.LAYER_MAMBA in layers:
+    fields.update(ssm_state=[_mamba_inner(p), int(p.mamba_d_state)])
   ropes = {letter: rope.describe() for letter, rope in (
       (letter, _rotation(p, letter)) for letter in dict.fromkeys(layers))
            if isinstance(rope, Rope)}
@@ -1234,6 +1362,29 @@ def _delta_mixer(p, n: int, letter: str, dtype):
   )
 
 
+def _mamba_inner(p) -> int:
+  return int(p.mamba_expand * p.hidden_size)
+
+
+def _mamba_mixer(p, n: int, letter: str, dtype):
+  del letter
+  if p.mamba_proj_bias:
+    raise ValueError(
+        'mamba_proj_bias true is not served: MambaMixer\'s in_proj and '
+        'out_proj have no bias')
+  return MambaMixer(
+      hidden_size=p.hidden_size,
+      inner=_mamba_inner(p),
+      state_size=p.mamba_d_state,
+      dt_rank=p.mamba_dt_rank,
+      conv_kernel=p.mamba_d_conv,
+      conv_bias=bool(p.mamba_conv_bias),
+      rms_norm_eps=p.rms_norm_eps,
+      dtype=dtype,
+      name=f'mamba_{n}',
+  )
+
+
 def _latent_attention(p, n: int, letter: str, dtype):
   if p.q_lora_rank is not None or (p.n_group, p.topk_group) != (1, 1):
     raise ValueError(
@@ -1266,6 +1417,7 @@ _ATTENTION = {
     config_lib.LAYER_BANDED_SOFTMAX: _banded_attention,
     config_lib.LAYER_POWER_RETENTION: _retention_attention,
     config_lib.LAYER_GATED_DELTA: _delta_mixer,
+    config_lib.LAYER_MAMBA: _mamba_mixer,
     config_lib.LAYER_LATENT: _latent_attention,
     config_lib.LAYER_GATED_SOFTMAX: _grouped_attention,
     config_lib.LAYER_WINDOW_SOFTMAX: _grouped_attention,
@@ -1391,7 +1543,8 @@ class EncoderStack(nn.Module):
       # norms, experts and feed-forwards are position-wise: flattened once,
       # here (init runs the modules on [B, L, H], which declare the
       # leaves). The Gated DeltaNet mixer's convolution runs along the
-      # window, so beside it a grouped-head layer flattens its own input.
+      # window, so beside it a grouped-head layer flattens its own input;
+      # the Mamba mixer runs on the flat stream as it finds it.
       if config_lib.LAYER_LATENT in layers and _latent_attention_path(
           p, length) == latent_attention.LATENT_WINDOW_TILE_KERNEL:
         x = x.reshape(batch * length, hidden)
@@ -1401,11 +1554,15 @@ class EncoderStack(nn.Module):
         grouped_kwargs = dict(window_length=length)
         if config_lib.LAYER_GATED_DELTA not in layers:
           x = x.reshape(batch * length, hidden)
+    # A Mamba mixer on the flat stream is told where its windows lie.
+    mamba_kwargs = dict(window_length=length) if x.ndim == 2 else {}
 
     for n, (attention, feed_forward) in enumerate(zip(layers, ffns)):
       attn, ffn, wrap, norm = _layer_modules(p, n, attention, feed_forward,
                                              self.dtype)
-      kwargs = grouped_kwargs if attention in GROUPED_LETTERS else attn_kwargs
+      kwargs = (grouped_kwargs if attention in GROUPED_LETTERS
+                else mamba_kwargs if attention == config_lib.LAYER_MAMBA
+                else attn_kwargs)
       if norm is not None:
         # The parallel form: one norm, both sublayers on it, one addition;
         # neither sublayer waits for the other.

@@ -318,6 +318,7 @@ _LAUNCH_FIELDS = (
     ('block_kind', str),
     ('attention_path', str),
     ('delta_rule_path', str),
+    ('ssm_scan_path', str),
     ('latent_attention_path', str),
     ('grouped_attention_path', str),
     ('grouped_product_path', str),
@@ -326,6 +327,7 @@ _LAUNCH_FIELDS = (
     ('block_form', str),
     ('layer_pattern', str),
     ('attention_window', int),
+    ('ssm_state', lambda state: 'x'.join(str(n) for n in state)),
     ('shared_experts', int),
     ('rope', lambda rope: ', '.join(
         f'{letter} {how}' for letter, how in dict(rope).items())),
@@ -494,6 +496,8 @@ def format_summary(summary: Dict[str, Any]) -> str:
         f'{forward["weight_bytes"] / 2**30:.3f} GiB of weights resident')
     if forward.get('layer_patterns'):
       delta_rule = ', '.join(forward.get('delta_rule_paths', ()))
+      scan = ', '.join(forward.get('ssm_scan_paths', ()))
+      states = ', '.join(forward.get('ssm_states', ()))
       latent = ', '.join(forward.get('latent_attention_paths', ()))
       grouped_heads = ', '.join(forward.get('grouped_attention_paths', ()))
       ffn =', '.join(forward.get('ffn_patterns', ()))
@@ -521,6 +525,7 @@ def format_summary(summary: Dict[str, Any]) -> str:
           + (f' (window: {windows})' if windows else '')
           + (f' (rope: {ropes})' if ropes else '')
           + (f' (delta rule: {delta_rule})' if delta_rule else '')
+          + (f' (selective scan: {scan}; state {states})' if scan else '')
           + (f' (latent attention: {latent})' if latent else '')
           + (f' (grouped-head attention: {grouped_heads})'
              if grouped_heads else '') + ''.join(

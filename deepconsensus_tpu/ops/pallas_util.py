@@ -110,6 +110,12 @@ LATENT_ATTENTION_VMEM_LIMIT_BYTES = BATCH_TILE_VMEM_LIMIT_BYTES
 # leave it; the limit is the batch-tiled kernels'.
 GROUPED_ATTENTION_VMEM_LIMIT_BYTES = BATCH_TILE_VMEM_LIMIT_BYTES
 
+# Scoped VMEM for the selective scan's kernel (ops/selective_scan.py): at
+# four windows of L=100 and 1,024 channels a step, Delta, Delta x and y of
+# both directions in float32 are 9.4 MiB, the five streams in and y out
+# twice each for the pipeline 9.4 MiB; beyond the default 16.
+SSM_SCAN_VMEM_LIMIT_BYTES = BATCH_TILE_VMEM_LIMIT_BYTES
+
 
 def batch_tile_compiler_params(
     vmem_limit_bytes: int = BATCH_TILE_VMEM_LIMIT_BYTES):

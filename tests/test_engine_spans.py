@@ -554,6 +554,7 @@ def test_forward_launch_says_what_the_forward_holds_and_computes(
   forward = json.loads(capsys.readouterr().out)['forward']
   assert forward == {'n_launches': 3, 'block_kinds': [kind],
                      'attention_paths': ['xla'], 'delta_rule_paths': [],
+                     'ssm_scan_paths': [], 'ssm_states': [],
                      'latent_attention_paths': [],
                      'grouped_attention_paths': [],
                      'grouped_product_paths': [],

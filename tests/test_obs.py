@@ -640,6 +640,7 @@ class TestSummarize:
     # text leaves its line out.
     assert s['forward'] == {'n_launches': 0, 'block_kinds': [],
                             'attention_paths': [], 'delta_rule_paths': [],
+                            'ssm_scan_paths': [], 'ssm_states': [],
                             'latent_attention_paths': [],
                             'grouped_attention_paths': [],
                             'grouped_product_paths': [],

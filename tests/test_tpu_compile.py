@@ -12,7 +12,8 @@ dense and one expert layer of the latent-attention kind at hidden 2048 with
 128 experts, batch 512, and its attention's kernel alone at that pack; one period of the parallel window-and-full kind at
 hidden 4096 with 16 of 128 experts, batch 256, and of the window-and-full
 kind at hidden 2304, batch 512, with the grouped-head attention's kernel
-alone at both packs; the grouped-product kernel
+alone at both packs and at the Mamba hybrid's group of 20; one Mamba
+mixer at hidden 2560, batch 256, its selective scan's kernel inside; the grouped-product kernel
 and the combine's kernel the three take, each alone at one turn of each). A compile that passes
 here is not a
 chip run — chip_smoke.py is — but a kernel Mosaic refuses fails here
@@ -485,6 +486,50 @@ def test_window_moe_forward_b512_at_published_widths(
   assert memory.temp_size_in_bytes < 1.59 * 2**30
 
 
+def test_mamba_mixer_b256_at_published_widths(one_chip, compiled_kernels,
+                                              monkeypatch):
+  """The seventh block kind's mixer as a ModelRunner without a mesh traces
+  it, by shape alone: one MambaMixer at the published widths (hidden 2560,
+  inner 5120, state 16, dt rank 160, 4 taps) on the flat stream of a pack of
+  256 windows of 100, bfloat16 leaves. The scan is one call of its kernel
+  that reads its operands where in_proj, the convolution and dt_proj left
+  them: no [25600, 5120] copy into another layout, no [L, I, S] array. (The
+  whole 28 layers compile to 5.34 GiB of arguments and 1.87 of
+  temporaries; the attention layers' grouped-head call at a group of 20 is
+  `test_grouped_window_tile_kernel_at_a_pack_of_each_cell[jamba_polish_F]`.)"""
+  mixer = model_lib.MambaMixer(
+      hidden_size=2560, inner=5120, state_size=16, dt_rank=160,
+      conv_kernel=4, conv_bias=True, rms_norm_eps=1e-6, dtype=jnp.bfloat16)
+  x = jax.ShapeDtypeStruct((25600, 2560), jnp.bfloat16, sharding=one_chip)
+  tree = jax.eval_shape(
+      lambda key: mixer.init(key, jnp.zeros((4, 100, 2560), jnp.bfloat16),
+                             True), jax.random.PRNGKey(0))
+  variables = jax.tree.map(
+      lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                     sharding=one_chip), tree)
+  monkeypatch.setattr(pallas_util, 'on_tpu', lambda: True)
+
+  def forward(variables, x):
+    with pallas_util.single_device_inference():
+      return mixer.apply(variables, x, True, window_length=100)
+
+  compiled = jax.jit(forward).lower(variables, x).compile()
+  text = compiled.as_text()
+  calls = re.findall(
+      r'%ssm_scan_window\S* = (\S+) custom-call\(([^)]*)\)', text)
+  assert [out.split('{')[0] for out, _ in calls] == ['bf16[25600,5120]']
+  assert [len(args.split(', ')) for _, args in calls] == [9]
+  entry = _entry_computation(text)
+  assert not [line for line in entry if ' copy(' in line
+              and re.search(r'\[(256,100|25600),(5120|10240)\]', line)]
+  assert not re.search(r'\[\S*100,5120,16\]|\[\S*5120,16,100\]', text)
+  memory = compiled.memory_analysis()
+  # in_proj's output (0.49 GiB), both directions' convolution and dt
+  # (0.98), the scan's y and what lies round them: 1.71 GiB as compiled; a
+  # twentieth over.
+  assert memory.temp_size_in_bytes < 1.80 * 2**30
+
+
 @pytest.mark.parametrize('rows,groups,hidden,width,columns,held_mib', [
     (153_600, 128, 2048, 768, (768, 2048), 2),
     (256_000, 256, 2048, 512, (512, 2048), 2),
@@ -551,14 +596,15 @@ def test_latent_window_tile_kernel_at_a_pack_of_the_cell(one_chip,
 
 
 @pytest.mark.parametrize('batch,heads,kv_heads,rotated', [
-    (512, 32, 4, True), (256, 128, 8, True), (256, 128, 8, False)],
+    (512, 32, 4, True), (256, 128, 8, True), (256, 128, 8, False),
+    (256, 20, 1, False)],
                          ids=['mellum_polish', 'commanda_polish_W',
-                              'commanda_polish_F'])
+                              'commanda_polish_F', 'jamba_polish_F'])
 def test_grouped_window_tile_kernel_at_a_pack_of_each_cell(
     one_chip, compiled_kernels, batch, heads, kv_heads, rotated):
-  """The grouped-head attention's kernel alone at the packs of the two cells
-  that run it: windows of 100 positions, 32 / 4 and 128 / 8 heads of 128
-  (groups of 8 and 16), q, k and v flat as the products write them, the
+  """The grouped-head attention's kernel alone at the packs of the cells
+  that run it: windows of 100 positions, 32 / 4, 128 / 8 and 20 / 1 heads of
+  128 (groups of 8, 16 and 20), q, k and v flat as the products write them, the
   rotation in VMEM (`pltpu.roll` of a half lane tile) or none; the step's
   32 and 64 head-windows within the call's VMEM limit."""
   from deepconsensus_tpu.ops import grouped_attention
